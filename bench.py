@@ -44,12 +44,10 @@ chip-level numbers (`published: {}`), so that figure is the anchor. Because a
 **MFU** (model FLOPs utilization): compiled-step FLOPs (XLA cost analysis)
 divided by measured step time and the chip's peak bf16 FLOP/s.
 
-Robustness: backend init goes through
-``horovod_tpu.common.backend.acquire_devices`` (retry + client reset +
-diagnostics). If the TPU cannot be brought up inside the retry budget the
-benchmark falls back to CPU — loudly, and with ``"platform": "cpu"`` in the
-JSON — so the run always produces a measured number rather than a traceback
-(round-1 failure mode: BENCH_r01.json rc=1).
+``--platform auto`` (the default) means the chip or nothing: where JAX
+finds no accelerator the run exits non-zero and prints no number.
+``--platform cpu`` is the virtual CPU mesh for tests and smoke scripts; its
+JSON says ``"platform": "cpu"`` and carries no MFU.
 """
 
 import argparse
@@ -239,15 +237,17 @@ _PEAK_BF16_TFLOPS = [
 
 
 def peak_flops_per_chip(device) -> float:
-    """Peak bf16 FLOP/s for this chip, or 0.0 if unknown (MFU omitted)."""
-    env = os.environ.get("HOROVOD_CHIP_PEAK_TFLOPS")
-    if env:
-        return float(env) * 1e12
-    kind = getattr(device, "device_kind", "").lower()
+    """Peak bf16 FLOP/s for this chip; a chip that is not in the table
+    is an error, not a default (the CPU mesh reports no MFU at all)."""
+    if device.platform == "cpu":
+        return 0.0
+    kind = device.device_kind.lower()
     for marker, tflops in _PEAK_BF16_TFLOPS:
         if marker in kind:
             return tflops * 1e12
-    return 0.0
+    raise SystemExit(f"no peak FLOP/s on record for device kind "
+                     f"{device.device_kind!r}: add it to "
+                     f"_PEAK_BF16_TFLOPS with its source")
 
 
 def step_flops_per_chip(compiled, global_items, n_chips,
@@ -258,8 +258,6 @@ def step_flops_per_chip(compiled, global_items, n_chips,
     fwd+bwd FLOPs per image/token) is global and gets divided down."""
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-            ca = ca[0]
         flops = float(ca.get("flops", 0.0))
         if flops > 0:
             return flops
@@ -269,86 +267,27 @@ def step_flops_per_chip(compiled, global_items, n_chips,
 
 
 def init_backend():
-    """Bring the backend up robustly; CPU fallback as a last resort.
-
-    Strategy (round-1 postmortem: BENCH_r01.json died rc=1 inside
-    ``hvd.init()`` on a transient UNAVAILABLE, and PJRT init can also *hang*):
-    1. probe the backend from a subprocess with a hard timeout — a hang
-       becomes a timeout, and a good probe warms the runtime;
-    2. on a good probe, ``acquire_devices`` in-process (retry + reset);
-    3. if the probe never succeeds, run on CPU — loudly, with
-       ``"platform": "cpu"`` recorded in the JSON line.
-    """
-    from horovod_tpu.common.backend import (
-        BackendInitError, acquire_devices, clear_stale_tpu_locks,
-        probe_backend, _reset_backends)
-
-    # Pre-probe hygiene (round-4 postmortem: a process killed mid-run can
-    # leave a libtpu lockfile that wedges every later PJRT creation).
-    clear_stale_tpu_locks()
-    probes = int(os.environ.get("HOROVOD_BENCH_PROBES", "3"))
-    probe_timeout = float(os.environ.get("HOROVOD_BENCH_PROBE_TIMEOUT", "150"))
-    ok = False
-    for i in range(probes):
-        if probe_backend(timeout=probe_timeout):
-            ok = True
-            break
-        if i + 1 < probes:
-            log(f"backend probe {i + 1}/{probes} failed; retrying in 10s")
-            time.sleep(10)
-
-    if ok:
-        try:
-            devices = acquire_devices(
-                retries=int(os.environ.get(
-                    "HOROVOD_BACKEND_INIT_RETRIES", "5")),
-                backoff=float(os.environ.get(
-                    "HOROVOD_BACKEND_INIT_BACKOFF", "5")))
-            return devices, devices[0].platform
-        except BackendInitError as e:
-            log(f"ACCELERATOR BACKEND UNAVAILABLE after good probe:\n{e}")
-
-    from horovod_tpu.common.config import _env_bool
-
-    if not _env_bool("HOROVOD_BENCH_CPU_FALLBACK", True):
-        raise SystemExit("accelerator backend unavailable and CPU fallback "
-                         "disabled (HOROVOD_BENCH_CPU_FALLBACK=0)")
-    log("falling back to CPU (benchmark number will NOT reflect TPU "
-        "performance; platform recorded in the JSON line)")
+    """The accelerator, or no run: ``--platform auto`` never measures on
+    the CPU. One process per chip — no probing child, no retry."""
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-    _reset_backends()
     devices = jax.devices()
-    return devices, "cpu"
+    platform = devices[0].platform
+    if platform == "cpu":
+        raise SystemExit(
+            "bench.py: JAX found no accelerator (platform 'cpu'). "
+            "--platform auto runs on the chip or not at all; pass "
+            "--platform cpu for the virtual CPU mesh.")
+    return devices, platform
 
 
 def force_cpu_backend(n_devices: int):
     """Deterministic CPU bring-up for smoke tests: n virtual CPU devices,
-    never touching (or waiting on) an accelerator backend. Same recipe as
-    ``__graft_entry__.dryrun_multichip`` — works even when the site has
-    preinitialized a TPU client."""
+    never touching (or waiting on) an accelerator backend."""
     import jax
 
-    # jax < 0.5 has no jax_num_cpu_devices config; the XLA flag (parsed
-    # at backend creation, which the reset below forces) is the portable
-    # spelling, so set it unconditionally before clearing backends.
-    flag = f"--xla_force_host_platform_device_count={n_devices}"
-    if flag not in os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "") + " " + flag).strip()
-    try:
-        from jax._src import xla_bridge
-
-        xla_bridge._clear_backends()
-        xla_bridge.get_backend.cache_clear()
-    except Exception as e:
-        log(f"backend force-reset unavailable ({e}); relying on config")
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n_devices)
-    except AttributeError:
-        pass  # jax < 0.5: XLA_FLAGS above carries the device count
+    jax.config.update("jax_num_cpu_devices", n_devices)
     devices = jax.devices()
     if len(devices) < n_devices:
         raise SystemExit(
@@ -360,39 +299,6 @@ def force_cpu_backend(n_devices: int):
 
 BASELINE_IMG_PER_SEC_PER_DEVICE = 1656.82 / 16  # docs/benchmarks.rst:27-43
 BASELINE_SCALING_EFFICIENCY = 0.90  # docs/benchmarks.rst:13-14 (512 GPUs)
-
-
-def load_stale_tpu_record(metric: str):
-    """Last known-good TPU measurement for ``metric`` from the archived
-    sweep logs (``HOROVOD_BENCH_STALE_DIR``, default ``BENCH_r05_sweep/``
-    next to this script).
-
-    When the TPU probe fails, the official artifact should carry the real
-    (stale, marked) TPU number rather than a meaningless CPU figure —
-    every line in those logs was measured on hardware and is
-    driver-checkable. Returns ``(record, source_path)`` or ``None``.
-    """
-    import glob
-
-    d = os.environ.get("HOROVOD_BENCH_STALE_DIR") or os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "BENCH_r05_sweep")
-    best = None
-    for path in sorted(glob.glob(os.path.join(d, "*.log"))):
-        try:
-            lines = open(path, errors="replace").read().splitlines()
-        except OSError:
-            continue
-        for ln in lines:
-            ln = ln.strip()
-            if not (ln.startswith("{") and '"metric"' in ln):
-                continue
-            try:
-                rec = json.loads(ln)
-            except ValueError:
-                continue
-            if rec.get("metric") == metric and rec.get("platform") == "tpu":
-                best = (rec, path)  # later files/lines win: LAST known-good
-    return best
 
 
 def summarize_profile(log_dir: str, top: int = 15) -> None:
@@ -665,19 +571,15 @@ def run_once(args, devices, platform, *, quantized=False, zero=False,
     images = jax.device_put(images, data_sh)
     labels = jax.device_put(labels, data_sh)
 
-    # Under --quantized, --zero, or --autotune (any leg) the optimizer
-    # owns the gradient reduction: reduce=False keeps the raw gradients
-    # per-rank locals so the fused (and, on the quantized leg,
-    # int8+error-feedback; on the zero leg, reduce-scattered) bucket wire
-    # inside tx.update is the one and only gradient collective — the wire
-    # the autotuner's fusion/hierarchical knobs actually steer
-    # (auto-psummed replicated grads never touch the fusion path).
-    reduce_in_optimizer = bool(args.quantized or getattr(args, "zero", False)
-                               or getattr(args, "autotune", False)
-                               or getattr(args, "overlap", False)
-                               or getattr(args, "zero_stage", None)
-                               or stage)
-
+    # The optimizer owns the gradient reduction on every leg:
+    # reduce=False keeps the raw gradients per-rank locals so the fused
+    # (and, on the quantized leg, int8+error-feedback; on the zero leg,
+    # reduce-scattered) bucket wire inside tx.update is the one and only
+    # gradient collective — the wire the autotuner's fusion/hierarchical
+    # knobs actually steer. A tape that reduced too would hand tx.update
+    # replicated AVERAGES, which it reads as autodiff's cross-rank SUM
+    # and divides by the world size again (found by chip_smoke.py's
+    # four-device rehearsal, PR 21; invisible on one chip).
     def spmd(p, bs, s, xb, yb):
         if zero3:
             # p is the shard tuple; the full params exist only between
@@ -687,8 +589,7 @@ def run_once(args, devices, platform, *, quantized=False, zero=False,
         else:
             pfull = p
         (loss, nbs), grads = hvd.value_and_grad(
-            loss_fn, has_aux=True,
-            reduce=not reduce_in_optimizer)(pfull, bs, xb, yb)
+            loss_fn, has_aux=True, reduce=False)(pfull, bs, xb, yb)
         nbs = hvd.allreduce_pytree(nbs, op=hvd.Average)
         updates, ns = tx.update(grads, s, p)
         return optax.apply_updates(p, updates), nbs, ns, hvd.allreduce(loss)
@@ -2767,27 +2668,22 @@ def run_serve(args, devices, platform, mesh_shape):
     def _cold_resize_stall(rset):
         """Cold-rebuild baseline for the resize A/B gate: disable every
         cache layer — the framework executable registry (memory + disk,
-        via HOROVOD_COMPILE_CACHE=0) and XLA's persistent cache (pointed
-        at a throwaway dir) — then resize down and back up with warm=False
-        so the drain window pays the full trace+compile, exactly what an
-        elastic resize cost before background precompile existed."""
-        import tempfile
-
-        import jax
-
+        via HOROVOD_COMPILE_CACHE=0) and XLA's persistent cache (turned
+        off, not re-pointed: the directory is part of what is cached) —
+        then resize down and back up with warm=False so the drain window
+        pays the full trace+compile, exactly what an elastic resize cost
+        before background precompile existed."""
         from horovod_tpu import compile as xc
 
         down_to = max(1, n_replicas // 2)
         prev_env = os.environ.get("HOROVOD_COMPILE_CACHE")
-        prev_dir = jax.config.jax_compilation_cache_dir
         os.environ["HOROVOD_COMPILE_CACHE"] = "0"
         try:
-            jax.config.update("jax_compilation_cache_dir",
-                              tempfile.mkdtemp(prefix="hvd-coldcache-"))
-            xc.clear_memory()
-            rset.resize(down_to, warm=False)
-            xc.clear_memory()
-            rset.resize(n_replicas, warm=False)
+            with xc.persistent_cache_disabled():
+                xc.clear_memory()
+                rset.resize(down_to, warm=False)
+                xc.clear_memory()
+                rset.resize(n_replicas, warm=False)
             return max(e["resize_stall_ms"]
                        for e in rset.resize_events[-2:])
         finally:
@@ -2795,7 +2691,6 @@ def run_serve(args, devices, platform, mesh_shape):
                 os.environ.pop("HOROVOD_COMPILE_CACHE", None)
             else:
                 os.environ["HOROVOD_COMPILE_CACHE"] = prev_env
-            jax.config.update("jax_compilation_cache_dir", prev_dir)
 
     from horovod_tpu.serve.engine import ServeStats
 
@@ -3125,10 +3020,10 @@ def main():
                          "resnet50/18 — reference convention is 64, "
                          "docs/benchmarks.rst:27-43, 128 keeps the MXU "
                          "fed on v5e; 8 sequences for gpt)")
-    ap.add_argument("--image-size", type=int, default=None,
+    ap.add_argument("--image-size", type=int, default=224,
                     help="square image side for resnet models (small "
                          "values speed up CPU smoke runs)")
-    ap.add_argument("--seq-len", type=int, default=None,
+    ap.add_argument("--seq-len", type=int, default=1024,
                     help="sequence length for --model gpt "
                          "(default 1024)")
     ap.add_argument("--vocab-size", type=int, default=32000,
@@ -3165,16 +3060,17 @@ def main():
                          "per-chip efficiency vs the smallest; the JSON "
                          "line becomes the scaling-efficiency metric")
     ap.add_argument("--platform", choices=["auto", "cpu"], default="auto",
-                    help="auto = robust TPU bring-up with CPU fallback; "
+                    help="auto = the accelerator JAX finds, or exit "
+                         "non-zero when there is none; "
                          "cpu = force an N-virtual-device CPU mesh "
                          "(--cpu-devices) for smoke-testing the scaling "
                          "sweep without pod hardware")
     ap.add_argument("--cpu-devices", type=int, default=8,
                     help="virtual device count for --platform cpu")
-    ap.add_argument("--num-warmup", type=int, default=None)
-    ap.add_argument("--num-iters", type=int, default=None,
+    ap.add_argument("--num-warmup", type=int, default=5)
+    ap.add_argument("--num-iters", type=int, default=10,
                     help="timing rounds (reference: 10)")
-    ap.add_argument("--num-batches-per-iter", type=int, default=None)
+    ap.add_argument("--num-batches-per-iter", type=int, default=10)
     ap.add_argument("--fp16-allreduce", action="store_true",
                     help="bf16 wire compression (reference flag name kept)")
     ap.add_argument("--quantized", action="store_true",
@@ -3349,18 +3245,8 @@ def main():
                     help="run K train steps per device call via lax.scan "
                          "(host-loop offload; hides per-dispatch latency)")
     args = ap.parse_args()
-    # None sentinels distinguish unset from explicitly-passed-default, so
-    # the CPU-fallback shrink can honor EXACTLY the flags the user typed.
-    _shrinkable = ("batch_size", "image_size", "num_warmup", "num_iters",
-                   "num_batches_per_iter", "seq_len")
-    explicit = {k: getattr(args, k) is not None for k in _shrinkable}
     if args.batch_size is None:
         args.batch_size = 8 if args.model == "gpt" else 128
-    for k, dflt in (("image_size", 224), ("num_warmup", 5),
-                    ("num_iters", 10), ("num_batches_per_iter", 10),
-                    ("seq_len", 1024)):
-        if getattr(args, k) is None:
-            setattr(args, k, dflt)
     if args.steps_per_call < 1:
         ap.error("--steps-per-call must be >= 1")
     if args.profile and args.num_iters < 2:
@@ -3504,25 +3390,6 @@ def main():
         devices, platform = force_cpu_backend(max(want, args.cpu_devices))
     else:
         devices, platform = init_backend()
-        if platform == "cpu":
-            # Accelerator-unavailable fallback: shrink the workload so the
-            # run still finishes inside a driver timeout (a TPU-sized
-            # ResNet-50 batch on CPU takes hours — the round-1 rc!=0
-            # failure mode). Only knobs the user left at defaults shrink.
-            shrunk = {}
-            if not explicit["batch_size"]:
-                args.batch_size = 8 if args.model != "gpt" else 2
-                shrunk["batch_size"] = args.batch_size
-            for name, small in (("image_size", 96), ("num_warmup", 1),
-                                ("num_iters", 3),
-                                ("num_batches_per_iter", 2),
-                                ("seq_len", 128)):
-                if not explicit[name]:
-                    setattr(args, name, small)
-                    shrunk[name] = small
-            if shrunk:
-                log(f"CPU fallback: shrunk workload {shrunk} so the run "
-                    f"completes (explicit flags are honored)")
     if args.chips is not None:
         if args.chips < 1:
             ap.error("--chips must be >= 1")
@@ -3956,32 +3823,6 @@ def main():
         return
 
     res = run_once(args, devices, platform, mesh_shape=mesh_shape)
-    if platform == "cpu" and args.platform != "cpu":
-        # TPU probe failed: the official artifact carries the last
-        # known-good TPU measurement (marked stale) instead of a
-        # meaningless CPU number; the CPU run rides along as a secondary
-        # field (VERDICT r5 Missing #2).
-        stale = load_stale_tpu_record(metric)
-        if stale is not None:
-            rec, src = stale
-            log(f"TPU unavailable: emitting last known-good TPU "
-                f"measurement from {src} (stale: true); the CPU fallback "
-                f"number rides in cpu_fallback")
-            print(json.dumps({
-                **rec,
-                "stale": True,
-                "stale_source": os.path.basename(src),
-                "cpu_fallback": {
-                    "value": round(res["per_chip"], 2),
-                    "unit": res["unit"],
-                    "chips": res["chips"],
-                    "step_ms_median": round(res["step_ms_median"], 3),
-                    "per_chip_batch": args.batch_size,
-                },
-            }), flush=True)
-            return
-        log("TPU unavailable and no stale TPU record matches "
-            f"{metric!r}; emitting the CPU fallback number")
     print(json.dumps({
         "metric": metric,
         "value": round(res["per_chip"], 2),
@@ -4000,39 +3841,6 @@ def main():
         **leg_compile_fields(res),
         "metrics_snapshot": res["metrics"],
         **gpt_fields,
-        **({"note": (
-            "HBM-roofline bound: profiled device busy time runs at "
-            "~peak effective bandwidth (conv+BN fusions 780-940 GB/s "
-            "vs 819 GB/s HBM peak on v5e incl. VMEM prefetch hits); "
-            "see README.md 'Benchmark methodology'. Matmul-bound "
-            "flagship via --model gpt (same step/collectives, Pallas "
-            "flash attention), re-measured r5 on hardware "
-            "(BENCH_r05_sweep/): GPT-124M 115.8k tok/s/chip MFU 0.42, "
-            "GPT-350M 42.3k tok/s/chip MFU 0.466 (both within ~1.5% "
-            "of r3: 117.2k / 42.9k). Fused-CE envelope: batch 32 x "
-            "128k vocab runs 75.9k tok/s MFU 0.45 where the dense "
-            "head cannot compile (17 GB logits vs 16 GB HBM); dense "
-            "wins 4-11% at every vocab that fits (README vocab "
-            "sweep). Weak-scaling harness: --scaling 1,..,64 (dryrun "
-            "leg 9)")}
-           if args.model == "resnet50"
-           and "v5 lite" in getattr(devices[0], "device_kind", "").lower()
-           else {}),
-        **({"note": (
-            "CPU FALLBACK — the accelerator backend was unavailable "
-            "(the probe diagnostics logged above give the specific "
-            "cause), so this number reflects nothing about TPU "
-            "performance. Real TPU measurements captured r5 "
-            "(BENCH_r05_sweep/ in-repo, driver-checkable logs): "
-            "ResNet-50 2164 img/s MFU 0.263 (noisy relay day; r3 "
-            "2271/0.276), GPT-124M 115.8k tok/s MFU 0.42, GPT-350M "
-            "42.3k tok/s MFU 0.466, GPT-350M remat b16 33.7k (remat "
-            "recompute tax - not a single-chip win). "
-            "scripts/tpu_round5b_measurements.sh re-captures the "
-            "missing legs (resumable via .done stamps); "
-            "scripts/relay_watch_and_sweep.sh launches it the moment "
-            "the relay returns.")}
-           if platform == "cpu" and args.platform != "cpu" else {}),
     }), flush=True)
 
 

@@ -52,7 +52,8 @@ def main():
     @jax.jit
     def train_step(p, s, xb, yb):
         def spmd(p, s, xb, yb):
-            loss, grads = hvd.value_and_grad(loss_fn)(p, xb, yb)
+            loss, grads = hvd.value_and_grad(
+                loss_fn, reduce=False)(p, xb, yb)  # tx owns the reduction
             updates, ns = tx.update(grads, s, p)
             return optax.apply_updates(p, updates), ns, hvd.allreduce(loss)
 

@@ -310,7 +310,7 @@ def autotune_session(
     ``tune_zero`` adds the ZeRO-sharding flag to the search space; leave
     it False (the default) unless ``make_step`` actually threads
     ``tuned.zero_sharding`` through (``DistributedOptimizer(tuned_params=
-    tuned)`` + ``hvd.value_and_grad(..., tuned_params=tuned)`` do) — the
+    tuned)`` + ``hvd.value_and_grad(..., reduce=False)`` do) — the
     knob restructures the optimizer state, so a step built without it
     would silently score a config it never ran. ``tune_overlap`` gates
     the ``overlap`` + ``num_comm_streams`` pair the same way (overlap ×

@@ -79,14 +79,9 @@ PP_AXIS = "hvd_pp"
 # (horovod_tpu/moe/layer.py).
 EP_AXIS = "hvd_ep"
 
-# ``jax.shard_map`` graduated from jax.experimental in jax 0.6; on the
-# pinned 0.4.x line only the experimental spelling exists. This resolver is
-# the single home every horovod_tpu caller (and the test suite, via
-# ``hvd.shard_map``) goes through, so either jax works unmodified.
-if getattr(jax, "shard_map", None) is not None:
-    shard_map = jax.shard_map
-else:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map
+# The single spelling every horovod_tpu caller (and the test suite, via
+# ``hvd.shard_map``) goes through.
+shard_map = jax.shard_map
 
 
 class _State:
@@ -131,9 +126,7 @@ def _build_mesh(
     target (docs/wire-plan.md); pods == 1 collapses to the 2-D mesh.
     """
     if devices is None:
-        from .backend import acquire_devices
-
-        devices = acquire_devices()
+        devices = jax.devices()
     devices = list(devices)
     if (ep_size is not None and ep_size > 1
             and pp_stages is not None and pp_stages > 1):
@@ -531,18 +524,9 @@ def _bound_axes() -> frozenset:
 
 
 def _axis_size(name) -> int:
-    """Size of a bound mesh axis. ``lax.axis_size`` appeared alongside the
-    graduated ``jax.shard_map``; on jax 0.4.x the size comes from the axis
-    env directly (the same source :func:`_bound_axes` reads)."""
+    """Size of a bound mesh axis."""
     try:
         return jax.lax.axis_size(name)
-    except AttributeError:  # jax < 0.6
-        from jax._src.core import get_axis_env
-
-        try:
-            return get_axis_env().axis_sizes[name]
-        except KeyError:
-            raise _unbound_axis_error(name) from None
     except NameError:
         raise _unbound_axis_error(name) from None
 

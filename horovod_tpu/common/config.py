@@ -136,10 +136,8 @@ class Config:
     # --- compile-once runtime (docs/compile.md): JAX persistent
     #     compilation cache + serialized-executable registry, armed from
     #     init so warm reruns / restarted workers skip lower+compile.
-    #     Dir defaults beside the autotune cache
-    #     (~/.cache/horovod_tpu/compile). ---
+    #     Placed by JAX_COMPILATION_CACHE_DIR (compile/cache.py). ---
     compile_cache: bool = True
-    compile_cache_dir: Optional[str] = None
 
     # --- timeline (operations.cc:420-434) ---
     timeline: Optional[str] = None
@@ -233,7 +231,6 @@ def from_env() -> Config:
         autotune_warm_start=_env_int("HOROVOD_AUTOTUNE_WARM_START", 0),
         calibration_cache=_env_str("HOROVOD_CALIBRATION_CACHE", None),
         compile_cache=_env_bool("HOROVOD_COMPILE_CACHE", True),
-        compile_cache_dir=_env_str("HOROVOD_COMPILE_CACHE_DIR", None),
         timeline=_env_str("HOROVOD_TIMELINE", None),
         timeline_mark_cycles=_env_bool("HOROVOD_TIMELINE_MARK_CYCLES", False),
         metrics_jsonl=_env_str("HOROVOD_METRICS_JSONL", None),

@@ -10,7 +10,8 @@ warm-pool entry point (``hvd.precompile``).
 
 from .cache import (CompileResult, arm_persistent_cache, cache_dir,
                     clear_memory, compile_count, enabled, executable_key,
-                    get_or_compile, reset_stats, stats)
+                    get_or_compile, persistent_cache_disabled,
+                    reset_stats, stats)
 from .aot import precompile
 
 __all__ = [
@@ -22,6 +23,7 @@ __all__ = [
     "enabled",
     "executable_key",
     "get_or_compile",
+    "persistent_cache_disabled",
     "precompile",
     "reset_stats",
     "stats",

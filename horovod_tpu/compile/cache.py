@@ -7,12 +7,13 @@ elastic resize, every restarted worker used to re-pay lowering + XLA
 compile for programs this process (or a previous one) already built.
 This module is the framework-level answer, two layers deep:
 
-1. :func:`arm_persistent_cache` points JAX's own persistent compilation
-   cache (``jax_compilation_cache_dir``) at a directory beside the
-   kernel-autotune cache, so *any* jit compile in the process can be
-   served from disk by XLA itself. Armed from :func:`horovod_tpu.init`
-   BEFORE the mesh exists — the knob only applies cleanly ahead of the
-   first compilation.
+1. :func:`arm_persistent_cache` turns on JAX's own persistent
+   compilation cache at :func:`cache_dir` — wherever
+   ``JAX_COMPILATION_CACHE_DIR`` says, else one fixed directory in the
+   checkout — so *any* jit compile in the process can be served from
+   disk by XLA itself. Armed from :func:`horovod_tpu.init` BEFORE the
+   mesh exists — the knob only applies cleanly ahead of the first
+   compilation.
 2. :class:`ExecutableCache` — a registry of *loaded executables* keyed by
    ``(tag, plan encoding, mesh_geometry() fingerprint, shape/dtype
    signature, jax version)``. A hit skips lowering AND compile entirely
@@ -33,6 +34,7 @@ error logs a warning and falls back to a cold compile.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import json
@@ -54,7 +56,7 @@ _warned = {"disk": False, "arm": False}
 
 #: Bump when the on-disk entry layout changes — stale-format entries are
 #: ignored (treated as misses), never an error.
-_FORMAT_VERSION = 1
+_FORMAT_VERSION = 2
 
 
 # ---------------------------------------------------------------------------
@@ -72,18 +74,24 @@ def enabled() -> bool:
     return _env_bool("HOROVOD_COMPILE_CACHE", True)
 
 
-def cache_dir() -> str:
-    """Root of the compile cache (``HOROVOD_COMPILE_CACHE_DIR``; default
-    beside the kernel-autotune cache). Two subtrees: ``xla/`` for JAX's
-    persistent compilation cache, ``exec/`` for serialized-executable
-    payloads + ``index.json``."""
-    from ..common.config import _env_str
+#: Where the cache lives when nobody placed it: one fixed, git-ignored
+#: directory in the checkout. The path is part of JAX's cache key, so a
+#: directory that moves between runs (a temp dir, a pid, the time) never
+#: hits, and the home directory is not this program's to write.
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".compile_cache")
 
-    d = _env_str("HOROVOD_COMPILE_CACHE_DIR", None)
-    if d:
-        return d
-    return os.path.join(os.path.expanduser("~"), ".cache", "horovod_tpu",
-                        "compile")
+
+def cache_dir() -> str:
+    """Root of everything that is compiled once and kept:
+    ``JAX_COMPILATION_CACHE_DIR`` where set, else
+    :data:`_CHECKOUT_CACHE_DIR`. JAX's persistent compilation cache
+    writes its entries into the root itself; ``exec/`` holds the
+    serialized-executable payloads + ``index.json`` and
+    ``kernel_autotune.json`` the Pallas block choices those executables
+    were compiled with (ops/kernel_autotune.py)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE_DIR
 
 
 def _exec_dir() -> str:
@@ -99,25 +107,29 @@ def _index_path() -> str:
 
 
 def arm_persistent_cache(config=None) -> Optional[str]:
-    """Point ``jax_compilation_cache_dir`` at the compile cache dir.
+    """Turn on JAX's persistent compilation cache at :func:`cache_dir`.
 
     Called from ``hvd.init`` before the mesh is built (before any
     compilation — the persistent cache only covers compiles issued after
-    arming). Thresholds are zeroed so fast CPU-mesh compiles persist
-    too: the CI smoke and warm-rerun gates run on the 2x4 host-platform
-    mesh where every compile is "too fast to be worth caching" under
-    JAX's defaults. Returns the armed directory, or None when disabled
-    or when arming fails (logged once, never raised)."""
+    arming). Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX has already
+    taken its directory from it and this function names no other; only
+    an unplaced cache is pointed at the checkout's. Thresholds are
+    zeroed so fast CPU-mesh compiles persist too: the CI smoke and
+    warm-rerun gates run on the 2x4 host-platform mesh where every
+    compile is "too fast to be worth caching" under JAX's defaults.
+    Returns the armed directory, or None when disabled or when arming
+    fails (logged once, never raised)."""
     if config is not None and not getattr(config, "compile_cache", True):
         return None
     if not enabled():
         return None
-    xla_dir = os.path.join(cache_dir(), "xla")
+    root = cache_dir()
     try:
         import jax
 
-        os.makedirs(xla_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", xla_dir)
+        os.makedirs(root, exist_ok=True)
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", root)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
@@ -128,7 +140,28 @@ def arm_persistent_cache(config=None) -> Optional[str]:
                            "(%s: %s) — compiles stay cold across "
                            "processes", type(e).__name__, str(e)[:200])
         return None
-    return xla_dir
+    return root
+
+
+@contextlib.contextmanager
+def persistent_cache_disabled():
+    """JAX's persistent compilation cache off for the block: a leg that
+    must pay a cold compile, or a compile for a described (unattached)
+    chip whose entry could never be read back. Off, not re-pointed at a
+    throwaway directory — JAX reads its directory once, and the
+    directory is part of every entry's key."""
+    import jax
+    from jax.experimental.compilation_cache import (
+        compilation_cache as _jcc)
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    _jcc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        _jcc.reset_cache()
 
 
 # ---------------------------------------------------------------------------
@@ -164,31 +197,32 @@ def _shapes_signature(shapes) -> str:
 
 
 def _mesh_fingerprint(mesh) -> str:
-    """``mesh_geometry()`` when the mesh fits the framework vocabulary;
-    otherwise (e.g. the serve engine's 1-D ``serve_tp`` mesh over a
-    device subset) a raw ``mesh<shape>@<axes>#<device-ids>`` form — two
-    replicas over different device slices are different executables."""
+    """``<geometry>#<device-ids>``: ``mesh_geometry()`` when the mesh
+    fits the framework vocabulary, otherwise (e.g. the serve engine's
+    1-D ``serve_tp`` mesh) a raw ``mesh<shape>@<axes>|world<N>|<kind>``
+    form. The ids are part of the key because a serialized executable
+    carries its device assignment: two replicas over different device
+    slices, or a 1-chip mesh and the whole host, are different
+    executables."""
     from ..common import basics
 
-    try:
+    if mesh is None:   # the live framework mesh, if there is one
+        mesh = basics._state.mesh if basics.is_initialized() else None
         if mesh is None:
-            return basics.mesh_geometry()
-        shp = mesh.devices.shape
-        if len(shp) >= 2:
-            return basics.mesh_geometry(mesh=mesh)
-    except Exception:
-        pass
-    if mesh is None:
-        return "nomesh"
+            return "nomesh"
     devs = list(mesh.devices.ravel())
-    shape = "x".join(str(int(v)) for v in mesh.devices.shape)
-    axes = ".".join(str(a) for a in mesh.axis_names)
-    ids = ",".join(str(getattr(d, "id", "?")) for d in devs)
+    if len(mesh.devices.shape) >= 2:
+        geo = basics.mesh_geometry(mesh=mesh)
+    else:
+        shape = "x".join(str(int(v)) for v in mesh.devices.shape)
+        axes = ".".join(str(a) for a in mesh.axis_names)
+        kind = str(getattr(devs[0], "device_kind", "unknown")
+                   or "unknown").strip().lower().replace(" ", "-")
+        geo = f"mesh{shape}@{axes}|world{len(devs)}|{kind}"
+    ids = ",".join(str(d.id) for d in devs)
     if len(ids) > 48:
         ids = hashlib.sha1(ids.encode()).hexdigest()[:12]
-    kind = str(getattr(devs[0], "device_kind", "unknown")
-               or "unknown").strip().lower().replace(" ", "-")
-    return f"mesh{shape}@{axes}#{ids}|world{len(devs)}|{kind}"
+    return f"{geo}#{ids}"
 
 
 def executable_key(tag: str, *, plan: Optional[str] = None,
@@ -216,8 +250,11 @@ def executable_key(tag: str, *, plan: Optional[str] = None,
 
 
 def _disk_load(key: str) -> Optional[Tuple[Any, float, dict]]:
-    """Deserialize ``key``'s executable from disk, or None. Any failure
-    (missing, corrupt, incompatible) is a logged miss."""
+    """Deserialize ``key``'s executable from disk onto the devices it was
+    compiled for (recorded with the entry); left to itself the loader
+    takes every local device and a 1-chip program then wants 8 shards.
+    None on any failure (missing, corrupt, incompatible, a device that
+    is not here): a logged miss."""
     if not enabled():
         return None
     try:
@@ -229,11 +266,15 @@ def _disk_load(key: str) -> Optional[Tuple[Any, float, dict]]:
     if not isinstance(meta, dict):
         return None
     try:
+        import jax
         from jax.experimental import serialize_executable as _se
 
+        by_id = {d.id: d for d in jax.devices()}
+        devices = [by_id[i] for i in meta["devices"]]
         with open(os.path.join(_exec_dir(), meta["file"]), "rb") as f:
             payload, in_tree, out_tree = pickle.loads(f.read())
-        compiled = _se.deserialize_and_load(payload, in_tree, out_tree)
+        compiled = _se.deserialize_and_load(
+            payload, in_tree, out_tree, execution_devices=devices)
         return (compiled, float(meta.get("compile_ms", 0.0)),
                 dict(meta.get("aux") or {}))
     except Exception as e:  # corrupt/foreign entry: cold compile instead
@@ -259,6 +300,8 @@ def _disk_store(key: str, compiled, compile_ms: float, aux: dict) -> None:
         from jax.experimental import serialize_executable as _se
 
         payload = pickle.dumps(_se.serialize(compiled))
+        devices = [d.id for d in
+                   compiled.runtime_executable().local_devices()]
     except Exception as e:  # unserializable backend: memory-only entry
         logger.debug("executable %s not serializable (%s) — memory-only",
                      key, str(e)[:200])
@@ -282,7 +325,7 @@ def _disk_store(key: str, compiled, compile_ms: float, aux: dict) -> None:
                     disk = json.load(f)
             except (FileNotFoundError, json.JSONDecodeError, ValueError):
                 pass
-            disk[key] = {"file": fname,
+            disk[key] = {"file": fname, "devices": devices,
                          "compile_ms": round(float(compile_ms), 3),
                          "aux": aux, "wall": time.time()}
             tmp = f"{path}.tmp.{os.getpid()}"

@@ -57,27 +57,6 @@ from ..common.basics import EP_AXIS
 from ..plan import compiler as _compiler
 from ..plan import planner as _planner
 
-if not hasattr(jax, "shard_map"):
-    # jax < 0.6: the experimental shard_map check_rep loop cannot handle
-    # a multiple-results primitive whose operand replication is the bare
-    # ``None`` of an untracked/constant-derived value — the upstream
-    # ``_standard_check`` rule returns ``None`` un-broadcast and the
-    # loop crashes on ``map(write, e.outvars, None)``. ``lax.top_k``
-    # (the router's expert selection) hits exactly this, so OVERWRITE
-    # its rule with one that always returns the per-output list.
-    try:  # pragma: no cover - version-gated compat
-        from jax.experimental import shard_map as _sm_compat
-        from jax._src.lax.lax import top_k_p as _top_k_p
-
-        def _top_k_rep_rule(mesh, x_rep, **params):
-            # Both outputs (values, indices) replicate exactly like the
-            # operand.
-            return [x_rep, x_rep]
-
-        _sm_compat._check_rules[_top_k_p] = _top_k_rep_rule
-    except Exception:  # pragma: no cover - internal-API drift
-        pass
-
 
 def _axis_size(axis) -> int:
     if axis is None:

@@ -117,53 +117,21 @@ def _world_size(axes: Tuple[str, ...]):
 
 def _vma(x) -> frozenset:
     """Varying-manual-axes of ``x``: which mesh axes the value differs
-    across. JAX >= 0.6 tracks this in the aval (``jax.typeof(x).vma``);
-    jax 0.4.x's ``shard_map(check_rep=True)`` tracks the complement — the
-    set of axes a value is provably *replicated* over — on its rewrite
-    tracers, so there vma = bound axes - rep. An empty set means the value
-    is provably identical on every device."""
+    across, as the aval tracks it (``jax.typeof(x).vma``). An empty set
+    means the value is provably identical on every device (or is not an
+    array at all)."""
     try:
         return frozenset(jax.typeof(x).vma)
-    except Exception:
-        pass
-    try:  # jax < 0.6: check_rep replication tracking
-        from jax.experimental.shard_map import get_replication
-
-        while True:
-            try:
-                rep = get_replication(x)
-                break
-            except Exception:
-                # Wrapper tracers (JVP/linearize) carry the rep on their
-                # primal; get_replication itself unwraps batching.
-                primal = getattr(x, "primal", None)
-                if primal is None:
-                    raise
-                x = primal
-        return frozenset(basics._bound_axes()) - frozenset(rep)
-    except Exception:  # pragma: no cover - non-traced / API drift
+    except (TypeError, AttributeError):  # not an array-like value
         return frozenset()
 
 
 def _pvary(x, axes) -> "jax.Array":
     """Cast ``x`` to be varying over ``axes`` (a free type-level
-    broadcast). ``lax.pcast`` on jax >= 0.6; jax 0.4.x spells the same
-    rep-set adjustment ``shard_map.pbroadcast``."""
+    broadcast)."""
     if not axes:
         return x
-    try:
-        return lax.pcast(x, tuple(axes), to="varying")
-    except AttributeError:  # jax < 0.6
-        from jax.experimental.shard_map import pbroadcast
-
-        try:
-            return pbroadcast(x, tuple(axes))
-        except Exception:
-            # pbroadcast rejects operands that are ALREADY device-varying
-            # over the axes — which only happens when the rep set was not
-            # recoverable from a wrapper tracer. Varying is what the
-            # caller wanted; the value itself is untouched either way.
-            return x
+    return lax.pcast(x, tuple(axes), to="varying")
 
 
 def pvary_missing(x, axes) -> "jax.Array":

@@ -48,28 +48,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU backend)
 
-if not hasattr(pltpu, "CompilerParams"):  # jax < 0.6 naming
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
-if not hasattr(jax, "shard_map"):
-    # jax < 0.6: the experimental shard_map's check_rep machinery has no
-    # replication rule for pallas_call. The sound rule for a per-device
-    # kernel: every output is replicated exactly over the axes ALL
-    # operands are replicated over (tensor operands are vma-harmonized
-    # before each call; scalar offset operands may stay replicated).
-    try:
-        from jax.experimental import shard_map as _sm_compat
-        from jax._src.pallas.pallas_call import pallas_call_p as _pc_p
-
-        def _pallas_rep_rule(mesh, *in_rep, **params):
-            reps = [set(r) for r in in_rep if r is not None]
-            return set.intersection(*reps) if reps else None
-
-        _sm_compat.register_check(_pc_p)(_pallas_rep_rule)
-        _sm_compat.register_norewrite(_pc_p)
-    except Exception:  # pragma: no cover - internal-API drift
-        pass
-
 _NEG_INF = -1e30  # finite: keeps running-max arithmetic NaN-free
 
 # Large blocks amortize Mosaic's per-grid-cell overhead and give the MXU
@@ -128,16 +106,11 @@ def _interpret() -> bool:
 def _out_struct(shape, dtype, *operands):
     """ShapeDtypeStruct whose varying-manual-axes are the union of the
     operands' — required inside ``jax.shard_map`` (check_vma), harmless
-    outside (vma=frozenset()). jax < 0.6 has no aval-level vma (its
-    shard_map tracks replication on the tracer instead), so the plain
-    struct is the correct spelling there."""
+    outside (vma=frozenset())."""
     from .collective_ops import _vma
 
     vma = frozenset().union(*[_vma(x) for x in operands])
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:  # jax < 0.6
-        return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _harmonize_vma(*arrays):

@@ -56,8 +56,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
-# flash_attention installs the jax<0.6 shard_map replication rule for
-# pallas_call and the CompilerParams alias — import for the side effects.
 from . import flash_attention as _flash
 from ..plan.accounting import _acct, _acct_enabled, fused_span
 
@@ -74,6 +72,25 @@ def _block_k_knob() -> int:
             f"HOROVOD_FUSED_BLOCK_K={v}: Pallas kernel blocks must be "
             f">= 128 (MXU/lane tile)")
     return v
+
+
+# These kernels hold whole operands in VMEM (no row/column grid), so the
+# TPU compiler refuses them above toy sizes (ROADMAP S7: run or remove).
+# Each caller prices its footprint from shapes with a model fitted to
+# what the v5e compiler accepted and refused (tests/test_tpu_lowering.py)
+# and fails here, by name, instead of minutes into a compile.
+_VMEM_LIMIT_BYTES = 16 * 2 ** 20
+
+
+def _check_vmem(kernel: str, shape_desc: str, nbytes: float) -> None:
+    if not _interpret() and nbytes > _VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"fused_collective.{kernel}: {shape_desc} needs about "
+            f"{nbytes / 2 ** 20:.1f} MiB of VMEM against the "
+            f"{_VMEM_LIMIT_BYTES // 2 ** 20} MiB the TPU compiler "
+            f"grants one kernel; the opt-in fused= kernels are not "
+            f"tiled for real sizes (ROADMAP S7) — run this size "
+            f"without fused=")
 
 
 def _resolve_axes(axes) -> Tuple[str, ...]:
@@ -162,6 +179,10 @@ def _matmul_accumulate(x, w, acc, *, block_k: Optional[int] = None):
     N = w.shape[1]
     bk = _flash._pick_block(K, block_k or _block_k_knob()) or K
     nk = K // bk
+    isz, asz = x.dtype.itemsize, acc.dtype.itemsize
+    # resident [m, N] acc in + out + fp32 scratch, double-buffered slabs
+    _check_vmem("_matmul_accumulate", f"[{m},{K}] @ [{K},{N}]",
+                m * N * (2 * asz + 4) + 2 * (m * bk + bk * N) * isz)
     return pl.pallas_call(
         functools.partial(_mm_acc_kernel, nk=nk),
         grid=(nk,),
@@ -201,6 +222,9 @@ def quantize_blockwise(blocks):
     [rows, nb], err fp32 [rows, nb, blk])`` — the kernel behind
     ``backend="pallas"`` on an int8 reduce-scatter/all-gather leg."""
     rows, nb, blk = blocks.shape
+    # fp32 in + err, int8 out, and one fp32 working copy
+    _check_vmem("quantize_blockwise", f"blocks {list(blocks.shape)}",
+                13.0 * rows * nb * blk)
     with fused_span("QUANT", quant_hbm_saved(rows, nb, blk)):
         return pl.pallas_call(
             _quant_kernel,
@@ -224,6 +248,9 @@ def dequantize_accumulate(qT, sT):
     fp32 in HBM. qT ``[rows, nb, blk]`` int8, sT ``[rows, nb]`` fp32 →
     ``[nb, blk]`` fp32."""
     rows, nb, blk = qT.shape
+    # int8 in, a half-width working copy, fp32 [nb, blk] out
+    _check_vmem("dequantize_accumulate", f"payload {list(qT.shape)}",
+                3.0 * rows * nb * blk + 4.0 * nb * blk)
     with fused_span("DEQUANT", dequant_hbm_saved(rows, nb, blk)):
         return pl.pallas_call(
             _dequant_acc_kernel,

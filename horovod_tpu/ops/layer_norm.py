@@ -11,8 +11,8 @@ pattern
 round-trips the stream an extra time whenever XLA does not fuse the add
 into the LayerNorm's reductions. This kernel computes both in one pass:
 one read of x and sublayer_out, one write of h (the stream continues
-through it) and y — the VERDICT r4 "fused LN+residual" MFU lever, built
-so the TPU A/B is one bench flag (``--fused-ln``).
+through it) and y — an MFU lever built so the TPU A/B is one bench flag
+(``--fused-ln``).
 
 Forward grid: row blocks of the flattened [N, C] stream; per-row mean /
 rstd live only in VMEM. The backward recomputes the row statistics from
@@ -37,9 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-if not hasattr(pltpu, "CompilerParams"):  # jax < 0.6 naming
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
 
 from .flash_attention import _harmonize_vma, _interpret, _out_struct
 

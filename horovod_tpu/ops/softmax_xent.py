@@ -33,10 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):  # jax < 0.6 naming
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 from .flash_attention import (
+    _block_knob,
     _harmonize_vma,
     _interpret,
     _out_struct,
@@ -47,14 +45,26 @@ _NEG_INF = -1e30
 
 # The dominant HBM cost is streaming the [V, C] weight matrix once per
 # row block (it exceeds VMEM), so block_n is the lever: W traffic per
-# kernel = (N / block_n) · V·C bytes. 1024 rows × a 640–1024-column vocab
-# block keeps x/acc/s under ~7 MB of VMEM while cutting W re-reads 4×
-# vs 256-row blocks (measured: the difference between losing and winning
-# against the dense einsum+optax head at V = 32000).
-from .flash_attention import _block_knob
+# kernel = (N / block_n) · V·C bytes — the larger the row block the
+# better, until the backward no longer fits. What bounds it is the
+# backward kernels' scoped VMEM (16 MiB on v5e): their fp32 [block, C]
+# accumulators and [bn, bv] score temporaries grow with block·C. Asked
+# of the v5e compiler (tests/test_tpu_lowering.py): bn·C = 512·1024
+# compiles at C = 768, 1024 and 2048 where 1024·1024 (20.15M) and
+# 512·2048 (19.17M) are refused; the vocab block tolerates 1.5× that
+# (bv·C = 640·1024 and 384·2048 compile, 1024·1024 beside bn = 512 is
+# refused at 16.24M).
+_ROW_BLOCK_ELEMS = 512 * 1024
 
-_DEF_BLOCK_N = _block_knob("HOROVOD_XENT_BLOCK_N", 1024)  # token rows/cell
-_DEF_BLOCK_V = _block_knob("HOROVOD_XENT_BLOCK_V", 1024)  # vocab cols/cell
+
+def _default_blocks(C: int):
+    """(block_n, block_v) preferences for hidden width ``C``: the largest
+    128-multiples (capped at 1024) whose backward stays inside scoped
+    VMEM. ``_pick_block`` then snaps them to divisors of N and V."""
+    def fit(elems):
+        return max(128, min(1024, elems // C // 128 * 128))
+
+    return fit(_ROW_BLOCK_ELEMS), fit(3 * _ROW_BLOCK_ELEMS // 2)
 
 
 def _onehot_mask(labels_col, j, bn, bv):
@@ -282,8 +292,10 @@ def linear_cross_entropy(x, w, labels, *,
     XLA formulation when no legal blocking exists.
 
     Blocks default to the kernel autotuner's cached/swept choice for this
-    (shape, chip) (ops/kernel_autotune.py) unless the
-    ``HOROVOD_XENT_BLOCK_N/V`` knobs or explicit arguments pin them.
+    (shape, chip) (ops/kernel_autotune.py), and off-TPU or with the sweep
+    off to :func:`_default_blocks` — derived from ``C`` so the backward
+    fits scoped VMEM — unless the ``HOROVOD_XENT_BLOCK_N/V`` knobs or
+    explicit arguments pin them.
     """
     import os
 
@@ -295,23 +307,24 @@ def linear_cross_entropy(x, w, labels, *,
         N *= d
     xf = x.reshape(N, C)
     lab = labels.reshape(N)
+    def_n, def_v = _default_blocks(C)
+    # Knobs are read at CALL time so a runtime os.environ override works;
+    # an empty string means unset (the shell idiom _env_int honors).
+    pinned = bool(os.environ.get("HOROVOD_XENT_BLOCK_N")
+                  or os.environ.get("HOROVOD_XENT_BLOCK_V"))
+    knob_n = _block_knob("HOROVOD_XENT_BLOCK_N", def_n)
+    knob_v = _block_knob("HOROVOD_XENT_BLOCK_V", def_v)
     if block_n is None and block_v is None:
-        if (os.environ.get("HOROVOD_XENT_BLOCK_N")
-                or os.environ.get("HOROVOD_XENT_BLOCK_V")):
-            block_n = _block_knob("HOROVOD_XENT_BLOCK_N", 1024)
-            block_v = _block_knob("HOROVOD_XENT_BLOCK_V", 1024)
-        else:
-            from . import kernel_autotune
+        from . import kernel_autotune
 
-            if kernel_autotune.enabled():
-                block_n, block_v = kernel_autotune.xent_blocks(
-                    N, V, C, x.dtype, (_DEF_BLOCK_N, _DEF_BLOCK_V),
-                    _pick_block)
-            else:
-                block_n, block_v = _DEF_BLOCK_N, _DEF_BLOCK_V
+        if pinned or not kernel_autotune.enabled():
+            block_n, block_v = knob_n, knob_v
+        else:
+            block_n, block_v = kernel_autotune.xent_blocks(
+                N, V, C, x.dtype, (def_n, def_v), _pick_block)
     else:
-        block_n = _DEF_BLOCK_N if block_n is None else block_n
-        block_v = _DEF_BLOCK_V if block_v is None else block_v
+        block_n = knob_n if block_n is None else block_n
+        block_v = knob_v if block_v is None else block_v
     bn, bv = _pick_block(N, block_n), _pick_block(V, block_v)
     if bn is None or bv is None:
         return _dense_xent(xf, w, lab, dtype=jnp.float32).reshape(lead)
@@ -324,10 +337,11 @@ def lm_head_loss(x, w, labels, *, mode: str = "auto"):
     """LM-head loss with measured dispatch: XLA's dense einsum+optax head
     wherever its logits fit, the fused Pallas kernel beyond.
 
-    Measured on one v5e (GPT-124M step, seq 1024, per-chip batch 8,
-    BENCH_r04 sweep): the dense head is uniformly FASTER at every vocab
-    that compiles — 110.4k vs 105.2k tok/s at V=32k, 94.5k vs 90.8k at
-    64k, 76.5k vs 70.5k at 128k, 55.4k vs 49.2k at 256k (4–11%; XLA's
+    Measured on one v5e (GPT-124M step, seq 1024, per-chip batch 8;
+    round 4, not re-measured): the dense head is uniformly FASTER at
+    every vocab that compiles — 110.4k vs 105.2k tok/s at V=32k, 94.5k
+    vs 90.8k at 64k, 76.5k vs 70.5k at 128k, 55.4k vs 49.2k at 256k
+    (4–11%; XLA's
     fused matmul+xent is near-roofline and its [N, V] round trip is
     cheaper than this kernel's extra W re-streams). There is NO
     throughput crossover: the fused kernel's value is the operating
@@ -347,12 +361,6 @@ def lm_head_loss(x, w, labels, *, mode: str = "auto"):
     if mode not in ("auto", "dense", "fused"):
         raise ValueError(f"mode must be auto|dense|fused, got {mode!r}")
     use_fused = mode == "fused"
-    # Read the block knob at CALL time (unlike the import-time module
-    # default) so a runtime os.environ override works the way the
-    # adjacent HOROVOD_XENT_AUTO_LOGITS_GB knob does. An empty string
-    # means unset (shell idiom), matching _env_int's treatment.
-    env_bn = os.environ.get("HOROVOD_XENT_BLOCK_N") or None
-    block_n = _block_knob("HOROVOD_XENT_BLOCK_N", _DEF_BLOCK_N)
     if mode == "auto":
         N = 1
         for d in x.shape[:-1]:
@@ -360,23 +368,6 @@ def lm_head_loss(x, w, labels, *, mode: str = "auto"):
         budget = float(os.environ.get(
             "HOROVOD_XENT_AUTO_LOGITS_GB", "10")) * 2 ** 30
         use_fused = N * w.shape[0] * 4.0 > budget
-        if use_fused and env_bn is None:
-            # Auto only fires at large N·V, where the 1024-row block's
-            # backward overflows the VMEM scoped stack inside a full
-            # train-step fusion context (measured: 17.18M vs the 16M
-            # limit at [32k tokens, 128k vocab]); 512 rows compiles and
-            # measures identically standalone (196.6 vs 196.9 ms).
-            block_n = min(512, block_n)
-            from . import kernel_autotune
-
-            if kernel_autotune.enabled():
-                # Tune within the in-context-safe grid (bn <= 512); the
-                # sweep-failure default stays the safe 512-row block.
-                block_n, bv = kernel_autotune.xent_blocks(
-                    N, w.shape[0], x.shape[-1], x.dtype,
-                    (block_n, _DEF_BLOCK_V), _pick_block)
-                return linear_cross_entropy(x, w, labels,
-                                            block_n=block_n, block_v=bv)
     if use_fused:
-        return linear_cross_entropy(x, w, labels, block_n=block_n)
+        return linear_cross_entropy(x, w, labels)
     return _dense_xent(x, w, labels)

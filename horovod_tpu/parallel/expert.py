@@ -131,7 +131,7 @@ def switch_moe_ragged(x, router_kernel, w1, b1, w2, b2, *,
       per-sender),
 
     which is strictly laxer than the fixed path's per-(sender, expert)
-    quota — the capacity-overflow cliff VERDICT r4 flagged.  Dropped
+    quota (whose capacity-overflow cliff it avoids).  Dropped
     tokens still emit zeros and ride the residual.
     """
     N, C = x.shape

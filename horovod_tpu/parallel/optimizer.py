@@ -75,7 +75,33 @@ from ..ops import fusion
 from ..ops.compression import Compression
 
 
-def _with_step_marker(tx):
+def _reject_reduced_gradients(leaves, axes) -> None:
+    """One reducer per step: refuse replicated gradients in a trace where
+    the tape has already reduced. This optimizer reads a replicated
+    gradient as autodiff's cross-rank SUM (plain ``jax.grad`` over
+    replicated parameters) and divides by the world; the tape's averages
+    look the same and would be divided twice — silently, and only on more
+    than one device. (A step that really has both, a reducing tape for one
+    model and plain ``jax.grad`` for another, is refused too: hand this
+    optimizer ``reduce=False`` gradients.)"""
+    from .tape import reduced_in_this_trace
+
+    axes_t = C._resolve_axes(axes)
+    if not (axes_t and reduced_in_this_trace()):
+        return
+    if any(jnp.issubdtype(jnp.asarray(l).dtype, jnp.floating)
+           and C._is_replicated(l, axes_t) for l in leaves):
+        raise ValueError(
+            "hvd.DistributedOptimizer was handed gradients that "
+            "hvd.value_and_grad / hvd.allreduce_gradients already reduced "
+            "in this step: it would divide them by the world size a "
+            "second time. Keep one reducer: pass reduce=False to "
+            "hvd.value_and_grad so the optimizer owns the reduction, or "
+            "keep the reducing tape and use the plain optax "
+            "transformation.")
+
+
+def _with_step_marker(tx, axes=None):
     """Host-side step markers around a DistributedOptimizer's update.
 
     When ``update`` runs eagerly (the host path / process-world mode)
@@ -94,6 +120,7 @@ def _with_step_marker(tx):
     def update(grads, state, params=None, **extra):
         leaves = jax.tree.leaves(grads)
         if leaves and isinstance(leaves[0], jax.core.Tracer):
+            _reject_reduced_gradients(leaves, axes)
             _metrics.counter("optimizer.update_traces").inc()
             return inner_update(grads, state, params, **extra)
         step_no[0] += 1
@@ -252,10 +279,7 @@ def _overlap_multi_steps(
     :class:`OverlapMultiStepsState` for the schedule and its contract.
 
     Branchless like :func:`_zero_multi_steps` (``where``-selected apply,
-    never ``lax.cond``), which also makes it the working
-    ``backward_passes_per_step`` spelling under ``shard_map``'s
-    replication checker on jax 0.4.x, where ``optax.MultiSteps``' cond
-    arms fail rep inference. Meaningful for per-rank local gradients
+    never ``lax.cond``). Meaningful for per-rank local gradients
     (``hvd.value_and_grad(..., reduce=False)``); already-psummed
     replicated gradients are detected statically (VMA) and fall back to
     accumulate-locally + one final reduction — MultiSteps semantics, no
@@ -555,7 +579,7 @@ def DistributedOptimizer(
             fused=fused,
             axes=axes,
             stage=zero_stage,
-        ))
+        ), axes)
 
     if gradient_predivide_factor != 1.0:
         # Average == Sum with the divisor split across pre/post scaling.
@@ -601,7 +625,7 @@ def DistributedOptimizer(
         # bucket reduction share a program region dependence-free.
         return _with_step_marker(
             _overlap_multi_steps(optimizer, backward_passes_per_step,
-                                 _allreduce, quantized=quantized))
+                                 _allreduce, quantized=quantized), axes)
 
     _res_read, _res_write = _lead_read, _lead_write
 
@@ -631,7 +655,7 @@ def DistributedOptimizer(
         # Accumulate locally, allreduce + apply every k-th microbatch
         # (reference: torch/optimizer.py:133-149).
         tx = optax.MultiSteps(tx, every_k_schedule=backward_passes_per_step)
-    return _with_step_marker(tx)
+    return _with_step_marker(tx, axes)
 
 
 def _validate_pp_knobs(pp_stages, pp_microbatches, pp_schedule,
@@ -857,8 +881,7 @@ class ZeroFullMultiStepsState(NamedTuple):
     convention — ``[world, *shape]`` outside the trace, ``[1, *shape]``
     inside). The mean of the k accumulated microbatches feeds the
     reduce-scatter on the k-th call; inner state and emitted updates are
-    ``where``-selected (branchless — ``lax.cond`` fails shard_map rep
-    inference on jax 0.4.x), so the wire runs every microbatch but
+    ``where``-selected (branchless), so the wire runs every microbatch but
     non-final results are discarded. Reshard only at cycle boundaries
     (``mini_step == 0``, ``acc`` zeros); :func:`zero_reshard_state`
     rebuilds the accumulator as zeros at the new world."""
@@ -976,8 +999,7 @@ def _build_zero_transform(
     # so the accumulator is a [padded // world] leaf, not a full gradient
     # replica. Stage 1 keeps the classic full local-gradient accumulator
     # (per-rank leading-axis state); the wire still runs every microbatch
-    # — branchless where-selection (lax.cond fails shard_map rep
-    # inference on jax 0.4.x) cannot elide a collective — so stage 1's
+    # — branchless where-selection cannot elide a collective — so stage 1's
     # distinguishing property is the accumulator LAYOUT, which is what
     # the bench's grad-bytes-per-rank A/B measures.
     k = backward_passes_per_step

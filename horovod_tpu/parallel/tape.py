@@ -21,6 +21,7 @@ allreduce exactly as the reference does.
 
 from __future__ import annotations
 
+import threading
 from typing import Optional
 
 import jax
@@ -29,6 +30,28 @@ from jax import lax
 from ..ops import collective_ops as C
 from ..ops import fusion
 from ..ops.compression import Compression
+
+
+# One gradient reducer per step. Gradients this module has reduced come
+# back replicated, and a replicated gradient is exactly what
+# DistributedOptimizer reads as autodiff's cross-rank SUM (it would divide
+# by the world a second time). Nothing in a value's type tells the two
+# apart, so the reduction leaves a note on the trace it ran in and the
+# optimizer refuses replicated gradients in that trace.
+_reduced = threading.local()
+
+
+def _note_reduced(axes) -> None:
+    if C._resolve_axes(axes):
+        _reduced.trace = jax.core.get_opaque_trace_state()
+
+
+def reduced_in_this_trace() -> bool:
+    """True when :func:`allreduce_gradients` (and so the default
+    :func:`value_and_grad`) has reduced gradients in the trace that is
+    current now."""
+    noted = getattr(_reduced, "trace", None)
+    return noted is not None and noted == jax.core.get_opaque_trace_state()
 
 
 def _pvary_tree(tree, axes_t):
@@ -75,6 +98,7 @@ def allreduce_gradients(
     (docs/wire-plan.md)."""
     if plan is not None and hasattr(plan, "gradient"):
         plan = plan.gradient  # a StepPlan: thread its gradient wire
+    _note_reduced(axes)
     return fusion.allreduce_pytree(
         grads, op=op, compression=compression,
         threshold_bytes=fusion_threshold_bytes, axes=axes,
@@ -115,12 +139,15 @@ def value_and_grad(
     the DistributedGradientTape of the JAX world
     (reference: tensorflow/__init__.py:511-576).
 
-    ``reduce=False`` still pvaries the differentiated arguments (so the
-    gradients come back as true per-rank locals instead of auto-psummed
-    fp32 sums) but skips the allreduce — the hand-off point for callers
-    that let :class:`~horovod_tpu.DistributedOptimizer` own the reduction,
-    e.g. to keep error-feedback state in the optimizer when
-    ``quantized=True``.
+    A step has ONE gradient reducer. The default (``reduce=True``) is the
+    owner here: the gradients come back replicated and reduced, ready for
+    a plain optax transformation. ``reduce=False`` still pvaries the
+    differentiated arguments (so the gradients come back as true per-rank
+    locals instead of auto-psummed fp32 sums) but skips the allreduce —
+    the hand-off that makes :class:`~horovod_tpu.DistributedOptimizer`
+    the owner. Handing the default's reduced gradients to a
+    ``DistributedOptimizer`` raises at trace time: it would read them as
+    autodiff's cross-rank sum and divide by the world a second time.
 
     ``zero`` / ``zero_stage`` (defaults: the ``HOROVOD_ZERO_STAGE`` /
     ``HOROVOD_ZERO_SHARDING`` knobs; ``zero=True`` aliases stage 2) mark

@@ -31,19 +31,19 @@ BENCH_ARGS=(--platform cpu --cpu-devices 8 --mesh-shape 2x4
     --num-warmup 1 --num-iters 2 --num-batches-per-iter 2)
 
 echo "== compile smoke: cold leg (empty cache) ==" >&2
-COLD=$(JAX_PLATFORMS=cpu HOROVOD_COMPILE_CACHE_DIR="$TMP/cache" \
+COLD=$(JAX_PLATFORMS=cpu JAX_COMPILATION_CACHE_DIR="$TMP/cache" \
     python bench.py "${BENCH_ARGS[@]}" | tail -n 1)
 echo "$COLD"
 
 echo "== compile smoke: warm leg (fresh process, populated cache) ==" >&2
-WARM=$(JAX_PLATFORMS=cpu HOROVOD_COMPILE_CACHE_DIR="$TMP/cache" \
+WARM=$(JAX_PLATFORMS=cpu JAX_COMPILATION_CACHE_DIR="$TMP/cache" \
     python bench.py "${BENCH_ARGS[@]}" | tail -n 1)
 echo "$WARM"
 
 SERVE="null"
 if [ "${COMPILE_SMOKE_SERVE:-1}" = "1" ]; then
     echo "== compile smoke: serve resize leg (background precompile vs cold rebuild) ==" >&2
-    SERVE=$(JAX_PLATFORMS=cpu HOROVOD_COMPILE_CACHE_DIR="$TMP/cache-serve" \
+    SERVE=$(JAX_PLATFORMS=cpu JAX_COMPILATION_CACHE_DIR="$TMP/cache-serve" \
         python bench.py --serve --platform cpu --cpu-devices 8 \
         --serve-requests "${COMPILE_SMOKE_SERVE_REQUESTS:-24}" \
         --serve-rate 50 | tail -n 1)

@@ -14,7 +14,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
-from jax0437_repros import _old_jax
 
 N = 8
 
@@ -83,12 +82,6 @@ def test_ragged_overflow_clamped():
     np.testing.assert_array_equal(out, exp_out)
 
 
-@pytest.mark.xfail(
-    _old_jax(), strict=False,
-    reason="upstream jax 0.4.37: grad-of-psum under old shard_map scales "
-           "gradients by the axis size — pure-jax repro: "
-           "tests/jax0437_repros.py::repro_grad_of_psum (fixed by the "
-           "jax.shard_map graduation, jax >= 0.6)")
 def test_ragged_gradient():
     # loss = psum over ranks of sum(out^2)/2  =>  dL/dx = x for delivered
     # rows, 0 for clamped-away rows (the exchange is a permutation+drop).

@@ -21,6 +21,7 @@ Covers the contract surface the CI bricks lean on:
     the strict span audit; hits emit COMPILE:CACHE_HIT instants.
 """
 
+import contextlib
 import json
 import os
 import subprocess
@@ -47,24 +48,38 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 N = 8
 
 
+@contextlib.contextmanager
+def _placed_cache(monkeypatch, path):
+    """``JAX_COMPILATION_CACHE_DIR=path`` as a fresh process would see
+    it: the variable for this repo's layers, jax's config for its own."""
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", path)
+    prev = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", path)
+    jcc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        jcc.reset_cache()
+
+
 @pytest.fixture()
 def fresh_cache(tmp_path, monkeypatch):
-    """Point the executable cache at an empty per-test directory and
+    """Place the whole compile cache in an empty per-test directory and
     zero the process counters, restoring both afterwards."""
-    monkeypatch.setenv("HOROVOD_COMPILE_CACHE_DIR", str(tmp_path))
     monkeypatch.delenv("HOROVOD_COMPILE_CACHE", raising=False)
-    # Isolate the XLA persistent cache too: an executable whose
-    # compile() was itself served from a (session-shared) XLA disk cache
-    # can serialize into a payload that will not deserialize in the same
-    # process — the registry tolerates that (cold-compile fallback), but
-    # these tests pin the clean-layer hit ladder.
-    prev_xla = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir",
-                      str(tmp_path / "xla"))
-    xcache.clear_memory()
-    xcache.reset_stats()
-    yield tmp_path
-    jax.config.update("jax_compilation_cache_dir", prev_xla)
+    # JAX took its directory from the variable at import, so the test —
+    # not the code under test — moves JAX's own layer along with it: an
+    # executable whose compile() was itself served from a session-shared
+    # XLA disk cache can serialize into a payload that will not
+    # deserialize in the same process, and these tests pin the
+    # clean-layer hit ladder.
+    with _placed_cache(monkeypatch, str(tmp_path)):
+        xcache.clear_memory()
+        xcache.reset_stats()
+        yield tmp_path
     xcache.clear_memory()
     xcache.reset_stats()
 
@@ -218,7 +233,7 @@ class TestHitLadder:
             " 'stats': cache.stats(), 'y3': float(out[3])}))\n")
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)
-        env["HOROVOD_COMPILE_CACHE_DIR"] = str(fresh_cache)
+        env["JAX_COMPILATION_CACHE_DIR"] = str(fresh_cache)
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
 
         def run():
@@ -277,7 +292,7 @@ class TestCorruptCacheTolerance:
                    for m in caplog.messages)
 
     def test_unwritable_cache_dir_still_compiles(self, monkeypatch):
-        monkeypatch.setenv("HOROVOD_COMPILE_CACHE_DIR",
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
                            "/proc/definitely/not/writable")
         xcache.clear_memory()
         r = get_or_compile("t_nodir", _lower_double())
@@ -288,20 +303,106 @@ class TestCorruptCacheTolerance:
 
 
 # ---------------------------------------------------------------------------
+# placement: where the cache lives, and which devices a disk hit lands on
+# ---------------------------------------------------------------------------
+
+
+class TestPlacement:
+    @pytest.fixture()
+    def config_updates(self, monkeypatch):
+        """Record jax.config.update calls instead of applying them, and
+        keep arming from creating directories."""
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: calls.append((k, v)))
+        monkeypatch.setattr(os, "makedirs", lambda *a, **k: None)
+        return calls
+
+    def test_env_set_names_no_other_dir(self, tmp_path, monkeypatch,
+                                        config_updates):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert arm_persistent_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in dict(config_updates)
+        # both layers (and the kernel block choices) live under it
+        from horovod_tpu.ops import kernel_autotune
+
+        monkeypatch.delenv("HOROVOD_AUTOTUNE_CACHE", raising=False)
+        assert xcache._index_path() == str(
+            tmp_path / "exec" / "index.json")
+        assert kernel_autotune._cache_path() == str(
+            tmp_path / "kernel_autotune.json")
+
+    def test_env_unset_is_the_fixed_checkout_dir(self, monkeypatch,
+                                                 config_updates):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = os.path.join(REPO, ".compile_cache")
+        assert arm_persistent_cache() == fixed
+        assert dict(config_updates)["jax_compilation_cache_dir"] == fixed
+        assert xcache._exec_dir() == os.path.join(fixed, "exec")
+
+    def test_env_unset_dir_is_identical_across_processes(self):
+        """Not a temp dir, a pid or the time: two processes agree, and
+        neither looks at the home directory."""
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env["HOME"] = "/nonexistent-home"
+        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+        script = ("from horovod_tpu.compile import cache_dir; "
+                  "print(cache_dir())")
+        seen = [subprocess.run([sys.executable, "-c", script], env=env,
+                               capture_output=True, text=True,
+                               timeout=300, check=True).stdout.strip()
+                for _ in range(2)]
+        assert seen == [os.path.join(REPO, ".compile_cache")] * 2
+
+    def test_one_device_mesh_never_hits_an_eight_device_entry(
+            self, fresh_cache):
+        """The scaling-sweep failure: on an 8-device host, a 1-chip leg
+        must neither take the 8-chip entry nor be loaded onto all 8."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from horovod_tpu.common import basics
+
+        f = jax.jit(lambda x: x * 2.0 + 1.0)
+
+        def get(mesh):
+            spec = jax.ShapeDtypeStruct(
+                (8,), jnp.float32, sharding=NamedSharding(mesh, P()))
+            return get_or_compile("t_subset", lambda: f.lower(spec),
+                                  mesh=mesh, shapes=(spec,))
+
+        devs = jax.devices()
+        m8 = basics._build_mesh(devs[:N], (2, 4))
+        # not device 0: the loader's default would put it there
+        m1 = basics._build_mesh(devs[3:4], (1, 1))
+        r8 = get(m8)
+        assert r8.source == "compiled"
+        xcache.clear_memory()
+        r1 = get(m1)
+        assert r1.key != r8.key and r1.source == "compiled"
+        xcache.clear_memory()
+        again = get(m1)
+        assert again.source == "disk"
+        x = jax.device_put(jnp.ones((8,), jnp.float32),
+                           NamedSharding(m1, P()))
+        out = again.compiled(x)
+        assert out.devices() == {devs[3]}
+        np.testing.assert_allclose(out, 3.0)
+        assert get(m8).source == "disk"
+
+
+# ---------------------------------------------------------------------------
 # arm_persistent_cache + hvd.precompile
 # ---------------------------------------------------------------------------
 
 
 class TestArmAndPrecompile:
-    def test_arm_points_jax_at_the_cache_dir(self, fresh_cache):
-        prev = jax.config.jax_compilation_cache_dir
-        try:
-            armed = arm_persistent_cache()
-            assert armed == os.path.join(str(fresh_cache), "xla")
-            assert os.path.isdir(armed)
-            assert jax.config.jax_compilation_cache_dir == armed
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
+    def test_arm_uses_the_placed_dir_for_both_layers(self, fresh_cache):
+        armed = arm_persistent_cache()
+        assert armed == str(fresh_cache) == xcache.cache_dir()
+        assert os.path.isdir(armed)
+        assert jax.config.jax_compilation_cache_dir == armed
+        assert xcache._exec_dir() == os.path.join(armed, "exec")
 
     def test_arm_respects_disable_knob(self, fresh_cache, monkeypatch):
         monkeypatch.setenv("HOROVOD_COMPILE_CACHE", "0")
@@ -414,7 +515,7 @@ class TestCompileSpans:
         from horovod_tpu.monitor import span_audit
 
         tl = str(tmp_path / "compile_tl.json")
-        monkeypatch.setenv("HOROVOD_COMPILE_CACHE_DIR",
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
                            str(tmp_path / "cc"))
         hvd.shutdown()
         os.environ["HOROVOD_TIMELINE"] = tl

@@ -23,7 +23,6 @@ from horovod_tpu.parallel.expert import (
     switch_moe_ragged,
 )
 from horovod_tpu.parallel.tensor import tp_merge_params
-from jax0437_repros import _old_jax
 
 
 def _layer_data(N=64, C=16, F=32, E=8, seed=0):
@@ -196,12 +195,6 @@ class TestSwitchMoERagged:
         expect = np.asarray(_per_token_expect(x_all, router, w1, b1, w2, b2))
         np.testing.assert_allclose(y_ragged, expect, rtol=1e-4, atol=1e-5)
 
-    @pytest.mark.xfail(
-        _old_jax(), strict=False,
-        reason="upstream jax 0.4.37: grad-of-psum under old shard_map "
-               "scales gradients by the axis size — pure-jax repro: "
-               "tests/jax0437_repros.py::repro_grad_of_psum (fixed by "
-               "the jax.shard_map graduation, jax >= 0.6)")
     def test_ragged_gradients_match_dense_no_drop(self):
         """d(loss)/d(params) through the ragged dispatch == world-1."""
         n = hvd.size()

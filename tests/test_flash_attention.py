@@ -16,7 +16,6 @@ import horovod_tpu as hvd
 from horovod_tpu.models import GPT, gpt_tiny
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel import sequence as seqpar
-from jax0437_repros import _old_jax
 
 
 def _qkv(B=1, T=128, H=2, D=32, seed=0, dtype=jnp.float32):
@@ -137,17 +136,7 @@ class TestFlashRingAttention:
     with logsumexp partial merging; backward replays the ring with dk/dv
     accumulators traveling alongside their blocks."""
 
-    @pytest.mark.parametrize("causal", [
-        True,
-        pytest.param(False, marks=pytest.mark.xfail(
-            _old_jax(), strict=False,
-            reason="upstream jax 0.4.37: axis_index over a mesh-axis "
-                   "tuple in a scan body lowers to stablehlo.partition_id"
-                   ", which the SPMD partitioner rejects (UNIMPLEMENTED) "
-                   "in the non-causal ring layout — pure-jax repro: "
-                   "tests/jax0437_repros.py::repro_partition_id (fixed "
-                   "by the jax.shard_map graduation, jax >= 0.6)")),
-    ])
+    @pytest.mark.parametrize("causal", [True, False])
     def test_matches_dense(self, causal):
         from horovod_tpu.ops.flash_attention import flash_ring_attention
 

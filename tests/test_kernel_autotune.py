@@ -70,13 +70,18 @@ class TestGetOrTune:
                              bench, (9,))
         assert out == (256,)
 
-    def test_all_failing_returns_default(self, fresh_cache, monkeypatch):
+    def test_all_failing_raises(self, fresh_cache, monkeypatch):
+        """On the chip nothing catches a kernel failure and carries on
+        with blocks nobody measured: the error names every candidate."""
         monkeypatch.setattr(at, "enabled", lambda: True)
 
         def bench(c):
-            raise RuntimeError("timing not linear")
+            raise RuntimeError("scoped vmem")
 
-        assert at.get_or_tune("k", "sig3", [(1,)], bench, (9,)) == (9,)
+        with pytest.raises(RuntimeError,
+                           match=r"ALL 1 candidates failed(.|\n)*"
+                                 r"\(1,\): RuntimeError: scoped vmem"):
+            at.get_or_tune("k", "sig3", [(1,)], bench, (9,))
         # nothing cached: a later process may succeed where this one failed
         assert not fresh_cache.exists() or "sig3" not in \
             fresh_cache.read_text()
@@ -186,6 +191,20 @@ class TestTraceTimeSweep:
             out = at.get_or_tune("k", "devsig", [(1,), (2,)], bench, (9,))
         assert out == (1,)
         assert seen and all(d is pinned for d in seen)
+
+
+@pytest.mark.parametrize("C", [768, 1024, 2048, 4096])
+def test_xent_candidates_never_exceed_the_rule(C):
+    """One source of what fits: the sweep looks at the C-derived default
+    blocks and below, never above them."""
+    from horovod_tpu.ops.flash_attention import _pick_block
+    from horovod_tpu.ops.softmax_xent import _default_blocks
+
+    default = _default_blocks(C)
+    cands = at.xent_candidates(8192, 131072, default, _pick_block)
+    assert default in cands
+    assert all(128 <= bn <= default[0] and 128 <= bv <= default[1]
+               for bn, bv in cands)
 
 
 class TestShapeGates:

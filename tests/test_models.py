@@ -133,7 +133,8 @@ def test_mnist_dp_training_step_decreases_loss():
     @jax.jit
     def step(params, opt_state, xb, yb):
         def spmd(params, opt_state, xb, yb):
-            loss, grads = hvd.value_and_grad(loss_fn)(params, xb, yb)
+            loss, grads = hvd.value_and_grad(
+                loss_fn, reduce=False)(params, xb, yb)
             updates, new_state = tx.update(grads, opt_state, params)
             return (optax.apply_updates(params, updates), new_state,
                     hvd.allreduce(loss))

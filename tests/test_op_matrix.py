@@ -43,11 +43,7 @@ def _is64(dtype):
 def _ctx(dtype):
     if not _is64(dtype):
         return contextlib.nullcontext()
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(True)
-    from jax.experimental import enable_x64  # jax < 0.6 spelling
-
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def spmd(f, in_specs, out_specs):

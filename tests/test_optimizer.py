@@ -13,7 +13,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
-from jax0437_repros import _old_jax
 
 N = 8
 
@@ -125,15 +124,6 @@ def test_predivide_requires_average():
                                  gradient_predivide_factor=2.0)
 
 
-@pytest.mark.xfail(
-    _old_jax(), strict=False,
-    reason="upstream jax 0.4.37: optax.MultiSteps selects its accumulate/"
-           "apply arms with lax.cond, whose mixed-replication branches "
-           "fail old shard_map's rep checker — pure-jax repro: "
-           "tests/jax0437_repros.py::repro_cond_rep_mismatch (fixed by "
-           "the jax.shard_map graduation, jax >= 0.6; overlap=True uses "
-           "the branchless _overlap_multi_steps accumulator, which "
-           "traces fine — see test_overlap.py)")
 def test_backward_passes_per_step_accumulates():
     # k accumulation steps at lr then one apply ≈ one step on the averaged
     # grads (reference: torch/optimizer.py:133-149). With SGD the result
@@ -166,6 +156,46 @@ def test_value_and_grad_allreduces():
                         in_specs=(P(), P(hvd.HVD_AXES)),
                         out_specs=P())(jnp.ones(3), jnp.asarray(xs))
     np.testing.assert_allclose(np.asarray(out), xs.mean(0), rtol=1e-5)
+
+
+def _one_sgd_update(tx, **tape_kw):
+    """One sgd(1.0) update of zeros(3) on f(p, x) = sum(p * x), rank r
+    holding row r of xs: the right answer is -mean(xs, 0)."""
+    xs = np.arange(N * 3, dtype=np.float32).reshape(N, 3)
+    p = jnp.zeros(3)
+
+    def spmd(p, s, x):
+        _, g = hvd.value_and_grad(
+            lambda p, x: jnp.sum(p * x), **tape_kw)(p, x[0])
+        return tx.update(g, s, p)[0]
+
+    out = jax.jit(hvd.shard_map(
+        spmd, mesh=hvd.mesh(), in_specs=(P(), P(), P(hvd.HVD_AXES)),
+        out_specs=P()))(p, tx.init(p), jnp.asarray(xs))
+    return np.asarray(out), -xs.mean(0)
+
+
+@pytest.mark.parametrize("pairing", ["optimizer_owns", "tape_owns"])
+def test_one_reducer_gives_the_mean_update(pairing):
+    # Either owner alone averages exactly once over the 8 ranks.
+    if pairing == "optimizer_owns":
+        out, want = _one_sgd_update(
+            hvd.DistributedOptimizer(optax.sgd(1.0)), reduce=False)
+    else:
+        out, want = _one_sgd_update(optax.sgd(1.0))
+    np.testing.assert_allclose(out, want, rtol=1e-6)
+
+
+@pytest.mark.parametrize("opt_kw", [
+    {}, {"backward_passes_per_step": 2}, {"zero": True},
+    {"overlap": True, "backward_passes_per_step": 2}],
+    ids=["plain", "multisteps", "zero", "overlap_multisteps"])
+def test_two_reducers_raise_at_trace_time(opt_kw):
+    # The reducing tape's averages handed to DistributedOptimizer would be
+    # divided by the world a second time (update / 8 here, silently).
+    tx = hvd.DistributedOptimizer(optax.sgd(1.0), **opt_kw)
+    with pytest.raises(ValueError, match="Keep one reducer"):
+        _one_sgd_update(tx)
 
 
 def test_distributed_gradient_tape_shim():

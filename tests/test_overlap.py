@@ -216,10 +216,7 @@ def test_overlap_zero_bit_identical():
 def test_overlap_backward_passes_double_buffer():
     """overlap × backward_passes_per_step=2 (replicated path): the
     double-buffered accumulator — k microbatches then one apply — matches
-    one step on the concatenated batch. This composition has no
-    MultiSteps equivalent on jax 0.4.x (cond rep mismatch, see
-    tests/jax0437_repros.py::repro_cond_rep_mismatch): the branchless
-    overlap accumulator is what makes it trace at all."""
+    one step on the concatenated batch."""
     rng = np.random.RandomState(4)
     x, y = make_data(rng)
     tx = hvd.DistributedOptimizer(optax.sgd(0.1), overlap=True,

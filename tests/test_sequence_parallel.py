@@ -164,7 +164,8 @@ class TestGPTSequenceParallel:
                 logits, tgt).mean()
 
         def spmd(params, opt_state, tok, tgt):
-            loss, grads = hvd.value_and_grad(loss_fn)(params, tok, tgt)
+            loss, grads = hvd.value_and_grad(
+                loss_fn, reduce=False)(params, tok, tgt)
             updates, new_state = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             loss = hvd.allreduce(loss)

@@ -155,8 +155,8 @@ class TestTPGPT:
             local = tp_merge_params(
                 jax.tree.map(lambda a: a[0], stk), rp)
             opt_state = tx.init(local)
-            loss, grads = hvd.value_and_grad(loss_fn, axes=hvd.CROSS_AXIS)(
-                local, tok, tgt)
+            loss, grads = hvd.value_and_grad(
+                loss_fn, axes=hvd.CROSS_AXIS, reduce=False)(local, tok, tgt)
             updates, _ = tx.update(grads, opt_state, local)
             new_local = optax.apply_updates(local, updates)
             new_qkv = new_local["h0"]["attn"]["qkv"]["kernel"]

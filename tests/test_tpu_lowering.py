@@ -1,159 +1,287 @@
-"""Cross-platform TPU lowering checks for every Pallas kernel.
+"""The main-path kernels, compiled by the TPU compiler for a described v5e.
 
 Interpreter-mode tests (the rest of the suite) verify kernel *numerics*
-but never run Mosaic's lowering-time legality checks — block shapes whose
-last two dims are neither (8, 128)-divisible nor equal to the array dims
-lower fine in interpreter mode and then fail on real hardware at compile
-time. That is exactly how the fused-LN backward's per-block ``(1, C)``
-dgamma/dbeta outputs survived a full CPU suite and died in the round-5
-hardware session (BENCH_r05_sweep/gpt350m_fusedln.log).
+but run none of the TPU compiler: a block shape Mosaic rejects, or a
+kernel that wants more scoped VMEM than a v5e grants (16 MiB), passes
+every one of them and fails the first time a chip sees it. libtpu is
+installed here and compiles for a chip that is described, not attached
+(/opt/skills/guides/on-chip-measurement, section 2), so these tests ask it
+— at the widths chip_smoke.py and bench.py run, about two seconds a case,
+no chip time. Nothing executes: a compile that passes is not a chip run.
 
-These tests force the non-interpreter kernels and AOT-lower for the
-``tpu`` platform on the CPU host (no device needed): the Mosaic lowering
-rule — including ``_check_block_mappings`` — runs during StableHLO
-lowering, so an illegal BlockSpec fails HERE, one round before hardware.
-Execution is NOT attempted (that needs a chip); legality is the contract.
+All of them live in THIS file, and the topology is described inside a
+module-scoped fixture, never at import: only one process at a time may
+load libtpu, pytest-xdist imports every test file in every worker, and the
+worker that is handed this file is the one that loads it.
 """
-
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
-jax.config.update("jax_platforms", "cpu")
+import horovod_tpu as hvd
 
 
-def _lower_tpu(fn, *args):
-    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+class _DescribedTpu:
+    """A described ``v5e:2x2``: shapes placed on its chips, and compiles."""
+
+    def __init__(self, topo):
+        self.one_chip = SingleDeviceSharding(topo.devices[0])
+        self.mesh = Mesh(np.array(topo.devices).reshape(2, 2), hvd.HVD_AXES)
+
+    def on_chip(self, tree):
+        """``tree``'s shapes (arrays or ShapeDtypeStructs) on chip 0."""
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=self.one_chip), tree)
+
+    def shape(self, shape, dtype, spec=None):
+        sharding = (self.one_chip if spec is None
+                    else NamedSharding(self.mesh, spec))
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    @staticmethod
+    def compile(fn, *args):
+        """Raises what the chip's compiler would raise."""
+        return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.fixture(scope="module")
+def tpu():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but can never be read back without the chip: keep it out.
+    with hvd.compile.persistent_cache_disabled():
+        yield _DescribedTpu(topo)
 
 
 @pytest.fixture()
 def real_kernels(monkeypatch):
-    """Force interpret=False so Mosaic lowering (and its block-mapping
-    legality checks) actually runs."""
+    """``interpret=False``: the kernels' own backend test sees this
+    process's CPU and would hand Mosaic nothing to compile."""
     import horovod_tpu.ops.flash_attention as F
+    import horovod_tpu.ops.fused_collective as FC
     import horovod_tpu.ops.layer_norm as L
     import horovod_tpu.ops.softmax_xent as X
 
-    monkeypatch.setattr(F, "_interpret", lambda: False)
-    monkeypatch.setattr(L, "_interpret", lambda: False)
-    monkeypatch.setattr(X, "_interpret", lambda: False)
-    yield
+    for mod in (F, FC, L, X):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("B,T,H,D", [
+    (8, 1024, 16, 64),     # GPT-350M train step (chip_smoke.py)
+    (16, 1024, 12, 64),    # GPT-124M bench shape
+    (1, 8192, 12, 64),     # examples/gpt_long_context.py
+])
+def test_flash_attention_compiles(tpu, real_kernels, B, T, H, D):
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    q = tpu.shape((B, T, H, D), jnp.bfloat16)
+
+    def f(q, k, v):
+        return jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    assert _has_kernel(tpu.compile(f, q, q, q))
 
 
 @pytest.mark.parametrize("B,T,C", [
-    (8, 1024, 1024),   # the round-5 hardware failure shape (350M blocks)
+    (8, 1024, 1024),   # 350M blocks: the shape Mosaic once refused
     (16, 1024, 768),   # 124M bench shape
     (1, 7, 256),       # N < 8 rows: single whole-array block
     (2, 300, 512),     # N not a block multiple: padded rows
 ])
-def test_ln_residual_lowers_for_tpu(real_kernels, B, T, C):
+def test_ln_residual_compiles(tpu, real_kernels, B, T, C):
     from horovod_tpu.ops.layer_norm import ln_residual
 
-    x = jnp.zeros((B, T, C), jnp.bfloat16)
-    g = jnp.ones((C,), jnp.float32)
-    b = jnp.zeros((C,), jnp.float32)
+    x = tpu.shape((B, T, C), jnp.bfloat16)
+    g = tpu.shape((C,), jnp.float32)
 
     def f(x, r, g, b):
-        def loss(x):
+        def loss(x, r, g, b):
             y, h = ln_residual(x, r, g, b, 1e-6)
             return y.astype(jnp.float32).sum() + h.astype(jnp.float32).sum()
 
-        return jax.grad(loss)(x)
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(x, r, g, b)
 
-    _lower_tpu(f, x, x, g, b)
+    assert _has_kernel(tpu.compile(f, x, x, g, g))
 
 
-@pytest.mark.parametrize("B,T,H,D,blocks", [
-    (16, 1024, 12, 64, None),      # 124M bench shape, default blocks
-    (8, 1024, 16, 64, None),       # 350M bench shape
-    (2, 1024, 4, 64, (512, 512)),  # explicit non-default blocking
-    (1, 384, 4, 128, None),        # whole-sequence single block
+@pytest.mark.parametrize("entry,N,V,C", [
+    ("auto", 32768, 131072, 768),    # the envelope: dense logits = 17 GB
+    ("fused", 8192, 32000, 1024),    # GPT-350M head, bench --lm-loss fused
+    ("fused", 8192, 50304, 2048),    # ROADMAP R1 width
+    ("public", 8192, 32000, 768),
+    ("public", 8192, 131072, 1024),  # widest vocab block the snap allows
+    ("public", 8192, 131072, 2048),
 ])
-def test_flash_attention_lowers_for_tpu(real_kernels, B, T, H, D, blocks):
-    from horovod_tpu.ops.flash_attention import flash_attention
+def test_lm_head_compiles_at_every_width(tpu, real_kernels, entry, N, V, C):
+    """The backward's scoped VMEM grows with block·C: the default blocks
+    are derived from C (ops/softmax_xent.py:_default_blocks), and every
+    entry point compiles at C in {768, 1024, 2048} with no knob set."""
+    x = tpu.shape((N, C), jnp.bfloat16)
+    w = tpu.shape((V, C), jnp.bfloat16)
+    y = tpu.shape((N,), jnp.int32)
 
-    q = jnp.zeros((B, T, H, D), jnp.bfloat16)
-    kw = {}
-    if blocks is not None:
-        kw = {"block_q": blocks[0], "block_k": blocks[1]}
-
-    def f(q, k, v):
-        def loss(q):
-            return flash_attention(q, k, v, causal=True,
-                                   **kw).astype(jnp.float32).sum()
-
-        return jax.grad(loss)(q)
-
-    _lower_tpu(f, q, q, q)
-
-
-@pytest.mark.parametrize("N,V,C", [
-    (1024, 32000, 768),    # bench LM head
-    (512, 1000, 256),      # small head
-])
-def test_linear_cross_entropy_lowers_for_tpu(real_kernels, N, V, C):
-    from horovod_tpu.ops.softmax_xent import linear_cross_entropy
-
-    x = jnp.zeros((N, C), jnp.bfloat16)
-    w = jnp.zeros((V, C), jnp.bfloat16)
-    y = jnp.zeros((N,), jnp.int32)
+    def head(x, w, y):
+        if entry == "public":
+            return hvd.linear_cross_entropy(x, w, y)
+        return hvd.lm_head_loss(x, w, y, mode=entry)
 
     def f(x, w, y):
-        def loss(x):
-            return linear_cross_entropy(x, w, y).mean()
+        return jax.grad(lambda x, w: head(x, w, y).mean(),
+                        argnums=(0, 1))(x, w)
 
-        return jax.grad(loss)(x)
-
-    _lower_tpu(f, x, w, y)
+    assert _has_kernel(tpu.compile(f, x, w, y))
 
 
-def test_quantized_allreduce_lowers_for_tpu():
-    """The quantized collective path AOT-lowers for the tpu platform: the
-    int8 all_to_all (hop 2), the masked int8 psum (hop 3), and the
-    round/clip/convert quantize math must all have TPU lowerings — checked
-    here, one round before hardware (the round-5 fused-LN lesson)."""
-    import numpy as np
-    from jax.sharding import Mesh, PartitionSpec as P
+@pytest.mark.parametrize("N,V,C", [(8192, 32000, 1024),
+                                   (8192, 50304, 2048)])
+def test_every_block_the_sweep_may_pick_compiles(tpu, real_kernels,
+                                                 N, V, C):
+    """On a chip the blocks come from the sweep, not from the default
+    (the sweep is off here: the backend is the CPU). Its candidates are
+    the default and halves of it — none above the rule — and each one
+    compiles, so whatever wins the timing is a block the chip accepts."""
+    from horovod_tpu.ops import kernel_autotune
+    from horovod_tpu.ops.softmax_xent import _default_blocks, _pick_block
 
-    import horovod_tpu as hvd
+    default = _default_blocks(C)
+    cands = kernel_autotune.xent_candidates(N, V, default, _pick_block)
+    assert default in cands and len(cands) == 4
+    assert all(bn <= default[0] and bv <= default[1] for bn, bv in cands)
+    x = tpu.shape((N, C), jnp.bfloat16)
+    w = tpu.shape((V, C), jnp.bfloat16)
+    y = tpu.shape((N,), jnp.int32)
+    for bn, bv in cands:
+        if (bn, bv) == default:
+            continue   # test_lm_head_compiles_at_every_width
+        assert _has_kernel(tpu.compile(
+            lambda x, w, y: jax.grad(
+                lambda x, w: hvd.linear_cross_entropy(
+                    x, w, y, block_n=bn, block_v=bv).mean(),
+                argnums=(0, 1))(x, w), x, w, y)), (bn, bv)
 
-    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), hvd.HVD_AXES)
+
+def test_fixed_1024_row_block_is_what_the_rule_avoids(tpu, real_kernels):
+    """The old constant default, pinned by hand: refused, scoped VMEM."""
+    x = tpu.shape((8192, 1024), jnp.bfloat16)
+    w = tpu.shape((32000, 1024), jnp.bfloat16)
+    y = tpu.shape((8192,), jnp.int32)
+
+    def f(x, w, y):
+        return jax.grad(lambda x: hvd.linear_cross_entropy(
+            x, w, y, block_n=1024, block_v=1024).mean())(x)
+
+    with pytest.raises(Exception, match="(?i)vmem"):
+        tpu.compile(f, x, w, y)
+
+
+def test_fused_ln_gpt_block_compiles(tpu, real_kernels):
+    """The composition a chip runs: full fwd+bwd of fused-LN GPT blocks at
+    d_model 1024 (flash attention + ln_residual in one program)."""
+    from horovod_tpu.models import GPT, GPTConfig
+
+    cfg = GPTConfig(vocab_size=1024, num_layers=2, num_heads=16,
+                    d_model=1024, d_ff=4096, max_seq_len=1024,
+                    attention="flash", fused_ln=True)
+    model = GPT(cfg)
+    tokens = tpu.shape((8, 1024), jnp.int32)
+    params = tpu.on_chip(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 1024), jnp.int32)))["params"])
+
+    def f(p, tokens):
+        return jax.grad(lambda p: model.apply(
+            {"params": p}, tokens).astype(jnp.float32).mean())(p)
+
+    assert _has_kernel(tpu.compile(f, params, tokens))
+
+
+def test_quantized_allreduce_compiles_over_four_chips(tpu):
+    """The int8 all_to_all (hop 2), the masked int8 psum (hop 3) and the
+    round/clip/convert quantize math, partitioned over the 2x2 mesh."""
 
     def f(x, r):
         def spmd(v, res):
             out, nr = hvd.quantized_allreduce(v[0], res[0], op=hvd.Sum)
             return out, nr[None]
 
-        return hvd.shard_map(spmd, mesh=mesh,
+        return hvd.shard_map(spmd, mesh=tpu.mesh,
                              in_specs=(P(hvd.HVD_AXES), P(hvd.HVD_AXES)),
                              out_specs=(P(), P(hvd.HVD_AXES)))(x, r)
 
-    x = jnp.zeros((8, 1024), jnp.float32)
-    _lower_tpu(f, x, x)
+    x = tpu.shape((4, 1024), jnp.float32, P(hvd.HVD_AXES))
+    text = tpu.compile(f, x, x).as_text()
+    assert "all-to-all" in text and "s8[" in text
 
 
-def test_fused_ln_gpt_block_lowers_for_tpu(real_kernels):
-    """The composition that actually failed on hardware: a fused-LN GPT
-    block's full fwd+bwd (flash attention + ln_residual together)."""
-    from horovod_tpu.models import GPT, gpt_tiny
+# -- ops/fused_collective.py: opt-in (fused=), never on the default path.
+#    Its kernels keep whole operands in VMEM, so they compile at toy sizes
+#    only; ROADMAP S7 decides whether they are tiled or removed.
 
-    cfg = gpt_tiny(attention="flash", fused_ln=True, max_seq_len=512)
-    model = GPT(cfg)
-    tokens = jnp.zeros((2, 512), jnp.int32)
-    # Abstract init: eager execution would run the forced non-interpret
-    # kernels on the CPU backend; shapes are all lowering needs.
-    params = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), tokens))["params"]
 
-    def f(p, tokens):
-        def loss(p):
-            return model.apply({"params": p},
-                               tokens).astype(jnp.float32).mean()
+def _fused_case(tpu, kernel, *dims):
+    import horovod_tpu.ops.fused_collective as FC
 
-        return jax.grad(loss)(p)
+    if kernel == "matmul_accumulate":
+        m, K, N = dims
+        return FC._matmul_accumulate, (
+            tpu.shape((m, K), jnp.bfloat16), tpu.shape((K, N), jnp.bfloat16),
+            tpu.shape((m, N), jnp.bfloat16))
+    if kernel == "quantize_blockwise":
+        return FC.quantize_blockwise, (tpu.shape(dims, jnp.float32),)
+    return FC.dequantize_accumulate, (tpu.shape(dims, jnp.int8),
+                                      tpu.shape(dims[:2], jnp.float32))
 
-    _lower_tpu(f, params, tokens)
+
+@pytest.mark.parametrize("kernel,dims", [
+    ("matmul_accumulate", (256, 1024, 1024)),
+    ("quantize_blockwise", (4, 1024, 256)),
+    ("dequantize_accumulate", (4, 1024, 256)),
+])
+def test_fused_collective_compiles_at_toy_size(tpu, real_kernels, kernel,
+                                               dims):
+    fn, args = _fused_case(tpu, kernel, *dims)
+    assert _has_kernel(tpu.compile(fn, *args))
+
+
+@pytest.mark.parametrize("kernel,dims", [
+    ("matmul_accumulate", (2048, 1024, 4096)),   # one 350M MLP tile
+    ("quantize_blockwise", (4, 16384, 256)),     # a 64 MiB gradient bucket
+    ("dequantize_accumulate", (4, 16384, 256)),
+])
+def test_fused_collective_refuses_real_sizes_by_name(tpu, real_kernels,
+                                                     kernel, dims):
+    """What the compiler refuses (RESOURCE_EXHAUSTED, after up to 154 s)
+    the opt-in refuses at trace time, saying why."""
+    fn, args = _fused_case(tpu, kernel, *dims)
+    with pytest.raises(ValueError, match=rf"{kernel}.*ROADMAP S7"):
+        tpu.compile(fn, *args)
+
+
+@pytest.mark.xfail(strict=True, raises=ValueError,
+                   reason="ROADMAP S7: _matmul_accumulate holds the whole "
+                          "[m, N] tile + fp32 scratch in VMEM; 2048x1024 @ "
+                          "1024x4096 needs 76 MiB of 16 — tile it or "
+                          "remove fused=")
+def test_fused_matmul_compiles_at_a_real_size(tpu, real_kernels):
+    fn, args = _fused_case(tpu, "matmul_accumulate", 2048, 1024, 4096)
+    tpu.compile(fn, *args)
