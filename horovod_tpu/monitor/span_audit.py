@@ -44,6 +44,24 @@ KNOWN_PREFIXES = frozenset({
     "COMPILE",     # executable-cache lower/compile/hit (docs/compile.md)
 })
 
+#: The ``jax.named_scope`` vocabulary of the compiled step: each name is
+#: opened at one place in ``parallel/`` or ``ops/`` and lands in the
+#: ``op_name`` of every HLO op traced under it, which the device trace
+#: carries (docs/observability.md "Scopes in the device trace"). Scopes
+#: nest; JAX adds the direction (``jvp(`` / ``transpose(``) itself. The
+#: Pallas kernels' ``name=`` are ``hvd_<kernel>`` beside them in ``ops/``.
+DEVICE_SCOPES = (
+    "hvd.grad",              # parallel/tape.py: the user's loss, fwd + bwd
+    "hvd.lm_head_loss",      # ops/softmax_xent.py: head matmul + xent
+    "hvd.flash_attention",   # ops/flash_attention.py: kernels + layout
+    "hvd.layer_norm",        # ops/layer_norm.py: fused residual + LN
+    "hvd.allreduce_grads",   # parallel/optimizer.py, tape.py: grad exchange
+    "hvd.bucket_pack",       # ops/fusion.py: leaves -> flat bucket
+    "hvd.bucket_allreduce",  # ops/fusion.py: the per-bucket wire op
+    "hvd.bucket_unpack",     # ops/fusion.py: flat bucket -> leaves
+    "hvd.optimizer_update",  # parallel/optimizer.py: the wrapped optax tx
+)
+
 
 def event_prefix(name: str) -> str:
     """The vocabulary prefix of an event name (the part before the

@@ -272,6 +272,7 @@ def _flash_fwd(q, k, v, scale, causal, bq, bk, q_off=0, k_off=0,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="hvd_flash_fwd",
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v)
 
 
@@ -396,6 +397,7 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="hvd_flash_bwd_dq",
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v, do, lse, delta)
 
 
@@ -435,6 +437,7 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="hvd_flash_bwd_dkv",
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v, do, lse, delta)
 
 
@@ -697,8 +700,10 @@ def flash_ring_attention(q, k, v, *, axis, causal: bool = True,
         return ring_attention(q, k, v, axis=axis, causal=causal,
                               scale=scale)
     scale_f = float(scale) if scale is not None else D ** -0.5
-    o = _ring(_pack(q), _pack(k), _pack(v), axis, scale_f, causal, bq, bk)
-    return _unpack(o, B, H)
+    with jax.named_scope("hvd.flash_attention"):
+        o = _ring(_pack(q), _pack(k), _pack(v), axis, scale_f, causal,
+                  bq, bk)
+        return _unpack(o, B, H)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -740,6 +745,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
         return dense_attention(q, k, v, causal=causal, scale=scale)
     scale = float(scale) if scale is not None else D ** -0.5
 
-    qp, kp, vp = _harmonize_vma(_pack(q), _pack(k), _pack(v))
-    o = _flash(qp, kp, vp, scale, causal, bq, bk)
-    return jnp.transpose(o.reshape(B, H, Tq, D), (0, 2, 1, 3))
+    # Outside the custom_vjp call, so that the backward kernels and the
+    # [B, T, H, D] <-> [BH, T, D] layout traffic carry the scope too.
+    with jax.named_scope("hvd.flash_attention"):
+        qp, kp, vp = _harmonize_vma(_pack(q), _pack(k), _pack(v))
+        o = _flash(qp, kp, vp, scale, causal, bq, bk)
+        return jnp.transpose(o.reshape(B, H, Tq, D), (0, 2, 1, 3))

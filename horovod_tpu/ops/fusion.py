@@ -200,15 +200,17 @@ def pack(bucket: Bucket, leaves: Sequence[jax.Array]) -> jax.Array:
     traced concatenate that XLA fuses). A zero-size leaf still owns its
     min-1 slot in the plan (plan_buckets), so it packs as slot padding."""
     flat = []
-    for i, size in zip(bucket.leaf_indices, bucket.sizes):
-        v = jnp.ravel(jnp.asarray(leaves[i]))
-        if v.shape[0] < size:  # zero-size leaf: fill its min-1 slot
-            v = jnp.zeros((size,), dtype=v.dtype)
-        flat.append(v)
-    buf = jnp.concatenate(flat) if len(flat) > 1 else flat[0]
-    pad = bucket.padded_size - buf.shape[0]
-    if pad:
-        buf = jnp.concatenate([buf, jnp.zeros((pad,), dtype=buf.dtype)])
+    with jax.named_scope("hvd.bucket_pack"):
+        for i, size in zip(bucket.leaf_indices, bucket.sizes):
+            v = jnp.ravel(jnp.asarray(leaves[i]))
+            if v.shape[0] < size:  # zero-size leaf: fill its min-1 slot
+                v = jnp.zeros((size,), dtype=v.dtype)
+            flat.append(v)
+        buf = jnp.concatenate(flat) if len(flat) > 1 else flat[0]
+        pad = bucket.padded_size - buf.shape[0]
+        if pad:
+            buf = jnp.concatenate([buf,
+                                   jnp.zeros((pad,), dtype=buf.dtype)])
     return buf
 
 
@@ -216,10 +218,11 @@ def unpack(bucket: Bucket, buf: jax.Array) -> List[jax.Array]:
     """Split a fused buffer back into leaves (MemcpyOutFusionBuffer)."""
     out = []
     off = 0
-    for size, shape in zip(bucket.sizes, bucket.shapes):
-        n = int(np.prod(shape, dtype=np.int64))  # real elems (slot >= 1)
-        out.append(jnp.reshape(buf[off:off + n], shape))
-        off += size
+    with jax.named_scope("hvd.bucket_unpack"):
+        for size, shape in zip(bucket.sizes, bucket.shapes):
+            n = int(np.prod(shape, dtype=np.int64))  # real elems (slot >= 1)
+            out.append(jnp.reshape(buf[off:off + n], shape))
+            off += size
     return out
 
 
@@ -407,31 +410,32 @@ def allreduce_pytree(
                 buf = pack(bucket, vleaves)
                 use_ef = (new_ef is not None
                           and jnp.issubdtype(bucket.dtype, jnp.floating))
-                if use_ef:
-                    rbuf = pack(bucket, v_ef)
-                    if overlap_on:
-                        red, rnew = C.allreduce_stream(
-                            buf, rbuf, bucket_id=j, op=op,
-                            compression=compression, axes=axes,
-                            prescale_factor=prescale_factor,
-                            postscale_factor=postscale_factor, block=block,
-                            fused=fused, plan=plan)
+                rbuf = pack(bucket, v_ef) if use_ef else None
+                with jax.named_scope("hvd.bucket_allreduce"):
+                    if use_ef:
+                        if overlap_on:
+                            red, rnew = C.allreduce_stream(
+                                buf, rbuf, bucket_id=j, op=op,
+                                compression=compression, axes=axes,
+                                prescale_factor=prescale_factor,
+                                postscale_factor=postscale_factor,
+                                block=block, fused=fused, plan=plan)
+                        else:
+                            red, rnew = C.quantized_allreduce(
+                                buf, rbuf, op=op, compression=compression,
+                                axes=axes, prescale_factor=prescale_factor,
+                                postscale_factor=postscale_factor,
+                                block=block, fused=fused, plan=plan)
                     else:
-                        red, rnew = C.quantized_allreduce(
-                            buf, rbuf, op=op, compression=compression,
-                            axes=axes, prescale_factor=prescale_factor,
-                            postscale_factor=postscale_factor, block=block,
-                            fused=fused, plan=plan)
-                else:
-                    rnew = None
-                    kw = dict(op=op, compression=compression, axes=axes,
-                              hierarchical=hierarchical,
-                              prescale_factor=prescale_factor,
-                              postscale_factor=postscale_factor,
-                              quantized=quantized, block=block,
-                              fused=fused, plan=plan)
-                    red = (C.allreduce_stream(buf, bucket_id=j, **kw)
-                           if overlap_on else C.allreduce(buf, **kw))
+                        rnew = None
+                        kw = dict(op=op, compression=compression, axes=axes,
+                                  hierarchical=hierarchical,
+                                  prescale_factor=prescale_factor,
+                                  postscale_factor=postscale_factor,
+                                  quantized=quantized, block=block,
+                                  fused=fused, plan=plan)
+                        red = (C.allreduce_stream(buf, bucket_id=j, **kw)
+                               if overlap_on else C.allreduce(buf, **kw))
                 issued.append((j, red, rnew))
             # Unpack AFTER the whole flight is issued: no consumer sits
             # between in-flight collectives, so the scheduler may run

@@ -120,7 +120,6 @@ def _bwd_kernel(h_ref, g_ref, dy_ref, dh_ref, dx_ref, dg_ref, db_ref,
         db_ref[...] = db_scr[...]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def ln_residual(x, res, gamma, beta, eps: float = 1e-5,
                 block_rows: int = _DEF_BLOCK_ROWS):
     """``h = x + res;  y = LN(h) * gamma + beta`` in one fused pass.
@@ -132,8 +131,14 @@ def ln_residual(x, res, gamma, beta, eps: float = 1e-5,
     Returns ``(y, h)`` — ``y`` in the stream dtype, ``h`` the updated
     residual stream (what the unfused pattern's add produces).
     """
-    y, h = _fwd_impl(x, res, gamma, beta, eps, block_rows)
-    return y, h
+    # Outside the custom_vjp call, so that the backward carries the scope.
+    with jax.named_scope("hvd.layer_norm"):
+        return _ln_residual(x, res, gamma, beta, eps, block_rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _ln_residual(x, res, gamma, beta, eps, block_rows):
+    return _fwd_impl(x, res, gamma, beta, eps, block_rows)
 
 
 def _flatten(a):
@@ -167,6 +172,7 @@ def _fwd_impl(x, res, gamma, beta, eps, block_rows):
         out_shape=[_out_struct((Np, C), x.dtype, x2, r2),
                    _out_struct((Np, C), x.dtype, x2, r2)],
         interpret=_interpret(),
+        name="hvd_ln_fwd",
     )(x2, r2, g2, b2)
     return y[:N].reshape(orig_shape), h[:N].reshape(orig_shape)
 
@@ -211,6 +217,7 @@ def _vjp_bwd(eps, block_rows, residuals, cts):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
+        name="hvd_ln_bwd",
     )(h2, g2, dy2, dh2)
     dx = dx[:N].reshape(orig_shape)
     dgamma = dgp[0].astype(gamma.dtype)
@@ -219,4 +226,4 @@ def _vjp_bwd(eps, block_rows, residuals, cts):
     return dx, dx, dgamma, dbeta
 
 
-ln_residual.defvjp(_vjp_fwd, _vjp_bwd)
+_ln_residual.defvjp(_vjp_fwd, _vjp_bwd)

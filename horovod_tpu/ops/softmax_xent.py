@@ -200,6 +200,7 @@ def _xent_fwd(x, w, labels8, bn, bv):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="hvd_xent_fwd",
     )(x, w, labels8)
     return loss8[:, 0], lse8
 
@@ -224,6 +225,7 @@ def _xent_bwd(x, w, labels8, lse8, g8, bn, bv):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="hvd_xent_bwd_dx",
     )(x, w, labels8, lse8, g8)
 
     dw = pl.pallas_call(
@@ -242,6 +244,7 @@ def _xent_bwd(x, w, labels8, lse8, g8, bn, bv):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="hvd_xent_bwd_dw",
     )(x, w, labels8, lse8, g8)
     return dx, dw
 
@@ -368,6 +371,7 @@ def lm_head_loss(x, w, labels, *, mode: str = "auto"):
         budget = float(os.environ.get(
             "HOROVOD_XENT_AUTO_LOGITS_GB", "10")) * 2 ** 30
         use_fused = N * w.shape[0] * 4.0 > budget
-    if use_fused:
-        return linear_cross_entropy(x, w, labels)
-    return _dense_xent(x, w, labels)
+    with jax.named_scope("hvd.lm_head_loss"):
+        if use_fused:
+            return linear_cross_entropy(x, w, labels)
+        return _dense_xent(x, w, labels)

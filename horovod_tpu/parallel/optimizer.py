@@ -101,33 +101,24 @@ def _reject_reduced_gradients(leaves, axes) -> None:
             "transformation.")
 
 
-def _with_step_marker(tx, axes=None):
-    """Host-side step markers around a DistributedOptimizer's update.
+def _with_update_guard(tx, axes=None):
+    """The checks every DistributedOptimizer flavour makes on ``update``.
 
-    When ``update`` runs eagerly (the host path / process-world mode)
-    each call IS one optimizer step: bracket it with
-    ``jax.profiler.StepTraceAnnotation`` — the device-trace step marker
-    that ``hvd.profile_window`` and the serve engine also use, so host
-    steps line up with device activity in a ``jax.profiler`` trace — and
-    count it in the metrics registry. Inside a trace (the compiled path,
-    where the annotation would mark the single retrace rather than the
-    steps) only the ``optimizer.update_traces`` counter advances; the
-    per-step markers there come from :func:`hvd.profile_window`.
+    Inside a trace (the compiled path) the one-reducer-per-step check
+    runs (:func:`_reject_reduced_gradients`). When ``update`` runs eagerly
+    (the host path / process-world mode) each call IS one optimizer step
+    and is counted in the metrics registry (``optimizer.steps``). Device-
+    trace step markers come from :func:`hvd.profile_window` on both paths.
     """
     inner_update = tx.update
-    step_no = [0]
 
     def update(grads, state, params=None, **extra):
         leaves = jax.tree.leaves(grads)
         if leaves and isinstance(leaves[0], jax.core.Tracer):
             _reject_reduced_gradients(leaves, axes)
-            _metrics.counter("optimizer.update_traces").inc()
-            return inner_update(grads, state, params, **extra)
-        step_no[0] += 1
-        _metrics.counter("optimizer.steps").inc()
-        with jax.profiler.StepTraceAnnotation("hvd_step",
-                                              step_num=step_no[0]):
-            return inner_update(grads, state, params, **extra)
+        else:
+            _metrics.counter("optimizer.steps").inc()
+        return inner_update(grads, state, params, **extra)
 
     return optax.GradientTransformationExtraArgs(tx.init, update)
 
@@ -342,7 +333,8 @@ def _overlap_multi_steps(
             pend_next = jax.tree.map(
                 lambda g_: jnp.where(is_last, jnp.zeros_like(g_), g_),
                 grads)
-        upd, inner_new = inner.update(red, state.inner, params, **extra)
+        with jax.named_scope("hvd.optimizer_update"):
+            upd, inner_new = inner.update(red, state.inner, params, **extra)
         updates = jax.tree.map(
             lambda u: jnp.where(is_last, u, jnp.zeros_like(u)), upd)
         inner_next = jax.tree.map(
@@ -565,7 +557,7 @@ def DistributedOptimizer(
             raise ValueError(
                 f"zero=True supports op=Average/Sum (a reduce-scatter of "
                 f"{op} has no decomposition), got {op}")
-        return _with_step_marker(_build_zero_transform(
+        return _with_update_guard(_build_zero_transform(
             optimizer,
             compression=compression,
             op=op,
@@ -599,31 +591,32 @@ def DistributedOptimizer(
             axes_t = C._resolve_axes(axes)
             n = C._world_size(axes_t) if axes_t else 1
             postscale = gradient_predivide_factor / n
-        return fusion.allreduce_pytree(
-            grads,
-            op=reduce_op,
-            compression=compression,
-            threshold_bytes=fusion_threshold_bytes,
-            axes=axes,
-            hierarchical=hierarchical,
-            prescale_factor=prescale,
-            postscale_factor=postscale,
-            presummed=True,  # invariant grads are autodiff-psummed sums
-            quantized=quantized,
-            error_feedback=error_feedback,
-            block=quant_block,
-            overlap=overlap,
-            num_comm_streams=num_comm_streams,
-            fused=fused,
-            plan=grad_plan,
-        )
+        with jax.named_scope("hvd.allreduce_grads"):
+            return fusion.allreduce_pytree(
+                grads,
+                op=reduce_op,
+                compression=compression,
+                threshold_bytes=fusion_threshold_bytes,
+                axes=axes,
+                hierarchical=hierarchical,
+                prescale_factor=prescale,
+                postscale_factor=postscale,
+                presummed=True,  # invariant grads are autodiff-psummed sums
+                quantized=quantized,
+                error_feedback=error_feedback,
+                block=quant_block,
+                overlap=overlap,
+                num_comm_streams=num_comm_streams,
+                fused=fused,
+                plan=grad_plan,
+            )
 
     if overlap and backward_passes_per_step > 1:
         # Mechanism 1 (docs/overlap.md): the double-buffered microbatch
         # accumulator owns the reduction (and, when quantized, the EF
         # residual) so microbatch t's backward and microbatch t-1's
         # bucket reduction share a program region dependence-free.
-        return _with_step_marker(
+        return _with_update_guard(
             _overlap_multi_steps(optimizer, backward_passes_per_step,
                                  _allreduce, quantized=quantized), axes)
 
@@ -642,10 +635,12 @@ def DistributedOptimizer(
     def update_fn(grads, state, params=None, **extra):
         if not quantized:
             reduced = _allreduce(grads)
-            return optimizer.update(reduced, state, params, **extra)
+            with jax.named_scope("hvd.optimizer_update"):
+                return optimizer.update(reduced, state, params, **extra)
         reduced, new_res = _allreduce(grads, _res_read(state.residual))
-        updates, new_inner = optimizer.update(
-            reduced, state.inner, params, **extra)
+        with jax.named_scope("hvd.optimizer_update"):
+            updates, new_inner = optimizer.update(
+                reduced, state.inner, params, **extra)
         return updates, QuantizedEFState(
             inner=new_inner,
             residual=_res_write(state.residual, new_res))
@@ -655,7 +650,7 @@ def DistributedOptimizer(
         # Accumulate locally, allreduce + apply every k-th microbatch
         # (reference: torch/optimizer.py:133-149).
         tx = optax.MultiSteps(tx, every_k_schedule=backward_passes_per_step)
-    return _with_step_marker(tx, axes)
+    return _with_update_guard(tx, axes)
 
 
 def _validate_pp_knobs(pp_stages, pp_microbatches, pp_schedule,
@@ -942,7 +937,8 @@ def _zero_multi_steps(inner: optax.GradientTransformation, k: int):
         is_last = t == (k - 1)
         mean = jax.tree.map(lambda a, g: a.astype(jnp.asarray(g).dtype),
                             acc, grads)
-        upd, inner_new = inner.update(mean, state.inner, params, **extra)
+        with jax.named_scope("hvd.optimizer_update"):
+            upd, inner_new = inner.update(mean, state.inner, params, **extra)
         updates = jax.tree.map(
             lambda u: jnp.where(is_last, u, jnp.zeros_like(u)), upd)
         inner_next = jax.tree.map(
@@ -1158,62 +1154,65 @@ def _build_zero_transform(
 
         gshards: List[Any] = [None] * len(plan)
         new_rs: List[Any] = [None] * len(plan)
-        for s in range(0, len(order), flight):
-            issued = []
-            for i in order[s:s + flight]:
-                b = plan[i]
-                buf = fusion.pack(b, gleaves)
-                if db:
-                    # Double buffer: this call's wire carries the PREVIOUS
-                    # microbatch's packed buckets (no dependence on this
-                    # call's backward); the final call folds the last
-                    # microbatch in (the wire is linear).
-                    pend = _res_read(ms.pending[i], in_trace)
-                    new_pending[i] = _res_write(
-                        ms.pending[i],
-                        jnp.where(is_last, jnp.zeros_like(buf), buf),
-                        in_trace)
-                    buf = jnp.where(is_last, pend + buf, pend)
-                is_float = jnp.issubdtype(b.dtype, jnp.floating)
-                wire, ctx = compression.compress(buf)
-                if eager_local:
-                    shard = C._scale(C._scale(wire, prescale), postscale)
-                    new_rs[i] = (None if state.residual is None
-                                 else state.residual[i])
+        with jax.named_scope("hvd.allreduce_grads"):
+            for s in range(0, len(order), flight):
+                issued = []
+                for i in order[s:s + flight]:
+                    b = plan[i]
+                    buf = fusion.pack(b, gleaves)
+                    if db:
+                        # Double buffer: this call's wire carries the PREVIOUS
+                        # microbatch's packed buckets (no dependence on this
+                        # call's backward); the final call folds the last
+                        # microbatch in (the wire is linear).
+                        pend = _res_read(ms.pending[i], in_trace)
+                        new_pending[i] = _res_write(
+                            ms.pending[i],
+                            jnp.where(is_last, jnp.zeros_like(buf), buf),
+                            in_trace)
+                        buf = jnp.where(is_last, pend + buf, pend)
+                    is_float = jnp.issubdtype(b.dtype, jnp.floating)
+                    wire, ctx = compression.compress(buf)
+                    if eager_local:
+                        shard = C._scale(C._scale(wire, prescale), postscale)
+                        new_rs[i] = (None if state.residual is None
+                                     else state.residual[i])
+                        gshards[i] = compression.decompress(shard, ctx)
+                        continue
+                    res = (None
+                           if not (use_quant and is_float and state.residual)
+                           else _res_read(state.residual[i], in_trace))
+                    rs_kw = dict(op=reduce_op, prescale_factor=prescale,
+                                 postscale_factor=postscale,
+                                 block=quant_block, fused=fused,
+                                 _presummed=True)
+                    if res is not None:
+                        if overlap:
+                            shard, nres = C.reduce_scatter_stream(
+                                wire, res, bucket_id=i, quantized=True,
+                                **rs_kw)
+                        else:
+                            shard, nres = C.reduce_scatter(
+                                wire, res, quantized=True, **rs_kw)
+                        new_rs[i] = _res_write(state.residual[i], nres,
+                                               in_trace)
+                    else:
+                        if overlap:
+                            shard = C.reduce_scatter_stream(
+                                wire, bucket_id=i,
+                                quantized=use_quant and is_float, **rs_kw)
+                        else:
+                            shard = C.reduce_scatter(
+                                wire, quantized=use_quant and is_float,
+                                **rs_kw)
+                        new_rs[i] = (None if state.residual is None
+                                     else state.residual[i])
+                    issued.append((i, shard, ctx))
+                # Decompress after the whole flight is issued: no consumer
+                # between in-flight scatters (flight == 1 == the serial
+                # schedule exactly).
+                for i, shard, ctx in issued:
                     gshards[i] = compression.decompress(shard, ctx)
-                    continue
-                res = (None
-                       if not (use_quant and is_float and state.residual)
-                       else _res_read(state.residual[i], in_trace))
-                rs_kw = dict(op=reduce_op, prescale_factor=prescale,
-                             postscale_factor=postscale,
-                             block=quant_block, fused=fused,
-                             _presummed=True)
-                if res is not None:
-                    if overlap:
-                        shard, nres = C.reduce_scatter_stream(
-                            wire, res, bucket_id=i, quantized=True, **rs_kw)
-                    else:
-                        shard, nres = C.reduce_scatter(
-                            wire, res, quantized=True, **rs_kw)
-                    new_rs[i] = _res_write(state.residual[i], nres,
-                                           in_trace)
-                else:
-                    if overlap:
-                        shard = C.reduce_scatter_stream(
-                            wire, bucket_id=i,
-                            quantized=use_quant and is_float, **rs_kw)
-                    else:
-                        shard = C.reduce_scatter(
-                            wire, quantized=use_quant and is_float, **rs_kw)
-                    new_rs[i] = (None if state.residual is None
-                                 else state.residual[i])
-                issued.append((i, shard, ctx))
-            # Decompress after the whole flight is issued: no consumer
-            # between in-flight scatters (flight == 1 == the serial
-            # schedule exactly).
-            for i, shard, ctx in issued:
-                gshards[i] = compression.decompress(shard, ctx)
 
         pshards = None
         if params is not None:
@@ -1239,8 +1238,9 @@ def _build_zero_transform(
                         for a, g in zip(ms.acc_shards, gshards))
             mean = tuple((a / k).astype(jnp.asarray(g).dtype)
                          for a, g in zip(acc, gshards))
-            upd, inner_new = optimizer.update(mean, ms.inner, pshards,
-                                              **extra)
+            with jax.named_scope("hvd.optimizer_update"):
+                upd, inner_new = optimizer.update(mean, ms.inner, pshards,
+                                                  **extra)
             ushards = tuple(
                 jnp.where(is_last, u, jnp.zeros_like(u)) for u in upd)
             inner_next = jax.tree.map(
@@ -1252,8 +1252,9 @@ def _build_zero_transform(
                 mini_step=(t + 1) % k, inner=inner_next,
                 acc_shards=acc_next, pending=tuple(new_pending))
         elif s1:
-            upd, inner_new = optimizer.update(tuple(gshards), ms.inner,
-                                              pshards, **extra)
+            with jax.named_scope("hvd.optimizer_update"):
+                upd, inner_new = optimizer.update(tuple(gshards), ms.inner,
+                                                  pshards, **extra)
             ushards = tuple(
                 jnp.where(is_last, u, jnp.zeros_like(u)) for u in upd)
             inner_next = jax.tree.map(
@@ -1263,8 +1264,9 @@ def _build_zero_transform(
                 mini_step=(t + 1) % k, inner=inner_next,
                 acc=new_acc_full)
         else:
-            ushards, new_inner = stx.update(tuple(gshards), state.inner,
-                                            pshards, **extra)
+            with jax.named_scope("hvd.optimizer_update"):
+                ushards, new_inner = stx.update(tuple(gshards), state.inner,
+                                                pshards, **extra)
 
         if stage == 3:
             # No trailing all-gather: the updates stay in shard space and

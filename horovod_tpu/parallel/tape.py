@@ -99,13 +99,14 @@ def allreduce_gradients(
     if plan is not None and hasattr(plan, "gradient"):
         plan = plan.gradient  # a StepPlan: thread its gradient wire
     _note_reduced(axes)
-    return fusion.allreduce_pytree(
-        grads, op=op, compression=compression,
-        threshold_bytes=fusion_threshold_bytes, axes=axes,
-        hierarchical=hierarchical, presummed=True,
-        quantized=quantized, error_feedback=error_feedback,
-        tuned_params=tuned_params, overlap=overlap,
-        num_comm_streams=num_comm_streams, fused=fused, plan=plan)
+    with jax.named_scope("hvd.allreduce_grads"):
+        return fusion.allreduce_pytree(
+            grads, op=op, compression=compression,
+            threshold_bytes=fusion_threshold_bytes, axes=axes,
+            hierarchical=hierarchical, presummed=True,
+            quantized=quantized, error_feedback=error_feedback,
+            tuned_params=tuned_params, overlap=overlap,
+            num_comm_streams=num_comm_streams, fused=fused, plan=plan)
 
 
 def value_and_grad(
@@ -223,7 +224,8 @@ def value_and_grad(
             args = list(args)
             for i in idxs:
                 args[i] = _pvary_tree(args[i], axes_t)
-        val, grads = vg(*args, **kwargs)
+        with jax.named_scope("hvd.grad"):
+            val, grads = vg(*args, **kwargs)
         if not reduce or zero_eff:
             return val, grads
         grads = allreduce_gradients(

@@ -1,0 +1,174 @@
+"""The names the program gives its work inside the compiled step:
+``span_audit.DEVICE_SCOPES`` in the ``op_name`` of the compiled HLO and
+``hvd_*`` on every Pallas kernel of ``ops/`` (docs/observability.md, "Scopes
+in the device trace"). The tiny GPT step is the benchmark's own
+(tests/benchmark/bench_tiny.py), on one and on four virtual CPU devices.
+"""
+
+import contextlib
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import horovod_tpu as hvd
+from horovod_tpu.compile.cache import persistent_cache_disabled
+from horovod_tpu.monitor.span_audit import DEVICE_SCOPES
+from horovod_tpu.ops import flash_attention as fa
+from horovod_tpu.ops import layer_norm as ln
+from horovod_tpu.ops import softmax_xent as sx
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                "benchmark"))
+import bench_tiny as tiny  # noqa: E402
+
+from benchmarks.builders import gpt_decoder  # noqa: E402
+
+KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv",
+           "hvd_xent_fwd", "hvd_xent_bwd_dx", "hvd_xent_bwd_dw",
+           "hvd_ln_fwd", "hvd_ln_bwd")
+# The tiny step runs the unfused LayerNorm, as every cell does today.
+OFF_STEP = {"hvd.layer_norm"}
+DIFFERENTIATED = {"hvd.grad", "hvd.lm_head_loss", "hvd.flash_attention",
+                  "hvd.layer_norm"}
+NESTED_IN = {"hvd.lm_head_loss": "hvd.grad",
+             "hvd.flash_attention": "hvd.grad",
+             "hvd.bucket_pack": "hvd.allreduce_grads",
+             "hvd.bucket_allreduce": "hvd.allreduce_grads",
+             "hvd.bucket_unpack": "hvd.allreduce_grads"}
+
+
+def _op_names(text: str) -> list:
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+def _step_text(n_devices: int) -> str:
+    session = gpt_decoder.build(tiny.CONFIG, tiny.JOB,
+                                jax.devices()[:n_devices])
+    return session.lower(session.abstract_args()).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def step_names():
+    """{devices: the op_names of the tiny step's compiled text}."""
+    try:
+        yield {n: _op_names(_step_text(n)) for n in (1, 4)}
+    finally:
+        hvd.shutdown()     # the builder owns init/shutdown: hand the
+        hvd.init()         # other tests their mesh back
+
+
+@pytest.fixture(scope="module")
+def layer_norm_names():
+    x = jnp.ones((2, 128, 64), jnp.bfloat16)
+    g = jnp.ones((64,), jnp.float32)
+
+    def loss(x, g):
+        y, h = ln.ln_residual(x, x, g, g)
+        return (y.astype(jnp.float32).sum() + h.astype(jnp.float32).sum())
+
+    return _op_names(jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        x, g).compile().as_text())
+
+
+@pytest.mark.parametrize("scope", DEVICE_SCOPES)
+def test_scope_reaches_the_compiled_program(scope, step_names,
+                                            layer_norm_names):
+    if scope in OFF_STEP:
+        programs = {"layer_norm": layer_norm_names}
+    else:
+        programs = {f"{n} device(s)": names
+                    for n, names in step_names.items()}
+    for what, names in programs.items():
+        under = [n for n in names if scope in n]
+        assert under, f"{scope} is in no op_name of the {what} program"
+        if scope in DIFFERENTIATED:
+            # JAX marks the direction itself; both must be there.
+            assert any("transpose(" in n for n in under), (scope, what)
+            assert any("transpose(" not in n for n in under), (scope, what)
+        if scope in NESTED_IN:
+            assert all(NESTED_IN[scope] in n for n in under), (scope, what)
+
+
+@pytest.mark.parametrize("kernel, scope", [
+    ("hvd_flash_bwd_dq", "hvd.flash_attention"),
+    ("hvd_flash_bwd_dkv", "hvd.flash_attention"),
+    ("hvd_ln_bwd", "hvd.layer_norm")])
+def test_custom_vjp_backward_inherits_the_scope(kernel, scope, step_names,
+                                                layer_norm_names):
+    """The trap: a scope opened inside the custom_vjp's forward function
+    would not reach the backward. In interpret mode a kernel's body is
+    traced into the program under its ``name=``."""
+    names = (layer_norm_names if scope == "hvd.layer_norm"
+             else step_names[4])
+    body = [n for n in names if kernel in n]
+    assert body, f"no op of {kernel} in the program"
+    assert all(scope in n and "transpose(" in n for n in body)
+
+
+def _pallas_names(jaxpr, found: set) -> set:
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.add(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_names(sub, found)
+    return found
+
+
+@pytest.fixture(scope="module")
+def kernel_names():
+    """The name of every pallas_call in forward and backward of the three
+    kernel modules of ``horovod_tpu/ops``."""
+    q = jnp.ones((1, 128, 2, 64), jnp.bfloat16)
+    x = jnp.ones((128, 64), jnp.bfloat16)
+    w = jnp.ones((256, 64), jnp.bfloat16)
+    lab = jnp.zeros((128,), jnp.int32)
+    g = jnp.ones((64,), jnp.float32)
+    programs = [
+        (lambda q: fa.flash_attention(q, q, q).astype(jnp.float32).sum(),
+         q),
+        (lambda x: sx.linear_cross_entropy(x, w, lab).sum(), x),
+        (lambda x: ln.ln_residual(x, x, g, g)[0].astype(
+            jnp.float32).sum(), x)]
+    found: set = set()
+    for fn, arg in programs:
+        _pallas_names(jax.make_jaxpr(jax.grad(fn))(arg).jaxpr, found)
+    return found
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_every_pallas_call_has_an_hvd_name(kernel, kernel_names):
+    assert kernel in kernel_names
+    assert all(n.startswith("hvd_") for n in kernel_names), kernel_names
+
+
+def _stripped(text: str) -> str:
+    """Compiled HLO text without what a scope may change: the metadata,
+    the tables of source locations it points into, and the instruction
+    names XLA derives from it."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"(?ms)^(FileNames|FunctionNames|FileLocations|"
+                  r"StackFrames)\n.*?\n\n", "", text)
+    return re.sub(r"%[A-Za-z_][\w.\-]*", "%", text)
+
+
+def test_scopes_are_metadata_only(monkeypatch):
+    """The optimized program is the same with every scope taken out: the
+    names cost nothing at run time."""
+    # JAX's persistent cache leaves metadata out of its key: with it on,
+    # the second compile would be handed the first one's text.
+    try:
+        with persistent_cache_disabled():
+            with_scopes = _step_text(1)
+            monkeypatch.setattr(jax, "named_scope",
+                                lambda name: contextlib.nullcontext())
+            without = _step_text(1)
+    finally:
+        monkeypatch.undo()
+        hvd.shutdown()
+        hvd.init()
+    assert "hvd.grad" in with_scopes and "hvd.grad" not in without
+    assert _stripped(with_scopes) == _stripped(without)
