@@ -20,22 +20,46 @@ kernels (guide: /opt/skills/guides/pallas_guide.md):
   ``delta = rowsum(dO ⊙ O)`` precomputed as a cheap fused elementwise op
   in plain XLA. Both kernels recompute probabilities from q, k and the
   saved logsumexp (recompute-over-store: O(T·D) residuals instead of
-  O(T²)).
-* causal masking skips fully-masked k-blocks via ``pl.when`` (upper
-  triangle costs nothing) when block positions are static; with runtime
-  offsets (ring partials) the mask runs with global positions instead.
+  O(T²)). The dK/dV kernel works on transposed score tiles ([tk, tq]), so
+  that dV = PᵀdO and dK = dSᵀQ are plain matmuls.
+* causal masking skips work at two levels when block positions are static
+  (zero offsets). **Grid cell**: a (bq, bk) cell wholly in the future is
+  skipped by ``pl.when`` (its k/v blocks are still fetched). **Sub-tile**:
+  a cell that the diagonal crosses — at T <= block, where one cell holds
+  the whole score square, that is every cell — is walked in (256, 256)
+  sub-tiles; a sub-tile wholly in the future is never computed (no
+  matmul, no softmax pass: 6 of 16 at T = 1024), only the sub-tiles the
+  diagonal crosses build a mask (4 of the other 10), and the rest run
+  bare. A cell wholly below the diagonal, a non-causal cell and a ring
+  partial stay one tile: nothing to skip there, and every cut costs a
+  column of row statistics per sub-tile.
+* what is per row or per operand is done there and not on the score
+  tile: ``scale`` is folded into q (into k in the dK/dV kernel) once per
+  sub-tile row, the mask is one compare of a hoisted iota difference
+  against a scalar, and the fully-masked-row guards (one select on a
+  [tq, 1] column) exist only with runtime offsets (ring partials): a
+  causal row with zero offsets always sees its own token.
 * :func:`flash_ring_attention` composes the kernels with sequence
   parallelism: K/V blocks rotate around the mesh axis via
   ``lax.ppermute`` while each ring step runs the flash kernel with
   global causal positions and partial outputs merge by logsumexp; the
   backward replays the ring with dk/dv accumulators traveling alongside
-  their blocks (they arrive home after n rotations).
+  their blocks (they arrive home after n rotations). With runtime offsets
+  nothing is skipped inside a kernel (whole future partials are skipped
+  by the ``lax.cond`` in ``_ring_fwd_impl``).
+
+Each kernel call adds, at trace time, to the monitor registry's
+``flash.tiles_total`` / ``flash.tiles_computed`` / ``flash.tiles_masked``
+(label ``kernel`` = ``fwd`` | ``bwd_dq`` | ``bwd_dkv``): the sub-tiles of
+one head's grid, how many are computed and how many of those are masked
+(16 / 10 / 4 at the benchmark cells' shape).
 
 Everything is static-shaped; block sizes adapt to divide the sequence
 (see ``_pick_block`` — a whole-sequence block covers anything <= the
 preferred block, and long sequences with no 128-aligned divisor fall back
-to the dense path). Off-TPU the kernels run in Pallas interpreter mode so
-the CPU test suite exercises the identical code path.
+to the dense path), sub-tiles to divide the block (``_sub_tile``). Off-TPU
+the kernels run in Pallas interpreter mode so the CPU test suite exercises
+the identical code path.
 """
 
 from __future__ import annotations
@@ -50,13 +74,24 @@ from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU backend)
 
 _NEG_INF = -1e30  # finite: keeps running-max arithmetic NaN-free
 
-# Large blocks amortize Mosaic's per-grid-cell overhead and give the MXU
-# deep work per cell: a [1024, 1024] f32 score tile is 4 MB of VMEM —
-# comfortably under the ~16 MB budget next to the q/k/v/o blocks and
-# scratch — and measured on v5e (GPT-124M, seq 1024) block size is worth
-# 2x end-to-end: 512-blocks beat the dense path by 28%, 1024-blocks add
-# another ~9% (117.2k vs 107.7k tok/s). Tunable like the other HOROVOD_*
-# knobs (e.g. for other chip generations' VMEM sizes).
+# Large grid blocks amortize Mosaic's per-grid-cell overhead; the causal
+# half is saved inside the cell (sub-tiles, below), not by a finer grid,
+# whose extra cells and k/v fetches cost more than their skipped work
+# saves. Measured on a TPU v5e, bf16, [8, 1024, 16, 64] causal, device
+# microseconds of one call of forward / dq / dk-dv (PERF.md, PR 25):
+#
+#   grid block  sub-tile      fwd    dq   dk/dv
+#   1024        none (PR 24)  769   635    849
+#   1024        1024          416   550    726   per-entry work hoisted only
+#   1024        512           336   419    555
+#   1024        256           329   367    505   <- _SUB_TILE
+#   1024        128           351   440    507
+#   512         256          1001   680    796   the finer grid
+#
+# A [1024, 1024] f32 score tile is 4 MB of VMEM; the kernels keep one (a
+# non-causal cell, a cell below the diagonal, a ring partial) or a few
+# 256 KB ones. Tunable like the other HOROVOD_* knobs (e.g. for other
+# chip generations' VMEM sizes).
 
 
 def _block_knob(name: str, default: int) -> int:
@@ -151,87 +186,261 @@ def _as_scalar(x):
     return jnp.broadcast_to(jnp.asarray(x, jnp.int32), (1, 8, 128))
 
 
-def _causal_mask(s, qoff, koff, i, j, bq, bk):
-    """Mask with GLOBAL positions: local block position + runtime offset.
+def _sub_tile(block: int, preferred: int) -> int:
+    """Sub-tile edge for a block: the largest multiple of 128 up to
+    ``preferred`` that divides it, else the block itself (a whole-sequence
+    block such as 100 has no aligned divisor: one sub-tile then)."""
+    for t in range(preferred, 127, -128):
+        if block % t == 0:
+            return t
+    return block
+
+
+def _sub_tiles(mode: str, bq: int, bk: int, sub_tile):
+    """(tq, tk): the sub-tiles a kernel walks inside a (bq, bk) cell of
+    ``mode``. Only a cell that can skip is cut up: every cut costs each
+    kernel a column of row statistics per sub-tile ([tq, 1]: as many vregs
+    as half a [tq, 256] pass), which only skipped sub-tiles pay back."""
+    if mode != _SKIP:
+        return bq, bk
+    return _sub_tile(bq, sub_tile[0]), _sub_tile(bk, sub_tile[1])
+
+
+# How a grid cell treats its sub-tiles. Which of them a call can meet is
+# static (``_cell_modes``); which one a cell is follows from the program
+# ids, so a kernel holds one copy of its body per mode, each under its
+# ``pl.when``, and every sub-tile loop has static bounds: the compiler
+# unrolls them and overlaps one sub-tile's matmuls with the next one's
+# softmax (a loop with bounds from the program ids ran 2-3x slower).
+_FULL = "full"      # wholly at or below the diagonal, or not causal:
+                    # every sub-tile, no mask
+_SKIP = "skip"      # first query == first key: sub-tiles past the
+                    # diagonal never computed, those it crosses masked
+_MASKED = "masked"  # crossed off the sub-tile lattice (bq != bk), or
+                    # runtime offsets: every sub-tile, masked
+
+
+# (tq, tk) inside a cell that skips. Measured on v5e at D = 64, bf16, block
+# (1024, 1024), all three kernels (PERF.md, PR 25): 256 x 256 is the best
+# or within 2% of it for each; 128 x 128 and 512 x 512 lose 8-9% overall.
+_SUB_TILE = (256, 256)
+
+
+def _cell_modes(causal, static_skip, nq, nk, bq, bk):
+    if not causal:
+        return (_FULL,)
+    if not static_skip:
+        return (_MASKED,)
+    if nq == nk == 1:
+        return (_SKIP,)
+    return (_FULL, _SKIP) if bq == bk else (_FULL, _SKIP, _MASKED)
+
+
+def _cell_is(mode, causal, static_skip, i, j, bq, bk):
+    """Is block (i, j) a cell of ``mode``? A k block contributes iff its
+    first key position <= the q block's last query position — decidable
+    from the block indices only when offsets are zero (static_skip). With
+    runtime offsets every block runs and the global-position mask does the
+    work (callers skip whole fully-masked PARTIALS host-side instead: see
+    _ring_fwd_impl; mixing the varying offset operands with program-id
+    arithmetic in a pl.when predicate trips vma checking). The always-run
+    case returns a traced truth (a literal ``True`` would inline the body,
+    which equally trips the HLO interpreter's vma checks under
+    shard_map). Works on Python ints too (``_tile_counts``)."""
+    if not (causal and static_skip):
+        return j >= 0
+    if mode == _FULL:
+        return j * bk + bk - 1 <= i * bq
+    if mode == _SKIP:
+        return j * bk == i * bq
+    return ((j * bk <= i * bq + bq - 1) & (j * bk + bk - 1 > i * bq)
+            & (j * bk != i * bq))
+
+
+def _k_tiles(mode, a, tq, bk, tk):
+    """Which k sub-tiles of the block q sub-tile ``a`` needs:
+    ``(n_full, hi)``. Sub-tiles [0, n_full) lie wholly at or below the
+    diagonal (no mask), [n_full, hi) are crossed by it (masked),
+    [hi, bk // tk) lie wholly in the future (never computed)."""
+    nks = bk // tk
+    if mode == _FULL:
+        return nks, nks
+    if mode == _MASKED:
+        return 0, nks
+    return min(a * tq + 1, bk) // tk, min(a * tq + tq - 1 + tk, bk) // tk
+
+
+def _q_tiles(mode, c, tk, bq, tq):
+    """The same from k sub-tile ``c``'s side: ``(lo, lo_full)``. q
+    sub-tiles [0, lo) lie wholly in the past of every key here (never
+    computed), [lo, lo_full) are crossed by the diagonal, [lo_full,
+    bq // tq) need no mask."""
+    nqs = bq // tq
+    if mode == _FULL:
+        return 0, 0
+    if mode == _MASKED:
+        return 0, nqs
+    return min(c * tk, bq) // tq, min(c * tk + tk + tq - 2, bq) // tq
+
+
+def _diagonal(tq, tk):
+    """Row index minus column index over a sub-tile: with it the causal
+    mask of any sub-tile is one compare against a scalar."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1))
+
+
+def _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode):
+    """GLOBAL position of the block's first query minus its first key's.
     Offsets arrive as operands (see ``_scalar_spec``) so ring/sharded
-    callers can pass traced values (e.g. ``axis_index * T_local``)."""
-    qpos = qoff + i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kpos = koff + j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where(qpos >= kpos, s, _NEG_INF)
+    callers can pass traced values (e.g. ``axis_index * T_local``). Only a
+    masked cell asks; an aligned one has 0 by definition."""
+    if mode != _MASKED:
+        return 0
+    return (qoff_ref[...][0, 0, 0] + i * bq
+            - koff_ref[...][0, 0, 0] - j * bk)
 
 
-def _run_pred(causal, static_skip, i, j, bq, bk):
-    """Should block (i, j) compute? A k block contributes iff its first key
-    position <= the q block's last query position — decidable statically
-    only when offsets are zero (static_skip). With runtime offsets every
-    block runs and the global-position mask does the work (callers skip
-    whole fully-masked PARTIALS host-side instead: see _ring_fwd_impl;
-    mixing the varying offset operands with program-id arithmetic in a
-    pl.when predicate trips vma checking). The always-run case returns a
-    traced truth (a literal ``True`` would inline the body, which equally
-    trips the HLO interpreter's vma checks under shard_map)."""
+def _last_k_block(causal, static_skip, i, bq, bk, nk):
+    """The last k block that q block ``i`` runs (``_cell_is``)."""
     if causal and static_skip:
-        return j * bk <= i * bq + bq - 1
-    return j >= 0
+        return jnp.minimum(nk - 1, (i * bq + bq - 1) // bk)
+    return nk - 1
+
+
+def _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk):
+    for mode in _cell_modes(causal, static_skip, nq, nk, bq, bk):
+        pl.when(_cell_is(mode, causal, static_skip, i, j, bq, bk))(
+            functools.partial(body, mode))
+
+
+def _tile_counts(causal, static_skip, nq, nk, bq, bk):
+    """(total, computed, masked) sub-tiles of one head, over the grid; a
+    cell that never runs counts at the lattice of the mode it is nearest
+    to (the last one: a future cell of a causal call is ``_SKIP``'s)."""
+    modes = _cell_modes(causal, static_skip, nq, nk, bq, bk)
+    total = computed = masked = 0
+    for i in range(nq):
+        for j in range(nk):
+            mine = [m for m in modes
+                    if _cell_is(m, causal, static_skip, i, j, bq, bk)]
+            mode = mine[0] if mine else _SKIP
+            tq, tk = _sub_tiles(mode, bq, bk, _SUB_TILE)
+            total += (bq // tq) * (bk // tk)
+            for a in range(bq // tq if mine else 0):
+                n_full, hi = _k_tiles(mode, a, tq, bk, tk)
+                computed += hi
+                masked += hi - n_full
+    return total, computed, masked
+
+
+def _count_tiles(kernel, *args):
+    """Trace-time counters of how often the in-cell tiling engages, per
+    head and kernel call (monitor registry, as plan/accounting.py keeps
+    trace-time wire bytes): nothing of this runs on the device."""
+    from ..monitor.registry import counter
+
+    for name, n in zip(("total", "computed", "masked"), _tile_counts(*args)):
+        counter(f"flash.tiles_{name}", kernel=kernel).inc(n)
+
+
+def _fwd_out(m, l, acc, o_dtype):
+    """(o, lse[.., 8]) of finished rows. Fully-masked rows have l == 0:
+    emit o = 0 and lse = -inf-like so a ring merge weights them out.
+    Visible rows always have l > 0 (a causal row sees at least its own
+    token). lse carries a sublane dim of 8 (Mosaic block-mapping minimum
+    for the trailing-two dims); value broadcast across it."""
+    safe_l = jnp.maximum(l, 1e-30)
+    lse = jnp.where(l > 0, m + jnp.log(safe_l), _NEG_INF)
+    return ((acc / safe_l).astype(o_dtype),
+            jnp.broadcast_to(lse, (lse.shape[0], 8)))
 
 
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr,
-                *, scale, causal, bq, bk, nk, static_skip):
+                *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile):
     i = pl.program_id(1)   # q block
     j = pl.program_id(2)   # k block (innermost: scratch carries across j)
+    carried = nk > 1       # else a q sub-tile finishes inside this cell
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    if carried:
+        @pl.when(j == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    run = _run_pred(causal, static_skip, i, j, bq, bk)
+    def body(mode):
+        tq, tk = _sub_tiles(mode, bq, bk, sub_tile)
+        d0 = _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode)
+        diag = _diagonal(tq, tk) if mode != _FULL else None
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]                                    # [bq, D]
-        k = k_ref[0]                                    # [bk, D]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        if causal:
-            s = _causal_mask(s, qoff_ref[...][0, 0, 0], koff_ref[...][0, 0, 0], i, j, bq, bk)
+        def tile(a, c, q, carry, masked):
+            m_prev, l_prev, acc = carry
+            cols = pl.ds(c * tk, tk)
+            s = jax.lax.dot_general(
+                q, k_ref[0, cols, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)      # [tq, tk]
+            if masked:
+                s = jnp.where(diag >= c * tk - a * tq - d0, s, _NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            m_sub = m_new
+            if not static_skip:
+                # Fully-masked rows (a ring partial that sees a k block
+                # entirely in its causal future): m_new stays at _NEG_INF
+                # and s - m_new == 0 would wrongly give p = 1. Subtracting
+                # 0 there instead gives p = exp(-1e30) = 0.
+                m_sub = jnp.where(m_new > _NEG_INF / 2, m_new, 0.0)
+            p = jnp.exp(s - m_sub)
+            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, cols, :],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, l_new, acc
 
-        m_prev = m_scr[:, 0:1]                          # [bq, 1]
-        l_prev = l_scr[:, 0:1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)       # [bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        # Fully-masked rows (possible when a ring partial sees a k block
-        # entirely in its causal future): m_new stays at _NEG_INF and
-        # s - m_new == 0 would wrongly give p = 1 — zero those rows.
-        p = jnp.where(m_new > _NEG_INF / 2, jnp.exp(s - m_new), 0.0)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        for a in range(bq // tq):
+            rows = pl.ds(a * tq, tq)
+            q = q_ref[0, rows, :] * scale               # [tq, D], once
+            if carried:
+                carry = m_scr[rows, :], l_scr[rows, :], acc_scr[rows, :]
+            else:
+                carry = (jnp.full((tq, 1), _NEG_INF, jnp.float32),
+                         jnp.zeros((tq, 1), jnp.float32),
+                         jnp.zeros((tq, q.shape[1]), jnp.float32))
+            n_full, hi = _k_tiles(mode, a, tq, bk, tk)
+            for c in range(hi):
+                carry = tile(a, c, q, carry, masked=c >= n_full)
+            if carried:
+                m_scr[rows, :], l_scr[rows, :], acc_scr[rows, :] = carry
+            else:
+                o_ref[0, rows, :], lse_ref[0, rows, :] = _fwd_out(
+                    *carry, o_ref.dtype)
 
-    j_last = jnp.minimum(nk - 1, (i * bq + bq - 1) // bk) \
-        if (causal and static_skip) else nk - 1
+    _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk)
 
-    @pl.when(j == j_last)
-    def _finish():
-        m = m_scr[:, 0:1]
-        l = l_scr[:, 0:1]
-        # Fully-masked rows have l == 0: emit o = 0 and lse = -inf-like so
-        # a ring merge weights them out. Visible rows always have l > 0
-        # (a causal row sees at least its own token).
-        safe_l = jnp.maximum(l, 1e-30)
-        o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
-        # lse carries a sublane dim of 8 (Mosaic block-mapping minimum for
-        # the trailing-two dims); value broadcast across it.
-        lse_ref[0] = jnp.broadcast_to(
-            jnp.where(l > 0, m + jnp.log(safe_l), _NEG_INF),
-            lse_ref.shape[1:])
+    if carried:
+        @pl.when(j == _last_k_block(causal, static_skip, i, bq, bk, nk))
+        def _finish():
+            o_ref[0], lse_ref[0] = _fwd_out(
+                m_scr[:], l_scr[:], acc_scr[:], o_ref.dtype)
+
+
+def _statics(scale, causal, bq, bk, static_skip):
+    """What a kernel call is specialised on, read where the call is made."""
+    return dict(scale=scale, causal=causal, bq=bq, bk=bk,
+                static_skip=static_skip, sub_tile=_SUB_TILE,
+                interpret=_interpret())
+
+
+# The three pallas_calls are traced once per (operand shapes, statics) and
+# inlined wherever they are called: a 24-layer step calls each 24 times, and
+# tracing the unrolled sub-tile bodies anew every time cost its set-up 20 s.
+_traced_once = functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("scale", "causal", "bq", "bk", "static_skip",
+                     "sub_tile", "interpret"))
 
 
 def _flash_fwd(q, k, v, scale, causal, bq, bk, q_off=0, k_off=0,
@@ -241,11 +450,21 @@ def _flash_fwd(q, k, v, scale, causal, bq, bk, q_off=0, k_off=0,
     ``q_off``/``k_off`` are global positions of the first query/key token
     (may be traced, e.g. ``lax.axis_index(...) * T_local`` under a ring);
     pass ``static_skip=False`` whenever they can be nonzero."""
+    _count_tiles("fwd", causal, static_skip, q.shape[1] // bq,
+                 k.shape[1] // bk, bq, bk)
+    return _fwd_call(q_off, k_off, q, k, v,
+                     **_statics(scale, causal, bq, bk, static_skip))
+
+
+@_traced_once
+def _fwd_call(q_off, k_off, q, k, v, *, scale, causal, bq, bk, static_skip,
+              sub_tile, interpret):
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     nq, nk = Tq // bq, Tk // bk
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               bq=bq, bk=bk, nk=nk, static_skip=static_skip)
+                               bq=bq, bk=bk, nq=nq, nk=nk,
+                               static_skip=static_skip, sub_tile=sub_tile)
     return pl.pallas_call(
         kernel,
         grid=(BH, nq, nk),
@@ -265,13 +484,13 @@ def _flash_fwd(q, k, v, scale, causal, bq, bk, q_off=0, k_off=0,
             _out_struct((BH, Tq, 8), jnp.float32, q, k, v, q_off, k_off),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),   # running max m
-            pltpu.VMEM((bq, 128), jnp.float32),   # running normalizer l
+            pltpu.VMEM((bq, 1), jnp.float32),     # running max m
+            pltpu.VMEM((bq, 1), jnp.float32),     # running normalizer l
             pltpu.VMEM((bq, D), jnp.float32),     # output accumulator
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=interpret,
         name="hvd_flash_fwd",
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v)
 
@@ -281,88 +500,151 @@ def _flash_fwd(q, k, v, scale, causal, bq, bk, q_off=0, k_off=0,
 # ---------------------------------------------------------------------------
 
 
+def _probs(s, lse, static_skip):
+    """p = exp(s - lse) over a sub-tile. Masked entries: s = -1e30 and
+    finite lse → p = 0 automatically. Fully-masked rows (ring partials
+    only) have lse = -1e30 from the forward, giving
+    exp(-1e30 - (-1e30)) = 1 on masked entries: subtract 0 there instead."""
+    if not static_skip:
+        lse = jnp.where(lse > _NEG_INF / 2, lse, 0.0)
+    return jnp.exp(s - lse)
+
+
 def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, acc_scr,
-                   *, scale, causal, bq, bk, nk, static_skip):
+                   *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile):
     i = pl.program_id(1)
     j = pl.program_id(2)
+    carried = nk > 1
 
-    @pl.when(j == 0)
-    def _init():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    if carried:
+        @pl.when(j == 0)
+        def _init():
+            acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    run = _run_pred(causal, static_skip, i, j, bq, bk)
+    def body(mode):
+        tq, tk = _sub_tiles(mode, bq, bk, sub_tile)
+        d0 = _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode)
+        diag = _diagonal(tq, tk) if mode != _FULL else None
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qoff_ref[...][0, 0, 0], koff_ref[...][0, 0, 0], i, j, bq, bk)
-        # Masked entries: s = -1e30 and finite lse → p = 0 automatically;
-        # fully-masked rows have lse = -1e30 from the forward, giving
-        # exp(-1e30 - (-1e30)) = 1 on masked entries — zero them.
-        p = jnp.where(lse_ref[0, :, 0:1] > _NEG_INF / 2,
-                      jnp.exp(s - lse_ref[0, :, 0:1]), 0.0)  # [bq, bk]
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, bk]
-        ds = p * (dp - delta_ref[0, :, 0:1])
-        acc_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        for a in range(bq // tq):
+            rows = pl.ds(a * tq, tq)
+            q = q_ref[0, rows, :] * scale
+            do = do_ref[0, rows, :]
+            lse = lse_ref[0, rows, 0:1]
+            delta = delta_ref[0, rows, 0:1]
+            acc = jnp.zeros((tq, q.shape[1]), jnp.float32)
+            n_full, hi = _k_tiles(mode, a, tq, bk, tk)
+            for c in range(hi):
+                cols = pl.ds(c * tk, tk)
+                k = k_ref[0, cols, :]
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [tq, tk]
+                if c >= n_full:
+                    s = jnp.where(diag >= c * tk - a * tq - d0, s,
+                                  _NEG_INF)
+                p = _probs(s, lse, static_skip)
+                dp = jax.lax.dot_general(
+                    do, v_ref[0, cols, :], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [tq, tk]
+                ds = p * (dp - delta)
+                acc += jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            if carried:
+                acc_scr[rows, :] += acc
+            else:
+                dq_ref[0, rows, :] = (acc * scale).astype(dq_ref.dtype)
 
-    j_last = jnp.minimum(nk - 1, (i * bq + bq - 1) // bk) \
-        if (causal and static_skip) else nk - 1
+    _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk)
 
-    @pl.when(j == j_last)
-    def _finish():
-        dq_ref[0] = (acc_scr[:] * scale).astype(dq_ref.dtype)
+    if carried:
+        @pl.when(j == _last_k_block(causal, static_skip, i, bq, bk, nk))
+        def _finish():
+            dq_ref[0] = (acc_scr[:] * scale).astype(dq_ref.dtype)
+
+
+def _as_row(col):
+    """[t, 1] → [1, t]: a per-query statistic laid along the lanes, for the
+    dk/dv kernel's transposed score tiles."""
+    t = col.shape[0]
+    if t % 128:
+        return col.T
+    return jnp.broadcast_to(col, (t, 128)).T[0:1, :]
 
 
 def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale, causal, bq, bk, nq, static_skip):
+                    *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile):
     j = pl.program_id(1)   # k block
     i = pl.program_id(2)   # q block (innermost: scratch carries across i)
+    carried = nq > 1
 
-    @pl.when(i == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+    if carried:
+        @pl.when(i == 0)
+        def _init():
+            dk_scr[:] = jnp.zeros_like(dk_scr)
+            dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    run = _run_pred(causal, static_skip, i, j, bq, bk)
+    def body(mode):
+        # The other way round: outer over k sub-tiles, inner over the q
+        # sub-tiles from the diagonal down, and every tile transposed
+        # ([tk, tq]: keys down the sublanes), so that dv = p^T do and
+        # dk = ds^T q are plain matmuls and no [tq, tk] tile goes through
+        # the transpose unit.
+        tq, tk = _sub_tiles(mode, bq, bk, sub_tile)
+        d0 = _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode)
+        diag = _diagonal(tk, tq) if mode != _FULL else None
+        nqs = bq // tq
+        lse = [_as_row(lse_ref[0, pl.ds(a * tq, tq), 0:1])
+               for a in range(nqs)]
+        delta = [_as_row(delta_ref[0, pl.ds(a * tq, tq), 0:1])
+                 for a in range(nqs)]
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            s = _causal_mask(s, qoff_ref[...][0, 0, 0], koff_ref[...][0, 0, 0], i, j, bq, bk)
-        p = jnp.where(lse_ref[0, :, 0:1] > _NEG_INF / 2,
-                      jnp.exp(s - lse_ref[0, :, 0:1]), 0.0)  # [bq, bk]
-        do = do_ref[0]                                   # [bq, D]
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bk, D]
-        dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, bk]
-        ds = p * (dp - delta_ref[0, :, 0:1])
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bk, D]
+        for c in range(bk // tk):
+            cols = pl.ds(c * tk, tk)
+            k = k_ref[0, cols, :] * scale               # [tk, D], once
+            v = v_ref[0, cols, :]
+            dk = dv = jnp.zeros((tk, k.shape[1]), jnp.float32)
+            lo, lo_full = _q_tiles(mode, c, tk, bq, tq)
+            for a in range(lo, nqs):
+                rows = pl.ds(a * tq, tq)
+                q = q_ref[0, rows, :]
+                do = do_ref[0, rows, :]
+                s = jax.lax.dot_general(
+                    k, q, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [tk, tq]
+                if a < lo_full:
+                    # key index - query index <= first q - first k
+                    s = jnp.where(diag <= a * tq + d0 - c * tk, s,
+                                  _NEG_INF)
+                p = _probs(s, lse[a], static_skip)
+                dv += jax.lax.dot_general(
+                    p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [tk, D]
+                dp = jax.lax.dot_general(
+                    v, do, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [tk, tq]
+                ds = p * (dp - delta[a])
+                dk += jax.lax.dot_general(
+                    ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # [tk, D]
+            # k carried the scale into s; dk = scale * ds^T q is still due it.
+            if carried:
+                dk_scr[cols, :] += dk
+                dv_scr[cols, :] += dv
+            else:
+                dk_ref[0, cols, :] = (dk * scale).astype(dk_ref.dtype)
+                dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
 
-    @pl.when(i == nq - 1)
-    def _finish():
-        dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+    _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk)
+
+    if carried:
+        @pl.when(i == nq - 1)
+        def _finish():
+            dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _prep_residuals(o, do):
@@ -374,11 +656,21 @@ def _prep_residuals(o, do):
 
 def _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, bq, bk,
                   q_off=0, k_off=0, static_skip=True):
+    _count_tiles("bwd_dq", causal, static_skip, q.shape[1] // bq,
+                 k.shape[1] // bk, bq, bk)
+    return _bwd_dq_call(q_off, k_off, q, k, v, do, lse, delta,
+                        **_statics(scale, causal, bq, bk, static_skip))
+
+
+@_traced_once
+def _bwd_dq_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
+                 bq, bk, static_skip, sub_tile, interpret):
     BH, Tq, D = q.shape
     nq, nk = Tq // bq, k.shape[1] // bk
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nk=nk, static_skip=static_skip),
+                          bq=bq, bk=bk, nq=nq, nk=nk,
+                          static_skip=static_skip, sub_tile=sub_tile),
         grid=(BH, nq, nk),
         in_specs=[
             _scalar_spec(),
@@ -396,19 +688,29 @@ def _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, bq, bk,
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=interpret,
         name="hvd_flash_bwd_dq",
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v, do, lse, delta)
 
 
 def _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, bq, bk,
                    q_off=0, k_off=0, static_skip=True):
+    _count_tiles("bwd_dkv", causal, static_skip, q.shape[1] // bq,
+                 k.shape[1] // bk, bq, bk)
+    return _bwd_dkv_call(q_off, k_off, q, k, v, do, lse, delta,
+                         **_statics(scale, causal, bq, bk, static_skip))
+
+
+@_traced_once
+def _bwd_dkv_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
+                  bq, bk, static_skip, sub_tile, interpret):
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     nq, nk = Tq // bq, Tk // bk
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nq=nq, static_skip=static_skip),
+                          bq=bq, bk=bk, nq=nq, nk=nk,
+                          static_skip=static_skip, sub_tile=sub_tile),
         grid=(BH, nk, nq),
         in_specs=[
             _scalar_spec(),
@@ -436,7 +738,7 @@ def _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, bq, bk,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=interpret,
         name="hvd_flash_bwd_dkv",
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v, do, lse, delta)
 

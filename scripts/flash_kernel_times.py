@@ -1,0 +1,135 @@
+#!/usr/bin/env python3
+"""Device time of the three flash-attention kernels, by sub-tile size.
+
+    chiprun -- python3 scripts/flash_kernel_times.py \
+        --shapes 8x1024x16x64c,16x1024x12x64c,1x8192x12x64c,8x1024x16x64f \
+        --subtiles 128x128,256x256,512x512,256x512
+
+Runs ``jax.grad(flash_attention)`` a few times under the profiler for each
+(shape, sub-tile) and prints the mean duration of the events named
+``hvd_flash_fwd`` / ``hvd_flash_bwd_dq`` / ``hvd_flash_bwd_dkv`` on the
+first device: the kernels alone, no layout traffic, no dispatch. A shape is
+``BxTxHxD`` + ``c`` (causal) or ``f`` (full); a sub-tile ``TQxTK`` (the
+rule's own choice when the list is empty). ``--module FILE`` times another
+copy of ``ops/flash_attention.py`` (e.g. the parent commit's) in the same
+process; such a copy ignores ``--subtiles`` unless it has ``_SUB_TILE``.
+Needs a TPU (anything else: exit 2). Results also go to
+``chiprun_out/flash_kernel_times.jsonl``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import importlib.util
+import json
+import os
+import sys
+import tempfile
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")
+
+
+def load_module(path):
+    if not path:
+        from horovod_tpu.ops import flash_attention as mod
+
+        return mod
+    # Under the package's name, so that the copy's relative imports hold.
+    spec = importlib.util.spec_from_file_location(
+        "horovod_tpu.ops._flash_other", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def kernel_us(trace_dir):
+    """{kernel name: mean us per event} on the first device of a trace."""
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    planes = [p for p in ProfileData.from_file(path).planes
+              if p.name.startswith("/device:TPU:")]
+    plane = min(planes, key=lambda p: p.name)
+    found = {k: [] for k in KERNELS}
+    for line in plane.lines:
+        if line.name != "XLA Ops":
+            continue
+        for e in line.events:
+            for k in KERNELS:
+                # "hvd_flash_bwd_dq" must not also count under "..._dkv".
+                if f"%{k}." in e.name or f"%{k} " in e.name:
+                    found[k].append(e.duration_ns * 1e-3)
+    return {k: (sum(v) / len(v), len(v)) for k, v in found.items() if v}
+
+
+def time_one(mod, shape, block, steps):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    B, T, H, D, causal = shape
+    rs = np.random.RandomState(0)
+    q, k, v = (jnp.asarray(rs.randn(B, T, H, D), jnp.bfloat16) * 0.3
+               for _ in range(3))
+
+    @jax.jit
+    def f(q, k, v):
+        return jax.grad(lambda q, k, v: mod.flash_attention(
+            q, k, v, causal=causal, block_q=block, block_k=block,
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    jax.block_until_ready(f(q, k, v))        # compile + warm
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        for _ in range(steps):
+            out = f(q, k, v)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        return kernel_us(d)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--shapes", default="8x1024x16x64c")
+    ap.add_argument("--subtiles", default="")
+    ap.add_argument("--blocks", default="1024",
+                    help="grid blocks (bq = bk), comma-separated")
+    ap.add_argument("--module", default="")
+    ap.add_argument("--steps", type=int, default=5)
+    args = ap.parse_args(argv)
+
+    import jax
+
+    if jax.default_backend() != "tpu":
+        print("flash_kernel_times: needs a TPU", file=sys.stderr)
+        return 2
+    mod = load_module(args.module)
+    shapes = [(*map(int, s[:-1].split("x")), s[-1] == "c")
+              for s in args.shapes.split(",")]
+    subs = [tuple(map(int, s.split("x")))
+            for s in args.subtiles.split(",") if s] or [None]
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/flash_kernel_times.jsonl", "a") as log:
+        for shape in shapes:
+            for block in map(int, args.blocks.split(",")):
+                for sub in subs:
+                    if sub is not None and hasattr(mod, "_SUB_TILE"):
+                        mod._SUB_TILE = sub
+                    try:
+                        us = time_one(mod, shape, block, args.steps)
+                    except Exception as e:   # a sub-tile Mosaic refuses
+                        us = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
+                    row = {"module": args.module or "tree", "shape": shape,
+                           "block": block, "subtile": sub, "us": us}
+                    print(json.dumps(row), flush=True)
+                    log.write(json.dumps(row) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

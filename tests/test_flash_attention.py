@@ -228,3 +228,160 @@ class TestFlashIntegration:
         ))(q, k, v)
         np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                    rtol=2e-4, atol=2e-5)
+
+
+def _fwd_and_grads(attn, q, k, v, w):
+    o = attn(q, k, v)
+    grads = jax.grad(lambda q, k, v: jnp.sum(
+        attn(q, k, v).astype(jnp.float32) * w), argnums=(0, 1, 2))(q, k, v)
+    return (o, *grads)
+
+
+class TestInCellTiling:
+    """The kernels walk (256, 256) sub-tiles of a grid cell that the
+    diagonal crosses: future sub-tiles are never computed, only those it
+    crosses are masked; any other cell is one tile. Forward and gradients
+    against ``dense_attention``."""
+
+    @pytest.mark.parametrize("T,causal,blocks,dtype,tol", [
+        (1024, True, (None, None), jnp.float32, 5e-6),   # the cells' case:
+        #   one whole-sequence block, 10 of its 16 sub-tiles, static bounds
+        (1024, True, (512, 512), jnp.float32, 5e-6),     # bounds from the
+        #   program ids: diagonal grid cells skip inside too
+        (1024, True, (512, 1024), jnp.float32, 5e-6),    # bq != bk
+        (1024, True, (1024, 512), jnp.float32, 5e-6),
+        (512, True, (256, 512), jnp.float32, 5e-6),
+        (384, True, (None, None), jnp.float32, 5e-6),    # 3 x 3 of 128
+        (640, True, (None, None), jnp.float32, 5e-6),    # 5 x 5 of 128
+        (200, True, (None, None), jnp.float32, 5e-6),    # no aligned
+        #   divisor: one sub-tile the size of the block
+        (512, False, (None, None), jnp.float32, 5e-6),   # every sub-tile
+        (1024, False, (512, 256), jnp.float32, 5e-6),
+        (512, True, (None, None), jnp.bfloat16, 2e-2),
+    ])
+    def test_matches_dense(self, T, causal, blocks, dtype, tol):
+        q, k, v = _qkv(T=T, H=1, D=16, seed=T, dtype=dtype)
+        w = jnp.asarray(np.random.RandomState(5).randn(16), jnp.float32)
+        got = _fwd_and_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=blocks[0], block_k=blocks[1]),
+            q, k, v, w)
+        want = _fwd_and_grads(lambda q, k, v: seqpar.dense_attention(
+            q, k, v, causal=causal), q, k, v, w)
+        for a, b, name in zip(got, want, ("o", "dq", "dk", "dv")):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert np.abs(a - b).max() <= tol * np.abs(b).max(), name
+
+    def test_multi_block_inside_shard_map(self):
+        """Sub-tile loops whose bounds come from the program ids, under
+        shard_map's varying-axes checking (batch sharded)."""
+        q, k, v = _qkv(B=8, T=512, H=1, D=16, seed=21)
+        spec = P(hvd.HVD_AXES)
+
+        def loss(q, k, v):
+            o = hvd.shard_map(
+                lambda a, b, c: flash_attention(
+                    a, b, c, causal=True, block_q=256, block_k=256),
+                mesh=hvd.mesh(), in_specs=(spec,) * 3, out_specs=spec,
+            )(q, k, v)
+            return jnp.sum(o * o)
+
+        gf = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        gd = jax.grad(lambda q, k, v: jnp.sum(seqpar.dense_attention(
+            q, k, v, causal=True) ** 2), argnums=(0, 1, 2))(q, k, v)
+        for a, b, name in zip(gf, gd, "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6,
+                err_msg=f"d{name} mismatch")
+
+    @pytest.mark.parametrize("q_off,k_off", [(0, 256), (512, 0), (256, 256),
+                                             (128, 384)])
+    def test_ring_partial_with_offsets(self, q_off, k_off):
+        """A ring partial (runtime offsets, ``static_skip=False``) runs
+        every sub-tile under the global-position mask; a row whose keys
+        all lie in its future gives o = 0 and lse = -1e30, and no
+        gradient."""
+        from horovod_tpu.ops import flash_attention as F
+
+        T, D = 512, 16
+        rs = np.random.RandomState(q_off + k_off)
+        q, k, v, do = (jnp.asarray(rs.randn(1, T, D), jnp.float32) * 0.3
+                       for _ in range(4))
+        scale = D ** -0.5
+        visible = (q_off + np.arange(T))[:, None] >= (
+            k_off + np.arange(T))[None, :]
+        seen = visible.any(axis=1)
+
+        def dense(q, k, v):
+            s = jnp.where(visible, jnp.einsum("btd,bsd->bts", q, k) * scale,
+                          -1e30)
+            p = jnp.where(visible, jax.nn.softmax(s, axis=-1), 0.0)
+            return (jnp.einsum("bts,bsd->btd", p, v),
+                    jax.nn.logsumexp(s, axis=-1))
+
+        o, lse = F._flash_fwd(q, k, v, scale, True, T, T,
+                              q_off=jnp.int32(q_off), k_off=jnp.int32(k_off),
+                              static_skip=False)
+        o_d, lse_d = dense(q, k, v)
+        np.testing.assert_allclose(np.asarray(o)[0, seen],
+                                   np.asarray(o_d)[0, seen],
+                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(lse)[0, seen, 0],
+                                   np.asarray(lse_d)[0, seen],
+                                   rtol=1e-5, atol=1e-6)
+        assert not np.asarray(o)[0, ~seen].any()
+        assert (np.asarray(lse)[0, ~seen] == -1e30).all()
+
+        delta = F._prep_residuals(o, do)
+        dq = F._flash_bwd_dq(q, k, v, do, lse, delta, scale, True, T, T,
+                             q_off=jnp.int32(q_off), k_off=jnp.int32(k_off),
+                             static_skip=False)
+        dk, dv = F._flash_bwd_dkv(q, k, v, do, lse, delta, scale, True, T,
+                                  T, q_off=jnp.int32(q_off),
+                                  k_off=jnp.int32(k_off), static_skip=False)
+        want = jax.grad(lambda q, k, v: jnp.sum(dense(q, k, v)[0] * do),
+                        argnums=(0, 1, 2))(q, k, v)
+        for a, b, name in zip((dq, dk, dv), want, "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6,
+                err_msg=f"d{name} mismatch")
+
+    def test_tile_counters(self):
+        """``flash.tiles_{total,computed,masked}{kernel=...}`` count, at
+        trace time and per head, the sub-tiles of a kernel call."""
+        from horovod_tpu.monitor.registry import counter
+        from horovod_tpu.ops import flash_attention as F
+
+        def read():
+            return {(n, kern): counter(f"flash.tiles_{n}", kernel=kern).value
+                    for n in ("total", "computed", "masked")
+                    for kern in ("fwd", "bwd_dq", "bwd_dkv")}
+
+        def traced(T, bq, bk, **kw):
+            x = jax.ShapeDtypeStruct((2, T, 64), jnp.bfloat16)
+            r = jax.ShapeDtypeStruct((2, T, 8), jnp.float32)
+            before = read()
+            jax.eval_shape(lambda q: F._flash_fwd(
+                q, q, q, 0.125, True, bq, bk, **kw), x)
+            for fn in (F._flash_bwd_dq, F._flash_bwd_dkv):
+                jax.eval_shape(lambda q, r: fn(
+                    q, q, q, q, r, r, 0.125, True, bq, bk, **kw), x, r)
+            after = read()
+            return {key: after[key] - before[key] for key in after}
+
+        for case, want in (
+                (traced(1024, 1024, 1024), (16, 10, 4)),   # the cells' shape
+                # A 2 x 2 grid: the two cells on the diagonal skip inside
+                # (3 of 4 sub-tiles, 2 masked), the one below is one tile,
+                # the one above never runs (counted as 4).
+                (traced(1024, 512, 512), (13, 7, 4)),
+                # bq != bk: cells (0, 0), (1, 2) skip inside (7 of 8,
+                # 2 masked); (0, 1), (1, 3) are crossed off the sub-tile
+                # lattice and run as one masked tile; two run whole, two
+                # never.
+                (traced(2048, 1024, 512), (36, 18, 6)),
+                (traced(1024, 1024, 1024, q_off=jnp.int32(1024),
+                        k_off=jnp.int32(0), static_skip=False),
+                 (1, 1, 1))):                              # a ring partial
+            for kern in ("fwd", "bwd_dq", "bwd_dkv"):
+                assert tuple(case[(n, kern)] for n in (
+                    "total", "computed", "masked")) == want, (kern, case)

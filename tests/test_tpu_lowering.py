@@ -84,19 +84,22 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("B,T,H,D", [
-    (8, 1024, 16, 64),     # GPT-350M train step (chip_smoke.py)
-    (16, 1024, 12, 64),    # GPT-124M bench shape
-    (1, 8192, 12, 64),     # examples/gpt_long_context.py
+@pytest.mark.parametrize("B,T,H,D,causal", [
+    (8, 1024, 16, 64, True),    # GPT-350M train step (chip_smoke.py)
+    (16, 1024, 12, 64, True),   # GPT-124M bench shape
+    (1, 8192, 12, 64, True),    # examples/gpt_long_context.py: sub-tile
+                                # loops with bounds from the program ids
+    (8, 1024, 16, 64, False),   # every sub-tile, no mask
+    (2, 640, 4, 64, True),      # a whole-sequence block of five sub-tiles
 ])
-def test_flash_attention_compiles(tpu, real_kernels, B, T, H, D):
+def test_flash_attention_compiles(tpu, real_kernels, B, T, H, D, causal):
     from horovod_tpu.ops.flash_attention import flash_attention
 
     q = tpu.shape((B, T, H, D), jnp.bfloat16)
 
     def f(q, k, v):
         return jax.grad(lambda q, k, v: flash_attention(
-            q, k, v, causal=True).astype(jnp.float32).sum(),
+            q, k, v, causal=causal).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
     assert _has_kernel(tpu.compile(f, q, q, q))
