@@ -125,6 +125,11 @@ from .ops.flash_attention import (  # noqa: F401
     flash_attention,
     flash_ring_attention,
 )
+from .ops.sparse_attention import (  # noqa: F401
+    index_select,
+    masked_attention,
+    sparse_attention,
+)
 from .ops.fused_collective import (  # noqa: F401
     fused_all_gather_matmul,
     fused_matmul_reduce_scatter,
@@ -166,6 +171,7 @@ from . import moe  # noqa: F401  (expert-parallel MoE, docs/moe.md)
 from .moe import (  # noqa: F401
     MoELayer,
     moe_ffn,
+    moe_ffn_dropless,
 )
 from .parallel.pipeline import (  # noqa: F401
     PPSchedule,

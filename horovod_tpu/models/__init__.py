@@ -3,6 +3,10 @@ tf.keras.applications ResNet50 et al., docs/benchmarks.rst)."""
 
 from .gpt import GPT, GPTConfig, gpt_small, gpt_tiny  # noqa: F401
 from .mnist import MnistNet  # noqa: F401
+from .sparse_moe_decoder import (  # noqa: F401
+    SparseMoEConfig,
+    SparseMoEDecoder,
+)
 from .resnet import (  # noqa: F401
     ResNet,
     ResNet18,

@@ -23,6 +23,7 @@ from .layer import (  # noqa: F401
     moe_capacity,
     moe_ef_residuals,
     moe_ffn,
+    moe_ffn_dropless,
     moe_positions,
     moe_router,
 )

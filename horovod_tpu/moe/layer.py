@@ -286,6 +286,99 @@ def moe_ef_residuals(n_tokens: int, d_model: int, num_experts: int,
 
 
 # ---------------------------------------------------------------------------
+# The dropless path: the experts this chip holds, as grouped matmuls.
+# ---------------------------------------------------------------------------
+
+#: Rows a held expert's group starts on a multiple of: the row tile of the
+#: TPU compiler's grouped matmul (its tile list at 131,072 rows and 16
+#: groups is 256 + 15 long). With every group on a tile boundary no tile
+#: is visited for two groups, and the tiles visited are the buffer's.
+GROUP_ALIGN = 512
+
+
+def moe_ffn_dropless(x, params, *, experts_per_token: int,
+                     first_expert: int = 0, ep_axis=None,
+                     router_logits=None):
+    """Top-k gated SiLU experts over tokens ``x [N, C]`` with no capacity:
+    no token-choice is ever dropped. The layer is told which experts it
+    holds: ``params["router"]`` is ``[C, E]`` over ALL the experts (the
+    published width), ``w1``, ``w3`` ``[E_held, C, F]`` and ``w2``
+    ``[E_held, F, C]`` are experts ``first_expert .. first_expert +
+    E_held``. Every token is routed over all E (softmax, top
+    ``experts_per_token``, gates renormalised to sum one); the result is
+    the held experts' part, ``sum_{e held, chosen} gate_e * W2_e(silu(W1_e
+    x) * W3_e x)``; what the absent experts would add is left out (it is
+    another chip's to add). Returns ``(y [N, C], MoEAux)`` with ``load``
+    the token-choices per GLOBAL expert and ``dropped_fraction`` 0.
+
+    The N*K token-choices are sorted by held expert and laid into a row
+    buffer in which every held expert's group starts on a multiple of
+    ``GROUP_ALIGN``; their tokens are gathered, the held experts run as
+    three ``lax.ragged_dot`` over the groups, and a scatter-add brings the
+    rows back to their tokens. The buffer holds N*K rows and a tile a
+    group, the most the held experts can be sent, so one program takes any
+    routing and nothing is dropped. The grouped matmuls run over EVERY row
+    tile of the buffer (the last group is given the rows no choice fills,
+    which are zero on the way in and out, in both directions): the layer's
+    time is the same under any routing. Computing the filled tiles alone
+    follows the load, 0.006 to 2.8 x uniform a layer at seeded weights
+    (PERF.md, PR 26); it is ROADMAP S13, and wants a balanced router
+    first. Without ``ep_axis`` bound nothing is exchanged; the exchange
+    across ``hvd_ep`` for this path is not built (ROADMAP R1)."""
+    if ep_axis is not None and _axis_size(ep_axis) > 1:
+        raise NotImplementedError(
+            "moe_ffn_dropless: the expert exchange across hvd_ep is not "
+            "built for the dropless path (ROADMAP R1); run it with the "
+            "experts this chip holds and no ep axis")
+    from ..monitor.registry import counter
+
+    N, C = x.shape
+    K, held = int(experts_per_token), params["w1"].shape[0]
+    E = params["router"].shape[-1]
+    if not 0 <= first_expert <= E - held:
+        raise ValueError(f"experts {first_expert}..{first_expert + held} "
+                         f"are not among the router's {E}")
+    A = GROUP_ALIGN
+    R = -(-N * K // A) * A + held * A
+    counter("moe.experts_held").inc(held)
+    counter("moe.rows_grouped").inc(R)
+    with jax.named_scope("hvd.moe_ffn"):
+        experts, gates, lb, z, _ = moe_router(
+            x, params["router"], topk=K, router_logits=router_logits)
+        local = experts.reshape(-1) - first_expert           # [N*K]
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        _, order = lax.sort((key, jnp.arange(N * K, dtype=jnp.int32)),
+                            num_keys=1)
+        sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
+        padded = -(-sizes // A) * A
+        start = jnp.cumsum(sizes) - sizes
+        first_row = jnp.cumsum(padded) - padded
+        # Row j of the buffer: its group (rows past the last group's tiles
+        # count to it), its rank there, and the sorted choice it holds.
+        row = jnp.arange(R, dtype=jnp.int32)
+        group = jnp.minimum(held - 1, jnp.sum(
+            row[:, None] >= (first_row + padded)[None, :], axis=1))
+        rank = row - first_row[group]
+        live = rank < sizes[group]
+        choice = order[jnp.where(live, start[group] + rank, 0)]
+        token, gate = choice // K, gates.reshape(-1)[choice]
+        live = live[:, None]
+        xs = jnp.where(live, x[token], 0)
+        every_row = padded.at[-1].add(R - jnp.sum(padded))
+        w1, w3, w2 = (params[n].astype(x.dtype) for n in ("w1", "w3", "w2"))
+        h = nn.silu(lax.ragged_dot(xs, w1, every_row)) \
+            * lax.ragged_dot(xs, w3, every_row)
+        ys = jnp.where(live, lax.ragged_dot(h, w2, every_row), 0)
+        y = jax.ops.segment_sum(ys * gate[:, None].astype(ys.dtype), token,
+                                num_segments=N)
+    load = jnp.sum(jax.nn.one_hot(experts, E, dtype=jnp.float32),
+                   axis=(0, 1))
+    return y.astype(x.dtype), MoEAux(
+        load_balance_loss=lb, z_loss=z, load=load,
+        dropped_fraction=jnp.zeros((), jnp.float32))
+
+
+# ---------------------------------------------------------------------------
 # The flax module.
 # ---------------------------------------------------------------------------
 

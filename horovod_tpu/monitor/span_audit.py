@@ -55,6 +55,9 @@ DEVICE_SCOPES = (
     "hvd.lm_head_loss",      # ops/softmax_xent.py: head matmul + xent
     "hvd.flash_attention",   # ops/flash_attention.py: kernels + layout
     "hvd.layer_norm",        # ops/layer_norm.py: fused residual + LN
+    "hvd.sparse_attention",  # ops/sparse_attention.py: kernels + layout
+    "hvd.sparse_indexer",    # ops/sparse_attention.py: scores + selection
+    "hvd.moe_ffn",           # moe/layer.py: dropless router..combine
     "hvd.allreduce_grads",   # parallel/optimizer.py, tape.py: grad exchange
     "hvd.bucket_pack",       # ops/fusion.py: leaves -> flat bucket
     "hvd.bucket_allreduce",  # ops/fusion.py: the per-bucket wire op

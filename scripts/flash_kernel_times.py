@@ -46,8 +46,9 @@ def load_module(path):
     return mod
 
 
-def kernel_us(trace_dir):
-    """{kernel name: mean us per event} on the first device of a trace."""
+def kernel_us(trace_dir, kernels=KERNELS):
+    """{kernel name: (mean us per event, events)} on the first device of a
+    trace, for the ``pallas_call`` names ``kernels``."""
     from jax.profiler import ProfileData
 
     path = sorted(glob.glob(os.path.join(
@@ -55,12 +56,12 @@ def kernel_us(trace_dir):
     planes = [p for p in ProfileData.from_file(path).planes
               if p.name.startswith("/device:TPU:")]
     plane = min(planes, key=lambda p: p.name)
-    found = {k: [] for k in KERNELS}
+    found = {k: [] for k in kernels}
     for line in plane.lines:
         if line.name != "XLA Ops":
             continue
         for e in line.events:
-            for k in KERNELS:
+            for k in kernels:
                 # "hvd_flash_bwd_dq" must not also count under "..._dkv".
                 if f"%{k}." in e.name or f"%{k} " in e.name:
                     found[k].append(e.duration_ns * 1e-3)

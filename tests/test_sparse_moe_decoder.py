@@ -1,0 +1,165 @@
+"""``horovod_tpu.models.SparseMoEDecoder`` against the plain reference
+(benchmarks/lib/reference_sparse_moe.py) on seeded random weights at a
+small size: loss, every gradient leaf and one AdamW step."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from benchmarks.lib import reference_sparse_moe as ref
+from horovod_tpu.models import SparseMoEConfig, SparseMoEDecoder
+
+CFG = {"layers": 2, "num_hidden_layers": 2, "hidden_size": 64,
+       "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+       "vocab_size": 96, "num_experts": 8, "num_local_experts": 4,
+       "first_local_expert": 2, "num_experts_per_tok": 2,
+       "moe_intermediate_size": 32, "rms_norm_eps": 1e-6,
+       "rope_theta": 10000000,
+       "sa_config": {"indexer_head_dim": 8, "indexer_num_heads": 2,
+                     "indexer_num_kv_heads": 1, "topk": 16}}
+OPT = {"lr": 3e-4, "b1": 0.9, "b2": 0.95, "eps": 1e-8, "weight_decay": 0.1,
+       "clip_norm": 1.0}
+T = 64
+SIZES = ref.sizes_from_config(CFG)
+
+
+def _tokens(seed):
+    return jax.random.randint(jax.random.key(seed), (1, T + 1), 0,
+                              CFG["vocab_size"])
+
+
+def _program_loss(model, toks):
+    def loss(p):
+        logits = model.apply({"params": p}, toks[:, :-1])
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+        return -jnp.take_along_axis(logp, toks[:, 1:, None], -1).sum()
+    return loss
+
+
+@pytest.fixture(scope="module")
+def float32_pair():
+    """(params, tokens, program (loss, grads), reference (loss, grads))
+    with the program in float32 at ``highest``: the same arithmetic."""
+    params = jax.jit(functools.partial(ref.make_params, s=SIZES))(
+        jnp.uint32(3))
+    toks = _tokens(1)
+    model = SparseMoEDecoder(SparseMoEConfig.from_dict(
+        CFG, dtype=jnp.float32))
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(_program_loss(model, toks))(params)
+    want = jax.value_and_grad(
+        lambda p: ref.loss_sum(p, toks, SIZES, q_block=32))(params)
+    return params, toks, got, want
+
+
+def test_parameter_tree_is_the_references():
+    model = SparseMoEDecoder(SparseMoEConfig.from_dict(CFG))
+    want = jax.eval_shape(model.init, jax.random.key(0),
+                          jax.ShapeDtypeStruct((1, T), jnp.int32))["params"]
+    got = jax.eval_shape(functools.partial(ref.make_params, s=SIZES),
+                         jax.ShapeDtypeStruct((), jnp.uint32))
+    assert jax.tree.structure(want) == jax.tree.structure(got)
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+
+
+def test_config_reads_the_published_keys():
+    cfg = SparseMoEConfig.from_dict(CFG)
+    assert (cfg.layers, cfg.topk, cfg.indexer_num_heads,
+            cfg.indexer_head_dim) == (2, 16, 2, 8)
+    assert (cfg.num_experts, cfg.num_local_experts,
+            cfg.first_local_expert) == (8, 4, 2)
+    assert SparseMoEConfig.from_dict(
+        {k: v for k, v in CFG.items() if k != "layers"}).layers == 2
+
+
+def test_loss_is_the_references(float32_pair):
+    """(a): float32 against float32: 1e-6 relative (sums in another
+    order)."""
+    _, _, (loss, _), (want, _) = float32_pair
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
+
+
+@pytest.mark.parametrize("leaf", sorted(ref.path_dict(jax.eval_shape(
+    functools.partial(ref.make_params, s=SIZES),
+    jax.ShapeDtypeStruct((), jnp.uint32)))))
+def test_gradient_leaf_is_the_references(float32_pair, leaf):
+    """(a) every gradient leaf, to 1e-5 of the leaf's largest entry
+    (float32 rounding through two layers); (d) the indexer's three
+    weights get exactly zero, in the program as in the reference."""
+    _, _, (_, got), (_, want) = float32_pair
+    a, b = ref.path_dict(got)[leaf], ref.path_dict(want)[leaf]
+    if "/indexer/" in leaf:
+        assert not np.asarray(a).any() and not np.asarray(b).any()
+        return
+    assert float(jnp.abs(b).max()) > 0
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                               atol=1e-5 * float(jnp.abs(b).max()))
+
+
+def test_one_adamw_step_is_the_references(float32_pair):
+    """(a): clip + AdamW through optax on the program's gradient lands
+    where the reference's written-out step lands: the worst leaf's change
+    agrees to 1e-4 of its norm (Adam divides by sqrt(v): a gradient entry
+    near zero turns its rounding into a step of up to lr)."""
+    params, toks, (_, grads), _ = float32_pair
+    n_tok = toks.shape[1] - 1
+    tx = optax.chain(optax.clip_by_global_norm(OPT["clip_norm"]), optax.adamw(
+        OPT["lr"], b1=OPT["b1"], b2=OPT["b2"], eps=OPT["eps"],
+        weight_decay=OPT["weight_decay"]))
+    g = jax.tree.map(lambda a: a / n_tok, grads)
+    updates, _ = tx.update(g, tx.init(params), params)
+    got = ref.leaf_norms(updates)
+    out = jax.jit(functools.partial(
+        ref.train_steps, s=SIZES, opt=OPT, micro_rows=1, q_block=32))(
+        jnp.uint32(3), toks[None])
+    np.testing.assert_allclose(
+        float(out["loss"][0]), float(float32_pair[2][0]) / n_tok, rtol=1e-6)
+    for leaf, want in out["delta_norm"].items():
+        # atol: the reference subtracts two float32 weight trees, so a
+        # change of lr * wd * w (an indexer leaf's, 7e-6) carries the
+        # weights' own rounding
+        np.testing.assert_allclose(float(got[leaf]), float(want), rtol=1e-4,
+                                   atol=5e-9, err_msg=leaf)
+
+
+def test_bfloat16_model_tracks_the_reference():
+    """The model as the benchmark runs it (bf16 activations): the loss
+    within 2e-3 of the float32 reference's (per token), no leaf's gradient
+    norm further than 6% of max(leaf, median leaf): selection and routing
+    flip near their thresholds at this size, one key is 1/16 of a row."""
+    params = jax.jit(functools.partial(ref.make_params, s=SIZES))(
+        jnp.uint32(5))
+    toks = _tokens(2)
+    model = SparseMoEDecoder(SparseMoEConfig.from_dict(CFG))
+    loss, grads = jax.value_and_grad(_program_loss(model, toks))(params)
+    want, wgrads = jax.value_and_grad(
+        lambda p: ref.loss_sum(p, toks, SIZES, q_block=32))(params)
+    assert abs(float(loss) - float(want)) / T < 2e-3
+    got, ref_norms = ref.leaf_norms(grads), ref.leaf_norms(wgrads)
+    floor = float(np.median([float(v) for v in ref_norms.values()]))
+    for leaf, n in ref_norms.items():
+        gap = abs(float(got[leaf]) - float(n)) / max(float(n), floor)
+        assert gap < 0.06, (leaf, gap)
+
+
+def test_return_hidden_feeds_the_untied_head():
+    import horovod_tpu as hvd
+
+    params = jax.jit(functools.partial(ref.make_params, s=SIZES))(
+        jnp.uint32(6))
+    toks = _tokens(3)
+    cfg = SparseMoEConfig.from_dict(CFG, dtype=jnp.float32)
+    logits = SparseMoEDecoder(cfg).apply({"params": params}, toks[:, :-1])
+    hidden = SparseMoEDecoder(SparseMoEConfig.from_dict(
+        CFG, dtype=jnp.float32, return_hidden=True)).apply(
+        {"params": params}, toks[:, :-1])
+    assert hidden.shape == (1, T, CFG["hidden_size"])
+    loss = hvd.lm_head_loss(hidden, params["head"], toks[:, 1:]).mean()
+    logp = jax.nn.log_softmax(logits, -1)
+    want = -jnp.take_along_axis(logp, toks[:, 1:, None], -1).mean()
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-4)

@@ -105,6 +105,33 @@ def test_flash_attention_compiles(tpu, real_kernels, B, T, H, D, causal):
     assert _has_kernel(tpu.compile(f, q, q, q))
 
 
+@pytest.mark.parametrize("T", [
+    16384,   # keye-vl2-30b-a3b.train-16k-1chip: [128, T] index keys (8 MB)
+             # and (1024, 1024) score tiles x 8 query heads in VMEM
+    2048,    # one attention block a row, two index chunks
+])
+def test_sparse_attention_compiles(tpu, real_kernels, T):
+    """The index kernel and the three masked-attention kernels at the
+    benchmark's widths: 32/4 heads of 128, indexer 16 x 64, topk 2048."""
+    from horovod_tpu.ops.sparse_attention import sparse_attention
+
+    q = tpu.shape((1, T, 32, 128), jnp.bfloat16)
+    k = tpu.shape((1, T, 4, 128), jnp.bfloat16)
+    qi = tpu.shape((1, T, 16, 64), jnp.bfloat16)
+    ki = tpu.shape((1, T, 64), jnp.bfloat16)
+    w = tpu.shape((1, T, 16), jnp.float32)
+
+    def f(q, k, v, qi, ki, w):
+        return jax.grad(lambda q, k, v: sparse_attention(
+            q, k, v, qi, ki, w, topk=2048).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    text = tpu.compile(f, q, k, k, qi, ki, w).as_text()
+    for name in ("hvd_index_select", "hvd_sparse_attn_fwd",
+                 "hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv"):
+        assert name in text
+
+
 @pytest.mark.parametrize("B,T,C", [
     (8, 1024, 1024),   # 350M blocks: the shape Mosaic once refused
     (16, 1024, 768),   # 124M bench shape
