@@ -13,10 +13,13 @@ holds), an untied head. No bias anywhere. Driven by the published
 bfloat16 activations and matmul operands with float32 accumulation;
 float32 parameters, norms, rotary angles, softmax statistics and router
 probabilities. Each block is rematerialised in the backward pass and keeps
-only the attention kernel's output and log-sum-exp rows (``jax.checkpoint``
-with ``save_only_these_names``): the forward kernel is the one part that
-costs more to redo (18 ms a layer at T = 16k) than to keep (0.14 GB); the
-selection is made again (7 ms) and its mask (0.27 GB) is not kept.
+two named values of ``hvd.sparse_attention`` (``jax.checkpoint`` with
+``save_only_these_names``): the forward kernel's output and log-sum-exp
+rows (0.14 GB a layer at T = 16k, against 18 ms to redo them) and the
+selection at one bit a pair (``T * T / 8`` bytes: 34 MB, against 7 ms of
+index kernel; its int8 mask, 0.27 GB, is not kept). The recomputed forward
+then runs neither kernel, nor the indexer's projections, which feed nothing
+but the selection.
 
 Initial weights: normal(``initializer_range``) for every matrix and the
 embedding, ones for every RMSNorm scale (the family's convention).
@@ -38,7 +41,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..moe.layer import moe_ffn_dropless
-from ..ops.sparse_attention import OUT_NAME, sparse_attention
+from ..ops.sparse_attention import (OUT_NAME, SELECTION_NAME,
+                                    sparse_attention)
 
 
 @dataclass(frozen=True)
@@ -213,7 +217,7 @@ class SparseMoEDecoder(nn.Module):
         x = embed.astype(cfg.dtype)[tokens]
         block = nn.remat(
             _Block, policy=jax.checkpoint_policies.save_only_these_names(
-                OUT_NAME))
+                OUT_NAME, SELECTION_NAME))
         for i in range(cfg.layers):
             x = block(cfg, name=f"h{i}")(x)
         x = _Scale(cfg.rms_norm_eps, name="ln_f")(x)

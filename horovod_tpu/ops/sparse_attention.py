@@ -32,17 +32,24 @@ kernels are):
 :func:`sparse_attention` composes them under the scopes
 ``hvd.sparse_indexer`` and ``hvd.sparse_attention``. No gradient reaches
 the indexer's operands or passes through the selection (the mask is an
-integer). For a rematerialised block the forward kernel's output and
-log-sum-exp row carry the ``checkpoint_name`` ``hvd_sparse_out`` (0.14 GB
-a layer at the benchmark's shape, against 18 ms of forward kernel), which
-``models/sparse_moe_decoder.py`` keeps; the selection (7 ms, 0.27 GB of
-mask) is made again.
+integer).
+
+What the backward pass keeps of the mask is one bit a (query, key) pair
+(:func:`pack_selection`: 34 MB a layer at the benchmark's T = 16k where
+the int8 mask is 268 MB), and its kernels read the unpacked form. For a
+rematerialised block two values carry a ``checkpoint_name``: the forward
+kernel's output and log-sum-exp row (``OUT_NAME``, 0.14 GB a layer against
+18 ms of forward kernel) and the packed selection (``SELECTION_NAME``,
+against 7 ms of index kernel and the indexer's projections).
+``models/sparse_moe_decoder.py`` keeps both, so the recomputed forward
+holds neither kernel: what feeds only a saved value is dead there.
 
 Trace-time counters (monitor registry): ``sparse_attn.topk``,
 ``sparse_attn.pairs_required`` (sum over queries of min(t + 1, topk), per
 query head) and ``sparse_attn.pairs_computed`` (entries of the score tiles
 a kernel runs), label ``kernel`` = ``index`` | ``fwd`` | ``bwd_dq`` |
-``bwd_dkv``.
+``bwd_dkv``; ``sparse_attn.selection_bytes`` (the packed selection a
+differentiated call names, ``B * T * T / 8``).
 """
 
 from __future__ import annotations
@@ -63,6 +70,7 @@ from . import flash_attention as _fa
 _NEG_INF = _fa._NEG_INF
 _INT_MIN = -2 ** 31
 OUT_NAME = "hvd_sparse_out"
+SELECTION_NAME = "hvd_sparse_selection"
 
 # Blocks: the index kernel keeps [_INDEX_BLOCK_Q, T] int32 keys in VMEM
 # (8 MB at T = 16k) and walks them _INDEX_CHUNK columns at a time; the
@@ -80,10 +88,10 @@ def _params(*semantics):
                                 vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _count(name: str, kernel: str, n: int) -> None:
+def _count(name: str, n: int, **labels: str) -> None:
     from ..monitor.registry import counter
 
-    counter(f"sparse_attn.{name}", kernel=kernel).inc(int(n))
+    counter(f"sparse_attn.{name}", **labels).inc(int(n))
 
 
 def pairs_required(T: int, topk: int) -> int:
@@ -224,15 +232,44 @@ def index_select(index_q, index_k, index_w, *, topk: int):
     ck = _fa._pick_block(T, _INDEX_CHUNK)
     if bq is None or ck is None:
         raise ValueError(f"sequence length {T} has no 128-aligned block")
-    _count("topk", "index", topk)
-    _count("pairs_required", "index", B * pairs_required(T, topk))
-    _count("pairs_computed", "index", B * sum(
-        -(-(i * bq + bq) // ck) * ck * bq for i in range(T // bq)))
+    _count("topk", topk, kernel="index")
+    _count("pairs_required", B * pairs_required(T, topk), kernel="index")
+    _count("pairs_computed", B * sum(
+        -(-(i * bq + bq) // ck) * ck * bq for i in range(T // bq)),
+        kernel="index")
     qi, ki, w = _fa._harmonize_vma(*(lax.stop_gradient(x) for x in (
         index_q, index_k, index_w)))
     mask, tau = _index_call(qi, ki, w, topk=int(topk), bq=bq, ck=ck,
                             interpret=_fa._interpret())
     return mask, tau[..., 0]
+
+
+# ---------------------------------------------------------------------------
+# the selection, one bit a pair
+# ---------------------------------------------------------------------------
+
+
+def pack_selection(mask):
+    """``mask [B, T, T]`` (non-zero = selected) as uint8 ``[B, T/8, T]``:
+    bit ``r`` of byte ``[b, t, s]`` is ``mask[b, r * T/8 + t, s]``. Eight
+    row slabs or-ed together, so that packing and unpacking are
+    elementwise passes at HBM's rate and no lane is shuffled. Each slab is
+    cut from the mask as given and compared on its own: the compiler then
+    makes one pass of it (a compare of the whole mask first is a second
+    ``[T, T]`` array in HBM)."""
+    B, T, S = mask.shape
+    slabs = mask.reshape(B, 8, T // 8, S)
+    return functools.reduce(jnp.bitwise_or, (
+        (slabs[:, r] != 0).astype(jnp.uint8) << r for r in range(8)))
+
+
+def unpack_selection(packed):
+    """The int8 0/1 mask ``[B, T, T]`` that :func:`pack_selection`
+    packed. A concatenation of the eight slabs, which the compiler writes
+    in place slab by slab; shifting a broadcast of the bytes by a row's
+    slab number reads better and leaves the broadcast in HBM."""
+    return jnp.concatenate(
+        [((packed >> r) & 1).astype(jnp.int8) for r in range(8)], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -457,14 +494,9 @@ def _bwd_dkv_call(q, k, v, mask, do, lse, delta, *, scale, hkv, bq, bk,
 def _count_pairs(kernel, q, topk_pairs, bq, bk):
     BHk, G, T, _ = q.shape
     cells = sum(_last_k(i, bq, bk) + 1 for i in range(T // bq))
-    _count("pairs_computed", kernel, BHk * G * cells * bq * bk)
+    _count("pairs_computed", BHk * G * cells * bq * bk, kernel=kernel)
     if topk_pairs is not None:
-        _count("pairs_required", kernel, BHk * G * topk_pairs)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _masked(q, k, v, mask, scale, hkv, required):
-    return _masked_fwd(q, k, v, mask, scale, hkv, required)[0]
+        _count("pairs_required", BHk * G * topk_pairs, kernel=kernel)
 
 
 def _kw(q, scale, hkv):
@@ -473,18 +505,38 @@ def _kw(q, scale, hkv):
                 bk=_fa._pick_block(T, _BLOCK_K), interpret=_fa._interpret())
 
 
-def _masked_fwd(q, k, v, mask, scale, hkv, required):
+def _forward(q, k, v, mask, scale, hkv, required):
     kw = _kw(q, scale, hkv)
     _count_pairs("fwd", q, required, kw["bq"], kw["bk"])
     o, lse = _fwd_call(q, k, v, mask, **kw)
     # One lane of the eight the kernel writes: a saved [.., T, 8] float32
     # pads to 128 lanes in HBM (256 MB a layer at T = 16k).
-    o, lse = (checkpoint_name(x, OUT_NAME) for x in (o, lse[..., 0]))
-    return o, (q, k, v, mask, o, lse)
+    return tuple(checkpoint_name(x, OUT_NAME) for x in (o, lse[..., 0]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _masked(q, k, v, mask, scale, hkv, required):
+    return _forward(q, k, v, mask, scale, hkv, required)[0]
+
+
+def _masked_fwd(q, k, v, mask, scale, hkv, required):
+    o, lse = _forward(q, k, v, mask, scale, hkv, required)
+    # The forward kernel reads the mask as given; the backward pass keeps
+    # it packed. Under a policy that saves SELECTION_NAME whatever made
+    # the mask feeds, in the recomputed forward, only this saved value.
+    selection = checkpoint_name(pack_selection(mask), SELECTION_NAME)
+    _count("selection_bytes", selection.nbytes)
+    return o, (q, k, v, selection, o, lse)
 
 
 def _masked_bwd(scale, hkv, required, res, do):
-    q, k, v, mask, o, lse = res
+    q, k, v, selection, o, lse = res
+    # The packed selection is there since the forward pass: unpacked as
+    # soon as it can be, the [T, T] mask lies in HBM through the backward
+    # pass of whatever follows the attention (0.27 GB more at the step's
+    # peak in the benchmark's model). It waits for the cotangent.
+    selection, do = lax.optimization_barrier((selection, do))
+    mask = unpack_selection(selection)
     kw = _kw(q, scale, hkv)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     lse, delta = (jnp.broadcast_to(x[..., None], (*x.shape, 8))

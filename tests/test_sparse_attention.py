@@ -154,6 +154,77 @@ def test_sparse_attention_is_selection_then_attention():
     np.testing.assert_array_equal(np.asarray(o), np.asarray(o2))
 
 
+def _selected(seed, T_):
+    x = _inputs(seed, T_)
+    return hvd.index_select(x["qi"][None], x["ki"][None], x["w"][None],
+                            topk=TOPK)[0]
+
+
+def _random_mask(low, high, shape):
+    return jnp.asarray(np.random.default_rng(0).integers(
+        low, high, shape).astype(np.int8))
+
+
+# name -> () -> mask [B, T, T]: what the backward pass must read back
+_SELECTIONS = {
+    # every causal pair ties at the threshold: a full lower triangle
+    "ties": lambda: jnp.asarray(np.tril(np.ones((1, 128, 128), np.int8))),
+    "empty_upper_triangle": lambda: _selected(3, T),
+    "T_not_a_multiple_of_1024": lambda: _selected(4, 384),
+    # each of the eight row slabs has its own bit: no two rows alike
+    "two_sequences_of_random_bits": lambda: _random_mask(0, 2, (2, 256, 256)),
+    # masked_attention reads non-zero as "attend"
+    "any_non_zero_is_selected": lambda: _random_mask(-2, 3, (1, 128, 128)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SELECTIONS))
+def test_packed_selection_unpacks_to_the_mask(case):
+    """One bit a pair: ``[B, T/8, T]`` uint8, bit r of byte [t, s] is row
+    ``r * T/8 + t``; unpacking gives the 0/1 int8 mask back to the bit."""
+    mask = _SELECTIONS[case]()
+    B, T_, _ = mask.shape
+    packed = sa.pack_selection(mask)
+    assert packed.dtype == jnp.uint8 and packed.shape == (B, T_ // 8, T_)
+    want = np.asarray(mask != 0)
+    rows = T_ // 8
+    for r in (0, 3, 7):
+        np.testing.assert_array_equal(np.asarray(packed >> r) & 1,
+                                      want[:, r * rows:(r + 1) * rows])
+    back = sa.unpack_selection(packed)
+    assert back.dtype == jnp.int8 and back.shape == mask.shape
+    np.testing.assert_array_equal(np.asarray(back), want.astype(np.int8))
+
+
+@pytest.mark.parametrize("T_", [T, 384])
+def test_the_backward_reads_the_selection_the_forward_made(monkeypatch, T_):
+    """``sparse_attention``'s value and its gradients in q, k and v are, to
+    the bit, ``masked_attention``'s on ``index_select``'s mask, and what
+    the backward kernels give when handed that int8 mask itself (packing
+    taken out: the arithmetic of before)."""
+    x = _inputs(13, T_)
+    names = ("q", "k", "v", "qi", "ki", "w")
+    args = tuple(x[n][None] for n in names)
+    ct = jax.random.normal(jax.random.key(14), (1, T_, H, D))
+    mask, _ = hvd.index_select(*args[3:], topk=TOPK)
+
+    def sparse(q, k, v):
+        return (hvd.sparse_attention(q, k, v, *args[3:], topk=TOPK)
+                * ct).sum()
+
+    def masked(q, k, v):
+        return (hvd.masked_attention(q, k, v, mask) * ct).sum()
+
+    got = jax.value_and_grad(sparse, argnums=(0, 1, 2))(*args[:3])
+    want = jax.value_and_grad(masked, argnums=(0, 1, 2))(*args[:3])
+    monkeypatch.setattr(sa, "pack_selection", lambda m: m)
+    monkeypatch.setattr(sa, "unpack_selection", lambda p: p)
+    unpacked = jax.value_and_grad(masked, argnums=(0, 1, 2))(*args[:3])
+    for other in (want, unpacked):
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(other)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_no_gradient_reaches_the_indexer_or_passes_the_selection():
     """(d): the indexer's operands get exactly zero, and q, k, v get what
     attention over the FIXED set gives them."""
@@ -224,6 +295,23 @@ def test_trace_time_counters():
     for kern in ("fwd", "bwd_dq", "bwd_dkv"):
         assert got[("pairs_required", kern)] == H * required
         assert got[("pairs_computed", kern)] == H * 128 * 128
+
+
+@pytest.mark.parametrize("B,T_", [(1, 128), (2, 256)])
+def test_selection_bytes_counter(B, T_):
+    """``sparse_attn.selection_bytes``: a differentiated call names
+    ``B * T * T / 8`` bytes of packed selection; a call that is not
+    differentiated packs nothing."""
+    xs = [_inputs(20 + b, T_) for b in range(B)]
+    args = tuple(jnp.stack([x[n] for x in xs])
+                 for n in ("q", "k", "v", "qi", "ki", "w"))
+    named = counter("sparse_attn.selection_bytes")
+    before = named.value
+    hvd.sparse_attention(*args, topk=TOPK)
+    assert named.value == before
+    jax.grad(lambda q: hvd.sparse_attention(q, *args[1:],
+                                            topk=TOPK).sum())(args[0])
+    assert named.value - before == B * T_ * T_ // 8
 
 
 def test_shapes_that_cannot_be_blocked_are_refused():

@@ -147,6 +147,45 @@ def test_bfloat16_model_tracks_the_reference():
         assert gap < 0.06, (leaf, gap)
 
 
+def _kernel_calls(jaxpr, counts=None):
+    """name -> number of ``pallas_call`` equations in ``jaxpr`` and every
+    jaxpr nested in it."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            counts[name] = counts.get(name, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_calls(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize("kept,index_calls", [
+    ("the_models_policy", CFG["layers"]),
+    ("the_attention_output_alone", 2 * CFG["layers"]),
+])
+def test_recomputed_forward_holds_no_index_kernel(monkeypatch, kept,
+                                                  index_calls):
+    """The blocks keep the packed selection beside the attention kernel's
+    output, so the differentiated model selects once a layer: what feeds
+    only a saved name is dropped from the recomputed forward. With the
+    selection's name out of the policy (the model of before) every layer
+    selects twice. The attention kernels run once a layer either way."""
+    import horovod_tpu.models.sparse_moe_decoder as module
+
+    if kept == "the_attention_output_alone":
+        monkeypatch.setattr(module, "SELECTION_NAME", "kept_by_no_value")
+    params = jax.eval_shape(functools.partial(ref.make_params, s=SIZES),
+                            jax.ShapeDtypeStruct((), jnp.uint32))
+    model = SparseMoEDecoder(SparseMoEConfig.from_dict(CFG))
+    calls = _kernel_calls(jax.make_jaxpr(jax.grad(
+        _program_loss(model, _tokens(4))))(params).jaxpr)
+    assert calls == {"hvd_index_select": index_calls,
+                     "hvd_sparse_attn_fwd": CFG["layers"],
+                     "hvd_sparse_attn_bwd_dq": CFG["layers"],
+                     "hvd_sparse_attn_bwd_dkv": CFG["layers"]}
+
+
 def test_return_hidden_feeds_the_untied_head():
     import horovod_tpu as hvd
 

@@ -105,6 +105,16 @@ def test_flash_attention_compiles(tpu, real_kernels, B, T, H, D, causal):
     assert _has_kernel(tpu.compile(f, q, q, q))
 
 
+def _sparse_shapes(tpu, T):
+    """(q, k, v, index_q, index_k, index_w) at the benchmark's widths:
+    32/4 heads of 128, indexer 16 x 64."""
+    kv = tpu.shape((1, T, 4, 128), jnp.bfloat16)
+    return (tpu.shape((1, T, 32, 128), jnp.bfloat16), kv, kv,
+            tpu.shape((1, T, 16, 64), jnp.bfloat16),
+            tpu.shape((1, T, 64), jnp.bfloat16),
+            tpu.shape((1, T, 16), jnp.float32))
+
+
 @pytest.mark.parametrize("T", [
     16384,   # keye-vl2-30b-a3b.train-16k-1chip: [128, T] index keys (8 MB)
              # and (1024, 1024) score tiles x 8 query heads in VMEM
@@ -115,21 +125,58 @@ def test_sparse_attention_compiles(tpu, real_kernels, T):
     benchmark's widths: 32/4 heads of 128, indexer 16 x 64, topk 2048."""
     from horovod_tpu.ops.sparse_attention import sparse_attention
 
-    q = tpu.shape((1, T, 32, 128), jnp.bfloat16)
-    k = tpu.shape((1, T, 4, 128), jnp.bfloat16)
-    qi = tpu.shape((1, T, 16, 64), jnp.bfloat16)
-    ki = tpu.shape((1, T, 64), jnp.bfloat16)
-    w = tpu.shape((1, T, 16), jnp.float32)
-
     def f(q, k, v, qi, ki, w):
         return jax.grad(lambda q, k, v: sparse_attention(
             q, k, v, qi, ki, w, topk=2048).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
 
-    text = tpu.compile(f, q, k, k, qi, ki, w).as_text()
+    text = tpu.compile(f, *_sparse_shapes(tpu, T)).as_text()
     for name in ("hvd_index_select", "hvd_sparse_attn_fwd",
                  "hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv"):
         assert name in text
+
+
+@pytest.mark.parametrize("kept,index_calls", [
+    (("OUT_NAME", "SELECTION_NAME"), 1),   # models/sparse_moe_decoder.py's
+    (("OUT_NAME",), 2),                    # the policy of before
+])
+def test_saved_selection_leaves_one_index_kernel(tpu, real_kernels, kept,
+                                                 index_calls):
+    """A rematerialised block that keeps the packed selection selects
+    once, in the program the TPU compiler makes of it, and what it keeps
+    is packed and unpacked in passes that put no second ``[T, T]`` array
+    into HBM (the whole mask compared before it is cut, or the bytes
+    broadcast before they are shifted)."""
+    import re
+
+    from horovod_tpu.ops import sparse_attention as sa
+
+    T = 2048
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *(getattr(sa, name) for name in kept))
+
+    def f(q, k, v, qi, ki, w):
+        # the scalings stand for the block's projections: work between
+        # the block's input and the op that is recomputed too
+        block = jax.checkpoint(
+            lambda q, k, v: sa.sparse_attention(
+                q * 2, k, v, qi * 2, ki, w, topk=2048), policy=policy)
+        return jax.grad(lambda q, k, v: block(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text = tpu.compile(f, *_sparse_shapes(tpu, T)).as_text()
+
+    def calls(name):
+        return len(re.findall(rf"%{name}[.\d]* = [^\n]*custom-call\(", text))
+
+    assert calls("hvd_index_select") == index_calls
+    for name in ("hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd_dq",
+                 "hvd_sparse_attn_bwd_dkv"):
+        assert calls(name) == 1
+    if "SELECTION_NAME" in kept:
+        assert f"u8[1,{T // 8},{T}]" in text
+        for whole in (f"u8[1,{T},{T}]", f"u8[8,{T // 8},{T}]"):
+            assert whole not in text
 
 
 @pytest.mark.parametrize("B,T,C", [
