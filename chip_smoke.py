@@ -26,12 +26,11 @@ milliseconds and peak memory are printed so a bring-up can be judged, and
 are not speed results: nothing printed here belongs in README or PERF.md as
 a rate.
 
-Train phase: GPT-350M exactly as ``bench.py --model gpt --gpt-scale 350m``
-builds it (24 layers, 16 heads, d_model 1024, d_ff 4096, vocab 32000, seq
-1024, bf16, Pallas flash attention, LM head through
-``lm_head_loss(mode="auto")``), per-chip batch 8, the README "Benchmark
-methodology" step: ``hvd.DistributedOptimizer`` inside ``hvd.shard_map``
-over ``hvd.mesh()`` with donated state. Serve phase: ``GenerationEngine``
+Train phase: GPT-350M (24 layers, 16 heads, d_model 1024, d_ff 4096,
+vocab 32000, seq 1024, bf16, Pallas flash attention, LM head through
+``lm_head_loss(mode="auto")``), per-chip batch 8, the step the benchmark's
+``benchmarks/builders/gpt_decoder.py`` builds: ``hvd.DistributedOptimizer``
+inside ``hvd.shard_map`` over ``hvd.mesh()`` with donated state. Serve phase: ``GenerationEngine``
 on a GPT-124M-width model through ``submit``/``run``, greedy tokens checked
 against a plain full-recompute decode.
 """
@@ -66,7 +65,7 @@ GREEDY_TIE_ATOL = 5e-2
 
 @dataclasses.dataclass(frozen=True)
 class Sizes:
-    # train (bench.py --gpt-scale 350m)
+    # train (GPT-350M)
     num_layers: int = 24
     num_heads: int = 16
     d_model: int = 1024

@@ -5,8 +5,8 @@ only the device→host snapshot at the step boundary (plus a queue put);
 serialization, checksumming, and the atomic commit run on this thread.
 Double buffering bounds host memory: at most TWO snapshots exist at once
 — one being written, one queued. A third ``submit`` blocks until the
-writer drains (that wait is the backpressure the bench's
-``ckpt_stall_ms`` would surface if saves outpace the disk).
+writer drains (that wait is the backpressure a trainer sees as a slow
+``save`` when saves outpace the disk).
 
 A failed write never kills the training process mid-step: the exception
 is captured and re-raised on the NEXT ``submit``/``drain`` (the reference

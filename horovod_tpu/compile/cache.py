@@ -401,9 +401,9 @@ def get_or_compile(tag: str, lower: Callable[[], Any], *,
     ``lower()`` returns a ``Lowered`` (``jit(fn).lower(*abstract_args)``)
     and only runs on a miss — a memory or disk hit skips lowering AND
     compile. ``aux_fn(lowered)`` (miss only) returns JSON-safe metadata
-    persisted with the entry and returned on every later hit; bench uses
-    it to keep wire-plan byte stats available on warm reruns where no
-    lowering happens. Never raises on cache trouble — the worst case is
+    persisted with the entry and returned on every later hit: it keeps
+    wire-plan byte stats available on warm reruns where no lowering
+    happens. Never raises on cache trouble — the worst case is
     a cold compile."""
     key = executable_key(tag, plan=plan, mesh=mesh, shapes=shapes,
                          extra=extra)
@@ -456,7 +456,7 @@ def get_or_compile(tag: str, lower: Callable[[], Any], *,
 
 
 # ---------------------------------------------------------------------------
-# stats (bench JSON + gates)
+# stats
 
 
 def stats() -> dict:
@@ -468,7 +468,8 @@ def stats() -> dict:
 
 def compile_count() -> int:
     """Number of TRUE compiles this process paid through the registry —
-    the quantity the warm-rerun perf gate asserts is zero."""
+    zero in a process that found every executable on disk
+    (tests/test_compile.py holds a second process to that)."""
     with _lock:
         return int(_stats["misses"])
 

@@ -111,7 +111,7 @@ def _extract_expert_load(registry_snap: Optional[dict]) -> Dict[str, float]:
     """Fold the per-expert load metrics of a registry snapshot into a
     compact ``{expert_id: tokens}`` dict: ``serve.expert_tokens{expert}``
     histogram sums (the serving engines) plus
-    ``moe.expert_tokens{expert}`` counters (the training bench leg)."""
+    ``moe.expert_tokens{expert}`` counters (a training loop's)."""
     if not registry_snap:
         return {}
     global _EXPERT_KEY_RE

@@ -62,9 +62,9 @@ logger = logging.getLogger("horovod_tpu.straggler")
 
 #: Canonical phase vocabulary (docs/observability.md). record_phase
 #: accepts any name, but detection/reporting tables order these first.
-#: ``wire.a2a`` is the MoE dispatch/combine wire (docs/moe.md) — fed by
-#: bench's ``--moe`` leg so a straggling expert group attributes to its
-#: exchange phase, separate from the gradient wire's hop classes.
+#: ``wire.a2a`` is the MoE dispatch/combine wire (docs/moe.md) — a
+#: training loop records it so a straggling expert group attributes to
+#: its exchange phase, separate from the gradient wire's hop classes.
 #: ``wire.kv`` is disaggregated serving's KV-migration wire
 #: (docs/serving.md) — a replica stuck in it is blocked on a
 #: prefill→decode handoff, not on compute. ``compile`` is

@@ -443,7 +443,7 @@ def _eager_shard_all_gather(shard, residual, name: Optional[str]):
 # under the still-executing backward. The wrappers change NO numerics —
 # they bracket the exact same collective with trace-time bookkeeping:
 # per-bucket OVERLAP:* timeline spans and WireStats.overlap_bytes (the
-# bench's comm_hidden_fraction numerator). The bracket itself
+# numerator of WireStats.hidden_fraction). The bracket itself
 # (plan/accounting.py overlap_stream) lives with the plan compiler, so
 # any plan-compiled collective is instrumented identically.
 # ---------------------------------------------------------------------------

@@ -109,7 +109,7 @@ def _vary(x, axes_t, *others):
 # ---------------------------------------------------------------------------
 # HBM-traffic model: bytes the fusion avoids round-tripping vs the
 # separate-op lowering. ONE definition shared by the kernels' trace-time
-# accounting, the planner's --dump-plan delta line, and the tests/bench
+# accounting, the plan table's ``fused:`` delta line, and the tests'
 # assertions (docs/fused-kernels.md, "HBM model").
 # ---------------------------------------------------------------------------
 

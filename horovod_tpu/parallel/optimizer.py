@@ -251,7 +251,8 @@ class QuantizedEFState(NamedTuple):
     rank's ``[1, *param_shape]`` slice inside), not the replicated ``P()``
     of the inner state. A spec prefix of
     ``QuantizedEFState(P(), hvd.data_pspec())`` does exactly that — see
-    ``bench.py --quantized`` for the worked example.
+    ``tests/test_overlap.py::test_overlap_quantized_ef_bit_identical`` for
+    the worked example.
     """
 
     inner: Any
@@ -997,7 +998,7 @@ def _build_zero_transform(
     # (per-rank leading-axis state); the wire still runs every microbatch
     # — branchless where-selection cannot elide a collective — so stage 1's
     # distinguishing property is the accumulator LAYOUT, which is what
-    # the bench's grad-bytes-per-rank A/B measures.
+    # tests/test_zero.py::test_stage1_full_accumulator_layout pins.
     k = backward_passes_per_step
     db = overlap and k > 1  # double-buffered accumulation
     s1 = stage == 1 and k > 1 and not db  # full-grad accumulation

@@ -939,8 +939,8 @@ def emit_schedule_spans(sched: PPSchedule) -> None:
     """Mirror the schedule onto the Timeline as per-rank ``PP:F`` /
     ``PP:B`` spans (tid ``pp-rank<r>``, tick-indexed timestamps) plus a
     ``PP:SCHEDULE`` instant carrying the measured bubble fraction —
-    ``span_audit`` audits the balance, ``obs_report``/bench read the
-    bubble (docs/pipeline.md). Trace-time, like every span here."""
+    ``span_audit`` audits the balance, ``scripts/obs_report.py`` reads
+    the bubble (docs/pipeline.md). Trace-time, like every span here."""
     from ..common import basics
 
     tl = basics._state.timeline if basics.is_initialized() else None
@@ -1228,7 +1228,8 @@ def pipelined_gpt_train(cfg, chunk_params, rest, tokens, targets, *,
                         interleave: int = 1, send_plan=None):
     """One fused GPT training computation under any pipeline schedule:
     returns ``(loss, d_chunk_params, d_rest)`` — the production entry
-    point behind ``bench.py --pp`` (docs/pipeline.md).
+    point (docs/pipeline.md; ``tests/test_pp.py`` holds it to the dense
+    model).
 
     ``chunk_params`` is this rank's ``[v, L/(n*v), ...]`` stacked tree
     from :func:`pp_split_chunks` (``v = 1`` for gpipe/1f1b);

@@ -106,7 +106,8 @@ class WireStats:
         """Fraction of this program's wire bytes issued through the
         overlap stream schedule (0.0 with overlap off; collectives
         outside the gradient bucket wire — loss allreduce, batch-stats —
-        keep it below 1.0). The bench's ``comm_hidden_fraction``."""
+        keep it below 1.0). Published as the ``comm.wire.hidden_fraction``
+        gauge; ``scripts/obs_report.py`` recomputes it from the bytes."""
         total = self.ici_bytes + self.dcn_bytes + self.pod_bytes
         return (self.overlap_bytes / total) if total else 0.0
 
@@ -185,8 +186,8 @@ def _acct(kind: str, wire_bytes: float, fp_bytes: Optional[float] = None):
 def bench_gbps() -> tuple:
     """(ici, dcn, pod) modeled link bandwidths in GB/s — the
     HOROVOD_BENCH_{ICI,DCN,POD}_GBPS knobs behind every modeled-time
-    number (bench.py step_time_breakdown, the per-bucket latency
-    histograms). The pod knob defaults to the DCN value, so 2-level
+    number (``modeled_wire_ms``, the per-bucket latency histograms,
+    the cost model's static defaults: docs/cost-model.md). The pod knob defaults to the DCN value, so 2-level
     meshes and unset-knob runs behave exactly as before."""
     ici = float(os.environ.get("HOROVOD_BENCH_ICI_GBPS", "100"))
     dcn = float(os.environ.get("HOROVOD_BENCH_DCN_GBPS", "25"))
@@ -196,9 +197,9 @@ def bench_gbps() -> tuple:
 
 def modeled_wire_ms(ici_bytes: float, dcn_bytes: float,
                     pod_bytes: float = 0.0) -> float:
-    """Modeled transfer time of a payload at the bench's (env-overridable)
-    link bandwidths — the same HOROVOD_BENCH_ICI_GBPS/DCN_GBPS/POD_GBPS
-    model behind bench.py's step_time_breakdown. On the compiled path this
+    """Modeled transfer time of a payload at the (env-overridable)
+    HOROVOD_BENCH_ICI_GBPS/DCN_GBPS/POD_GBPS link bandwidths of
+    :func:`bench_gbps`. On the compiled path this
     is the only per-bucket latency that exists at trace time (XLA owns the
     runtime schedule); the eager path measures wall time instead. Applied
     to a :class:`WireStats` record this is the "measured" side of the
@@ -323,7 +324,7 @@ def _acct_pp(hop: str, wire_bytes: float, fp_bytes: Optional[float] = None,
     """Account a pipeline send leg: charges ``wire_bytes`` to the ``hop``
     link class exactly like any other leg (so ``comm.bytes{hop}`` and
     the per-hop WireStats totals include it), and ADDITIONALLY to the
-    pipeline's own counters so bench/obs can separate the inter-stage
+    pipeline's own counters so a report can separate the inter-stage
     wire from the gradient wire (docs/pipeline.md)."""
     _acct(hop, wire_bytes, fp_bytes)
     if _metrics.metrics_enabled():
@@ -340,7 +341,7 @@ def _acct_a2a(hop: str, wire_bytes: float,
     """Account a MoE a2a leg: charges ``wire_bytes`` to the ``hop`` link
     class exactly like any other leg (so ``comm.bytes{hop}`` and the
     per-hop WireStats totals include it), and ADDITIONALLY to the MoE
-    wire's own counters so bench/obs can separate the expert
+    wire's own counters so a report can separate the expert
     dispatch/combine traffic from the gradient wire (docs/moe.md)."""
     _acct(hop, wire_bytes, fp_bytes)
     if _metrics.metrics_enabled():
@@ -358,7 +359,7 @@ def _acct_kv(hop: str, wire_bytes: float,
     """Account a KV-migration send leg: charges ``wire_bytes`` to the
     ``hop`` link class exactly like any other leg (so
     ``comm.bytes{hop}`` and the per-hop WireStats totals include it),
-    and ADDITIONALLY to the serving handoff's own counters so bench/obs
+    and ADDITIONALLY to the serving handoff's own counters so a report
     can separate prefill→decode migration traffic from the training and
     pipeline wires (docs/serving.md). ``transfers`` bumps only when a
     whole slot finished migrating — chunked transfers charge bytes per

@@ -37,8 +37,9 @@ planner.StepPlan` into predicted milliseconds:
 The ``modeled_ms`` field of every priced leg is the PURE bytes/bandwidth
 number at the static ``HOROVOD_BENCH_*_GBPS`` knobs — exactly what the
 trace-time :class:`~horovod_tpu.plan.accounting.WireStats` model would
-charge — so ``predicted - modeled`` is the drift surface the perf gate
-checks (``scripts/perf_gate.sh cost``, docs/cost-model.md).
+charge — so ``predicted - modeled`` is the drift surface
+``scripts/cost_smoke.sh`` and ``tests/test_cost.py`` check
+(docs/cost-model.md).
 """
 
 from __future__ import annotations
@@ -192,7 +193,7 @@ class PlanCost:
 
     def by_leg(self, leg: ir.Leg) -> Tuple[float, float]:
         """(modeled_ms, predicted_ms) summed over the rows charged to
-        ``leg`` — the two --dump-plan table columns."""
+        ``leg`` — the plan table's two columns."""
         modeled = sum(l.modeled_ms for l in self.legs if l.leg is leg)
         pred = sum(l.total_ms for l in self.legs if l.leg is leg)
         return modeled, pred
@@ -429,8 +430,9 @@ def price_a2a(plan: ir.WirePlan, payload_bytes: float, *,
     """Price ``issues`` identical a2a exchanges of a ``payload_bytes``
     dispatch buffer over ``ep`` expert groups: the per-exchange
     wire/alpha/quant terms times the layer's issue count (two per MoE
-    layer — dispatch, then combine) — the predicted side of the bench
-    ``--moe`` leg's a2a drift pair (docs/moe.md). ``modeled_ms`` is the
+    layer — dispatch, then combine) — the predicted side of the a2a
+    drift pair (docs/moe.md; the bytes are held to the traced ones by
+    ``tests/test_moe.py``). ``modeled_ms`` is the
     same bytes at the static modeled bandwidths, exactly what the
     trace-time accounting would charge for the same issues."""
     model = model or CostModel.from_env()
@@ -450,8 +452,9 @@ def price_send(plan: ir.WirePlan, payload_bytes: float, *,
                model: Optional[CostModel] = None) -> dict:
     """Price ``issues`` identical send-plan hops of a ``payload_bytes``
     activation: the per-send wire/alpha/quant terms times the schedule's
-    issue count — the predicted side of the bench ``--pp`` leg's
-    send-leg drift pair (docs/pipeline.md). ``modeled_ms`` is the same
+    issue count — the predicted side of the send-leg drift pair
+    (docs/pipeline.md; ``tests/test_pp.py`` holds the bytes to the
+    traced ones). ``modeled_ms`` is the same
     bytes at the static modeled bandwidths, exactly what the trace-time
     accounting would charge for the same issues."""
     model = model or CostModel.from_env()
@@ -472,8 +475,8 @@ def price_kv_migrate(plan: ir.WirePlan, payload_bytes: float, *,
     """Price ``transfers`` prefill→decode KV handoffs of a
     ``payload_bytes`` slot payload each: the per-migration
     wire/alpha/quant terms times the handoff count — the predicted side
-    of the bench ``--disagg`` leg's migration drift pair
-    (docs/serving.md). ``modeled_ms`` is the same bytes at the static
+    of the migration drift pair (docs/serving.md;
+    ``tests/test_serve_disagg.py`` holds the bytes to the lowering's). ``modeled_ms`` is the same bytes at the static
     modeled bandwidths, exactly what :func:`~horovod_tpu.plan.compiler.
     lower_kv_migrate` charges for the same transfers (residual pass
     included — the leg-byte predictor doubles quantized bytes when the

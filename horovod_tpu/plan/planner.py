@@ -28,9 +28,9 @@ knobs                            gradient wire plan
 it resolves unset knobs exactly like ``DistributedOptimizer`` would (env
 config included) and returns a :class:`StepPlan` whose :meth:`~StepPlan.
 table` renders legs, hops, wire dtypes, streams, and predicted per-device
-wire bytes from the trace-time cost model — ``bench.py --dump-plan``
-prints it, and golden-text tests pin it so plan regressions show up as
-readable diffs.
+wire bytes from the trace-time cost model — ``print(hvd.describe_plan(
+...).table())`` shows it, and golden-text tests (``tests/test_plan.py``)
+pin it so plan regressions show up as readable diffs.
 
 :func:`encode_tuned` / :func:`decode_tuned` are the autotuner's compact
 plan encoding (leg order, per-hop dtype, stream placement): the GP
@@ -343,8 +343,8 @@ def predict_a2a_bytes(plan: WirePlan, n: int, itemsize: float,
 
 def pp_bubble_bound(stages: int, microbatches: int) -> float:
     """The no-overlap GPipe analytic bubble bound ``(S-1)/(M+S-1)`` —
-    the fraction the perf gate holds every measured pipeline schedule
-    strictly under (docs/pipeline.md)."""
+    the fraction ``tests/test_pp.py`` holds every interleaved and
+    zero-bubble schedule strictly under (docs/pipeline.md)."""
     s, m = int(stages), max(1, int(microbatches))
     return (s - 1) / (m + s - 1) if s > 1 else 0.0
 
@@ -578,7 +578,7 @@ def predict_fused_hbm_saved(plan: WirePlan, n: int, itemsize: int,
     vs their separate-op lowering, for a payload of ``n`` elements — the
     same model the kernels charge at trace time
     (ops/fused_collective.py: ``quant_hbm_saved``/``dequant_hbm_saved``),
-    rendered by the ``--dump-plan`` table's ``fused:`` line."""
+    rendered by the plan table's ``fused:`` line."""
     from ..ops import fused_collective as _fused
 
     nl, nc, npod = _mesh_sizes(mesh_shape)
@@ -678,15 +678,15 @@ class StepPlan:
         """Render the step plan as a fixed-width text table (legs, hops,
         wire dtypes, streams, predicted per-device wire bytes AND
         predicted milliseconds for a ``payload_bytes`` gradient payload)
-        — the ``--dump-plan`` / golden-test format.
+        — the golden-test format.
 
         The ``model ms`` column is the pure bytes-at-modeled-bandwidth
         number (the trace-time WireStats model, HOROVOD_BENCH_*_GBPS);
         ``pred ms`` adds the cost model's launch-latency and
         quantize-kernel terms (docs/cost-model.md). ``model`` is a
         :class:`~horovod_tpu.plan.cost.CostModel` (default: the static
-        env triples, so golden text stays deterministic; ``--dump-plan``
-        passes the calibrated model when one is stored)."""
+        env triples, so golden text stays deterministic; pass
+        ``get_cost_model()`` to price with a stored calibration)."""
         from . import cost as _cost
 
         model = model or _cost.CostModel.from_env()
@@ -740,7 +740,7 @@ class StepPlan:
         if self.send is not None:
             # The pipeline wire, priced PER SEND ISSUE (one activation
             # microbatch over one hop; the schedule issues 2 x ticks of
-            # these per step — bench reports the step total).
+            # these per step — cost.price_send gives the step total).
             rows = predict_leg_bytes(self.send, n, itemsize,
                                      self.mesh_shape)
             plan_cost = _cost.price_plan(self.send, n, itemsize,

@@ -5,10 +5,10 @@ Usage:
     scripts/obs_report.py --timeline TL.json --metrics METRICS.jsonl \
         [--flight FLIGHT_DIR] [--json OUT.json]
 
-Produce the artifacts with any bench/training run::
+Produce the artifacts with any training run::
 
     HOROVOD_TIMELINE=tl.json HOROVOD_METRICS_JSONL=metrics.jsonl \
-        python bench.py --overlap ...
+        python examples/jax_synthetic.py
 
 Report sections (docs/observability.md):
 
@@ -19,10 +19,11 @@ Report sections (docs/observability.md):
   the stall.warnings counters;
 * **Overlap** — comm_hidden_fraction recomputed from the registry's
   comm.wire.* gauges (overlap / (ici + dcn) bytes of the last traced
-  program) — must reproduce the bench-reported value within 1%;
+  program) — reproduces ``WireStats.hidden_fraction``
+  (tests/test_overlap.py);
 * **Wire budget** — measured per-device wire bytes per hop vs the
-  modeled transfer time at HOROVOD_BENCH_ICI_GBPS/DCN_GBPS (the same
-  bandwidth model behind bench.py's step_time_breakdown), and the DCN
+  modeled transfer time at HOROVOD_BENCH_ICI_GBPS/DCN_GBPS
+  (``plan.accounting.bench_gbps``), and the DCN
   fp-equivalent reduction of the quantized wire;
 * **Straggler table** — per-rank per-phase skew from the
   ``straggler.*`` gauges (monitor/straggler.py), detections, step-skew
@@ -32,8 +33,7 @@ Report sections (docs/observability.md):
   cross-rank join of any dumps present.
 
 Exit 0 on success, 2 on usage/artifact errors. ``--json`` additionally
-writes the report as one machine-readable dict (what obs_smoke.sh
-asserts on).
+writes the report as one machine-readable dict.
 """
 
 import argparse

@@ -23,8 +23,8 @@ answers the three questions an on-call asks first
   to the crash (was the dead rank dragging before it died?).
 
 Exit 0 on success, 2 when the directory holds no parseable dumps.
-``--json`` writes the machine-readable report (what the chaos tests and
-``scripts/obs_smoke.sh`` assert on).
+``--json`` writes the machine-readable report (what the chaos tests
+assert on).
 """
 
 import argparse

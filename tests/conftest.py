@@ -43,8 +43,7 @@ def pytest_configure(config):
         "see docs/robustness.md and scripts/chaos_soak.py")
     config.addinivalue_line(
         "markers", "serve: continuous-batching generation engine test "
-        "(horovod_tpu/serve/) — see docs/serving.md and "
-        "scripts/serve_smoke.sh")
+        "(horovod_tpu/serve/) — see docs/serving.md")
 
 
 @pytest.fixture(scope="session", autouse=True)

@@ -564,92 +564,6 @@ class TestSpanAudit:
 
 
 # ---------------------------------------------------------------------------
-# perf-gate verdict snapshot (scripts/_perf_gate_check.py satellite)
-
-
-def _load_gate_module():
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "scripts", "_perf_gate_check.py")
-    spec = importlib.util.spec_from_file_location("_perf_gate_check", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestPerfGateSnapshot:
-    def test_verdicts_written_as_metrics_jsonl(self, tmp_path,
-                                               monkeypatch):
-        mod = _load_gate_module()
-        out = str(tmp_path / "gate.jsonl")
-        monkeypatch.setenv("PERF_GATE_METRICS_JSONL", out)
-        assert mod.gate(90.0, 100.0, 0.6, "serve goodput", leg="serve")
-        assert not mod.gate(10.0, 100.0, 0.6, "serve throughput",
-                            leg="serve")
-        mod.write_verdict_snapshot()
-        rec = json.loads(open(out).read().strip())
-        assert rec["kind"] == "metrics"
-        g = rec["gauges"]
-        assert g["perf_gate.measured{leg=serve,what=serve_goodput}"] == 90.0
-        assert g["perf_gate.pass{leg=serve,what=serve_goodput}"] == 1.0
-        assert g["perf_gate.pass{leg=serve,what=serve_throughput}"] == 0.0
-        assert rec["counters"][
-            "perf_gate.regressions{leg=serve,what=serve_throughput}"] == 1.0
-        assert rec["perf_gate"]["pass"] is False
-
-
-class TestPerfGateTrainBaselineFallback:
-    """Empty-trajectory train legs gate against (or self-seed) the
-    committed BENCH_train_baseline.json instead of silently passing."""
-
-    def _mod(self, tmp_path):
-        mod = _load_gate_module()
-        mod.TRAIN_BASELINE = str(tmp_path / "BENCH_train_baseline.json")
-        return mod
-
-    def test_first_run_seeds_then_gates(self, tmp_path):
-        mod = self._mod(tmp_path)
-        rec = {"metric": "img_per_sec", "platform": "testplat",
-               "value": 100.0}
-        assert mod._train_baseline_gate(rec, "train", 0.6, False) == 0
-        seeded = json.loads(open(mod.TRAIN_BASELINE).read())
-        assert seeded["img_per_sec|testplat"]["value"] == 100.0
-        # Within tolerance of the seeded baseline: pass.
-        ok = dict(rec, value=70.0)
-        assert mod._train_baseline_gate(ok, "train", 0.6, False) == 0
-        # A regression below the floor: fail.
-        bad = dict(rec, value=10.0)
-        assert mod._train_baseline_gate(bad, "train", 0.6, False) == 1
-        # PERF_GATE_UPDATE re-seeds instead of gating.
-        assert mod._train_baseline_gate(bad, "train", 0.6, True) == 0
-        seeded = json.loads(open(mod.TRAIN_BASELINE).read())
-        assert seeded["img_per_sec|testplat"]["value"] == 10.0
-
-    def test_keys_are_metric_and_platform_scoped(self, tmp_path):
-        mod = self._mod(tmp_path)
-        a = {"metric": "img_per_sec", "platform": "cpu", "value": 50.0}
-        b = {"metric": "img_per_sec", "platform": "tpu", "value": 9.0}
-        assert mod._train_baseline_gate(a, "train", 0.6, False) == 0
-        # A different platform seeds its own key; no cross-gating.
-        assert mod._train_baseline_gate(b, "train", 0.6, False) == 0
-        seeded = json.loads(open(mod.TRAIN_BASELINE).read())
-        assert set(seeded) == {"img_per_sec|cpu", "img_per_sec|tpu"}
-
-    def test_non_numeric_value_is_a_usage_error(self, tmp_path):
-        mod = self._mod(tmp_path)
-        rec = {"metric": "img_per_sec", "platform": "cpu"}
-        assert mod._train_baseline_gate(rec, "train", 0.6, False) == 2
-
-    def test_corrupt_baseline_reseeds(self, tmp_path):
-        mod = self._mod(tmp_path)
-        with open(mod.TRAIN_BASELINE, "w") as f:
-            f.write("{not json")
-        rec = {"metric": "img_per_sec", "platform": "cpu", "value": 5.0}
-        assert mod._train_baseline_gate(rec, "train", 0.6, False) == 0
-        seeded = json.loads(open(mod.TRAIN_BASELINE).read())
-        assert seeded["img_per_sec|cpu"]["value"] == 5.0
-
-
-# ---------------------------------------------------------------------------
 # Flight recorder (monitor/flight.py)
 
 

@@ -7,8 +7,6 @@ processes over the native TCP data plane (tests/mxnet_worker.py).
 """
 
 import os
-import socket
-import subprocess
 import sys
 
 import numpy as np
@@ -17,6 +15,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import fake_mxnet  # noqa: E402
+from test_native_core import _run_world  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(REPO, "tests", "mxnet_worker.py")
@@ -129,43 +128,6 @@ class TestWorldOne:
             import horovod_tpu.mxnet  # noqa: F401
 
 
-def _free_port():
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-def _run_world(n, timeout=300):
-    port = _free_port()
-    procs = []
-    for r in range(n):
-        env = dict(os.environ)
-        env.pop("XLA_FLAGS", None)
-        env.update({
-            "PYTHONPATH": REPO,
-            "HOROVOD_RANK": str(r),
-            "HOROVOD_SIZE": str(n),
-            "HOROVOD_CONTROLLER_ADDR": "127.0.0.1",
-            "HOROVOD_CONTROLLER_PORT": str(port),
-        })
-        procs.append(subprocess.Popen(
-            [sys.executable, WORKER], env=env,
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True))
-    outs, ok = [], True
-    for p in procs:
-        try:
-            out, _ = p.communicate(timeout=timeout)
-        except subprocess.TimeoutExpired:
-            p.kill()
-            out, _ = p.communicate()
-            ok = False
-        outs.append(out)
-        ok = ok and p.returncode == 0
-    assert ok, "mxnet worker failures:\n" + "\n----\n".join(outs)
-
-
 class TestMultiProcess:
     def test_world_2(self):
-        _run_world(2)
+        _run_world(2, timeout=300, worker=WORKER)

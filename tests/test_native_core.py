@@ -9,6 +9,7 @@ import os
 import socket
 import subprocess
 import sys
+import tempfile
 
 import numpy as np
 import pytest
@@ -118,6 +119,12 @@ def _run_world(n, extra_env=None, timeout=120, worker=WORKER,
                local_size=None):
     port = _free_port()
     procs = []
+    # Each worker writes to a file of its own, never to a pipe: the pipes
+    # were read one rank after the other, so a rank that printed more than
+    # a pipe holds (64 KiB: jaxlib logs ~6 KB for every executable it
+    # loads from a warm compile cache) blocked in write() while the rank
+    # being read waited for it in a collective, until the timeout.
+    logs = [tempfile.TemporaryFile(mode="w+") for _ in range(n)]
     for r in range(n):
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)  # workers don't need the 8-device mesh
@@ -140,18 +147,21 @@ def _run_world(n, extra_env=None, timeout=120, worker=WORKER,
         env.update(extra_env or {})
         procs.append(subprocess.Popen(
             [sys.executable, worker], env=env,
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True))
-    outs = []
+            stdout=logs[r], stderr=subprocess.STDOUT))
     ok = True
     for p in procs:
         try:
-            out, _ = p.communicate(timeout=timeout)
+            p.wait(timeout=timeout)
         except subprocess.TimeoutExpired:
             p.kill()
-            out, _ = p.communicate()
+            p.wait()
             ok = False
-        outs.append(out)
         ok = ok and p.returncode == 0
+    outs = []
+    for log in logs:
+        log.seek(0)
+        outs.append(log.read())
+        log.close()
     assert ok, "worker failures:\n" + "\n----\n".join(outs)
     return outs
 

@@ -11,14 +11,17 @@ Core invariants:
   * the reverse-layer bucket schedule orders buckets by descending max
     leaf index, leaf→bucket assignment untouched;
   * streamed collectives emit ``OVERLAP:*`` timeline spans and account
-    ``WireStats.overlap_bytes`` (the bench's ``comm_hidden_fraction``);
+    ``WireStats.overlap_bytes`` (the numerator of ``hidden_fraction``),
+    and ``scripts/obs_report.py`` joins the two artifacts;
   * eager world-of-1 fallback matches the plain optimizer.
 
 All compiled tests run on the 8-device CPU mesh shaped 2x4 so the
 hierarchical/DCN decompositions are exercised under the stream schedule.
 """
 
+import importlib.util
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -362,6 +365,43 @@ def test_wire_stats_overlap_accounting():
     assert ws_on.streamed_buckets >= 1
     # below 1.0: the loss allreduce is not part of the gradient stream
     assert 0.0 < ws_on.hidden_fraction < 1.0
+
+
+def test_obs_report_joins_timeline_and_metrics(tmp_path):
+    """scripts/obs_report.py over one overlapped step's two artifacts
+    (Timeline JSON + metrics JSONL): balanced spans, nonzero ICI bytes,
+    no stalls, and the hidden fraction it recomputes from the
+    ``comm.wire.*`` gauges is the traced program's own."""
+    from horovod_tpu import monitor
+    from horovod_tpu.monitor import JsonlSink
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "obs_report.py")
+    spec = importlib.util.spec_from_file_location("_obs_report", path)
+    obs_report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(obs_report)
+
+    tl, jsonl = str(tmp_path / "tl.json"), str(tmp_path / "m.jsonl")
+    hvd.start_timeline(tl)
+    try:
+        ws = _trace_overlap_step(overlap=True)
+    finally:
+        hvd.stop_timeline()
+    JsonlSink(jsonl).write(monitor.metrics().snapshot())
+
+    report = obs_report.build_report(tl, jsonl)
+    assert report["spans_balanced"], report["span_imbalance"]
+    assert report["total_spans"] > 0
+    assert any(k.startswith("OVERLAP:ALLREDUCE")
+               for k in report["activity_time_us"])
+    wb = report["wire_budget"]
+    assert wb["ici_bytes_per_step_device"] == pytest.approx(ws.ici_bytes)
+    assert wb["ici_bytes_per_step_device"] > 0
+    assert not report["stalls"] and report["stall_warnings"] == 0
+    assert report["comm_hidden_fraction"] == pytest.approx(
+        ws.hidden_fraction)
+    assert 0.0 < report["comm_hidden_fraction"] < 1.0
+    assert report["streamed_buckets"] == ws.streamed_buckets
 
 
 def test_zero_overlap_streams_rs_and_ag():

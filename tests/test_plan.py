@@ -617,17 +617,3 @@ class TestThreeLevel:
         m3 = basics._build_mesh(jax.devices()[:N], (2, 2, 2))
         assert m3.devices.shape == (2, 2, 2)
         assert m3.axis_names == basics.ALL_AXES
-
-    def test_bench_mesh_shape_parsing(self):
-        import bench
-
-        assert bench.parse_mesh_shape("2x4") == (2, 4)
-        assert bench.parse_mesh_shape("2x2x2") == (2, 2, 2)
-        assert bench.parse_mesh_shape("2,2,2") == (2, 2, 2)
-        with pytest.raises(ValueError, match="CROSSxLOCAL"):
-            bench.parse_mesh_shape("2x")
-        with pytest.raises(ValueError, match="CROSSxLOCAL"):
-            bench.parse_mesh_shape("2x2x2x2")
-        with pytest.raises(ValueError, match=">= 1"):
-            bench.parse_mesh_shape("0x8")
-        assert bench.mesh_shape_str((2, 2, 2)) == "2x2x2"

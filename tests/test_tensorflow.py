@@ -3,9 +3,6 @@ test/parallel/test_tensorflow.py + test_keras.py, SURVEY §4): single-process
 semantics plus real multi-process workers over localhost TCP."""
 
 import os
-import socket
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -15,6 +12,7 @@ keras = pytest.importorskip("keras")
 
 import horovod_tpu.tensorflow as hvd_tf  # noqa: E402
 import horovod_tpu.keras as hvd_keras  # noqa: E402
+from test_native_core import _run_world  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(REPO, "tests", "tf_worker.py")
@@ -370,43 +368,6 @@ class TestMXNetGate:
             import horovod_tpu.mxnet  # noqa: F401
 
 
-def _free_port():
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-def _run_world(n, timeout=420):
-    port = _free_port()
-    procs = []
-    for r in range(n):
-        env = dict(os.environ)
-        env.pop("XLA_FLAGS", None)
-        env.update({
-            "PYTHONPATH": REPO,
-            "HOROVOD_RANK": str(r),
-            "HOROVOD_SIZE": str(n),
-            "HOROVOD_CONTROLLER_ADDR": "127.0.0.1",
-            "HOROVOD_CONTROLLER_PORT": str(port),
-        })
-        procs.append(subprocess.Popen(
-            [sys.executable, WORKER], env=env,
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True))
-    outs, ok = [], True
-    for p in procs:
-        try:
-            out, _ = p.communicate(timeout=timeout)
-        except subprocess.TimeoutExpired:
-            p.kill()
-            out, _ = p.communicate()
-            ok = False
-        outs.append(out)
-        ok = ok and p.returncode == 0
-    assert ok, "tf worker failures:\n" + "\n----\n".join(outs)
-
-
 class TestMultiProcess:
     def test_world_2(self):
-        _run_world(2)
+        _run_world(2, timeout=420, worker=WORKER)

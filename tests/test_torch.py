@@ -6,15 +6,13 @@ plus real multi-process workers over localhost TCP (SURVEY §4).
 """
 
 import os
-import socket
-import subprocess
-import sys
 
 import numpy as np
 import pytest
 import torch
 
 import horovod_tpu.torch as hvd_torch
+from test_native_core import _run_world
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(REPO, "tests", "torch_worker.py")
@@ -320,44 +318,7 @@ class TestTorchElastic:
         assert set(iter(s2)) == set(iter(sampler))
 
 
-def _free_port():
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-def _run_world(n, timeout=180):
-    port = _free_port()
-    procs = []
-    for r in range(n):
-        env = dict(os.environ)
-        env.pop("XLA_FLAGS", None)
-        env.update({
-            "PYTHONPATH": REPO,
-            "HOROVOD_RANK": str(r),
-            "HOROVOD_SIZE": str(n),
-            "HOROVOD_CONTROLLER_ADDR": "127.0.0.1",
-            "HOROVOD_CONTROLLER_PORT": str(port),
-        })
-        procs.append(subprocess.Popen(
-            [sys.executable, WORKER], env=env,
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True))
-    outs, ok = [], True
-    for p in procs:
-        try:
-            out, _ = p.communicate(timeout=timeout)
-        except subprocess.TimeoutExpired:
-            p.kill()
-            out, _ = p.communicate()
-            ok = False
-        outs.append(out)
-        ok = ok and p.returncode == 0
-    assert ok, "torch worker failures:\n" + "\n----\n".join(outs)
-
-
 class TestMultiProcess:
     @pytest.mark.parametrize("n", [2, 4])
     def test_world(self, n):
-        _run_world(n)
+        _run_world(n, timeout=180, worker=WORKER)
