@@ -131,6 +131,9 @@ class _Attention(nn.Module):
         qkv = nn.Dense(3 * H * D, dtype=cfg.dtype, name="qkv",
                        kernel_init=nn.initializers.normal(0.02))(x)
         q, k, v = jnp.split(qkv, 3, axis=-1)
+        # The 4-D views are free: ``flash_attention`` folds them back and
+        # its kernels index [B, T, H * D] as it lies (in the compiled step
+        # the split's three slices are the forward kernel's operands).
         q = q.reshape(B, T, H, D)
         k = k.reshape(B, T, H, D)
         v = v.reshape(B, T, H, D)

@@ -11,14 +11,33 @@ dwarfs the matmul time on a bandwidth-limited chip.
 This module implements the standard flash-attention schedule as Pallas TPU
 kernels (guide: /opt/skills/guides/pallas_guide.md):
 
-* forward: grid (B·H, Tq/bq, Tk/bk); the k-block axis is innermost, so the
-  per-q-block running max ``m``, normalizer ``l`` and output accumulator
-  live in VMEM scratch across k-steps; scores never leave VMEM. Emits the
-  logsumexp residual for the backward pass.
+* forward: one grid cell per (head, q block, k block); the k-block axis is
+  innermost, so the per-q-block running max ``m``, normalizer ``l`` and
+  output accumulator live in VMEM scratch across k-steps; scores never
+  leave VMEM. Emits the logsumexp residual for the backward pass.
+* layout: the kernels read q, k, v, dO and write o, dq, dk, dv as the
+  projections produce and consume them, ``[B, T, H·D]``, wherever whole
+  heads fill whole 128-lane blocks: ``128 % D == 0 and (H·D) % 128 == 0``
+  (head dim 64 with an even head count, head dim 128, the tensor-parallel
+  shards that keep that; ``_reads_in_place``). A block is (rows of the
+  sequence, 128 lanes): the width of an HBM tile, of a vector register
+  and of the MXU, so every block is dense in HBM and every store a whole
+  register. At D = 64 a block holds a pair of heads, which take turns on a
+  grid axis of their own: the block indices do not depend on it, so a
+  block is fetched once for both and the output block stays put; a head's
+  matmuls contract over all 128 lanes with the other head's lanes of one
+  operand zeroed (a row factor, folded into ``scale`` where there is one)
+  and cost what 64 lanes cost, which half-fill the MXU anyway; the wrong
+  half of an accumulator is dropped once, where it is written out. No
+  transpose stands around such a call (they were 8 a layer, each a pass
+  through HBM into a ``[B·H, T, 64]`` array whose 128-lane tiles are half
+  empty: a quarter of the attention's time). Any other shape is packed to
+  ``[B·H, T, D]`` first, one head a block; the kernel bodies are the same.
 * backward: the split-kernel formulation — one kernel accumulates dQ over
   k-blocks, a second accumulates dK/dV over q-blocks — with the
-  ``delta = rowsum(dO ⊙ O)`` precomputed as a cheap fused elementwise op
-  in plain XLA. Both kernels recompute probabilities from q, k and the
+  ``delta = rowsum(dO ⊙ O)`` precomputed in plain XLA (``_prep_residuals``).
+  The per-query statistics (logsumexp, delta) are one dense row of lanes a
+  head, ``[B·H, 1, T]``. Both kernels recompute probabilities from q, k and the
   saved logsumexp (recompute-over-store: O(T·D) residuals instead of
   O(T²)). The dK/dV kernel works on transposed score tiles ([tk, tq]), so
   that dV = PᵀdO and dK = dSᵀQ are plain matmuls.
@@ -48,11 +67,14 @@ kernels (guide: /opt/skills/guides/pallas_guide.md):
   nothing is skipped inside a kernel (whole future partials are skipped
   by the ``lax.cond`` in ``_ring_fwd_impl``).
 
-Each kernel call adds, at trace time, to the monitor registry's
-``flash.tiles_total`` / ``flash.tiles_computed`` / ``flash.tiles_masked``
-(label ``kernel`` = ``fwd`` | ``bwd_dq`` | ``bwd_dkv``): the sub-tiles of
-one head's grid, how many are computed and how many of those are masked
-(16 / 10 / 4 at the benchmark cells' shape).
+Trace-time counters in the monitor registry (docs/observability.md):
+``flash.layout`` (label ``path`` = ``in_place`` | ``packed``) counts one per
+``flash_attention`` call on the side its operands' shape decides; each
+kernel call adds to ``flash.tiles_total`` / ``flash.tiles_computed`` /
+``flash.tiles_masked`` (label ``kernel`` = ``fwd`` | ``bwd_dq`` |
+``bwd_dkv``): the sub-tiles of one head's grid, how many are computed and
+how many of those are masked (16 / 10 / 4 at the benchmark cells' shape,
+in either layout).
 
 Everything is static-shaped; block sizes adapt to divide the sequence
 (see ``_pick_block`` — a whole-sequence block covers anything <= the
@@ -179,11 +201,52 @@ def _scalar_spec():
     operands with sharded tensor operands under shard_map's vma checking —
     a tile-aligned VMEM operand behaves identically on both backends and
     costs 4 KB."""
-    return pl.BlockSpec((1, 8, 128), lambda b, i, j: (0, 0, 0))
+    return pl.BlockSpec((1, 8, 128), lambda *_: (0, 0, 0))
 
 
 def _as_scalar(x):
     return jnp.broadcast_to(jnp.asarray(x, jnp.int32), (1, 8, 128))
+
+
+_LANES = 128   # a vector register's, an HBM tile's and the MXU's width
+
+
+def _reads_in_place(H: int, D: int) -> bool:
+    """Can the kernels index ``[B, T, H * D]`` as it lies? Whole heads must
+    fill whole 128-lane blocks: D = 64 with an even head count (a pair a
+    block), D = 128 (a head a block), the tensor-parallel shards that keep
+    that. Everything else is packed to ``[B * H, T, D]`` first."""
+    return _LANES % D == 0 and (H * D) % _LANES == 0
+
+
+def _head_lanes(g, G, shape):
+    """Over ``shape``: is the lane one of head ``g``'s, of the ``G`` heads
+    side by side in a block?"""
+    d = shape[-1] // G
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return (lane >= g * d) & (lane < g * d + d)
+
+
+def _on_head(g, G, ref, value):
+    """``value`` as a factor for a sub-tile of ``ref``: on head ``g``'s
+    lanes only, 0 on the block's other heads', so that a matmul which
+    contracts over all the block's lanes sees one head. The Python number
+    itself where a block is one head."""
+    if G == 1:
+        return value
+    lanes = _head_lanes(g, G, (1, ref.shape[-1]))
+    return jnp.where(lanes, value, 0.0).astype(ref.dtype)
+
+
+def _put(ref, at, new, g, G):
+    """Write a finished accumulator. The matmuls ran over all the block's
+    lanes; the other heads' are dropped here, once, by keeping what the
+    block already holds there. (Measured a call at the cells' shape: a
+    store of half the lanes under a ``pl.when`` per head 27% slower, a
+    masked 32-bit store 1% slower in the forward.)"""
+    if G > 1:
+        new = jnp.where(_head_lanes(g, G, new.shape), new, ref[at])
+    ref[at] = new
 
 
 def _sub_tile(block: int, preferred: int) -> int:
@@ -344,23 +407,52 @@ def _count_tiles(kernel, *args):
         counter(f"flash.tiles_{name}", kernel=kernel).inc(n)
 
 
+def _as_row(col):
+    """[t, 1] → [1, t]: a per-query statistic laid along the lanes, as the
+    statistics lie in HBM and as the dk/dv kernel's transposed score tiles
+    want them."""
+    t = col.shape[0]
+    if t % 128:
+        return col.T
+    return jnp.broadcast_to(col, (t, 128)).T[0:1, :]
+
+
+def _as_cols(*rows):
+    """[1, t] rows → [t, 1] columns, back down the sublanes for the dq
+    kernel's score tiles: stacked, so that all go through one transpose
+    (of 128 sublanes: 13% faster a dq call than one of 8, and than a
+    transpose a row)."""
+    t = rows[0].shape[1]
+    if t % 128:
+        return tuple(row.T for row in rows)
+    shape = (128, t)
+    sublane = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    stacked = jnp.broadcast_to(rows[-1], shape)
+    for n in range(len(rows) - 2, -1, -1):
+        stacked = jnp.where(sublane == n, jnp.broadcast_to(rows[n], shape),
+                            stacked)
+    cols = stacked.T
+    return tuple(cols[:, n:n + 1] for n in range(len(rows)))
+
+
 def _fwd_out(m, l, acc, o_dtype):
-    """(o, lse[.., 8]) of finished rows. Fully-masked rows have l == 0:
+    """(o, lse [1, t]) of finished rows. Fully-masked rows have l == 0:
     emit o = 0 and lse = -inf-like so a ring merge weights them out.
     Visible rows always have l > 0 (a causal row sees at least its own
-    token). lse carries a sublane dim of 8 (Mosaic block-mapping minimum
-    for the trailing-two dims); value broadcast across it."""
+    token). A statistic is one dense row of lanes per head, [N * heads, 1,
+    T] in HBM: as [.., T, 8] columns it was written lane-padded to 16 times
+    its size, and the kernels' largest operand."""
     safe_l = jnp.maximum(l, 1e-30)
     lse = jnp.where(l > 0, m + jnp.log(safe_l), _NEG_INF)
-    return ((acc / safe_l).astype(o_dtype),
-            jnp.broadcast_to(lse, (lse.shape[0], 8)))
+    return (acc / safe_l).astype(o_dtype), _as_row(lse)
 
 
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr,
-                *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile):
-    i = pl.program_id(1)   # q block
-    j = pl.program_id(2)   # k block (innermost: scratch carries across j)
+                *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile, G):
+    i = pl.program_id(2)   # q block
+    g = pl.program_id(3)   # head inside the lane block (``_specs``)
+    j = pl.program_id(4)   # k block (innermost: scratch carries across j)
     carried = nk > 1       # else a q sub-tile finishes inside this cell
 
     if carried:
@@ -374,6 +466,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         tq, tk = _sub_tiles(mode, bq, bk, sub_tile)
         d0 = _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode)
         diag = _diagonal(tq, tk) if mode != _FULL else None
+        qscale = _on_head(g, G, q_ref, scale)
 
         def tile(a, c, q, carry, masked):
             m_prev, l_prev, acc = carry
@@ -402,7 +495,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
         for a in range(bq // tq):
             rows = pl.ds(a * tq, tq)
-            q = q_ref[0, rows, :] * scale               # [tq, D], once
+            q = q_ref[0, rows, :] * qscale              # [tq, D], once
             if carried:
                 carry = m_scr[rows, :], l_scr[rows, :], acc_scr[rows, :]
             else:
@@ -415,22 +508,23 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             if carried:
                 m_scr[rows, :], l_scr[rows, :], acc_scr[rows, :] = carry
             else:
-                o_ref[0, rows, :], lse_ref[0, rows, :] = _fwd_out(
-                    *carry, o_ref.dtype)
+                o, lse_ref[0, :, rows] = _fwd_out(*carry, o_ref.dtype)
+                _put(o_ref, (0, rows), o, g, G)
 
     _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk)
 
     if carried:
         @pl.when(j == _last_k_block(causal, static_skip, i, bq, bk, nk))
         def _finish():
-            o_ref[0], lse_ref[0] = _fwd_out(
+            o, lse_ref[0] = _fwd_out(
                 m_scr[:], l_scr[:], acc_scr[:], o_ref.dtype)
+            _put(o_ref, (0,), o, g, G)
 
 
-def _statics(scale, causal, bq, bk, static_skip):
+def _statics(scale, causal, bq, bk, static_skip, heads):
     """What a kernel call is specialised on, read where the call is made."""
     return dict(scale=scale, causal=causal, bq=bq, bk=bk,
-                static_skip=static_skip, sub_tile=_SUB_TILE,
+                static_skip=static_skip, heads=heads, sub_tile=_SUB_TILE,
                 interpret=_interpret())
 
 
@@ -439,13 +533,55 @@ def _statics(scale, causal, bq, bk, static_skip):
 # tracing the unrolled sub-tile bodies anew every time cost its set-up 20 s.
 _traced_once = functools.partial(
     jax.jit, inline=True,
-    static_argnames=("scale", "causal", "bq", "bk", "static_skip",
+    static_argnames=("scale", "causal", "bq", "bk", "static_skip", "heads",
                      "sub_tile", "interpret"))
 
 
+def _specs(heads, width, bq, bk, kv_major=False):
+    """``(P, G, lanes, q_rows, k_rows, stats)`` of one kernel call over
+    operands ``[N, T, width]`` with ``heads`` heads side by side. How the
+    last dimension is cut: ``lanes`` a block, ``P`` blocks a row, ``G``
+    heads inside a block. One head (the packed layout): the whole
+    dimension, whatever its width. Several (``_reads_in_place``): 128
+    lanes, so that every block is a dense HBM tile and a dense vector
+    store, and the G heads of a block take turns on a grid axis of their
+    own; the block indices do not depend on it, so a block is fetched once
+    for all of them and the output block stays put.
+
+    The grid is (N, P, outer blocks, G, inner blocks): rows of the batch
+    (times heads when packed), lane blocks, the blocks the output belongs
+    to, the heads of a lane block, the blocks accumulated over; with
+    several inner blocks a lane block's are fetched once per head of it.
+    ``q_rows`` / ``k_rows`` make the spec of a [N, T, width] operand
+    blocked along the queries / keys, ``stats`` that of a per-query
+    [N * heads, 1, Tq] statistic (``_fwd_out``)."""
+    if heads == 1:
+        lanes, P, G = width, 1, 1
+    else:
+        lanes, P, G = _LANES, width // _LANES, _LANES * heads // width
+    if kv_major:   # the dk/dv kernel: (n, p, j, g, i)
+        qi, ki = 4, 2
+    else:          # forward and dq: (n, p, i, g, j)
+        qi, ki = 2, 4
+    q_rows = lambda: pl.BlockSpec(
+        (1, bq, lanes), lambda *ids: (ids[0], ids[qi], ids[1]))
+    k_rows = lambda: pl.BlockSpec(
+        (1, bk, lanes), lambda *ids: (ids[0], ids[ki], ids[1]))
+    stats = lambda: pl.BlockSpec(
+        (1, 1, bq),
+        lambda *ids: ((ids[0] * P + ids[1]) * G + ids[3], 0, ids[qi]))
+    return P, G, lanes, q_rows, k_rows, stats
+
+
+# The head axis revisits the output block, the innermost one accumulates.
+_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary", "arbitrary")
+
+
 def _flash_fwd(q, k, v, scale, causal, bq, bk, q_off=0, k_off=0,
-               static_skip=True):
-    """q,k,v: [BH, T, D] → (o [BH, Tq, D], lse [BH, Tq, 8] f32).
+               static_skip=True, heads=1):
+    """q,k,v: [N, T, heads * D] → (o [N, Tq, heads * D], lse [N * heads,
+    1, Tq] f32): ``heads`` = 1 is the packed layout ([B * H, T, D]), more
+    the projections' own ([B, T, H * D], ``_reads_in_place``).
 
     ``q_off``/``k_off`` are global positions of the first query/key token
     (may be traced, e.g. ``lax.axis_index(...) * T_local`` under a ring);
@@ -453,43 +589,37 @@ def _flash_fwd(q, k, v, scale, causal, bq, bk, q_off=0, k_off=0,
     _count_tiles("fwd", causal, static_skip, q.shape[1] // bq,
                  k.shape[1] // bk, bq, bk)
     return _fwd_call(q_off, k_off, q, k, v,
-                     **_statics(scale, causal, bq, bk, static_skip))
+                     **_statics(scale, causal, bq, bk, static_skip, heads))
 
 
 @_traced_once
 def _fwd_call(q_off, k_off, q, k, v, *, scale, causal, bq, bk, static_skip,
-              sub_tile, interpret):
-    BH, Tq, D = q.shape
+              heads, sub_tile, interpret):
+    N, Tq, W = q.shape
     Tk = k.shape[1]
     nq, nk = Tq // bq, Tk // bk
+    P, G, lanes, q_rows, k_rows, stats = _specs(heads, W, bq, bk)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, nq=nq, nk=nk,
-                               static_skip=static_skip, sub_tile=sub_tile)
+                               static_skip=static_skip, sub_tile=sub_tile,
+                               G=G)
     return pl.pallas_call(
         kernel,
-        grid=(BH, nq, nk),
-        in_specs=[
-            _scalar_spec(),
-            _scalar_spec(),
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 8), lambda b, i, j: (b, i, 0)),
-        ],
+        grid=(N, P, nq, G, nk),
+        in_specs=[_scalar_spec(), _scalar_spec(),
+                  q_rows(), k_rows(), k_rows()],
+        out_specs=[q_rows(), stats()],
         out_shape=[
-            _out_struct((BH, Tq, D), q.dtype, q, k, v, q_off, k_off),
-            _out_struct((BH, Tq, 8), jnp.float32, q, k, v, q_off, k_off),
+            _out_struct((N, Tq, W), q.dtype, q, k, v, q_off, k_off),
+            _out_struct((N * heads, 1, Tq), jnp.float32, q, k, v, q_off,
+                        k_off),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),     # running max m
             pltpu.VMEM((bq, 1), jnp.float32),     # running normalizer l
-            pltpu.VMEM((bq, D), jnp.float32),     # output accumulator
+            pltpu.VMEM((bq, lanes), jnp.float32),  # output accumulator
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
         name="hvd_flash_fwd",
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v)
@@ -512,9 +642,11 @@ def _probs(s, lse, static_skip):
 
 def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, acc_scr,
-                   *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
+                   *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile,
+                   G):
+    i = pl.program_id(2)
+    g = pl.program_id(3)
+    j = pl.program_id(4)
     carried = nk > 1
 
     if carried:
@@ -526,13 +658,16 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         tq, tk = _sub_tiles(mode, bq, bk, sub_tile)
         d0 = _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode)
         diag = _diagonal(tq, tk) if mode != _FULL else None
+        qscale = _on_head(g, G, q_ref, scale)
+        own = _on_head(g, G, do_ref, 1.0)
 
         for a in range(bq // tq):
             rows = pl.ds(a * tq, tq)
-            q = q_ref[0, rows, :] * scale
+            q = q_ref[0, rows, :] * qscale
             do = do_ref[0, rows, :]
-            lse = lse_ref[0, rows, 0:1]
-            delta = delta_ref[0, rows, 0:1]
+            if G > 1:   # dp contracts over the lanes: this head's only
+                do = do * own
+            lse, delta = _as_cols(lse_ref[0, :, rows], delta_ref[0, :, rows])
             acc = jnp.zeros((tq, q.shape[1]), jnp.float32)
             n_full, hi = _k_tiles(mode, a, tq, bk, tk)
             for c in range(hi):
@@ -555,30 +690,25 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             if carried:
                 acc_scr[rows, :] += acc
             else:
-                dq_ref[0, rows, :] = (acc * scale).astype(dq_ref.dtype)
+                _put(dq_ref, (0, rows), (acc * scale).astype(dq_ref.dtype),
+                     g, G)
 
     _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk)
 
     if carried:
         @pl.when(j == _last_k_block(causal, static_skip, i, bq, bk, nk))
         def _finish():
-            dq_ref[0] = (acc_scr[:] * scale).astype(dq_ref.dtype)
-
-
-def _as_row(col):
-    """[t, 1] → [1, t]: a per-query statistic laid along the lanes, for the
-    dk/dv kernel's transposed score tiles."""
-    t = col.shape[0]
-    if t % 128:
-        return col.T
-    return jnp.broadcast_to(col, (t, 128)).T[0:1, :]
+            _put(dq_ref, (0,), (acc_scr[:] * scale).astype(dq_ref.dtype),
+                 g, G)
 
 
 def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile):
-    j = pl.program_id(1)   # k block
-    i = pl.program_id(2)   # q block (innermost: scratch carries across i)
+                    *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile,
+                    G):
+    j = pl.program_id(2)   # k block
+    g = pl.program_id(3)   # head inside the lane block
+    i = pl.program_id(4)   # q block (innermost: scratch carries across i)
     carried = nq > 1
 
     if carried:
@@ -597,15 +727,17 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         d0 = _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode)
         diag = _diagonal(tk, tq) if mode != _FULL else None
         nqs = bq // tq
-        lse = [_as_row(lse_ref[0, pl.ds(a * tq, tq), 0:1])
-               for a in range(nqs)]
-        delta = [_as_row(delta_ref[0, pl.ds(a * tq, tq), 0:1])
-                 for a in range(nqs)]
+        kscale = _on_head(g, G, k_ref, scale)
+        own = _on_head(g, G, v_ref, 1.0)
+        lse = [lse_ref[0, :, pl.ds(a * tq, tq)] for a in range(nqs)]
+        delta = [delta_ref[0, :, pl.ds(a * tq, tq)] for a in range(nqs)]
 
         for c in range(bk // tk):
             cols = pl.ds(c * tk, tk)
-            k = k_ref[0, cols, :] * scale               # [tk, D], once
+            k = k_ref[0, cols, :] * kscale              # [tk, D], once
             v = v_ref[0, cols, :]
+            if G > 1:   # dp contracts over the lanes: this head's only
+                v = v * own
             dk = dv = jnp.zeros((tk, k.shape[1]), jnp.float32)
             lo, lo_full = _q_tiles(mode, c, tk, bq, tq)
             for a in range(lo, nqs):
@@ -635,118 +767,125 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                 dk_scr[cols, :] += dk
                 dv_scr[cols, :] += dv
             else:
-                dk_ref[0, cols, :] = (dk * scale).astype(dk_ref.dtype)
-                dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
+                _put(dk_ref, (0, cols), (dk * scale).astype(dk_ref.dtype),
+                     g, G)
+                _put(dv_ref, (0, cols), dv.astype(dv_ref.dtype), g, G)
 
     _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk)
 
     if carried:
         @pl.when(i == nq - 1)
         def _finish():
-            dk_ref[0] = (dk_scr[:] * scale).astype(dk_ref.dtype)
-            dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+            _put(dk_ref, (0,), (dk_scr[:] * scale).astype(dk_ref.dtype),
+                 g, G)
+            _put(dv_ref, (0,), dv_scr[:].astype(dv_ref.dtype), g, G)
 
 
-def _prep_residuals(o, do):
-    """delta = rowsum(dO ⊙ O) with the broadcast sublane dim."""
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                             # [BH, Tq]
-    return jnp.broadcast_to(delta[..., None], (*delta.shape, 8))
+def _prep_residuals(o, do, heads=1):
+    """delta = rowsum(dO ⊙ O) per head, [N * heads, 1, Tq] as the kernels
+    read a statistic, from the arrays as they lie."""
+    N, T, W = o.shape
+    prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if heads == 1:
+        delta = jnp.sum(prod, axis=-1)                   # [BH, Tq]
+    else:
+        # A sum over each head's D lanes of [B, T, H * D], as a matmul with
+        # the heads' 0/1 indicator [H, H * D]: it gives [B, H, T], the
+        # statistics' order, and the product is its operand's fusion. As a
+        # reduce over [B, T, H, D] the compiler wrote the f32 product out
+        # and transposed it first (3 passes over 33.5 MB a layer of
+        # gpt2-medium).
+        own = (jnp.arange(W)[None, :] // (W // heads)
+               == jnp.arange(heads)[:, None]).astype(jnp.float32)
+        delta = jax.lax.dot_general(
+            jnp.broadcast_to(own, (N, heads, W)), prod,
+            (((2,), (2,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    return delta.reshape(N * heads, 1, T)
 
 
 def _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, bq, bk,
-                  q_off=0, k_off=0, static_skip=True):
+                  q_off=0, k_off=0, static_skip=True, heads=1):
     _count_tiles("bwd_dq", causal, static_skip, q.shape[1] // bq,
                  k.shape[1] // bk, bq, bk)
     return _bwd_dq_call(q_off, k_off, q, k, v, do, lse, delta,
-                        **_statics(scale, causal, bq, bk, static_skip))
+                        **_statics(scale, causal, bq, bk, static_skip,
+                                   heads))
 
 
 @_traced_once
 def _bwd_dq_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
-                 bq, bk, static_skip, sub_tile, interpret):
-    BH, Tq, D = q.shape
+                 bq, bk, static_skip, heads, sub_tile, interpret):
+    N, Tq, W = q.shape
     nq, nk = Tq // bq, k.shape[1] // bk
+    P, G, lanes, q_rows, k_rows, stats = _specs(heads, W, bq, bk)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, nk=nk,
-                          static_skip=static_skip, sub_tile=sub_tile),
-        grid=(BH, nq, nk),
-        in_specs=[
-            _scalar_spec(),
-            _scalar_spec(),
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),   # q
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),   # k
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),   # v
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),   # do
-            pl.BlockSpec((1, bq, 8), lambda b, i, j: (b, i, 0)),   # lse
-            pl.BlockSpec((1, bq, 8), lambda b, i, j: (b, i, 0)),   # delta
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        out_shape=_out_struct((BH, Tq, D), q.dtype, q, k, v, do, lse,
+                          static_skip=static_skip, sub_tile=sub_tile, G=G),
+        grid=(N, P, nq, G, nk),
+        in_specs=[_scalar_spec(), _scalar_spec(),
+                  q_rows(), k_rows(), k_rows(),       # q, k, v
+                  q_rows(), stats(), stats()],        # do, lse, delta
+        out_specs=q_rows(),
+        out_shape=_out_struct((N, Tq, W), q.dtype, q, k, v, do, lse,
                               delta, q_off, k_off),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bq, lanes), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
         name="hvd_flash_bwd_dq",
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v, do, lse, delta)
 
 
 def _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, bq, bk,
-                   q_off=0, k_off=0, static_skip=True):
+                   q_off=0, k_off=0, static_skip=True, heads=1):
     _count_tiles("bwd_dkv", causal, static_skip, q.shape[1] // bq,
                  k.shape[1] // bk, bq, bk)
     return _bwd_dkv_call(q_off, k_off, q, k, v, do, lse, delta,
-                         **_statics(scale, causal, bq, bk, static_skip))
+                         **_statics(scale, causal, bq, bk, static_skip,
+                                    heads))
 
 
 @_traced_once
 def _bwd_dkv_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
-                  bq, bk, static_skip, sub_tile, interpret):
-    BH, Tq, D = q.shape
+                  bq, bk, static_skip, heads, sub_tile, interpret):
+    N, Tq, W = q.shape
     Tk = k.shape[1]
     nq, nk = Tq // bq, Tk // bk
+    P, G, lanes, q_rows, k_rows, stats = _specs(heads, W, bq, bk,
+                                                kv_major=True)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, nk=nk,
-                          static_skip=static_skip, sub_tile=sub_tile),
-        grid=(BH, nk, nq),
-        in_specs=[
-            _scalar_spec(),
-            _scalar_spec(),
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),   # q
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),   # k
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),   # v
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),   # do
-            pl.BlockSpec((1, bq, 8), lambda b, j, i: (b, i, 0)),   # lse
-            pl.BlockSpec((1, bq, 8), lambda b, j, i: (b, i, 0)),   # delta
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-        ],
+                          static_skip=static_skip, sub_tile=sub_tile, G=G),
+        grid=(N, P, nk, G, nq),
+        in_specs=[_scalar_spec(), _scalar_spec(),
+                  q_rows(), k_rows(), k_rows(),       # q, k, v
+                  q_rows(), stats(), stats()],        # do, lse, delta
+        out_specs=[k_rows(), k_rows()],
         out_shape=[
-            _out_struct((BH, Tk, D), k.dtype, q, k, v, do, lse, delta,
+            _out_struct((N, Tk, W), k.dtype, q, k, v, do, lse, delta,
                         q_off, k_off),
-            _out_struct((BH, Tk, D), v.dtype, q, k, v, do, lse, delta,
+            _out_struct((N, Tk, W), v.dtype, q, k, v, do, lse, delta,
                         q_off, k_off),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, lanes), jnp.float32),
+            pltpu.VMEM((bk, lanes), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
         name="hvd_flash_bwd_dkv",
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v, do, lse, delta)
 
 
-def _flash_bwd(q, k, v, o, lse, do, scale, causal, bq, bk):
-    delta = _prep_residuals(o, do)
-    dq = _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, bq, bk)
-    dk, dv = _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, bq, bk)
+def _flash_bwd(q, k, v, o, lse, do, heads, scale, causal, bq, bk):
+    delta = _prep_residuals(o, do, heads)
+    dq = _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, bq, bk,
+                       heads=heads)
+    dk, dv = _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, bq, bk,
+                            heads=heads)
     return dq, dk, dv
 
 
@@ -771,21 +910,20 @@ def _pick_block(T: int, preferred: int) -> Optional[int]:
     return None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale, causal, bq, bk):
-    o, _ = _flash_fwd(q, k, v, scale, causal, bq, bk)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, heads, scale, causal, bq, bk):
+    o, _ = _flash_fwd(q, k, v, scale, causal, bq, bk, heads=heads)
     return o
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, bq, bk):
-    o, lse = _flash_fwd(q, k, v, scale, causal, bq, bk)
+def _flash_vjp_fwd(q, k, v, heads, scale, causal, bq, bk):
+    o, lse = _flash_fwd(q, k, v, scale, causal, bq, bk, heads=heads)
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(scale, causal, bq, bk, res, g):
+def _flash_vjp_bwd(heads, scale, causal, bq, bk, res, g):
     q, k, v, o, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, o, lse, g, scale, causal, bq, bk)
-    return dq, dk, dv
+    return _flash_bwd(q, k, v, o, lse, g, heads, scale, causal, bq, bk)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -839,7 +977,7 @@ def _ring_fwd_impl(q, k, v, axis, scale, causal, bq, bk):
             o_i, lse_i = _flash_fwd(
                 q, k_blk, v_blk, scale, causal, bq, bk,
                 q_off=my * T_local, k_off=src * T_local, static_skip=False)
-            return o_i.astype(jnp.float32), lse_i[:, :, 0]  # [BH,T,D],[BH,T]
+            return o_i.astype(jnp.float32), lse_i[:, 0, :]  # [BH,T,D],[BH,T]
 
         if causal:
             # A block from a later shard (src > my) is entirely in the
@@ -891,7 +1029,7 @@ def _ring_bwd_impl(q, k, v, o, lse, do, axis, scale, causal, bq, bk):
     def _vary(x):
         return pvary_missing(x, axes_t)
 
-    lse8 = jnp.broadcast_to(lse[..., None], (*lse.shape, 8))
+    lse = lse[:, None, :]                                # as the kernels'
     delta = _prep_residuals(o, do)
 
     def contrib(dq, k_blk, v_blk, dk_blk, dv_blk, i):
@@ -899,10 +1037,10 @@ def _ring_bwd_impl(q, k, v, o, lse, do, axis, scale, causal, bq, bk):
 
         def compute(k_blk, v_blk):
             q_off, k_off = my * T_local, src * T_local
-            dq_i = _flash_bwd_dq(q, k_blk, v_blk, do, lse8, delta, scale,
+            dq_i = _flash_bwd_dq(q, k_blk, v_blk, do, lse, delta, scale,
                                  causal, bq, bk, q_off=q_off, k_off=k_off,
                                  static_skip=False)
-            dk_i, dv_i = _flash_bwd_dkv(q, k_blk, v_blk, do, lse8, delta,
+            dk_i, dv_i = _flash_bwd_dkv(q, k_blk, v_blk, do, lse, delta,
                                         scale, causal, bq, bk, q_off=q_off,
                                         k_off=k_off, static_skip=False)
             return (dq_i.astype(jnp.float32), dk_i.astype(jnp.float32),
@@ -1047,9 +1185,20 @@ def flash_attention(q, k, v, *, causal: bool = True,
         return dense_attention(q, k, v, causal=causal, scale=scale)
     scale = float(scale) if scale is not None else D ** -0.5
 
+    from ..monitor.registry import counter
+
+    # By shape alone: the projections' own [B, T, H * D] where whole heads
+    # fill whole lane blocks (a reshape is no copy), else packed by head.
+    in_place = _reads_in_place(H, D)
+    counter("flash.layout",
+            path="in_place" if in_place else "packed").inc()
     # Outside the custom_vjp call, so that the backward kernels and the
-    # [B, T, H, D] <-> [BH, T, D] layout traffic carry the scope too.
+    # packed path's [B, T, H, D] <-> [BH, T, D] traffic carry the scope too.
     with jax.named_scope("hvd.flash_attention"):
+        if in_place:
+            qp, kp, vp = _harmonize_vma(*(
+                x.reshape(B, x.shape[1], H * D) for x in (q, k, v)))
+            o = _flash(qp, kp, vp, H, scale, causal, bq, bk)
+            return o.reshape(B, Tq, H, D)
         qp, kp, vp = _harmonize_vma(_pack(q), _pack(k), _pack(v))
-        o = _flash(qp, kp, vp, scale, causal, bq, bk)
-        return jnp.transpose(o.reshape(B, H, Tq, D), (0, 2, 1, 3))
+        return _unpack(_flash(qp, kp, vp, 1, scale, causal, bq, bk), B, H)

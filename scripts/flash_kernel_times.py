@@ -8,9 +8,13 @@
 Runs ``jax.grad(flash_attention)`` a few times under the profiler for each
 (shape, sub-tile) and prints the mean duration of the events named
 ``hvd_flash_fwd`` / ``hvd_flash_bwd_dq`` / ``hvd_flash_bwd_dkv`` on the
-first device: the kernels alone, no layout traffic, no dispatch. A shape is
-``BxTxHxD`` + ``c`` (causal) or ``f`` (full); a sub-tile ``TQxTK`` (the
-rule's own choice when the list is empty). ``--module FILE`` times another
+first device: the kernels alone, no dispatch. The operands are what the
+model's projections give, ``[B, T, H * D]`` arrays seen as ``[B, T, H, D]``
+inside the jitted function, so a kernel is timed on the layout it meets in
+a step; ``other`` is every other device op of a call together (the layout
+traffic and ``delta``; the test's own ``sum`` and its cotangent are a few
+us of it). A shape is ``BxTxHxD`` + ``c`` (causal) or ``f`` (full); a
+sub-tile ``TQxTK`` (the rule's own choice when the list is empty). ``--module FILE`` times another
 copy of ``ops/flash_attention.py`` (e.g. the parent commit's) in the same
 process; such a copy ignores ``--subtiles`` unless it has ``_SUB_TILE``.
 Needs a TPU (anything else: exit 2). Results also go to
@@ -46,9 +50,10 @@ def load_module(path):
     return mod
 
 
-def kernel_us(trace_dir, kernels=KERNELS):
+def kernel_us(trace_dir, kernels=KERNELS, calls=0):
     """{kernel name: (mean us per event, events)} on the first device of a
-    trace, for the ``pallas_call`` names ``kernels``."""
+    trace, for the ``pallas_call`` names ``kernels``; with ``calls``, also
+    ``other``: (us of every other op per call, their events)."""
     from jax.profiler import ProfileData
 
     path = sorted(glob.glob(os.path.join(
@@ -57,15 +62,22 @@ def kernel_us(trace_dir, kernels=KERNELS):
               if p.name.startswith("/device:TPU:")]
     plane = min(planes, key=lambda p: p.name)
     found = {k: [] for k in kernels}
+    other = []
     for line in plane.lines:
         if line.name != "XLA Ops":
             continue
         for e in line.events:
-            for k in kernels:
-                # "hvd_flash_bwd_dq" must not also count under "..._dkv".
-                if f"%{k}." in e.name or f"%{k} " in e.name:
-                    found[k].append(e.duration_ns * 1e-3)
-    return {k: (sum(v) / len(v), len(v)) for k, v in found.items() if v}
+            # "hvd_flash_bwd_dq" must not also count under "..._dkv".
+            mine = [k for k in kernels
+                    if f"%{k}." in e.name or f"%{k} " in e.name]
+            for k in mine:
+                found[k].append(e.duration_ns * 1e-3)
+            if not mine:
+                other.append(e.duration_ns * 1e-3)
+    us = {k: (sum(v) / len(v), len(v)) for k, v in found.items() if v}
+    if calls and other:
+        us["other"] = (sum(other) / calls, len(other))
+    return us
 
 
 def time_one(mod, shape, block, steps):
@@ -75,13 +87,14 @@ def time_one(mod, shape, block, steps):
 
     B, T, H, D, causal = shape
     rs = np.random.RandomState(0)
-    q, k, v = (jnp.asarray(rs.randn(B, T, H, D), jnp.bfloat16) * 0.3
+    q, k, v = (jnp.asarray(rs.randn(B, T, H * D), jnp.bfloat16) * 0.3
                for _ in range(3))
 
     @jax.jit
     def f(q, k, v):
         return jax.grad(lambda q, k, v: mod.flash_attention(
-            q, k, v, causal=causal, block_q=block, block_k=block,
+            *(x.reshape(B, T, H, D) for x in (q, k, v)), causal=causal,
+            block_q=block, block_k=block,
         ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
     jax.block_until_ready(f(q, k, v))        # compile + warm
@@ -91,7 +104,7 @@ def time_one(mod, shape, block, steps):
             out = f(q, k, v)
         jax.block_until_ready(out)
         jax.profiler.stop_trace()
-        return kernel_us(d)
+        return kernel_us(d, calls=steps)
 
 
 def main(argv=None) -> int:

@@ -325,11 +325,11 @@ class TestInCellTiling:
         np.testing.assert_allclose(np.asarray(o)[0, seen],
                                    np.asarray(o_d)[0, seen],
                                    rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(lse)[0, seen, 0],
+        np.testing.assert_allclose(np.asarray(lse)[0, 0, seen],
                                    np.asarray(lse_d)[0, seen],
                                    rtol=1e-5, atol=1e-6)
         assert not np.asarray(o)[0, ~seen].any()
-        assert (np.asarray(lse)[0, ~seen] == -1e30).all()
+        assert (np.asarray(lse)[0, 0, ~seen] == -1e30).all()
 
         delta = F._prep_residuals(o, do)
         dq = F._flash_bwd_dq(q, k, v, do, lse, delta, scale, True, T, T,
@@ -358,7 +358,7 @@ class TestInCellTiling:
 
         def traced(T, bq, bk, **kw):
             x = jax.ShapeDtypeStruct((2, T, 64), jnp.bfloat16)
-            r = jax.ShapeDtypeStruct((2, T, 8), jnp.float32)
+            r = jax.ShapeDtypeStruct((2, 1, T), jnp.float32)
             before = read()
             jax.eval_shape(lambda q: F._flash_fwd(
                 q, q, q, 0.125, True, bq, bk, **kw), x)
@@ -385,3 +385,183 @@ class TestInCellTiling:
             for kern in ("fwd", "bwd_dq", "bwd_dkv"):
                 assert tuple(case[(n, kern)] for n in (
                     "total", "computed", "masked")) == want, (kern, case)
+
+
+def _layout_counts():
+    from horovod_tpu.monitor.registry import counter
+
+    return {path: counter("flash.layout", path=path).value
+            for path in ("in_place", "packed")}
+
+
+def _assert_close(got, want, tol):
+    for a, b, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= tol * np.abs(b).max(), name
+
+
+def _primitives(jaxpr):
+    """Names of every primitive in a jaxpr, sub-jaxprs included (the
+    kernels' own bodies are one ``pallas_call`` each: what runs inside a
+    kernel is not layout traffic)."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _primitives(sub)
+    return names
+
+
+class TestInPlaceLayout:
+    """Where whole heads fill whole 128-lane blocks (D = 64 with an even
+    head count, D = 128), the kernels index ``[B, T, H * D]`` as the
+    projections give it: no transpose around a call, the heads of a lane
+    block take turns on a grid axis. Any other shape is packed to
+    ``[B * H, T, D]`` as before. Same numbers either way."""
+
+    @pytest.mark.parametrize("H,D", [(16, 64), (12, 64), (4, 128)])
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("T,blocks", [
+        (256, (None, None)),    # one block: a head finishes inside a cell
+        (384, (128, 128)),      # 3 x 3 blocks: carried across k blocks,
+        #   and a lane block's k/v come once per head of it
+        (512, (256, 128)),      # bq != bk
+    ])
+    def test_matches_dense(self, H, D, causal, T, blocks):
+        from horovod_tpu.ops.flash_attention import _reads_in_place
+
+        assert _reads_in_place(H, D)
+        q, k, v = _qkv(B=2 if T == 256 else 1, T=T, H=H, D=D, seed=T + H)
+        w = jnp.asarray(np.random.RandomState(5).randn(H, D), jnp.float32)
+        before = _layout_counts()
+        got = _fwd_and_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=blocks[0], block_k=blocks[1]),
+            q, k, v, w)
+        after = _layout_counts()
+        # _fwd_and_grads traces the public function twice.
+        assert after["in_place"] - before["in_place"] == 2
+        assert after["packed"] == before["packed"]
+        want = _fwd_and_grads(lambda q, k, v: seqpar.dense_attention(
+            q, k, v, causal=causal), q, k, v, w)
+        _assert_close(got, want, 5e-6)
+
+    @pytest.mark.parametrize("H,D", [(16, 64), (4, 128)])
+    def test_bf16(self, H, D):
+        q, k, v = _qkv(T=256, H=H, D=D, seed=H, dtype=jnp.bfloat16)
+        w = jnp.asarray(np.random.RandomState(5).randn(H, D), jnp.float32)
+        got = _fwd_and_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True), q, k, v, w)
+        want = _fwd_and_grads(lambda q, k, v: seqpar.dense_attention(
+            q, k, v, causal=True), q, k, v, w)
+        assert all(a.dtype == jnp.bfloat16 for a in got)
+        _assert_close(got, want, 2e-2)
+
+    @pytest.mark.parametrize("H,D", [(3, 64), (2, 80), (2, 32)])
+    def test_other_shapes_stay_packed(self, H, D):
+        """An odd head count at D = 64, a head dim that does not divide
+        128, a row narrower than a lane block: today's path, one count a
+        call on its side, the same numbers."""
+        from horovod_tpu.ops.flash_attention import _reads_in_place
+
+        assert not _reads_in_place(H, D)
+        q, k, v = _qkv(T=256, H=H, D=D, seed=D)
+        w = jnp.asarray(np.random.RandomState(5).randn(H, D), jnp.float32)
+        before = _layout_counts()
+        got = _fwd_and_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True), q, k, v, w)
+        after = _layout_counts()
+        assert after["packed"] - before["packed"] == 2
+        assert after["in_place"] == before["in_place"]
+        want = _fwd_and_grads(lambda q, k, v: seqpar.dense_attention(
+            q, k, v, causal=True), q, k, v, w)
+        _assert_close(got, want, 5e-6)
+
+    @pytest.mark.parametrize("H,D,in_place", [
+        (16, 64, True), (12, 64, True), (4, 128, True), (3, 64, False)])
+    def test_no_transpose_around_an_in_place_call(self, H, D, in_place):
+        """The jaxpr of ``flash_attention`` and of its gradient: three
+        kernels and, for an in-place shape, no ``transpose`` primitive
+        (the packed path has its eight)."""
+        x = jax.ShapeDtypeStruct((2, 256, H, D), jnp.bfloat16)
+
+        def grads(q, k, v):
+            return jax.grad(lambda q, k, v: flash_attention(
+                q, k, v, causal=True).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+
+        fwd = _primitives(jax.make_jaxpr(lambda q, k, v: flash_attention(
+            q, k, v, causal=True))(x, x, x).jaxpr)
+        both = _primitives(jax.make_jaxpr(grads)(x, x, x).jaxpr)
+        assert fwd.count("pallas_call") == 1
+        assert both.count("pallas_call") == 3
+        assert fwd.count("transpose") == (0 if in_place else 4)
+        assert both.count("transpose") == (0 if in_place else 8)
+
+    def test_inside_shard_map(self):
+        """The benchmark's step calls it under ``hvd.shard_map`` (batch
+        sharded): varying-axes checking over the five-axis grid."""
+        q, k, v = _qkv(B=8, T=256, H=2, D=64, seed=31)
+        spec = P(hvd.HVD_AXES)
+
+        def loss(q, k, v):
+            o = hvd.shard_map(
+                lambda a, b, c: flash_attention(a, b, c, causal=True),
+                mesh=hvd.mesh(), in_specs=(spec,) * 3, out_specs=spec,
+            )(q, k, v)
+            return jnp.sum(o * o)
+
+        gf = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+        gd = jax.grad(lambda q, k, v: jnp.sum(seqpar.dense_attention(
+            q, k, v, causal=True) ** 2), argnums=(0, 1, 2))(q, k, v)
+        for a, b, name in zip(gf, gd, "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6,
+                err_msg=f"d{name} mismatch")
+
+    def test_tile_counters_at_the_cells_shape(self):
+        """``flash.tiles_*`` count one head's grid whatever the layout: 16
+        sub-tiles, 10 computed, 4 masked at T = 1024, as before."""
+        from horovod_tpu.monitor.registry import counter
+
+        def read():
+            return {(n, kern): counter(f"flash.tiles_{n}", kernel=kern).value
+                    for n in ("total", "computed", "masked")
+                    for kern in ("fwd", "bwd_dq", "bwd_dkv")}
+
+        x = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16)
+        before = read()
+        jax.eval_shape(lambda q, k, v: jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, block_q=1024,
+                block_k=1024).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v), x, x, x)
+        after = read()
+        for kern in ("fwd", "bwd_dq", "bwd_dkv"):
+            assert tuple(after[(n, kern)] - before[(n, kern)] for n in (
+                "total", "computed", "masked")) == (16, 10, 4), kern
+
+    def test_gpt_in_place_matches_gpt_dense(self):
+        """The model's call site: 2 heads of 64 (one lane block) through
+        ``attention="flash"``, logits and parameter gradients against the
+        dense model's."""
+        cfg_d = gpt_tiny(dtype=jnp.float32, d_model=128, num_heads=2)
+        cfg_f = gpt_tiny(dtype=jnp.float32, d_model=128, num_heads=2,
+                         attention="flash")
+        rs = np.random.RandomState(0)
+        tokens = jnp.asarray(rs.randint(0, cfg_d.vocab_size, (2, 128)))
+        variables = GPT(cfg_d).init(jax.random.PRNGKey(0), tokens)
+
+        def loss(cfg):
+            return lambda v: jnp.mean(GPT(cfg).apply(v, tokens) ** 2)
+
+        before = _layout_counts()
+        lf, gf = jax.value_and_grad(loss(cfg_f))(variables)
+        assert _layout_counts()["in_place"] > before["in_place"]
+        ld, gd = jax.value_and_grad(loss(cfg_d))(variables)
+        np.testing.assert_allclose(float(lf), float(ld), rtol=1e-5)
+        for a, b in zip(jax.tree_util.tree_leaves(gf),
+                        jax.tree_util.tree_leaves(gd)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=1e-6)

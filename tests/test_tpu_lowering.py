@@ -91,6 +91,8 @@ def _has_kernel(compiled) -> bool:
                                 # loops with bounds from the program ids
     (8, 1024, 16, 64, False),   # every sub-tile, no mask
     (2, 640, 4, 64, True),      # a whole-sequence block of five sub-tiles
+    (2, 2048, 8, 128, True),    # a head a lane block, read in place
+    (8, 1024, 3, 64, True),     # an odd head count: the packed layout
 ])
 def test_flash_attention_compiles(tpu, real_kernels, B, T, H, D, causal):
     from horovod_tpu.ops.flash_attention import flash_attention
