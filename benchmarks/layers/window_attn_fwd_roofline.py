@@ -1,0 +1,44 @@
+"""Device trace: the windowed flash forward kernel's share of its roofline.
+Least time for one call over the BAND's pairs only, K and V read once a KV
+head (benchmarks/lib/kernels_window.py, from the ``window_attention`` shape
+the builder states), over the mean measured time of the events named
+``hvd_flash_fwd_win`` on the first device: what a tile computes under the
+causal or the window mask is not counted, so the share cannot pass 100%."""
+
+from benchmarks.lib import kernels, kernels_window, manifest as mf, scopes
+
+NAME, UNIT = "window_attn_fwd_roofline", "%"
+LAYER, MOVES = "Kernels", "tokens_per_s_per_chip"
+ENTRY = "window_attention"
+KERNELS = ("hvd_flash_fwd_win",)
+
+
+def share(run, name: str, entry: str, kernel_names: tuple, cost):
+    """100 x least / measured for one call of every kernel in
+    ``kernel_names`` together (told by the op's own name, as
+    ``sparse_attn_fwd_roofline.kernel_seconds`` tells them: a full call's
+    name is not a windowed call's), at the builder's ``kernel_shapes``
+    ``entry``; None where there is no trace, no such entry or no such
+    kernel (a program without them)."""
+    shape = dict(run.kernel_shapes.get(entry) or {})
+    scoped = scopes.of(run)
+    if scoped is None or run.peak is None or not shape:
+        return None
+    kernel_seconds = mf.load_module(
+        "layers", "sparse_attn_fwd_roofline").kernel_seconds
+    parts = [kernel_seconds(scoped, k) for k in kernel_names]
+    if not all(parts):
+        return None
+    least, bound = kernels.roofline(*cost(**shape), run.peak)
+    mean = sum(sum(p) / len(p) for p in parts)
+    pairs = kernels_window.visible_pairs(shape["seq"], shape.get("window"))
+    run.note(f"{name}: {[len(p) for p in parts]} calls of "
+             f"{'+'.join(kernel_names)}, mean "
+             + " + ".join(f"{sum(p) / len(p) * 1e6:.1f}" for p in parts)
+             + f" us, least {least * 1e6:.1f} us over {pairs:.0f} visible "
+             f"pairs a head, bound by {bound}")
+    return 100.0 * least / mean
+
+
+def read(run):
+    return share(run, NAME, ENTRY, KERNELS, kernels_window.attn_fwd_cost)

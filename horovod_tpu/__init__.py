@@ -172,6 +172,7 @@ from .moe import (  # noqa: F401
     MoELayer,
     moe_ffn,
     moe_ffn_dropless,
+    router_bias_update,
 )
 from .parallel.pipeline import (  # noqa: F401
     PPSchedule,

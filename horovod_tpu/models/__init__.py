@@ -6,6 +6,7 @@ from .mnist import MnistNet  # noqa: F401
 from .sparse_moe_decoder import (  # noqa: F401
     SparseMoEConfig,
     SparseMoEDecoder,
+    update_router_biases,
 )
 from .resnet import (  # noqa: F401
     ResNet,

@@ -1,48 +1,71 @@
-"""Decoder with grouped-KV attention behind a learned sparse indexer and a
-dropless mixture of gated experts (the language model of
-Keye-VL-2.0-30B-A3B; docs/sparse_attention.md, docs/moe.md).
+"""One configuration-driven decoder block for the mixture-of-experts
+families (docs/moe.md, docs/sparse_attention.md, docs/flash_window.md): a
+layer's attention kind and MLP kind come from the configuration, layer by
+layer, and the block is written once.
 
-A second block beside ``models/gpt.py``, which is not stretched to hold
-it: RMSNorm, rotary position over the whole head, grouped KV heads with a
-per-head RMSNorm on q and k, :func:`hvd.sparse_attention` (every query
-attends the ``topk`` keys its indexer scores highest), top-k routed SiLU
-experts through :func:`hvd.moe_ffn_dropless` (told which experts this chip
-holds), an untied head. No bias anywhere. Driven by the published
-``config.json`` key names (:meth:`SparseMoEConfig.from_dict`).
+* attention kind (``SparseMoEConfig.layer_types``): ``"sparse"`` —
+  grouped-KV attention behind a learned indexer, every query attending the
+  ``topk`` keys its indexer scores highest (:func:`hvd.sparse_attention`);
+  ``"sliding_attention"`` — causal attention over the last
+  ``sliding_window`` keys; ``"full_attention"`` — causal attention over all
+  of them. The last two run :func:`hvd.flash_attention` with grouped KV
+  heads (``window=`` on a sliding layer).
+* MLP kind: a dense gated MLP on the first ``num_dense_layers`` layers,
+  else routed experts through :func:`hvd.moe_ffn_dropless` (told which
+  experts this chip holds), beside ``num_shared_experts`` shared ones that
+  every token passes (scope ``hvd.shared_expert``, outside
+  ``hvd.moe_ffn``).
 
+Two published families are built from their own ``config.json`` keys
+(:meth:`SparseMoEConfig.from_dict`, by ``model_type``):
+
+* Keye-VL-2.0's language model (``sa_config`` present): every layer
+  ``sparse`` + routed, pre-norm residuals, rotary position on every layer,
+  softmax top-k router.
+* ``afmoe`` (Trinity): ``layer_types`` of sliding and full attention,
+  sandwich norms (a norm before AND after each of attention and MLP), an
+  output gate on attention (``o * sigmoid(u Wg)``), rotary position on the
+  sliding layers only, an embedding scaled by ``sqrt(hidden_size)``, a
+  sigmoid router whose selection bias is STATE: it lives in the flax
+  collection ``router_bias`` (no gradient, no weight decay), the step
+  carries it beside parameters and optimizer state, and
+  :func:`update_router_biases` moves it once a step from the step's expert
+  counts (``cfg.return_load`` hands them out).
+
+RMSNorm, per-head RMSNorm on q and k, no bias anywhere, an untied head.
 bfloat16 activations and matmul operands with float32 accumulation;
 float32 parameters, norms, rotary angles, softmax statistics and router
-probabilities. Each block is rematerialised in the backward pass and keeps
-two named values of ``hvd.sparse_attention`` (``jax.checkpoint`` with
-``save_only_these_names``): the forward kernel's output and log-sum-exp
-rows (0.14 GB a layer at T = 16k, against 18 ms to redo them) and the
-selection at one bit a pair (``T * T / 8`` bytes: 34 MB, against 7 ms of
-index kernel; its int8 mask, 0.27 GB, is not kept). The recomputed forward
-then runs neither kernel, nor the indexer's projections, which feed nothing
-but the selection.
+scores. Each block is rematerialised in the backward pass and keeps the
+attention kernel's output and log-sum-exp rows (``jax.checkpoint`` with
+``save_only_these_names``; a sparse layer also its selection at one bit a
+pair): the recomputed forward then runs no attention kernel.
 
 Initial weights: normal(``initializer_range``) for every matrix and the
-embedding, ones for every RMSNorm scale (the family's convention).
+embedding, ones for every RMSNorm scale, zeros for a router's bias.
 
-The vision tower is not built: on text tokens the three ``mrope_section``
-position ids coincide and the rotary embedding is the ordinary one. The
-indexer's own training loss (DeepSeek-V3.2's KL against head-summed
-attention probabilities) is not built either: under the language-model
-loss alone the indexer's weights receive a zero gradient (ROADMAP R0).
+Keye's vision tower is not built (on text tokens the three
+``mrope_section`` position ids coincide), nor the indexer's own training
+loss: under the language-model loss alone the indexer's weights receive a
+zero gradient (ROADMAP R0).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..moe.layer import moe_ffn_dropless
+from ..moe.layer import moe_ffn_dropless, router_bias_update
+from ..ops import flash_attention as _flash
 from ..ops.sparse_attention import (OUT_NAME, SELECTION_NAME,
                                     sparse_attention)
+
+SPARSE, SLIDING, FULL = "sparse", "sliding_attention", "full_attention"
+BIAS_COLLECTION = "router_bias"
 
 
 @dataclass(frozen=True)
@@ -65,22 +88,67 @@ class SparseMoEConfig:
     topk: int = 2048
     initializer_range: float = 0.02
 
+    # A layer's kinds. ``layer_types`` None: every layer ``sparse``.
+    layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: Optional[int] = None
+    num_dense_layers: int = 0         # leading layers with a dense MLP ...
+    intermediate_size: int = 0        # ... of this width
+    num_shared_experts: int = 0
+    # The router (moe/layer.py ``moe_router``).
+    scoring: str = "softmax"
+    route_norm: bool = True
+    route_scale: float = 1.0
+    load_balance_coeff: float = 0.0   # > 0: a selection bias as state
+    # The block around them.
+    sandwich_norms: bool = False      # a norm after attention and MLP too
+    attention_gate: bool = False      # o * sigmoid(u Wg)
+    rope_layers: str = "all"          # or "sliding": NoPE on full layers
+    embed_scale: float = 1.0
+
     dtype: jnp.dtype = jnp.bfloat16
     return_hidden: bool = False
+    return_load: bool = False         # also {layer: token-choices [E]}
+
+    def attention_kind(self, i: int) -> str:
+        return SPARSE if self.layer_types is None else self.layer_types[i]
+
+    def has_router_bias(self) -> bool:
+        return self.scoring == "sigmoid" and self.load_balance_coeff > 0
 
     @classmethod
     def from_dict(cls, cfg: dict, **overrides) -> "SparseMoEConfig":
-        """From a ``config.json`` as published (``sa_config`` nested);
-        ``layers`` is the depth to build where given, else
-        ``num_hidden_layers``."""
-        sa = cfg["sa_config"]
-        if sa.get("indexer_num_kv_heads", 1) != 1:
-            raise ValueError("the indexer is built for one key head")
+        """From a ``config.json`` as published; ``layers`` is the depth to
+        build where given, else ``num_hidden_layers``. The family is told
+        by its keys: a nested ``sa_config`` (the learned indexer) or
+        ``model_type`` ``afmoe``."""
         flat = {k: cfg[k] for k in cls.__dataclass_fields__ if k in cfg}
         flat.setdefault("layers", cfg.get("num_hidden_layers"))
-        flat.update(indexer_num_heads=sa["indexer_num_heads"],
-                    indexer_head_dim=sa["indexer_head_dim"],
-                    topk=sa["topk"], rope_theta=float(cfg["rope_theta"]))
+        flat["rope_theta"] = float(cfg["rope_theta"])
+        if "sa_config" in cfg:
+            sa = cfg["sa_config"]
+            if sa.get("indexer_num_kv_heads", 1) != 1:
+                raise ValueError("the indexer is built for one key head")
+            for key in ("layer_types", "sliding_window", "intermediate_size"):
+                flat.pop(key, None)   # published, and unused by this family
+            flat.update(indexer_num_heads=sa["indexer_num_heads"],
+                        indexer_head_dim=sa["indexer_head_dim"],
+                        topk=sa["topk"])
+        elif cfg.get("model_type") == "afmoe":
+            kinds = tuple(cfg["layer_types"])[:flat["layers"]]
+            if len(kinds) != flat["layers"] or set(kinds) - {SLIDING, FULL}:
+                raise ValueError(f"layer_types {kinds} for {flat['layers']} "
+                                 f"layers")
+            if cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
+                raise NotImplementedError("group-limited routing")
+            flat.update(
+                layer_types=kinds, scoring=cfg.get("score_func", "sigmoid"),
+                sandwich_norms=True, attention_gate=True,
+                rope_layers="sliding",
+                embed_scale=(cfg["hidden_size"] ** 0.5
+                             if cfg.get("mup_enabled") else 1.0))
+        else:
+            raise ValueError("neither an sa_config nor model_type afmoe: "
+                             "a family this decoder does not know")
         flat.update(overrides)
         return cls(**flat)
 
@@ -136,12 +204,18 @@ class _Indexer(nn.Module):
         return qi, ki, proj("ww", Hi).astype(jnp.float32)
 
 
+def _output_gate(o, g):
+    """o * sigmoid(g), the sigmoid in float32."""
+    return o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(o.dtype)
+
+
 class _Attention(nn.Module):
     cfg: SparseMoEConfig
+    kind: str = SPARSE
 
     @nn.compact
-    def __call__(self, u, index):
-        cfg = self.cfg
+    def __call__(self, u, index=None):
+        cfg, kind = self.cfg, self.kind
         B, T, d = u.shape
         H, Hk, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
                     cfg.head_dim)
@@ -158,9 +232,33 @@ class _Attention(nn.Module):
         q = head_norm("q_norm", (u @ w("wq", d, H * D)).reshape(B, T, H, D))
         k = head_norm("k_norm", (u @ w("wk", d, Hk * D)).reshape(B, T, Hk, D))
         v = (u @ w("wv", d, Hk * D)).reshape(B, T, Hk, D)
-        o = sparse_attention(rope(q, cfg.rope_theta), rope(k, cfg.rope_theta),
-                             v, *index, topk=cfg.topk)
-        return o.reshape(B, T, H * D) @ w("wo", H * D, d)
+        if cfg.rope_layers == "all" or kind == SLIDING:
+            q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
+        if kind == SPARSE:
+            o = sparse_attention(q, k, v, *index, topk=cfg.topk)
+        else:
+            o = _flash.flash_attention(
+                q, k, v, causal=True,
+                window=cfg.sliding_window if kind == SLIDING else None)
+        o = o.reshape(B, T, H * D)
+        if cfg.attention_gate:
+            o = _output_gate(o, u @ w("wg", d, H * D))
+        return o @ w("wo", H * D, d)
+
+
+class _GatedMLP(nn.Module):
+    """W2(silu(W1 z) * W3 z): the dense layers' MLP and a shared expert."""
+    cfg: SparseMoEConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, z):
+        cfg, d, f = self.cfg, z.shape[-1], self.width
+        init = nn.initializers.normal(cfg.initializer_range)
+        w1, w3 = (self.param(n, init, (d, f), jnp.float32).astype(cfg.dtype)
+                  for n in ("w1", "w3"))
+        w2 = self.param("w2", init, (f, d), jnp.float32).astype(cfg.dtype)
+        return (nn.silu(z @ w1) * (z @ w3)) @ w2
 
 
 class _MoE(nn.Module):
@@ -168,6 +266,9 @@ class _MoE(nn.Module):
 
     @nn.compact
     def __call__(self, z):
+        """(y, token-choices per expert [E] of this rank's tokens)."""
+        from ..monitor.registry import counter
+
         cfg = self.cfg
         B, T, d = z.shape
         held, f = cfg.num_local_experts, cfg.moe_intermediate_size
@@ -179,31 +280,64 @@ class _MoE(nn.Module):
             "w3": self.param("w3", init, (held, d, f), jnp.float32),
             "w2": self.param("w2", init, (held, f, d), jnp.float32),
         }
+        router = {}
+        if cfg.scoring != "softmax":
+            router = dict(scoring=cfg.scoring, route_norm=cfg.route_norm,
+                          route_scale=cfg.route_scale)
+        if cfg.has_router_bias():
+            router["bias"] = self.variable(
+                BIAS_COLLECTION, "bias", jnp.zeros, (cfg.num_experts,),
+                jnp.float32).value
         y, aux = moe_ffn_dropless(
             z.reshape(B * T, d), params,
             experts_per_token=cfg.num_experts_per_tok,
-            first_expert=cfg.first_local_expert)
+            first_expert=cfg.first_local_expert, **router)
         self.sow("intermediates", "moe_expert_load", aux.load)
-        return y.reshape(B, T, d)
+        y = y.reshape(B, T, d)
+        if cfg.num_shared_experts:
+            width = cfg.num_shared_experts * f
+            counter("moe.shared_width").inc(width)
+            with jax.named_scope("hvd.shared_expert"):
+                y = y + _GatedMLP(cfg, width, name="shared")(z)
+        return y, aux.load
 
 
 class _Block(nn.Module):
     cfg: SparseMoEConfig
+    index: int = 0
 
     @nn.compact
     def __call__(self, x):
-        cfg = self.cfg
-        u = _Scale(cfg.rms_norm_eps, name="ln1")(x)
-        index = _Indexer(cfg, name="indexer")(u)
-        h = x + _Attention(cfg, name="attn")(u, index)
-        return h + _MoE(cfg, name="moe")(
-            _Scale(cfg.rms_norm_eps, name="ln2")(h))
+        """(y, the layer's token-choices per expert or None)."""
+        cfg, i = self.cfg, self.index
+        kind = cfg.attention_kind(i)
+
+        def norm(name, t):
+            return _Scale(cfg.rms_norm_eps, name=name)(t)
+
+        def after(name, t):
+            return norm(name, t) if cfg.sandwich_norms else t
+
+        u = norm("ln1", x)
+        index = (_Indexer(cfg, name="indexer")(u),) if kind == SPARSE else ()
+        h = x + after("ln1_post", _Attention(cfg, kind, name="attn")(
+            u, *index))
+        z = norm("ln2", h)
+        if i < cfg.num_dense_layers:
+            m, load = _GatedMLP(cfg, cfg.intermediate_size, name="mlp")(z), None
+        else:
+            m, load = _MoE(cfg, name="moe")(z)
+        return h + after("ln2_post", m), load
 
 
 class SparseMoEDecoder(nn.Module):
     """tokens [B, T] int32 -> logits [B, T, vocab] float32, or the final
     normed hidden states [B, T, d] with ``cfg.return_hidden`` (for
-    ``hvd.lm_head_loss(h, params["head"], labels)``: the head is untied)."""
+    ``hvd.lm_head_loss(h, params["head"], labels)``: the head is untied).
+    With ``cfg.return_load`` a pair: that, and ``{layer name: token-choices
+    per expert [E]}`` of the routed layers (what
+    :func:`update_router_biases` reads). A family with a router bias is
+    applied with its ``router_bias`` collection beside ``params``."""
     cfg: SparseMoEConfig
 
     @nn.compact
@@ -215,13 +349,33 @@ class SparseMoEDecoder(nn.Module):
         head = self.param("head", init,
                           (cfg.vocab_size, cfg.hidden_size), jnp.float32)
         x = embed.astype(cfg.dtype)[tokens]
+        if cfg.embed_scale != 1.0:
+            x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
         block = nn.remat(
             _Block, policy=jax.checkpoint_policies.save_only_these_names(
-                OUT_NAME, SELECTION_NAME))
+                OUT_NAME, SELECTION_NAME, _flash.OUT_NAME))
+        loads = {}
         for i in range(cfg.layers):
-            x = block(cfg, name=f"h{i}")(x)
+            x, load = block(cfg, i, name=f"h{i}")(x)
+            if load is not None:
+                loads[f"h{i}"] = load
         x = _Scale(cfg.rms_norm_eps, name="ln_f")(x)
-        if cfg.return_hidden:
-            return x
-        return jnp.einsum("btc,vc->btv", x, head.astype(cfg.dtype),
-                          preferred_element_type=jnp.float32)
+        if not cfg.return_hidden:
+            x = jnp.einsum("btc,vc->btv", x, head.astype(cfg.dtype),
+                           preferred_element_type=jnp.float32)
+        return (x, loads) if cfg.return_load else x
+
+
+def update_router_biases(biases, loads, *, coeff: float, reduce=None):
+    """The routers' selection biases after one step: ``biases`` is the
+    model's ``router_bias`` collection (``{layer: {"moe": {"bias": [E]}}}``),
+    ``loads`` what the model returned with ``cfg.return_load``. ``reduce``
+    sums a count over the data axes (``lambda n: hvd.allreduce(n,
+    op=hvd.Sum)`` inside the step's ``shard_map``; nothing to exchange on
+    one chip) so that every rank holds the same biases. The rule is
+    :func:`hvd.router_bias_update`'s."""
+    with jax.named_scope("hvd.router_bias_update"):
+        return {layer: {"moe": {"bias": router_bias_update(
+            tree["moe"]["bias"],
+            loads[layer] if reduce is None else reduce(loads[layer]),
+            coeff=coeff)}} for layer, tree in biases.items()}

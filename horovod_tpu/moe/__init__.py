@@ -26,4 +26,5 @@ from .layer import (  # noqa: F401
     moe_ffn_dropless,
     moe_positions,
     moe_router,
+    router_bias_update,
 )

@@ -87,26 +87,56 @@ def moe_capacity(n_tokens: int, num_experts: int,
                         // num_experts)))
 
 
+SCORINGS = ("softmax", "sigmoid")
+
+
 def moe_router(x, router_kernel, *, topk: int = 2,
-               router_logits=None):
+               router_logits=None, scoring: str = "softmax",
+               bias=None, route_norm: bool = True,
+               route_scale: float = 1.0):
     """Top-k routing of tokens ``x [N, C]`` through ``router_kernel
     [C, E]``. Returns ``(experts [N, K] int32, gates [N, K] fp32,
     load_balance_loss, z_loss, probs [N, E])``.
 
     ``router_logits`` overrides the computed logits (tests pin routing
-    deterministically with it; shape ``[N, E]``)."""
+    deterministically with it; shape ``[N, E]``).
+
+    ``scoring``: ``"softmax"`` over the E logits (the default: the top K
+    probabilities, renormalised to sum one) or ``"sigmoid"``, an
+    independent score an expert (``probs`` is then the scores). With the
+    sigmoid, ``bias`` ``[E]`` is added to the scores FOR THE SELECTION
+    ONLY: the K experts are the largest of ``score + bias``, the gates are
+    their scores alone (the bias chooses, it never weighs), divided by
+    their sum (+ 1e-20) where ``route_norm`` and multiplied by
+    ``route_scale``. The bias is state, not a parameter: no gradient
+    reaches it (:func:`router_bias_update` moves it)."""
     E = router_kernel.shape[-1]
     if topk < 1 or topk > E:
         raise ValueError(f"topk must be in 1..{E} (num experts), got "
                          f"{topk}")
+    if scoring not in SCORINGS:
+        raise ValueError(f"scoring is one of {SCORINGS}, got {scoring!r}")
     if router_logits is None:
         router_logits = jnp.einsum(
             "nc,ce->ne", x.astype(jnp.float32),
             router_kernel.astype(jnp.float32))
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    gate_vals, experts = lax.top_k(probs, topk)          # [N, K]
-    gates = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    if scoring == "sigmoid":
+        probs = jax.nn.sigmoid(router_logits)
+        chosen_by = probs if bias is None else probs + lax.stop_gradient(
+            bias.astype(jnp.float32))
+        _, experts = lax.top_k(chosen_by, topk)              # [N, K]
+        gates = jnp.take_along_axis(probs, experts, axis=-1)
+        if route_norm:
+            gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        gates = gates * route_scale
+    else:
+        if bias is not None or not route_norm or route_scale != 1.0:
+            raise ValueError("a selection bias, route_norm=False and "
+                             "route_scale belong to scoring='sigmoid'")
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        gate_vals, experts = lax.top_k(probs, topk)          # [N, K]
+        gates = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
     # Load-balance aux (Switch eq. 4): f_e from the TOP-1 assignment
     # (the loss targets the primary routing decision), P_e = mean probs.
     top1 = jax.nn.one_hot(experts[:, 0], E, dtype=jnp.float32)
@@ -296,9 +326,31 @@ def moe_ef_residuals(n_tokens: int, d_model: int, num_experts: int,
 GROUP_ALIGN = 512
 
 
+def router_bias_update(bias, load, *, coeff: float):
+    """One step of the balancing rule on a router's selection bias
+    ``[E]``, from the step's token-choices per expert ``load`` ``[E]``
+    (``MoEAux.load``, summed over the data axes by the caller:
+    ``hvd.allreduce(load, op=hvd.Sum)``, so that every rank holds the same
+    bias):
+
+        delta = coeff * sign(mean(load) - load);  delta -= mean(delta)
+        bias += delta
+
+    An expert that got more than its share is chosen a little less often
+    next step, and the biases keep summing to what they summed to. Called
+    once a step after the optimizer's update; nothing differentiates
+    through it."""
+    with jax.named_scope("hvd.router_bias_update"):
+        load = lax.stop_gradient(load.astype(jnp.float32))
+        delta = coeff * jnp.sign(jnp.mean(load) - load)
+        return bias + (delta - jnp.mean(delta)).astype(bias.dtype)
+
+
 def moe_ffn_dropless(x, params, *, experts_per_token: int,
                      first_expert: int = 0, ep_axis=None,
-                     router_logits=None):
+                     router_logits=None, scoring: str = "softmax",
+                     bias=None, route_norm: bool = True,
+                     route_scale: float = 1.0):
     """Top-k gated SiLU experts over tokens ``x [N, C]`` with no capacity:
     no token-choice is ever dropped. The layer is told which experts it
     holds: ``params["router"]`` is ``[C, E]`` over ALL the experts (the
@@ -310,6 +362,10 @@ def moe_ffn_dropless(x, params, *, experts_per_token: int,
     x) * W3_e x)``; what the absent experts would add is left out (it is
     another chip's to add). Returns ``(y [N, C], MoEAux)`` with ``load``
     the token-choices per GLOBAL expert and ``dropped_fraction`` 0.
+    ``scoring``, ``bias``, ``route_norm`` and ``route_scale`` are
+    :func:`moe_router`'s: sigmoid scores, a selection bias carried as
+    state, gates scaled; the defaults are the softmax router above. A
+    shared expert is the caller's, beside this call and outside its scope.
 
     The N*K token-choices are sorted by held expert and laid into a row
     buffer in which every held expert's group starts on a multiple of
@@ -342,9 +398,12 @@ def moe_ffn_dropless(x, params, *, experts_per_token: int,
     R = -(-N * K // A) * A + held * A
     counter("moe.experts_held").inc(held)
     counter("moe.rows_grouped").inc(R)
+    counter("moe.scoring", kind=scoring).inc()
     with jax.named_scope("hvd.moe_ffn"):
         experts, gates, lb, z, _ = moe_router(
-            x, params["router"], topk=K, router_logits=router_logits)
+            x, params["router"], topk=K, router_logits=router_logits,
+            scoring=scoring, bias=bias, route_norm=route_norm,
+            route_scale=route_scale)
         local = experts.reshape(-1) - first_expert           # [N*K]
         key = jnp.where((local >= 0) & (local < held), local, held)
         _, order = lax.sort((key, jnp.arange(N * K, dtype=jnp.int32)),

@@ -54,10 +54,13 @@ DEVICE_SCOPES = (
     "hvd.grad",              # parallel/tape.py: the user's loss, fwd + bwd
     "hvd.lm_head_loss",      # ops/softmax_xent.py: head matmul + xent
     "hvd.flash_attention",   # ops/flash_attention.py: kernels + layout
+    "hvd.flash_window",      # ops/flash_attention.py: a windowed call, inside
     "hvd.layer_norm",        # ops/layer_norm.py: fused residual + LN
     "hvd.sparse_attention",  # ops/sparse_attention.py: kernels + layout
     "hvd.sparse_indexer",    # ops/sparse_attention.py: scores + selection
     "hvd.moe_ffn",           # moe/layer.py: dropless router..combine
+    "hvd.shared_expert",     # models/sparse_moe_decoder.py: beside moe_ffn
+    "hvd.router_bias_update",  # moe/layer.py: the balancing bias, a step
     "hvd.allreduce_grads",   # parallel/optimizer.py, tape.py: grad exchange
     "hvd.bucket_pack",       # ops/fusion.py: leaves -> flat bucket
     "hvd.bucket_allreduce",  # ops/fusion.py: the per-bucket wire op

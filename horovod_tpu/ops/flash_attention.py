@@ -58,6 +58,28 @@ kernels (guide: /opt/skills/guides/pallas_guide.md):
   against a scalar, and the fully-masked-row guards (one select on a
   [tq, 1] column) exist only with runtime offsets (ring partials): a
   causal row with zero offsets always sees its own token.
+* grouped KV heads (``k``, ``v`` with fewer heads than ``q``) are found
+  where they lie, never repeated in HBM: the forward and dq kernels' k/v
+  index maps send query head ``h`` to KV head ``h // group`` (in place a
+  head is a block then, D = 128; any other grouped shape is packed), and
+  the dk/dv kernel's grid counts KV heads with the query heads of a group
+  on an axis of their own that the k, v, dk, dv block indices do not
+  depend on: a K/V block is fetched once for its group and dk, dv are
+  summed over the group in the kernel's scratch.
+* a window (``window=W``: query t sees keys t - W + 1 .. t; causal, zero
+  offsets, square blocks) makes the inner grid axis walk the BAND: the
+  ``ceil((W - 1) / b) + 1`` k blocks a q block's window touches, offset
+  from the q block (``_specs``), and no others, so no block past the
+  window's far side is fetched or computed. A band offset is a kind of
+  cell known when the kernel is traced (``_band_cells``): wholly visible
+  (the ``_FULL`` body), or cut into sub-tiles of which those wholly in
+  the future or wholly past the window are never computed and those the
+  diagonal or the window's far edge crosses are masked, the far edge by
+  the same hoisted iota difference against a second scalar
+  (``_Band.kind``, ``_visible``). Every branch is on grid indices and
+  static sizes. The three kernels of a windowed call are named
+  ``hvd_flash_*_win``. ``window=None`` with one head count compiles what
+  this file compiled before it knew either (docs/flash_window.md).
 * :func:`flash_ring_attention` composes the kernels with sequence
   parallelism: K/V blocks rotate around the mesh axis via
   ``lax.ppermute`` while each ring step runs the flash kernel with
@@ -74,7 +96,9 @@ kernel call adds to ``flash.tiles_total`` / ``flash.tiles_computed`` /
 ``flash.tiles_masked`` (label ``kernel`` = ``fwd`` | ``bwd_dq`` |
 ``bwd_dkv``): the sub-tiles of one head's grid, how many are computed and
 how many of those are masked (16 / 10 / 4 at the benchmark cells' shape,
-in either layout).
+in either layout); a windowed call counts under one more label,
+``window``. ``flash.kv_group`` adds the query heads a KV head of every
+grouped call.
 
 Everything is static-shaped; block sizes adapt to divide the sequence
 (see ``_pick_block`` — a whole-sequence block covers anything <= the
@@ -86,11 +110,13 @@ the identical code path.
 
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (TPU backend)
 
@@ -131,7 +157,7 @@ _DEF_BLOCK_Q = _block_knob("HOROVOD_FLASH_BLOCK_Q", 1024)
 _DEF_BLOCK_K = _block_knob("HOROVOD_FLASH_BLOCK_K", 1024)
 
 
-def _resolve_blocks(B, Tq, Tk, H, D, dtype, causal):
+def _resolve_blocks(B, Tq, Tk, H, D, dtype, causal, window=None, Hkv=None):
     """Block sizes for a flash call that pinned neither block: env knobs
     win; otherwise the kernel autotuner's cached/swept choice (TPU,
     single-process); otherwise the hand-tuned defaults. Multi-process
@@ -152,7 +178,8 @@ def _resolve_blocks(B, Tq, Tk, H, D, dtype, causal):
         return _DEF_BLOCK_Q, _DEF_BLOCK_K
     return kernel_autotune.flash_blocks(
         B, Tq, Tk, H, D, dtype, causal,
-        (_DEF_BLOCK_Q, _DEF_BLOCK_K), _pick_block)
+        (_DEF_BLOCK_Q, _DEF_BLOCK_K), _pick_block, window=window,
+        kv_heads=None if Hkv in (None, H) else Hkv)
 
 
 def _interpret() -> bool:
@@ -264,7 +291,7 @@ def _sub_tiles(mode: str, bq: int, bk: int, sub_tile):
     ``mode``. Only a cell that can skip is cut up: every cut costs each
     kernel a column of row statistics per sub-tile ([tq, 1]: as many vregs
     as half a [tq, 256] pass), which only skipped sub-tiles pay back."""
-    if mode != _SKIP:
+    if mode != _SKIP and not isinstance(mode, _Band):
         return bq, bk
     return _sub_tile(bq, sub_tile[0]), _sub_tile(bk, sub_tile[1])
 
@@ -346,6 +373,92 @@ def _q_tiles(mode, c, tk, bq, tq):
     return min(c * tk, bq) // tq, min(c * tk + tk + tq - 2, bq) // tq
 
 
+class _Band(NamedTuple):
+    """A grid cell of a WINDOWED call: visible pairs are ``0 <= t - s <
+    window``. With ``bq == bk`` and zero offsets the cell at band offset
+    ``jj`` of any q block has the same static distance ``d0`` from its
+    first key to its first query, so, like the three modes above, what its
+    sub-tiles do is known when the kernel is traced."""
+    d0: int
+    window: int
+
+    def kind(self, a_minus_c: int, tq: int, tk: int):
+        """What sub-tile (a, c), ``a_minus_c = a * tq - c * tk``, needs:
+        ``None`` never computed (wholly in the future or wholly past the
+        window), else which masks: ``""`` bare, ``"c"`` the diagonal
+        crosses it, ``"w"`` the window's far edge does, ``"cw"`` both."""
+        base = self.d0 + a_minus_c           # t - s at the tile's corner
+        if base + tq - 1 < 0 or base - (tk - 1) >= self.window:
+            return None
+        return (("c" if base - (tk - 1) < 0 else "")
+                + ("w" if base + tq - 1 >= self.window else ""))
+
+    def whole(self, bq: int, bk: int) -> bool:
+        """Is every pair of the cell visible?"""
+        return self.kind(0, bq, bk) == ""
+
+
+def _band_blocks(window: int, b: int, nk: int) -> int:
+    """k blocks of ``b`` the band of one q block of ``b`` can touch: keys
+    ``i * b - window + 1 .. i * b + b - 1``."""
+    return min(nk, -(-(window - 1) // b) + 1)
+
+
+def _band_cells(window: int, b: int, nkb: int):
+    """``[(mode, lo, hi)]``: the band offsets ``lo..hi`` (0 the farthest
+    block, ``nkb - 1`` the diagonal's) that run one copy of the body.
+    Wholly visible cells share ``_FULL``'s."""
+    cells = []
+    for jj in range(nkb):
+        band = _Band((nkb - 1 - jj) * b, window)
+        mode = _FULL if band.whole(b, b) else band
+        if cells and cells[-1][0] == mode == _FULL:
+            cells[-1] = (mode, cells[-1][1], jj)
+        else:
+            cells.append((mode, jj, jj))
+    return cells
+
+
+def _k_plan(mode, a, tq, bk, tk):
+    """``[(c, kind)]``: the k sub-tiles q sub-tile ``a`` computes, in
+    order, each with the masks it needs (``_Band.kind``)."""
+    if isinstance(mode, _Band):
+        return [(c, kind) for c in range(bk // tk)
+                if (kind := mode.kind(a * tq - c * tk, tq, tk)) is not None]
+    n_full, hi = _k_tiles(mode, a, tq, bk, tk)
+    return [(c, "c" if c >= n_full else "") for c in range(hi)]
+
+
+def _q_plan(mode, c, tk, bq, tq):
+    """The same from k sub-tile ``c``'s side: ``[(a, kind)]``."""
+    if isinstance(mode, _Band):
+        return [(a, kind) for a in range(bq // tq)
+                if (kind := mode.kind(a * tq - c * tk, tq, tk)) is not None]
+    lo, lo_full = _q_tiles(mode, c, tk, bq, tq)
+    return [(a, "c" if a < lo_full else "") for a in range(lo, bq // tq)]
+
+
+def _visible(s, diag, kind, off, window, transposed=False):
+    """Scores with the invisible entries of a masked sub-tile at -1e30.
+    ``diag`` is the hoisted row minus column index; ``off`` the scalar the
+    diagonal sits at: first key minus first query of the sub-tile, or,
+    ``transposed`` (keys down the rows), first query minus first key. The
+    window's far edge is the same compare against a second scalar."""
+    if not kind:
+        return s
+    if transposed:
+        seen = diag <= off if "c" in kind else None
+        if "w" in kind:
+            near = diag > off - window
+            seen = near if seen is None else seen & near
+    else:
+        seen = diag >= off if "c" in kind else None
+        if "w" in kind:
+            near = diag < off + window
+            seen = near if seen is None else seen & near
+    return jnp.where(seen, s, _NEG_INF)
+
+
 def _diagonal(tq, tk):
     """Row index minus column index over a sub-tile: with it the causal
     mask of any sub-tile is one compare against a scalar."""
@@ -358,53 +471,85 @@ def _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode):
     Offsets arrive as operands (see ``_scalar_spec``) so ring/sharded
     callers can pass traced values (e.g. ``axis_index * T_local``). Only a
     masked cell asks; an aligned one has 0 by definition."""
+    if isinstance(mode, _Band):
+        return mode.d0
     if mode != _MASKED:
         return 0
     return (qoff_ref[...][0, 0, 0] + i * bq
             - koff_ref[...][0, 0, 0] - j * bk)
 
 
-def _last_k_block(causal, static_skip, i, bq, bk, nk):
-    """The last k block that q block ``i`` runs (``_cell_is``)."""
+def _last_k_block(causal, static_skip, i, bq, bk, nk, band=None):
+    """The last k block that q block ``i`` runs (``_cell_is``); in a
+    windowed call the band's last, the diagonal's."""
+    if band:
+        return band[1] - 1
     if causal and static_skip:
         return jnp.minimum(nk - 1, (i * bq + bq - 1) // bk)
     return nk - 1
 
 
-def _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk):
-    for mode in _cell_modes(causal, static_skip, nq, nk, bq, bk):
-        pl.when(_cell_is(mode, causal, static_skip, i, j, bq, bk))(
-            functools.partial(body, mode))
+def _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk, band=None,
+               valid=None):
+    """One copy of ``body`` per kind of cell the call can meet, each under
+    its ``pl.when``. In a windowed call (``band`` = (window, blocks a
+    band)) ``j`` is the cell's offset inside the band and ``valid`` says
+    whether the band has a block there (it sticks out of the sequence at
+    its ends)."""
+    if band is None:
+        for mode in _cell_modes(causal, static_skip, nq, nk, bq, bk):
+            pl.when(_cell_is(mode, causal, static_skip, i, j, bq, bk))(
+                functools.partial(body, mode))
+        return
+    for mode, lo, hi in _band_cells(band[0], bq, band[1]):
+        here = (j == lo) if lo == hi else (j >= lo) & (j <= hi)
+        pl.when(valid & here)(functools.partial(body, mode))
 
 
-def _tile_counts(causal, static_skip, nq, nk, bq, bk):
+def _tile_counts(causal, static_skip, nq, nk, bq, bk, window=None):
     """(total, computed, masked) sub-tiles of one head, over the grid; a
     cell that never runs counts at the lattice of the mode it is nearest
-    to (the last one: a future cell of a causal call is ``_SKIP``'s)."""
-    modes = _cell_modes(causal, static_skip, nq, nk, bq, bk)
+    to (the last one: a future cell of a causal call is ``_SKIP``'s). A
+    windowed call's grid holds the band's cells alone; the cells outside
+    it count to the total, at the cut-up lattice."""
+    if window is None:
+        modes = _cell_modes(causal, static_skip, nq, nk, bq, bk)
+        cell_modes = {(i, j): [m for m in modes if _cell_is(
+            m, causal, static_skip, i, j, bq, bk)]
+            for i in range(nq) for j in range(nk)}
+    else:
+        nkb = _band_blocks(window, bq, nk)
+        cell_modes = {(i, j): [] for i in range(nq) for j in range(nk)}
+        for mode, lo, hi in _band_cells(window, bq, nkb):
+            for i in range(nq):
+                for jj in range(lo, hi + 1):
+                    if i - (nkb - 1) + jj >= 0:
+                        cell_modes[i, i - (nkb - 1) + jj] = [mode]
     total = computed = masked = 0
-    for i in range(nq):
-        for j in range(nk):
-            mine = [m for m in modes
-                    if _cell_is(m, causal, static_skip, i, j, bq, bk)]
-            mode = mine[0] if mine else _SKIP
-            tq, tk = _sub_tiles(mode, bq, bk, _SUB_TILE)
-            total += (bq // tq) * (bk // tk)
-            for a in range(bq // tq if mine else 0):
-                n_full, hi = _k_tiles(mode, a, tq, bk, tk)
-                computed += hi
-                masked += hi - n_full
+    for mine in cell_modes.values():
+        mode = mine[0] if mine else _SKIP
+        tq, tk = _sub_tiles(mode, bq, bk, _SUB_TILE)
+        total += (bq // tq) * (bk // tk)
+        for a in range(bq // tq if mine else 0):
+            plan = _k_plan(mode, a, tq, bk, tk)
+            computed += len(plan)
+            masked += sum(1 for _, kind in plan if kind)
     return total, computed, masked
 
 
-def _count_tiles(kernel, *args):
+def _count_tiles(kernel, *args, window=None):
     """Trace-time counters of how often the in-cell tiling engages, per
     head and kernel call (monitor registry, as plan/accounting.py keeps
-    trace-time wire bytes): nothing of this runs on the device."""
+    trace-time wire bytes): nothing of this runs on the device. A windowed
+    call counts under a label of its own, ``window`` = its width."""
     from ..monitor.registry import counter
 
-    for name, n in zip(("total", "computed", "masked"), _tile_counts(*args)):
-        counter(f"flash.tiles_{name}", kernel=kernel).inc(n)
+    labels = dict(kernel=kernel)
+    if window is not None:
+        labels["window"] = str(window)
+    for name, n in zip(("total", "computed", "masked"),
+                       _tile_counts(*args, window=window)):
+        counter(f"flash.tiles_{name}", **labels).inc(n)
 
 
 def _as_row(col):
@@ -449,11 +594,14 @@ def _fwd_out(m, l, acc, o_dtype):
 
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr,
-                *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile, G):
+                *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile, G,
+                band=None):
     i = pl.program_id(2)   # q block
     g = pl.program_id(3)   # head inside the lane block (``_specs``)
-    j = pl.program_id(4)   # k block (innermost: scratch carries across j)
-    carried = nk > 1       # else a q sub-tile finishes inside this cell
+    j = pl.program_id(4)   # k block (innermost: scratch carries across j);
+    #                        windowed: its offset inside the band
+    window, inner = band if band else (None, nk)
+    carried = inner > 1    # else a q sub-tile finishes inside this cell
 
     if carried:
         @pl.when(j == 0)
@@ -468,22 +616,23 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         diag = _diagonal(tq, tk) if mode != _FULL else None
         qscale = _on_head(g, G, q_ref, scale)
 
-        def tile(a, c, q, carry, masked):
+        def tile(a, c, q, carry, kind):
             m_prev, l_prev, acc = carry
             cols = pl.ds(c * tk, tk)
             s = jax.lax.dot_general(
                 q, k_ref[0, cols, :], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)      # [tq, tk]
-            if masked:
-                s = jnp.where(diag >= c * tk - a * tq - d0, s, _NEG_INF)
+            s = _visible(s, diag, kind, c * tk - a * tq - d0, window)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             m_sub = m_new
-            if not static_skip:
+            if not static_skip or "w" in kind:
                 # Fully-masked rows (a ring partial that sees a k block
-                # entirely in its causal future): m_new stays at _NEG_INF
-                # and s - m_new == 0 would wrongly give p = 1. Subtracting
-                # 0 there instead gives p = exp(-1e30) = 0.
+                # entirely in its causal future; a row whose window ends
+                # before this sub-tile, the first its q block meets):
+                # m_new stays at _NEG_INF and s - m_new == 0 would wrongly
+                # give p = 1. Subtracting 0 there instead gives
+                # p = exp(-1e30) = 0.
                 m_sub = jnp.where(m_new > _NEG_INF / 2, m_new, 0.0)
             p = jnp.exp(s - m_sub)
             l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
@@ -502,30 +651,37 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 carry = (jnp.full((tq, 1), _NEG_INF, jnp.float32),
                          jnp.zeros((tq, 1), jnp.float32),
                          jnp.zeros((tq, q.shape[1]), jnp.float32))
-            n_full, hi = _k_tiles(mode, a, tq, bk, tk)
-            for c in range(hi):
-                carry = tile(a, c, q, carry, masked=c >= n_full)
+            for c, kind in _k_plan(mode, a, tq, bk, tk):
+                carry = tile(a, c, q, carry, kind)
             if carried:
                 m_scr[rows, :], l_scr[rows, :], acc_scr[rows, :] = carry
             else:
                 o, lse_ref[0, :, rows] = _fwd_out(*carry, o_ref.dtype)
                 _put(o_ref, (0, rows), o, g, G)
 
-    _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk)
+    _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk, band,
+               band and j >= inner - 1 - i)
 
     if carried:
-        @pl.when(j == _last_k_block(causal, static_skip, i, bq, bk, nk))
+        @pl.when(j == _last_k_block(causal, static_skip, i, bq, bk, nk,
+                                    band))
         def _finish():
             o, lse_ref[0] = _fwd_out(
                 m_scr[:], l_scr[:], acc_scr[:], o_ref.dtype)
             _put(o_ref, (0,), o, g, G)
 
 
-def _statics(scale, causal, bq, bk, static_skip, heads):
+def _statics(scale, causal, bq, bk, static_skip, heads, window=None,
+             group=1):
     """What a kernel call is specialised on, read where the call is made."""
+    if window is not None and not (causal and static_skip and bq == bk):
+        raise ValueError(
+            "a windowed flash call is causal, has zero offsets (no ring "
+            f"partial) and square blocks, got causal={causal} "
+            f"static_skip={static_skip} blocks ({bq}, {bk})")
     return dict(scale=scale, causal=causal, bq=bq, bk=bk,
                 static_skip=static_skip, heads=heads, sub_tile=_SUB_TILE,
-                interpret=_interpret())
+                interpret=_interpret(), window=window, group=group)
 
 
 # The three pallas_calls are traced once per (operand shapes, statics) and
@@ -534,10 +690,11 @@ def _statics(scale, causal, bq, bk, static_skip, heads):
 _traced_once = functools.partial(
     jax.jit, inline=True,
     static_argnames=("scale", "causal", "bq", "bk", "static_skip", "heads",
-                     "sub_tile", "interpret"))
+                     "sub_tile", "interpret", "window", "group"))
 
 
-def _specs(heads, width, bq, bk, kv_major=False):
+def _specs(heads, width, bq, bk, kv_major=False, group=1, band=None,
+           nq=None):
     """``(P, G, lanes, q_rows, k_rows, stats)`` of one kernel call over
     operands ``[N, T, width]`` with ``heads`` heads side by side. How the
     last dimension is cut: ``lanes`` a block, ``P`` blocks a row, ``G``
@@ -554,23 +711,82 @@ def _specs(heads, width, bq, bk, kv_major=False):
     several inner blocks a lane block's are fetched once per head of it.
     ``q_rows`` / ``k_rows`` make the spec of a [N, T, width] operand
     blocked along the queries / keys, ``stats`` that of a per-query
-    [N * heads, 1, Tq] statistic (``_fwd_out``)."""
+    [N * heads, 1, Tq] statistic (``_fwd_out``).
+
+    Grouped KV heads (``group`` query heads share one; a head is a block
+    then, ``_in_place``): ``heads`` and ``width`` are q's, and k, v are
+    ``[N, T, width / group]``, or ``[N / group, T, width]`` packed. The
+    forward and dq kernels keep their grid and find q head ``h``'s keys in
+    block ``h // group``. The dk/dv kernel's N and P count KV heads and its
+    fourth axis is the query head inside the group: the k, v and dk, dv
+    block indices do not depend on it, so a KV block is fetched once for
+    its group and dk, dv are summed over the group in the kernel's scratch.
+
+    Windowed (``band`` = blocks a band, with ``nq`` q blocks): the inner
+    axis walks the band and not the sequence: offset ``jj`` of q block
+    ``i`` is k block ``i - (band - 1) + jj``, and q block ``j + ii`` past
+    k block ``j`` in the dk/dv kernel. Where the band sticks out of the
+    sequence the index stays on the edge block (already there, or next to
+    come: no fetch of its own) and the kernel skips the cell. No block
+    outside the band is fetched."""
     if heads == 1:
         lanes, P, G = width, 1, 1
     else:
         lanes, P, G = _LANES, width // _LANES, _LANES * heads // width
+    if group > 1 and G > 1:
+        raise ValueError("grouped KV heads share no lane block "
+                         "(``_in_place``)")
     if kv_major:   # the dk/dv kernel: (n, p, j, g, i)
         qi, ki = 4, 2
+        P //= group if heads > 1 else 1
     else:          # forward and dq: (n, p, i, g, j)
         qi, ki = 2, 4
-    q_rows = lambda: pl.BlockSpec(
-        (1, bq, lanes), lambda *ids: (ids[0], ids[qi], ids[1]))
-    k_rows = lambda: pl.BlockSpec(
-        (1, bk, lanes), lambda *ids: (ids[0], ids[ki], ids[1]))
+
+    def q_block(ids):
+        if band and kv_major:
+            return jnp.minimum(ids[ki] + ids[qi], nq - 1)
+        return ids[qi]
+
+    def k_block(ids):
+        if band and not kv_major:
+            return jnp.maximum(ids[qi] - (band - 1) + ids[ki], 0)
+        return ids[ki]
+
+    if group == 1:
+        q_at = lambda ids: (ids[0], q_block(ids), ids[1])
+        k_at = lambda ids: (ids[0], k_block(ids), ids[1])
+        head = lambda ids: (ids[0] * P + ids[1]) * G + ids[3]
+    elif kv_major:   # n, p count KV heads; ids[3] the head in the group
+        if heads == 1:
+            q_at = lambda ids: (ids[0] * group + ids[3], q_block(ids), 0)
+        else:
+            q_at = lambda ids: (ids[0], q_block(ids),
+                                ids[1] * group + ids[3])
+        k_at = lambda ids: (ids[0], k_block(ids), ids[1])
+        head = lambda ids: (ids[0] * P + ids[1]) * group + ids[3]
+    else:            # n, p count query heads
+        q_at = lambda ids: (ids[0], q_block(ids), ids[1])
+        if heads == 1:
+            k_at = lambda ids: (ids[0] // group, k_block(ids), 0)
+        else:
+            k_at = lambda ids: (ids[0], k_block(ids), ids[1] // group)
+        head = lambda ids: ids[0] * P + ids[1]
+    q_rows = lambda: pl.BlockSpec((1, bq, lanes), lambda *ids: q_at(ids))
+    k_rows = lambda: pl.BlockSpec((1, bk, lanes), lambda *ids: k_at(ids))
     stats = lambda: pl.BlockSpec(
-        (1, 1, bq),
-        lambda *ids: ((ids[0] * P + ids[1]) * G + ids[3], 0, ids[qi]))
+        (1, 1, bq), lambda *ids: (head(ids), 0, q_block(ids)))
     return P, G, lanes, q_rows, k_rows, stats
+
+
+# A windowed call's three kernels carry names of their own: a reader that
+# matches an op's whole name tells them from the full calls, one that looks
+# for the full calls' names as substrings takes all six for kernels.
+_WIN = "_win"
+
+
+def _band(window, b, nk):
+    """(window, blocks a band) of a windowed call, else None."""
+    return None if window is None else (window, _band_blocks(window, b, nk))
 
 
 # The head axis revisits the output block, the innermost one accumulates.
@@ -578,34 +794,39 @@ _SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary", "arbitrary")
 
 
 def _flash_fwd(q, k, v, scale, causal, bq, bk, q_off=0, k_off=0,
-               static_skip=True, heads=1):
+               static_skip=True, heads=1, window=None, group=1):
     """q,k,v: [N, T, heads * D] → (o [N, Tq, heads * D], lse [N * heads,
     1, Tq] f32): ``heads`` = 1 is the packed layout ([B * H, T, D]), more
     the projections' own ([B, T, H * D], ``_reads_in_place``).
 
     ``q_off``/``k_off`` are global positions of the first query/key token
     (may be traced, e.g. ``lax.axis_index(...) * T_local`` under a ring);
-    pass ``static_skip=False`` whenever they can be nonzero."""
+    pass ``static_skip=False`` whenever they can be nonzero. ``window``:
+    query t sees keys t - window + 1 .. t. ``group``: query heads a KV
+    head (k, v hold ``heads / group`` heads, or N / group rows packed)."""
     _count_tiles("fwd", causal, static_skip, q.shape[1] // bq,
-                 k.shape[1] // bk, bq, bk)
+                 k.shape[1] // bk, bq, bk, window=window)
     return _fwd_call(q_off, k_off, q, k, v,
-                     **_statics(scale, causal, bq, bk, static_skip, heads))
+                     **_statics(scale, causal, bq, bk, static_skip, heads,
+                                window, group))
 
 
 @_traced_once
 def _fwd_call(q_off, k_off, q, k, v, *, scale, causal, bq, bk, static_skip,
-              heads, sub_tile, interpret):
+              heads, sub_tile, interpret, window, group):
     N, Tq, W = q.shape
     Tk = k.shape[1]
     nq, nk = Tq // bq, Tk // bk
-    P, G, lanes, q_rows, k_rows, stats = _specs(heads, W, bq, bk)
+    band = _band(window, bq, nk)
+    P, G, lanes, q_rows, k_rows, stats = _specs(
+        heads, W, bq, bk, group=group, band=band and band[1], nq=nq)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, nq=nq, nk=nk,
                                static_skip=static_skip, sub_tile=sub_tile,
-                               G=G)
+                               G=G, band=band)
     return pl.pallas_call(
         kernel,
-        grid=(N, P, nq, G, nk),
+        grid=(N, P, nq, G, band[1] if band else nk),
         in_specs=[_scalar_spec(), _scalar_spec(),
                   q_rows(), k_rows(), k_rows()],
         out_specs=[q_rows(), stats()],
@@ -621,7 +842,7 @@ def _fwd_call(q_off, k_off, q, k, v, *, scale, causal, bq, bk, static_skip,
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
-        name="hvd_flash_fwd",
+        name="hvd_flash_fwd" + _WIN * bool(band),
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v)
 
 
@@ -643,11 +864,12 @@ def _probs(s, lse, static_skip):
 def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, acc_scr,
                    *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile,
-                   G):
+                   G, band=None):
     i = pl.program_id(2)
     g = pl.program_id(3)
-    j = pl.program_id(4)
-    carried = nk > 1
+    j = pl.program_id(4)   # windowed: the cell's offset inside the band
+    window, inner = band if band else (None, nk)
+    carried = inner > 1
 
     if carried:
         @pl.when(j == 0)
@@ -669,16 +891,14 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 do = do * own
             lse, delta = _as_cols(lse_ref[0, :, rows], delta_ref[0, :, rows])
             acc = jnp.zeros((tq, q.shape[1]), jnp.float32)
-            n_full, hi = _k_tiles(mode, a, tq, bk, tk)
-            for c in range(hi):
+            for c, kind in _k_plan(mode, a, tq, bk, tk):
                 cols = pl.ds(c * tk, tk)
                 k = k_ref[0, cols, :]
                 s = jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)  # [tq, tk]
-                if c >= n_full:
-                    s = jnp.where(diag >= c * tk - a * tq - d0, s,
-                                  _NEG_INF)
+                s = _visible(s, diag, kind, c * tk - a * tq - d0,
+                             window)
                 p = _probs(s, lse, static_skip)
                 dp = jax.lax.dot_general(
                     do, v_ref[0, cols, :], (((1,), (1,)), ((), ())),
@@ -693,10 +913,12 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 _put(dq_ref, (0, rows), (acc * scale).astype(dq_ref.dtype),
                      g, G)
 
-    _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk)
+    _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk, band,
+               band and j >= inner - 1 - i)
 
     if carried:
-        @pl.when(j == _last_k_block(causal, static_skip, i, bq, bk, nk))
+        @pl.when(j == _last_k_block(causal, static_skip, i, bq, bk, nk,
+                                    band))
         def _finish():
             _put(dq_ref, (0,), (acc_scr[:] * scale).astype(dq_ref.dtype),
                  g, G)
@@ -705,14 +927,22 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
                     *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile,
-                    G):
+                    G, band=None, group=1):
     j = pl.program_id(2)   # k block
-    g = pl.program_id(3)   # head inside the lane block
-    i = pl.program_id(4)   # q block (innermost: scratch carries across i)
-    carried = nq > 1
+    g = pl.program_id(3)   # head inside the lane block; with grouped KV
+    #                        heads (one head a block then) the query head
+    #                        inside the group: dk, dv sum over it here
+    i = pl.program_id(4)   # q block (innermost: scratch carries across i);
+    #                        windowed: how many blocks past the k block
+    window, inner = band if band else (None, nq)
+    carried = inner > 1 or group > 1
+
+    def at(step, head):
+        """At the inner axis's ``step`` (and, grouped, on ``head``)?"""
+        return (i == step) & (g == head) if group > 1 else i == step
 
     if carried:
-        @pl.when(i == 0)
+        @pl.when(at(0, 0))
         def _init():
             dk_scr[:] = jnp.zeros_like(dk_scr)
             dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -739,18 +969,16 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             if G > 1:   # dp contracts over the lanes: this head's only
                 v = v * own
             dk = dv = jnp.zeros((tk, k.shape[1]), jnp.float32)
-            lo, lo_full = _q_tiles(mode, c, tk, bq, tq)
-            for a in range(lo, nqs):
+            for a, kind in _q_plan(mode, c, tk, bq, tq):
                 rows = pl.ds(a * tq, tq)
                 q = q_ref[0, rows, :]
                 do = do_ref[0, rows, :]
                 s = jax.lax.dot_general(
                     k, q, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)  # [tk, tq]
-                if a < lo_full:
-                    # key index - query index <= first q - first k
-                    s = jnp.where(diag <= a * tq + d0 - c * tk, s,
-                                  _NEG_INF)
+                # key index - query index <= first q - first k
+                s = _visible(s, diag, kind, a * tq + d0 - c * tk, window,
+                             transposed=True)
                 p = _probs(s, lse[a], static_skip)
                 dv += jax.lax.dot_general(
                     p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
@@ -771,10 +999,16 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                      g, G)
                 _put(dv_ref, (0, cols), dv.astype(dv_ref.dtype), g, G)
 
-    _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk)
+    if band:
+        # The q block ``i`` blocks past k block ``j`` sees it as its band's
+        # cell ``inner - 1 - i`` (offset 0 is a q block's farthest).
+        _each_mode(body, causal, static_skip, i, inner - 1 - i, bq, bk, nq,
+                   nk, band, i < nq - j)
+    else:
+        _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk)
 
     if carried:
-        @pl.when(i == nq - 1)
+        @pl.when(at(inner - 1, group - 1))
         def _finish():
             _put(dk_ref, (0,), (dk_scr[:] * scale).astype(dk_ref.dtype),
                  g, G)
@@ -806,25 +1040,30 @@ def _prep_residuals(o, do, heads=1):
 
 
 def _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, bq, bk,
-                  q_off=0, k_off=0, static_skip=True, heads=1):
+                  q_off=0, k_off=0, static_skip=True, heads=1, window=None,
+                  group=1):
     _count_tiles("bwd_dq", causal, static_skip, q.shape[1] // bq,
-                 k.shape[1] // bk, bq, bk)
+                 k.shape[1] // bk, bq, bk, window=window)
     return _bwd_dq_call(q_off, k_off, q, k, v, do, lse, delta,
                         **_statics(scale, causal, bq, bk, static_skip,
-                                   heads))
+                                   heads, window, group))
 
 
 @_traced_once
 def _bwd_dq_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
-                 bq, bk, static_skip, heads, sub_tile, interpret):
+                 bq, bk, static_skip, heads, sub_tile, interpret, window,
+                 group):
     N, Tq, W = q.shape
     nq, nk = Tq // bq, k.shape[1] // bk
-    P, G, lanes, q_rows, k_rows, stats = _specs(heads, W, bq, bk)
+    band = _band(window, bq, nk)
+    P, G, lanes, q_rows, k_rows, stats = _specs(
+        heads, W, bq, bk, group=group, band=band and band[1], nq=nq)
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, nk=nk,
-                          static_skip=static_skip, sub_tile=sub_tile, G=G),
-        grid=(N, P, nq, G, nk),
+                          static_skip=static_skip, sub_tile=sub_tile, G=G,
+                          band=band),
+        grid=(N, P, nq, G, band[1] if band else nk),
         in_specs=[_scalar_spec(), _scalar_spec(),
                   q_rows(), k_rows(), k_rows(),       # q, k, v
                   q_rows(), stats(), stats()],        # do, lse, delta
@@ -834,32 +1073,36 @@ def _bwd_dq_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
         scratch_shapes=[pltpu.VMEM((bq, lanes), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
-        name="hvd_flash_bwd_dq",
+        name="hvd_flash_bwd_dq" + _WIN * bool(band),
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v, do, lse, delta)
 
 
 def _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, bq, bk,
-                   q_off=0, k_off=0, static_skip=True, heads=1):
+                   q_off=0, k_off=0, static_skip=True, heads=1, window=None,
+                   group=1):
     _count_tiles("bwd_dkv", causal, static_skip, q.shape[1] // bq,
-                 k.shape[1] // bk, bq, bk)
+                 k.shape[1] // bk, bq, bk, window=window)
     return _bwd_dkv_call(q_off, k_off, q, k, v, do, lse, delta,
                          **_statics(scale, causal, bq, bk, static_skip,
-                                    heads))
+                                    heads, window, group))
 
 
 @_traced_once
 def _bwd_dkv_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
-                  bq, bk, static_skip, heads, sub_tile, interpret):
-    N, Tq, W = q.shape
-    Tk = k.shape[1]
-    nq, nk = Tq // bq, Tk // bk
-    P, G, lanes, q_rows, k_rows, stats = _specs(heads, W, bq, bk,
-                                                kv_major=True)
+                  bq, bk, static_skip, heads, sub_tile, interpret, window,
+                  group):
+    N, Tk, W = k.shape
+    nq, nk = q.shape[1] // bq, Tk // bk
+    band = _band(window, bq, nk)
+    P, G, lanes, q_rows, k_rows, stats = _specs(
+        heads, q.shape[2], bq, bk, kv_major=True, group=group,
+        band=band and band[1], nq=nq)
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, nk=nk,
-                          static_skip=static_skip, sub_tile=sub_tile, G=G),
-        grid=(N, P, nk, G, nq),
+                          static_skip=static_skip, sub_tile=sub_tile, G=G,
+                          band=band, group=group),
+        grid=(N, P, nk, max(G, group), band[1] if band else nq),
         in_specs=[_scalar_spec(), _scalar_spec(),
                   q_rows(), k_rows(), k_rows(),       # q, k, v
                   q_rows(), stats(), stats()],        # do, lse, delta
@@ -876,16 +1119,17 @@ def _bwd_dkv_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
-        name="hvd_flash_bwd_dkv",
+        name="hvd_flash_bwd_dkv" + _WIN * bool(band),
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v, do, lse, delta)
 
 
-def _flash_bwd(q, k, v, o, lse, do, heads, scale, causal, bq, bk):
+def _flash_bwd(q, k, v, o, lse, do, heads, scale, causal, bq, bk,
+               window=None, group=1):
     delta = _prep_residuals(o, do, heads)
     dq = _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, bq, bk,
-                       heads=heads)
+                       heads=heads, window=window, group=group)
     dk, dv = _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, bq, bk,
-                            heads=heads)
+                            heads=heads, window=window, group=group)
     return dq, dk, dv
 
 
@@ -910,20 +1154,30 @@ def _pick_block(T: int, preferred: int) -> Optional[int]:
     return None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, heads, scale, causal, bq, bk):
-    o, _ = _flash_fwd(q, k, v, scale, causal, bq, bk, heads=heads)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, heads, scale, causal, bq, bk, window, group):
+    o, _ = _flash_fwd(q, k, v, scale, causal, bq, bk, heads=heads,
+                      window=window, group=group)
     return o
 
 
-def _flash_vjp_fwd(q, k, v, heads, scale, causal, bq, bk):
-    o, lse = _flash_fwd(q, k, v, scale, causal, bq, bk, heads=heads)
+# Under ``jax.checkpoint`` with ``save_only_these_names(OUT_NAME)`` the
+# forward kernel's output and log-sum-exp rows are kept, and the recomputed
+# forward of a rematerialised block runs no flash kernel.
+OUT_NAME = "hvd_flash_out"
+
+
+def _flash_vjp_fwd(q, k, v, heads, scale, causal, bq, bk, window, group):
+    o, lse = (checkpoint_name(x, OUT_NAME) for x in _flash_fwd(
+        q, k, v, scale, causal, bq, bk, heads=heads, window=window,
+        group=group))
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(heads, scale, causal, bq, bk, res, g):
+def _flash_vjp_bwd(heads, scale, causal, bq, bk, window, group, res, g):
     q, k, v, o, lse = res
-    return _flash_bwd(q, k, v, o, lse, g, heads, scale, causal, bq, bk)
+    return _flash_bwd(q, k, v, o, lse, g, heads, scale, causal, bq, bk,
+                      window, group)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -1146,17 +1400,53 @@ def flash_ring_attention(q, k, v, *, axis, causal: bool = True,
         return _unpack(o, B, H)
 
 
+def _dense_fallback(q, k, v, causal, window, scale):
+    """The dense path for a sequence no block divides, with grouped KV
+    heads and a window where the call has them."""
+    from ..parallel.sequence import dense_attention
+
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    if window is None:
+        return dense_attention(q, k, v, causal=causal, scale=scale)
+    T, D = q.shape[1], q.shape[3]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * (
+        D ** -0.5 if scale is None else scale)
+    d = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+    s = jnp.where((d >= 0) & (d < window), s, _NEG_INF)
+    return jnp.einsum("bhqk,bkhd->bqhd",
+                      jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
+
+
+def _in_place(H: int, Hkv: int, D: int) -> bool:
+    """``_reads_in_place``, and with grouped KV heads a head a block
+    (D = 128): the heads of one lane block would read different KV heads
+    otherwise, and such a call is packed."""
+    return _reads_in_place(H, D) and (H == Hkv or D == _LANES)
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
+                    window: Optional[int] = None,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None):
-    """Exact attention with the flash schedule. Layout [B, T, H, D].
+    """Exact attention with the flash schedule. Layout: q [B, T, H, D],
+    k and v [B, T, Hkv, D] with ``H % Hkv == 0``: query head h reads KV
+    head ``h // (H // Hkv)``, and k, v are never repeated in HBM (their
+    gradients come back [B, T, Hkv, D], summed over each group inside the
+    dk/dv kernel). ``window``: query t sees keys ``t - window + 1 .. t``
+    (its own token is one of the ``window``); needs ``causal``. A window
+    the sequence fits in is no window. ``window=None`` and ``H == Hkv``
+    compile the kernels they always did (docs/flash_window.md).
 
     Differentiable (custom VJP with Pallas backward kernels). Block sizes
     shrink to a divisor of the sequence when needed (a single whole-sequence
     block is always legal — Mosaic accepts block dims equal to the array
     dim); only a long sequence with no 128-aligned divisor falls back to
-    the dense path — numerics are identical either way.
+    the dense path — numerics are identical either way. A windowed call's
+    blocks are square (the smaller of the two).
 
     ``block_q``/``block_k`` default to the kernel autotuner's choice for
     this (shape, chip) — swept once, cached on disk
@@ -1164,13 +1454,22 @@ def flash_attention(q, k, v, *, causal: bool = True,
     knobs pin them or the caller passes explicit values.
     """
     B, Tq, H, D = q.shape
-    Tk = k.shape[1]
+    Tk, Hkv = k.shape[1], k.shape[2]
     if causal and Tq != Tk:
         raise ValueError(
             f"causal flash attention needs Tq == Tk, got {Tq} != {Tk}")
+    if H % Hkv or v.shape[2] != Hkv:
+        raise ValueError(f"{H} query heads do not share {Hkv} (k) / "
+                         f"{v.shape[2]} (v) KV heads evenly")
+    group = H // Hkv
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window is causal and holds the query's own "
+                             f"token, got causal={causal} window={window}")
+        window = None if window >= Tk else int(window)
     if block_q is None and block_k is None:
         block_q, block_k = _resolve_blocks(B, Tq, Tk, H, D, q.dtype,
-                                           causal)
+                                           causal, window, Hkv)
     else:
         block_q = _DEF_BLOCK_Q if block_q is None else block_q
         block_k = _DEF_BLOCK_K if block_k is None else block_k
@@ -1178,27 +1477,37 @@ def flash_attention(q, k, v, *, causal: bool = True,
         raise ValueError(
             f"block_q/block_k must be >= 128 (MXU/lane tile), got "
             f"{block_q}/{block_k}")
+    if window is not None:
+        block_q = block_k = min(block_q, block_k)
     bq, bk = _pick_block(Tq, block_q), _pick_block(Tk, block_k)
     if bq is None or bk is None:
-        from ..parallel.sequence import dense_attention
-
-        return dense_attention(q, k, v, causal=causal, scale=scale)
+        return _dense_fallback(q, k, v, causal, window, scale)
     scale = float(scale) if scale is not None else D ** -0.5
 
     from ..monitor.registry import counter
 
     # By shape alone: the projections' own [B, T, H * D] where whole heads
     # fill whole lane blocks (a reshape is no copy), else packed by head.
-    in_place = _reads_in_place(H, D)
+    in_place = _in_place(H, Hkv, D)
     counter("flash.layout",
             path="in_place" if in_place else "packed").inc()
+    if group > 1:
+        counter("flash.kv_group").inc(group)
     # Outside the custom_vjp call, so that the backward kernels and the
     # packed path's [B, T, H, D] <-> [BH, T, D] traffic carry the scope too.
-    with jax.named_scope("hvd.flash_attention"):
+    with jax.named_scope("hvd.flash_attention"), _window_scope(window):
         if in_place:
             qp, kp, vp = _harmonize_vma(*(
-                x.reshape(B, x.shape[1], H * D) for x in (q, k, v)))
-            o = _flash(qp, kp, vp, H, scale, causal, bq, bk)
+                x.reshape(B, x.shape[1], x.shape[2] * D) for x in (q, k, v)))
+            o = _flash(qp, kp, vp, H, scale, causal, bq, bk, window, group)
             return o.reshape(B, Tq, H, D)
         qp, kp, vp = _harmonize_vma(_pack(q), _pack(k), _pack(v))
-        return _unpack(_flash(qp, kp, vp, 1, scale, causal, bq, bk), B, H)
+        return _unpack(_flash(qp, kp, vp, 1, scale, causal, bq, bk, window,
+                              group), B, H)
+
+
+def _window_scope(window):
+    """``hvd.flash_window`` inside ``hvd.flash_attention`` around a
+    windowed call (forward and backward), nothing around any other."""
+    return (contextlib.nullcontext() if window is None
+            else jax.named_scope("hvd.flash_window"))

@@ -354,14 +354,28 @@ def _timed_chain(step_fn, args, target_seconds: float = 0.25,
 
 def flash_blocks(B: int, Tq: int, Tk: int, H: int, D: int, dtype,
                  causal: bool, default: Tuple[int, int],
-                 pick_block) -> Tuple[int, int]:
-    """Autotuned (block_q, block_k) for a flash-attention shape."""
+                 pick_block, window: Optional[int] = None,
+                 kv_heads: Optional[int] = None) -> Tuple[int, int]:
+    """Autotuned (block_q, block_k) for a flash-attention shape. A window
+    and grouped KV heads (``kv_heads`` < ``H``) are part of the shape: they
+    key the cache and the timed call has them. A windowed call's blocks are
+    square, so only square candidates are timed. For such a shape no
+    candidate is above the hand-tuned default and the whole backward is
+    timed (dk, dv too): a (1024, 2048) blocking won the forward-and-dq
+    sweep of the full grouped call at T = 8192 by 5% and its dk/dv kernel
+    then overflowed scoped VMEM inside the step (PERF.md, PR 30), which a
+    sweep standing alone cannot see. Ungrouped calls without a window keep
+    the candidates and the timed call they had."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     sig = f"B{B}.Tq{Tq}.Tk{Tk}.H{H}.D{D}.{jnp.dtype(dtype).name}" \
           f".{'c' if causal else 'f'}"
+    if window is not None:
+        sig += f".w{window}"
+    if kv_heads is not None:
+        sig += f".kv{kv_heads}"
 
     # Too-small workloads (e.g. the B=1 model.init trace) neither benefit
     # from tuning nor time reliably — keep the default, don't sweep.
@@ -370,8 +384,12 @@ def flash_blocks(B: int, Tq: int, Tk: int, H: int, D: int, dtype,
 
     # Candidate grid, deduplicated by the EFFECTIVE blocking after the
     # legality shrink (different preferences can collapse to one choice).
+    # Grouped or windowed: the whole backward, nothing above the default.
+    bounded = window is not None or kv_heads is not None
     grid = [(bq, bk) for bq in (512, 1024, 2048) for bk in (512, 1024,
-                                                            2048)]
+                                                            2048)
+            if (window is None or bq == bk)
+            and not (bounded and max(bq, bk) > max(default))]
     seen, cands = set(), []
     for bq, bk in grid:
         eff = (pick_block(Tq, bq), pick_block(Tk, bk))
@@ -390,13 +408,17 @@ def flash_blocks(B: int, Tq: int, Tk: int, H: int, D: int, dtype,
 
         rs = np.random.RandomState(0)
         q = jnp.asarray(rs.randn(B, Tq, H, D), dtype) * 0.3
-        k = jnp.asarray(rs.randn(B, Tk, H, D), dtype) * 0.3
-        v = jnp.asarray(rs.randn(B, Tk, H, D), dtype) * 0.3
+        k = jnp.asarray(rs.randn(B, Tk, kv_heads or H, D), dtype) * 0.3
+        v = jnp.asarray(rs.randn(B, Tk, kv_heads or H, D), dtype) * 0.3
 
         def step(q, k, v):
-            g = jax.grad(lambda q: flash_attention(
-                q, k, v, causal=causal, block_q=bq,
-                block_k=bk).astype(jnp.float32).sum())(q)
+            g = jax.grad(lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, window=window, block_q=bq,
+                block_k=bk).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2) if bounded else 0)(q, k, v)
+            if bounded:   # dk, dv [.., Hkv, D] into the carry as well
+                g = g[0] + sum(jnp.repeat(x, H // x.shape[2], axis=2)
+                               for x in g[1:])
             # Couple the carry to the grad with a small NON-ZERO factor:
             # a 0.0 coupling is constant-folded and the whole chain DCE'd
             # into a no-op (measured: 0.000 ms "kernels").

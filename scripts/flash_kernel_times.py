@@ -13,7 +13,11 @@ model's projections give, ``[B, T, H * D]`` arrays seen as ``[B, T, H, D]``
 inside the jitted function, so a kernel is timed on the layout it meets in
 a step; ``other`` is every other device op of a call together (the layout
 traffic and ``delta``; the test's own ``sum`` and its cotangent are a few
-us of it). A shape is ``BxTxHxD`` + ``c`` (causal) or ``f`` (full); a
+us of it). A shape is ``BxTxHxD`` + ``c`` (causal) or ``f`` (full), then
+``.kvN`` for N KV heads under the H query heads and ``.wN`` for a window of
+N keys (``1x8192x32x128c.kv4.w2048``: a windowed call's events carry the
+names ``hvd_flash_*_win``, so one windowed and one grouped call are timed
+alone against the full call ``1x8192x32x128c``); a
 sub-tile ``TQxTK`` (the rule's own choice when the list is empty). ``--module FILE`` times another
 copy of ``ops/flash_attention.py`` (e.g. the parent commit's) in the same
 process; such a copy ignores ``--subtiles`` unless it has ``_SUB_TILE``.
@@ -33,7 +37,9 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")
+KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv",
+           "hvd_flash_fwd_win", "hvd_flash_bwd_dq_win",
+           "hvd_flash_bwd_dkv_win")
 
 
 def load_module(path):
@@ -85,16 +91,19 @@ def time_one(mod, shape, block, steps):
     import jax.numpy as jnp
     import numpy as np
 
-    B, T, H, D, causal = shape
+    B, T, H, D, causal, kv_heads, window = shape
     rs = np.random.RandomState(0)
-    q, k, v = (jnp.asarray(rs.randn(B, T, H * D), jnp.bfloat16) * 0.3
-               for _ in range(3))
+    q, k, v = (jnp.asarray(rs.randn(B, T, n * D), jnp.bfloat16) * 0.3
+               for n in (H, kv_heads, kv_heads))
+    # Only what the shape asks for: a copy from before the window (the
+    # parent's, by --module) is still called as it was.
+    extra = {} if window is None else {"window": window}
 
     @jax.jit
     def f(q, k, v):
         return jax.grad(lambda q, k, v: mod.flash_attention(
-            *(x.reshape(B, T, H, D) for x in (q, k, v)), causal=causal,
-            block_q=block, block_k=block,
+            *(x.reshape(B, T, -1, D) for x in (q, k, v)), causal=causal,
+            block_q=block, block_k=block, **extra,
         ).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
 
     jax.block_until_ready(f(q, k, v))        # compile + warm
@@ -105,6 +114,22 @@ def time_one(mod, shape, block, steps):
         jax.block_until_ready(out)
         jax.profiler.stop_trace()
         return kernel_us(d, calls=steps)
+
+
+def parse_shape(text: str):
+    """``BxTxHxD{c|f}[.kvN][.wN]`` -> (B, T, H, D, causal, KV heads,
+    window or None)."""
+    base, *options = text.split(".")
+    B, T, H, D = map(int, base[:-1].split("x"))
+    kv_heads, window = H, None
+    for opt in options:
+        if opt.startswith("kv"):
+            kv_heads = int(opt[2:])
+        elif opt.startswith("w"):
+            window = int(opt[1:])
+        else:
+            raise ValueError(f"shape option {opt!r} in {text!r}")
+    return B, T, H, D, base[-1] == "c", kv_heads, window
 
 
 def main(argv=None) -> int:
@@ -123,8 +148,7 @@ def main(argv=None) -> int:
         print("flash_kernel_times: needs a TPU", file=sys.stderr)
         return 2
     mod = load_module(args.module)
-    shapes = [(*map(int, s[:-1].split("x")), s[-1] == "c")
-              for s in args.shapes.split(",")]
+    shapes = [parse_shape(s) for s in args.shapes.split(",")]
     subs = [tuple(map(int, s.split("x")))
             for s in args.subtiles.split(",") if s] or [None]
     os.makedirs("chiprun_out", exist_ok=True)

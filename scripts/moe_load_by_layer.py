@@ -1,9 +1,12 @@
 #!/usr/bin/env python3
 """Token-choices the held experts get, layer by layer, at seeded weights.
 
-    chiprun -- python3 scripts/moe_load_by_layer.py --workload <cell> --seeds 1,2,3
+    chiprun -- python3 scripts/moe_load_by_layer.py --workload <cell> --seeds 1,2,3 [--steps 20]
 
-One forward pass of a cell's model on its first batch for each seed; prints,
+One forward pass of a cell's model on its first batch for each seed (after
+``--steps`` training steps of the cell's own compiled step, where given: a
+family whose routers carry a balancing bias as state moves it every step,
+and the load after some steps is the bias at work); prints,
 per layer, the held experts' token-choices as a multiple of what uniform
 routing gives them (N * K * held / E). A layer that computes the filled
 row tiles alone takes time by these counts, and their spread over seeds
@@ -25,6 +28,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", default="1,2,3")
+    ap.add_argument("--steps", type=int, default=0)
     args = ap.parse_args(argv)
     sys.path.insert(0, ROOT)
     import jax
@@ -42,17 +46,28 @@ def main(argv=None) -> int:
     uniform = session.tokens_per_step * s["top_k"] * held / s["experts"]
 
     @jax.jit
-    def loads(params, x):
-        _, state = session.model.apply({"params": params}, x,
+    def loads(params, biases, x):
+        variables = {"params": params}
+        if biases is not None:      # the routers' selection biases
+            variables["router_bias"] = biases
+        _, state = session.model.apply(variables, x,
                                        mutable=["intermediates"])
         return {k: v["moe"]["moe_expert_load"][0]
-                for k, v in state["intermediates"].items()}
+                for k, v in state["intermediates"].items() if "moe" in v}
 
     for seed in (int(x) for x in args.seeds.split(",") if x):
         session.init_state(seed)
         session.place_inputs(seed)
-        out = jax.device_get(loads(session.params, session.pool[0][0]))
-        row = {"seed": seed,
+        if args.steps:
+            if session.compiled is None:
+                session.compile()
+            for _ in range(args.steps):
+                loss = session.step()
+            jax.block_until_ready(loss)
+        out = jax.device_get(loads(session.params,
+                                   getattr(session, "biases", None),
+                                   session.pool[0][0]))
+        row = {"seed": seed, "steps": args.steps,
                "held_load_over_uniform": {
                    k: round(float(np.sum(v[first:first + held]) / uniform), 3)
                    for k, v in sorted(out.items())}}

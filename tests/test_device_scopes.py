@@ -24,12 +24,16 @@ from horovod_tpu.ops import softmax_xent as sx
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "benchmark"))
 import bench_tiny as tiny  # noqa: E402
+import bench_tiny_afmoe as tiny_afmoe  # noqa: E402
 import bench_tiny_sparse as tiny_sparse  # noqa: E402
 
-from benchmarks.builders import gpt_decoder, sparse_moe_decoder  # noqa: E402
+from benchmarks.builders import (afmoe, gpt_decoder,  # noqa: E402
+                                 sparse_moe_decoder)
 from horovod_tpu.ops import sparse_attention as spa  # noqa: E402
 
 KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv",
+           "hvd_flash_fwd_win", "hvd_flash_bwd_dq_win",
+           "hvd_flash_bwd_dkv_win",
            "hvd_xent_fwd", "hvd_xent_bwd_dx", "hvd_xent_bwd_dw",
            "hvd_ln_fwd", "hvd_ln_bwd", "hvd_index_select",
            "hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd_dq",
@@ -37,15 +41,22 @@ KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv",
 # The tiny GPT step runs the unfused LayerNorm, as every cell does today,
 # and none of the sparse decoder's work: that has a tiny step of its own.
 SPARSE_STEP = {"hvd.sparse_attention", "hvd.sparse_indexer", "hvd.moe_ffn"}
-OFF_STEP = {"hvd.layer_norm"} | SPARSE_STEP
+# Nor a window, a shared expert or a router's bias: the tiny afmoe step's
+# (tests/benchmark/bench_tiny_afmoe.py).
+AFMOE_STEP = {"hvd.flash_window", "hvd.shared_expert",
+              "hvd.router_bias_update"}
+OFF_STEP = {"hvd.layer_norm"} | SPARSE_STEP | AFMOE_STEP
 # The indexer is forward only: no gradient reaches it.
 DIFFERENTIATED = {"hvd.grad", "hvd.lm_head_loss", "hvd.flash_attention",
-                  "hvd.layer_norm", "hvd.sparse_attention", "hvd.moe_ffn"}
+                  "hvd.layer_norm", "hvd.sparse_attention", "hvd.moe_ffn",
+                  "hvd.flash_window", "hvd.shared_expert"}
 NESTED_IN = {"hvd.lm_head_loss": "hvd.grad",
              "hvd.flash_attention": "hvd.grad",
              "hvd.sparse_attention": "hvd.grad",
              "hvd.sparse_indexer": "hvd.grad",
              "hvd.moe_ffn": "hvd.grad",
+             "hvd.flash_window": "hvd.flash_attention",
+             "hvd.shared_expert": "hvd.grad",
              "hvd.bucket_pack": "hvd.allreduce_grads",
              "hvd.bucket_allreduce": "hvd.allreduce_grads",
              "hvd.bucket_unpack": "hvd.allreduce_grads"}
@@ -86,6 +97,19 @@ def sparse_step_names():
 
 
 @pytest.fixture(scope="module")
+def afmoe_step_names():
+    """The op_names of the tiny afmoe step, one device."""
+    try:
+        session = afmoe.build(tiny_afmoe.CONFIG, tiny_afmoe.JOB,
+                              jax.devices()[:1])
+        yield _op_names(session.lower(
+            session.abstract_args()).compile().as_text())
+    finally:
+        hvd.shutdown()
+        hvd.init()
+
+
+@pytest.fixture(scope="module")
 def layer_norm_names():
     x = jnp.ones((2, 128, 64), jnp.bfloat16)
     g = jnp.ones((64,), jnp.float32)
@@ -101,9 +125,12 @@ def layer_norm_names():
 @pytest.mark.parametrize("scope", DEVICE_SCOPES)
 def test_scope_reaches_the_compiled_program(scope, step_names,
                                             layer_norm_names,
-                                            sparse_step_names):
+                                            sparse_step_names,
+                                            afmoe_step_names):
     if scope in SPARSE_STEP:
         programs = {"sparse decoder": sparse_step_names}
+    elif scope in AFMOE_STEP:
+        programs = {"afmoe decoder": afmoe_step_names}
     elif scope in OFF_STEP:
         programs = {"layer_norm": layer_norm_names}
     else:
@@ -125,15 +152,19 @@ def test_scope_reaches_the_compiled_program(scope, step_names,
     ("hvd_flash_bwd_dkv", "hvd.flash_attention"),
     ("hvd_ln_bwd", "hvd.layer_norm"),
     ("hvd_sparse_attn_bwd_dq", "hvd.sparse_attention"),
-    ("hvd_sparse_attn_bwd_dkv", "hvd.sparse_attention")])
+    ("hvd_sparse_attn_bwd_dkv", "hvd.sparse_attention"),
+    ("hvd_flash_bwd_dq_win", "hvd.flash_window"),
+    ("hvd_flash_bwd_dkv_win", "hvd.flash_window")])
 def test_custom_vjp_backward_inherits_the_scope(kernel, scope, step_names,
                                                 layer_norm_names,
-                                                sparse_step_names):
+                                                sparse_step_names,
+                                                afmoe_step_names):
     """The trap: a scope opened inside the custom_vjp's forward function
     would not reach the backward. In interpret mode a kernel's body is
     traced into the program under its ``name=``."""
     names = {"hvd.layer_norm": layer_norm_names,
-             "hvd.sparse_attention": sparse_step_names}.get(
+             "hvd.sparse_attention": sparse_step_names,
+             "hvd.flash_window": afmoe_step_names}.get(
         scope, step_names[4])
     body = [n for n in names if kernel in n]
     assert body, f"no op of {kernel} in the program"
@@ -162,6 +193,9 @@ def kernel_names():
            jnp.ones((1, 128, 2)))
     programs = [
         (lambda q: fa.flash_attention(q, q, q).astype(jnp.float32).sum(),
+         q),
+        (lambda q: fa.flash_attention(q, q[:, :, :1], q[:, :, :1],
+                                      window=32).astype(jnp.float32).sum(),
          q),
         (lambda q: spa.sparse_attention(q, q, q, *idx, topk=16).astype(
             jnp.float32).sum(), q),

@@ -14,6 +14,7 @@ from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.models import GPT, gpt_tiny
+from horovod_tpu.monitor.registry import counter
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel import sequence as seqpar
 
@@ -565,3 +566,157 @@ class TestInPlaceLayout:
                         jax.tree_util.tree_leaves(gd)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-3, atol=1e-6)
+
+
+def _dense_window(q, k, v, window):
+    """Plain attention with grouped KV heads and a window: query t sees
+    keys t - window + 1 .. t."""
+    B, T, H, D = q.shape
+    group = H // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * D ** -0.5
+    ahead = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+    seen = ahead >= 0
+    if window is not None:
+        seen &= ahead < window
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+def _grouped_qkv(T, H, Hkv, D, seed=0):
+    rs = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rs.randn(1, T, n, D), jnp.float32)
+                 for n in (H, Hkv, Hkv, H))
+
+
+class TestWindowAndGroupedHeads:
+    """``flash_attention(window=, k/v with fewer heads)`` against dense
+    attention: forward and all three gradients, in both layouts
+    (docs/flash_window.md)."""
+
+    T, BLOCK = 256, 128
+
+    @pytest.mark.parametrize("layout", ["by_shape", "packed"])
+    @pytest.mark.parametrize("D", [64, 128])
+    @pytest.mark.parametrize("group", [1, 8])
+    @pytest.mark.parametrize("window", [None, 128, 77, 256, 300])
+    def test_matches_dense(self, window, group, D, layout, monkeypatch):
+        from horovod_tpu.ops import flash_attention as F
+
+        H = 8
+        if layout == "packed":
+            monkeypatch.setattr(F, "_reads_in_place", lambda H, D: False)
+        q, k, v, w = _grouped_qkv(self.T, H, H // group, D)
+        before = _layout_counts()
+        got = _fwd_and_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, block_q=self.BLOCK,
+            block_k=self.BLOCK), q, k, v, w)
+        want = _fwd_and_grads(
+            lambda q, k, v: _dense_window(q, k, v, window), q, k, v, w)
+        _assert_close(got, want, 2e-4)
+        assert got[2].shape == k.shape and got[3].shape == v.shape
+        in_place = layout == "by_shape" and (group == 1 or D == 128)
+        after = _layout_counts()
+        assert (after["in_place"] > before["in_place"]) == in_place
+        assert (after["packed"] > before["packed"]) == (not in_place)
+
+    def test_a_window_the_sequence_fits_in_is_no_window(self):
+        """``window >= T`` compiles the causal kernels: no ``_win`` name in
+        the jaxpr, and the windowed counters stay where they were."""
+        q, k, v, _ = _grouped_qkv(128, 2, 2, 64)
+        text = str(jax.make_jaxpr(lambda q, k, v: flash_attention(
+            q, k, v, window=128))(q, k, v))
+        assert "hvd_flash_fwd" in text and "_win" not in text
+        text = str(jax.make_jaxpr(lambda q, k, v: flash_attention(
+            q, k, v, window=127))(q, k, v))
+        assert "hvd_flash_fwd_win" in text
+
+    @pytest.mark.parametrize("bad", [dict(window=0), dict(window=8,
+                                                          causal=False)])
+    def test_rejects_a_window_that_is_none(self, bad):
+        q, k, v, _ = _grouped_qkv(128, 2, 2, 64)
+        with pytest.raises(ValueError, match="window"):
+            flash_attention(q, k, v, **bad)
+
+    def test_rejects_heads_that_do_not_share_evenly(self):
+        q, k, v, _ = _grouped_qkv(128, 6, 4, 64)
+        with pytest.raises(ValueError, match="share"):
+            flash_attention(q, k, v)
+
+    def test_kv_group_is_counted(self):
+        before = counter("flash.kv_group").value
+        q, k, v, _ = _grouped_qkv(128, 8, 2, 64)
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v), q, k, v)
+        assert counter("flash.kv_group").value - before == 4
+
+    @pytest.mark.parametrize("window", [1, 100, 128, 200, 257, 384, 511])
+    @pytest.mark.parametrize("block", [128, 256, 512])
+    def test_tile_counters_are_the_bands(self, window, block, monkeypatch):
+        """``flash.tiles_*{window=}`` of a windowed call against a brute
+        force count over the positions: the cells of the grid are the
+        blocks the band touches, a cell wholly inside the band is one
+        tile, any other is cut into sub-tiles of which those with a
+        visible pair are computed and those with an invisible one too are
+        masked."""
+        from horovod_tpu.ops import flash_attention as F
+
+        monkeypatch.setattr(F, "_SUB_TILE", (128, 128))
+        T, sub = 512, 128
+        ahead = np.arange(T)[:, None] - np.arange(T)[None, :]
+        seen = (ahead >= 0) & (ahead < window)
+        n = T // block
+        total = computed = masked = 0
+        for i in range(n):
+            for j in range(n):
+                cell = seen[i * block:(i + 1) * block,
+                            j * block:(j + 1) * block]
+                edge = block if cell.all() else sub
+                tiles = [cell[a:a + edge, c:c + edge]
+                         for a in range(0, block, edge)
+                         for c in range(0, block, edge)]
+                total += len(tiles)
+                computed += sum(t.any() for t in tiles)
+                masked += sum(t.any() and not t.all() for t in tiles)
+
+        def counts():
+            return {(name, kern): counter(
+                f"flash.tiles_{name}", kernel=kern,
+                window=str(window)).value
+                for name in ("total", "computed", "masked")
+                for kern in ("fwd", "bwd_dq", "bwd_dkv")}
+
+        before = counts()
+        q, k, v, _ = _grouped_qkv(T, 2, 1, 64)
+        jax.eval_shape(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, window=window, block_q=block,
+            block_k=block).sum(), argnums=(0, 1, 2)), q, k, v)
+        got = {key: counts()[key] - before[key] for key in before}
+        for kern in ("fwd", "bwd_dq", "bwd_dkv"):
+            assert (got["total", kern], got["computed", kern],
+                    got["masked", kern]) == (total, computed, masked), kern
+
+    def test_no_block_past_the_window_is_in_the_grid(self):
+        """The k axis of a windowed call's grid spans the band's blocks
+        alone: 2 of the 4 at T = 512, window 128, blocks of 128."""
+        from horovod_tpu.ops import flash_attention as F
+
+        q, k, v, _ = _grouped_qkv(512, 2, 1, 128)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, window=128, block_q=128, block_k=128).sum(),
+            argnums=(0, 1, 2)))(q, k, v)
+        grids = {}
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    grids[eqn.params["name"]] = tuple(
+                        eqn.params["grid_mapping"].grid)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jaxpr.jaxpr)
+        assert F._band_blocks(128, 128, 4) == 2
+        assert grids["hvd_flash_fwd_win"] == (1, 2, 4, 1, 2)
+        assert grids["hvd_flash_bwd_dq_win"] == (1, 2, 4, 1, 2)
+        # The dk/dv kernel's second axis counts KV heads, its fourth the
+        # query heads of a group: dk, dv are summed over them in scratch.
+        assert grids["hvd_flash_bwd_dkv_win"] == (1, 1, 4, 2, 2)

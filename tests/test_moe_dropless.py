@@ -252,3 +252,161 @@ def test_the_exchange_across_ep_is_not_built():
     finally:
         hvd.shutdown()
         hvd.init()
+
+
+# -- the sigmoid router, its selection bias and the shared expert -------------
+# (benchmarks/lib/reference_afmoe.py; docs/moe.md "Scoring kinds")
+
+from benchmarks.lib import reference_afmoe as afref  # noqa: E402
+from horovod_tpu.moe.layer import moe_router, router_bias_update  # noqa: E402
+
+SCALE = 2.826
+AF = dict(top_k=K, route_norm=True, route_scale=SCALE, expert_first=0,
+          experts=E, shared=1)
+
+
+def _plain_sigmoid_route(x, router, bias, norm=True, scale=SCALE):
+    """A loop a token: scores, the K largest of score + bias, gates from
+    the scores alone."""
+    scores = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64)
+                                   @ np.asarray(router, np.float64))))
+    experts, gates = [], []
+    for row in scores:
+        chosen = np.argsort(-(row + np.asarray(bias, np.float64)),
+                            kind="stable")[:K]
+        g = row[chosen]
+        if norm:
+            g = g / (g.sum() + 1e-20)
+        experts.append(chosen)
+        gates.append(g * scale)
+    return np.asarray(experts), np.asarray(gates)
+
+
+@pytest.mark.parametrize("norm, scale", [(True, SCALE), (True, 1.0),
+                                         (False, 1.0), (False, 0.5)])
+@pytest.mark.parametrize("biased", [False, True])
+def test_sigmoid_router_is_the_plain_loop(biased, norm, scale):
+    p, x = _params(2)
+    bias = (0.3 * jax.random.normal(jax.random.key(9), (E,)) if biased
+            else jnp.zeros((E,)))
+    with jax.default_matmul_precision("highest"):
+        experts, gates, _, _, scores = moe_router(
+            x, p["router"], topk=K, scoring="sigmoid", bias=bias,
+            route_norm=norm, route_scale=scale)
+    want_e, want_g = _plain_sigmoid_route(x, p["router"], bias, norm, scale)
+    np.testing.assert_array_equal(np.asarray(experts), want_e)
+    np.testing.assert_allclose(np.asarray(gates), want_g, rtol=2e-5)
+    assert float(scores.min()) >= 0 and float(scores.max()) <= 1
+
+
+def test_the_bias_chooses_and_never_weighs():
+    """A large bias on expert 6 puts it among every token's choices; the
+    gates are still made of the scores alone, so the same choices forced
+    without a bias give the same gates, and no gradient reaches the
+    bias."""
+    p, x = _params(3)
+    bias = jnp.zeros((E,)).at[6].set(5.0)
+    experts, gates, *_ = moe_router(x, p["router"], topk=K,
+                                    scoring="sigmoid", bias=bias,
+                                    route_scale=SCALE)
+    assert bool((experts == 6).any(axis=-1).all())
+    scores = jax.nn.sigmoid(x @ p["router"])
+    picked = jnp.take_along_axis(scores, experts, -1)
+    np.testing.assert_allclose(
+        np.asarray(gates),
+        np.asarray(SCALE * picked / picked.sum(-1, keepdims=True)),
+        rtol=1e-5)
+
+    def loss(bias, router):
+        _, g, *_ = moe_router(x, router, topk=K, scoring="sigmoid",
+                              bias=bias, route_scale=SCALE)
+        return (g ** 2).sum()
+
+    d_bias, d_router = jax.grad(loss, argnums=(0, 1))(bias, p["router"])
+    assert float(jnp.abs(d_bias).max()) == 0.0
+    assert float(jnp.abs(d_router).max()) > 0.0
+
+
+def test_softmax_scoring_refuses_the_sigmoid_options():
+    p, x = _params(3)
+    for bad in (dict(bias=jnp.zeros((E,))), dict(route_norm=False),
+                dict(route_scale=2.0), dict(scoring="tanh")):
+        with pytest.raises(ValueError):
+            moe_router(x, p["router"], topk=K, **bad)
+
+
+@pytest.mark.parametrize("coeff", [0.001, 0.01])
+def test_bias_update_rule(coeff):
+    """delta = coeff * sign(mean(n) - n), re-centred; the biases' sum stays
+    put, an overloaded expert's goes down, an expert at the mean moves by
+    the re-centring alone."""
+    load = jnp.asarray([40.0, 10.0, 24.0, 24.0, 30.0, 20.0, 24.0, 20.0])
+    bias = jnp.linspace(-0.01, 0.01, E)
+    got = router_bias_update(bias, load, coeff=coeff)
+    sign = np.sign(24.0 - np.asarray(load))
+    delta = coeff * sign
+    want = np.asarray(bias) + delta - delta.mean()
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-8)
+    np.testing.assert_allclose(float(got.sum()), float(bias.sum()),
+                               atol=1e-7)
+    assert got[0] < bias[0] and got[1] > bias[1]
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(afref.bias_update(bias, load,
+                                                            coeff)),
+                               atol=1e-8)
+    assert float(jnp.abs(jax.grad(lambda b: router_bias_update(
+        b, load, coeff=coeff).sum())(bias) - 1.0).max()) == 0.0
+
+
+def _afmoe_params(seed):
+    p, x = _params(seed)
+    ks = jax.random.split(jax.random.key(100 + seed), 3)
+    p["shared"] = {"w1": 0.3 * jax.random.normal(ks[0], (C, F)),
+                   "w3": 0.3 * jax.random.normal(ks[1], (C, F)),
+                   "w2": 0.3 * jax.random.normal(ks[2], (F, C))}
+    return p, x
+
+
+@pytest.mark.parametrize("held", [8, 4, 1])
+@pytest.mark.parametrize("biased", [False, True])
+def test_sigmoid_shares_add_up_with_the_shared_expert_counted_once(held,
+                                                                   biased):
+    """E / held shares of ``held`` experts each (eight shares of 16 in the
+    cell), every one routed by the sigmoid router with the same bias, plus
+    the shared expert ONCE, equal the uncut reference layer; each share is
+    the reference's share; the counts are over all the experts."""
+    p, x = _afmoe_params(4)
+    bias = (0.2 * jax.random.normal(jax.random.key(5), (E,)) if biased
+            else jnp.zeros((E,)))
+    router = dict(scoring="sigmoid", bias=bias, route_norm=True,
+                  route_scale=SCALE)
+    with jax.default_matmul_precision("highest"):
+        parts, loads = zip(*[
+            hvd.moe_ffn_dropless(x, _share(p, first, held),
+                                 experts_per_token=K, first_expert=first,
+                                 **router) for first in range(0, E, held)])
+        experts, gates = afref.route(x, p["router"], bias, AF, MM)
+        for part, first in zip(parts, range(0, E, held)):
+            want = afref.moe_share(x, _share(p, first, held), experts,
+                                   gates, first, MM)
+            np.testing.assert_allclose(np.asarray(part), np.asarray(want),
+                                       atol=5e-5)
+        shared = afref.gated_mlp(x, p["shared"], MM)
+        whole, counts = afref.moe(x, p, bias, AF, MM)
+    np.testing.assert_allclose(np.asarray(sum(parts) + shared),
+                               np.asarray(whole), atol=1e-4)
+    for aux in loads:
+        np.testing.assert_array_equal(np.asarray(aux.load),
+                                      np.asarray(counts))
+    assert float(counts.sum()) == N * K
+
+
+def test_scoring_is_counted():
+    p, x = _params(0)
+    before = {k: counter("moe.scoring", kind=k).value
+              for k in ("softmax", "sigmoid")}
+    hvd.moe_ffn_dropless(x, p, experts_per_token=K)
+    hvd.moe_ffn_dropless(x, p, experts_per_token=K, scoring="sigmoid",
+                         route_scale=SCALE)
+    assert {k: counter("moe.scoring", kind=k).value - before[k]
+            for k in before} == {"softmax": 1, "sigmoid": 1}

@@ -107,6 +107,59 @@ def test_flash_attention_compiles(tpu, real_kernels, B, T, H, D, causal):
     assert _has_kernel(tpu.compile(f, q, q, q))
 
 
+@pytest.mark.parametrize("T,H,Hkv,D,window,block", [
+    (8192, 32, 4, 128, 2048, 1024),   # trinity-mini.train-8k-1chip, a
+                                      # sliding layer: a band of 3 blocks
+    (8192, 32, 4, 128, 2048, 512),    # the sweep's other candidate: 5
+    (8192, 32, 4, 128, None, 1024),   # the same cell's full layer
+    (8192, 32, 4, 128, None, 512),
+    (4096, 8, 8, 64, 1000, 1024),     # a pair of heads a block, a window
+                                      # off the sub-tile lattice
+    (4096, 8, 2, 64, 2048, 1024),     # grouped at D = 64: packed
+    (2048, 6, 2, 128, 300, 1024),     # a window inside one sub-tile row
+])
+def test_flash_window_and_grouped_heads_compile(tpu, real_kernels, T, H,
+                                                Hkv, D, window, block):
+    """The windowed and grouped kernels at the cell's widths: the kernels
+    carry their own names, K and V stay at their KV heads (nothing of
+    q's size is made from them), and the gradients come back in k's and
+    v's shapes."""
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    q = tpu.shape((1, T, H, D), jnp.bfloat16)
+    kv = tpu.shape((1, T, Hkv, D), jnp.bfloat16)
+
+    def f(q, k, v):
+        return jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window, block_q=block,
+            block_k=block).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    compiled = tpu.compile(f, q, kv, kv)
+    text = compiled.as_text()
+    tail = "_win" if window else ""
+    for name in ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"):
+        assert f"%{name}{tail}." in text or f"%{name}{tail} " in text, name
+        if window:   # and no full call beside it
+            assert f"%{name}." not in text and f"%{name} " not in text
+    dq, dk, dv = compiled.out_info
+    assert (dq.shape, dk.shape, dv.shape) == (q.shape, kv.shape, kv.shape)
+    if Hkv < H and D == 128:
+        # In place: K and V go into the kernels as they are. Nothing of
+        # q's size is made from a value of k's size (a K repeated a query
+        # head would be) but by the kernels themselves.
+        import re
+
+        kv_sized = set(re.findall(
+            rf"(%[\w.\-]+) = bf16\[1,{T},{Hkv * D}\]", text))
+        assert kv_sized
+        for line in text.splitlines():
+            if (f" = bf16[1,{T},{H * D}]" in line
+                    and "custom-call(" not in line):
+                used = set(re.findall(r"%[\w.\-]+", line.split(" = ", 1)[1]))
+                assert not used & kv_sized, line[:200]
+
+
 def _sparse_shapes(tpu, T):
     """(q, k, v, index_q, index_k, index_w) at the benchmark's widths:
     32/4 heads of 128, indexer 16 x 64."""
