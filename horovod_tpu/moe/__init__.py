@@ -27,4 +27,6 @@ from .layer import (  # noqa: F401
     moe_positions,
     moe_router,
     router_bias_update,
+    rows_filled,
+    rows_grouped,
 )

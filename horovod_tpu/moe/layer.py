@@ -54,6 +54,8 @@ from jax import lax
 
 from ..common import basics
 from ..common.basics import EP_AXIS
+from ..ops.collective_ops import _vma, pvary_missing
+from ..ops.flash_attention import _harmonize_vma
 from ..plan import compiler as _compiler
 from ..plan import planner as _planner
 
@@ -326,6 +328,142 @@ def moe_ef_residuals(n_tokens: int, d_model: int, num_experts: int,
 GROUP_ALIGN = 512
 
 
+#: Rows of the buffer one trip of the walk takes: a multiple of
+#: ``GROUP_ALIGN``, and the smallest one. One tile a trip read 18.2 ms
+#: forward + backward at uniform load where two read 19.1 and four 21.4
+#: (the sparse cell's sizes; PERF.md, PR 31), and it is what the walk's
+#: body counts on: a chunk lies in ONE expert's group, so its group sizes
+#: are one entry and its weight gradients one expert's slab.
+CHUNK_ROWS = GROUP_ALIGN
+
+
+def rows_grouped(choices: int, held: int) -> int:
+    """The bound on :func:`moe_ffn_dropless`'s row buffer: every one of the
+    ``choices`` (N * K) on a held expert, and a tile a group of padding."""
+    return (-(-choices // GROUP_ALIGN) + held) * GROUP_ALIGN
+
+
+def _chunk(c, plan, K):
+    """Rows ``[c, c + 1) * CHUNK_ROWS`` of the buffer, ``c`` under
+    :func:`_trips`: the group they lie in, the token-choice and the token
+    each holds (past the last of them where it holds none: read as zeros,
+    dropped when written) and the group sizes of the chunk (all its rows
+    are the one group's)."""
+    order, sizes, start, first_row, padded = plan
+    lo = c * CHUNK_ROWS
+    group = jnp.sum(lo >= first_row + padded)
+    lane = jnp.arange(CHUNK_ROWS, dtype=jnp.int32)
+    rank = lo - first_row[group] + lane
+    live = rank < sizes[group]
+    choice = order[jnp.where(live, start[group] + rank, 0)]
+    n = order.shape[0]
+    gs = jnp.where(jnp.arange(sizes.shape[0]) == group, CHUNK_ROWS, 0)
+    return (group, jnp.where(live, choice, n + lane),
+            jnp.where(live, choice // K, n // K + lane), gs)
+
+
+def _rows(a, at):
+    """``a[at]``, zeros where ``at`` lies past the end (a dead row)."""
+    return a.at[at].get(mode="fill", fill_value=0)
+
+
+def _trips(plan):
+    return -(-jnp.sum(plan[-1]) // CHUNK_ROWS)
+
+
+def _zeros_like_of(x):
+    """``zeros(shape, dtype)`` varying over the mesh axes ``x`` varies over:
+    what a loop's carry has to be from its first trip under shard_map."""
+    axes = tuple(sorted(_vma(x)))
+    return lambda shape, dtype: pvary_missing(jnp.zeros(shape, dtype), axes)
+
+
+@jax.custom_vjp
+def _walk(x, gates, w1, w3, w2, plan):
+    """``y [N, C]``: the held experts' part of the mixture for tokens ``x``,
+    gates ``[N, K]`` and weights in ``x``'s dtype, walked over the filled
+    rows of the buffer that ``plan`` lays out (``order`` of the choices
+    sorted by held expert, the groups' ``sizes``, their ``start`` in that
+    order, their ``first_row`` in the buffer, their ``padded`` sizes)."""
+    return _walk_fwd(x, gates, w1, w3, w2, plan)[0]
+
+
+def _walk_fwd(x, gates, w1, w3, w2, plan):
+    K = gates.shape[1]
+    gate_of = gates.reshape(-1)
+
+    def body(c, y):
+        _, choice, token, gs = _chunk(c, plan, K)
+        xs = _rows(x, token)
+        h = nn.silu(lax.ragged_dot(xs, w1, gs)) * lax.ragged_dot(xs, w3, gs)
+        ys = lax.ragged_dot(h, w2, gs)
+        return y.at[token].add(
+            ys * _rows(gate_of, choice)[:, None].astype(ys.dtype),
+            mode="drop")
+
+    y = lax.fori_loop(0, _trips(plan), body,
+                      _zeros_like_of(x)(x.shape, x.dtype))
+    return y, (x, gates, w1, w3, w2, plan)
+
+
+def _walk_bwd(res, dy):
+    x, gates, w1, w3, w2, plan = res
+    N, K = gates.shape
+    gate_of = gates.reshape(-1)
+    wt1, wt3, wt2 = (jnp.swapaxes(w, 1, 2) for w in (w1, w3, w2))
+    f32, zeros = jnp.float32, _zeros_like_of(x)
+
+    def dw_of(a, b, group, acc):
+        slab = lax.dynamic_index_in_dim(acc, group, 0, keepdims=False)
+        return lax.dynamic_update_index_in_dim(acc, slab + jnp.einsum(
+            "ak,an->kn", a, b, preferred_element_type=f32), group, 0)
+
+    def body(c, carry):
+        dx, dgate, dw1, dw3, dw2 = carry
+        group, choice, token, gs = _chunk(c, plan, K)
+        xs, dyr = _rows(x, token), _rows(dy, token)
+        gate = _rows(gate_of, choice)[:, None]
+        h, pull = jax.vjp(lambda a, b: nn.silu(a) * b,
+                          lax.ragged_dot(xs, w1, gs),
+                          lax.ragged_dot(xs, w3, gs))
+        dhu = lax.ragged_dot(dyr, wt2, gs)        # dy W2^T, not yet gated
+        dh1, dh3 = pull(dhu * gate.astype(dhu.dtype))
+        dxs = lax.ragged_dot(dh1, wt1, gs) + lax.ragged_dot(dh3, wt3, gs)
+        return (dx.at[token].add(dxs, mode="drop"),
+                dgate.at[choice].add(
+                    jnp.sum((h * dhu).astype(f32), axis=-1), mode="drop"),
+                dw_of(xs, dh1, group, dw1), dw_of(xs, dh3, group, dw3),
+                dw_of(h, dyr * gate.astype(dyr.dtype), group, dw2))
+
+    dx, dgate, dw1, dw3, dw2 = lax.fori_loop(
+        0, _trips(plan), body,
+        (zeros(x.shape, x.dtype), zeros((N * K,), f32),
+         *(zeros(w.shape, f32) for w in (w1, w3, w2))))
+    # The weights came in the activations' dtype and their gradients leave
+    # in it, rounded once from the float32 sums as the grouped matmul's own
+    # transposes round theirs. One barrier with dx, which the layer below
+    # waits for: the compiler otherwise keeps every layer's float32
+    # accumulators to the optimizer (1.2 to 1.8 GB more in the two cells).
+    return (*lax.optimization_barrier((
+        dx, dgate.reshape(N, K).astype(gates.dtype),
+        dw1.astype(w1.dtype), dw3.astype(w3.dtype), dw2.astype(w2.dtype))),
+        None)
+
+
+_walk.defvjp(_walk_fwd, _walk_bwd)
+
+
+def rows_filled(load, first_expert: int, held: int):
+    """Rows of :func:`moe_ffn_dropless`'s buffer that a step fills, from
+    its token-choices per GLOBAL expert ``load`` (``MoEAux.load``): every
+    held expert's count rounded up to ``GROUP_ALIGN``. The walk takes
+    ``ceil(rows_filled / CHUNK_ROWS)`` trips of the ``moe.row_chunks`` it
+    could; beside ``moe.rows_grouped`` (the bound) it says how far the
+    walk went."""
+    mine = jnp.asarray(load)[first_expert:first_expert + held]
+    return jnp.sum(-(-mine.astype(jnp.int32) // GROUP_ALIGN) * GROUP_ALIGN)
+
+
 def router_bias_update(bias, load, *, coeff: float):
     """One step of the balancing rule on a router's selection bias
     ``[E]``, from the step's token-choices per expert ``load`` ``[E]``
@@ -369,18 +507,26 @@ def moe_ffn_dropless(x, params, *, experts_per_token: int,
 
     The N*K token-choices are sorted by held expert and laid into a row
     buffer in which every held expert's group starts on a multiple of
-    ``GROUP_ALIGN``; their tokens are gathered, the held experts run as
-    three ``lax.ragged_dot`` over the groups, and a scatter-add brings the
-    rows back to their tokens. The buffer holds N*K rows and a tile a
-    group, the most the held experts can be sent, so one program takes any
-    routing and nothing is dropped. The grouped matmuls run over EVERY row
-    tile of the buffer (the last group is given the rows no choice fills,
-    which are zero on the way in and out, in both directions): the layer's
-    time is the same under any routing. Computing the filled tiles alone
-    follows the load, 0.006 to 2.8 x uniform a layer at seeded weights
-    (PERF.md, PR 26); it is ROADMAP S13, and wants a balanced router
-    first. Without ``ep_axis`` bound nothing is exchanged; the exchange
-    across ``hvd_ep`` for this path is not built (ROADMAP R1)."""
+    ``GROUP_ALIGN``: dead rows lie only at the end of a group's last tile
+    and past ``rows_filled = sum(padded)``. The buffer is bounded by ``R =
+    N*K`` rows and a tile a group (``moe.rows_grouped``), the most the held
+    experts can be sent, and is never made: the FILLED rows are walked in
+    chunks of ``CHUNK_ROWS`` by a loop whose trips, ``ceil(rows_filled /
+    CHUNK_ROWS)``, are a value of the step (at most ``moe.row_chunks``). A
+    trip gathers its rows' tokens, runs the three ``lax.ragged_dot`` with
+    the chunk's own group sizes, weighs by the gates and adds the rows to
+    their tokens. So one program takes any routing with no branch and no
+    threshold, nothing is dropped, and the layer's time grows with the rows
+    the step filled in steps of one chunk: a quarter of what computing
+    every tile of the bound cost at uniform load, more than it only above
+    about five times that (PERF.md, PR 31; :func:`rows_filled` reads how
+    far a step's walk went from ``MoEAux.load``). A loop with a traced trip
+    count has no reverse rule: the walk is a ``custom_vjp`` whose backward
+    is the same walk (the hidden rows made again, ``dx`` added to its
+    tokens, the gates' gradient, the weight gradients summed in float32 a
+    tile's expert at a time), under the scope ``hvd.moe_ffn`` in both
+    directions. Without ``ep_axis`` bound nothing is exchanged; the
+    exchange across ``hvd_ep`` for this path is not built (ROADMAP R1)."""
     if ep_axis is not None and _axis_size(ep_axis) > 1:
         raise NotImplementedError(
             "moe_ffn_dropless: the expert exchange across hvd_ep is not "
@@ -394,10 +540,10 @@ def moe_ffn_dropless(x, params, *, experts_per_token: int,
     if not 0 <= first_expert <= E - held:
         raise ValueError(f"experts {first_expert}..{first_expert + held} "
                          f"are not among the router's {E}")
-    A = GROUP_ALIGN
-    R = -(-N * K // A) * A + held * A
+    R = rows_grouped(N * K, held)
     counter("moe.experts_held").inc(held)
     counter("moe.rows_grouped").inc(R)
+    counter("moe.row_chunks").inc(R // CHUNK_ROWS)
     counter("moe.scoring", kind=scoring).inc()
     with jax.named_scope("hvd.moe_ffn"):
         experts, gates, lb, z, _ = moe_router(
@@ -409,27 +555,11 @@ def moe_ffn_dropless(x, params, *, experts_per_token: int,
         _, order = lax.sort((key, jnp.arange(N * K, dtype=jnp.int32)),
                             num_keys=1)
         sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
-        padded = -(-sizes // A) * A
-        start = jnp.cumsum(sizes) - sizes
-        first_row = jnp.cumsum(padded) - padded
-        # Row j of the buffer: its group (rows past the last group's tiles
-        # count to it), its rank there, and the sorted choice it holds.
-        row = jnp.arange(R, dtype=jnp.int32)
-        group = jnp.minimum(held - 1, jnp.sum(
-            row[:, None] >= (first_row + padded)[None, :], axis=1))
-        rank = row - first_row[group]
-        live = rank < sizes[group]
-        choice = order[jnp.where(live, start[group] + rank, 0)]
-        token, gate = choice // K, gates.reshape(-1)[choice]
-        live = live[:, None]
-        xs = jnp.where(live, x[token], 0)
-        every_row = padded.at[-1].add(R - jnp.sum(padded))
-        w1, w3, w2 = (params[n].astype(x.dtype) for n in ("w1", "w3", "w2"))
-        h = nn.silu(lax.ragged_dot(xs, w1, every_row)) \
-            * lax.ragged_dot(xs, w3, every_row)
-        ys = jnp.where(live, lax.ragged_dot(h, w2, every_row), 0)
-        y = jax.ops.segment_sum(ys * gate[:, None].astype(ys.dtype), token,
-                                num_segments=N)
+        padded = -(-sizes // GROUP_ALIGN) * GROUP_ALIGN
+        plan = (order, sizes, jnp.cumsum(sizes) - sizes,
+                jnp.cumsum(padded) - padded, padded)
+        y = _walk(*_harmonize_vma(x, gates, *(
+            params[n].astype(x.dtype) for n in ("w1", "w3", "w2"))), plan)
     load = jnp.sum(jax.nn.one_hot(experts, E, dtype=jnp.float32),
                    axis=(0, 1))
     return y.astype(x.dtype), MoEAux(

@@ -3,6 +3,7 @@ no capacity and no drops, against the plain reference's mixture
 (benchmarks/lib/reference_sparse_moe.py; docs/moe.md, guide model-configs
 section 4)."""
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +12,7 @@ import pytest
 import horovod_tpu as hvd
 from benchmarks.lib import reference_sparse_moe as ref
 from benchmarks.lib.reference_gpt2 import _mm
-from horovod_tpu.moe.layer import GROUP_ALIGN
+from horovod_tpu.moe.layer import CHUNK_ROWS, GROUP_ALIGN
 from horovod_tpu.monitor.registry import counter
 
 MM = _mm("float32")
@@ -123,34 +124,93 @@ def test_even_and_skewed_routing_agree_with_the_reference(skewed):
                                atol=1e-5 * float(jnp.abs(g_want[0]).max()))
 
 
-@pytest.mark.parametrize("routing", ["even", "skewed", "none_held"])
-def test_every_routing_runs_every_tile(routing, monkeypatch):
-    """The layer's time must not follow the routing: whatever the held
-    experts are sent, the grouped matmuls are handed groups that start on
-    tile boundaries, hold their expert's load and add up to the whole
-    buffer, so the same row tiles run, each for one expert."""
-    n, held, first = 1024, 2, 4
+ROUTINGS = ["even", "skewed", "none_held", "all_held"]
+
+
+def _routed(routing, n=1024, held=2, first=4):
+    """x ``[n, C]`` whose first feature is 1 and a router kernel whose
+    first row pushes the routing: evenly over the eight experts; every
+    token's first choice on the last held expert; no choice on a held
+    expert; every choice on a held expert (the whole buffer's N * K rows:
+    the old worst case)."""
     p, _ = _params(6)
-    x = jax.random.normal(jax.random.key(2), (n, C))
-    logits = jax.random.normal(jax.random.key(3), (n, E))
+    x = jax.random.normal(jax.random.key(2), (n, C)).at[:, 0].set(1.0)
+    push = jnp.zeros((E,))
     if routing == "skewed":
-        logits = logits.at[:, 5].set(9.0)
+        push = push.at[first + held - 1].set(9.0 * C)
     if routing == "none_held":
-        logits = logits.at[:, 4:6].set(-9.0)
+        push = push.at[first:first + held].set(-9.0 * C)
+    if routing == "all_held":
+        push = push.at[first:first + held].set(9.0 * C)
+    p["router"] = p["router"].at[0].set(push)
+    return x, _share(p, first, held), first, held
+
+
+def _watch_ragged_dot(monkeypatch):
+    """Every ``lax.ragged_dot`` of the layer reports the rows it was handed
+    and its group sizes each time it RUNS (a trip of the walk at a time)."""
     seen = []
     real = jax.lax.ragged_dot
-    monkeypatch.setattr(jax.lax, "ragged_dot", lambda a, b, sizes: (
-        seen.append((a.shape[0], np.asarray(sizes))), real(a, b, sizes))[1])
-    _, aux = hvd.moe_ffn_dropless(x, _share(p, first, held),
-                                  experts_per_token=K, first_expert=first,
-                                  router_logits=logits)
-    load = np.asarray(aux.load[first:first + held])
+
+    def watched(a, b, sizes, **kw):
+        jax.debug.callback(lambda s, rows=a.shape[0]: seen.append(
+            (rows, np.asarray(s))), sizes)
+        return real(a, b, sizes, **kw)
+
+    monkeypatch.setattr(jax.lax, "ragged_dot", watched)
+    return seen
+
+
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_the_walk_hands_ragged_dot_the_filled_rows(routing, monkeypatch):
+    """Whatever the held experts are sent, the rows handed to each of the
+    three grouped matmuls over the walk add up to every held expert's load
+    rounded up to a tile, ``rows_filled``, and never to more; each trip's
+    groups lie on tile boundaries; the trips are ``ceil(rows_filled /
+    CHUNK_ROWS)`` of the ``moe.row_chunks`` the buffer has."""
+    x, share, first, held = _routed(routing)
+    n = x.shape[0]
+    seen = _watch_ragged_dot(monkeypatch)
+    chunks = counter("moe.row_chunks")
+    before = chunks.value
+    _, aux = hvd.moe_ffn_dropless(x, share, experts_per_token=K,
+                                  first_expert=first)
+    jax.effects_barrier()
+    load = np.asarray(aux.load[first:first + held]).astype(int)
     assert (load.sum() == 0) == (routing == "none_held")
-    assert (load[1] == n) == (routing == "skewed")
-    assert len(seen) == 3
+    assert (load[-1] == n) == (routing in ("skewed", "all_held"))
+    assert (load.sum() == n * K) == (routing == "all_held")
+    padded = -(-load // GROUP_ALIGN) * GROUP_ALIGN
+    filled = int(hvd.moe.rows_filled(aux.load, first, held))
+    assert filled == padded.sum() <= hvd.moe.rows_grouped(n * K, held)
+    trips = -(-filled // CHUNK_ROWS)
+    assert len(seen) == 3 * trips
+    assert chunks.value - before == hvd.moe.rows_grouped(
+        n * K, held) // CHUNK_ROWS >= trips
     for rows, sizes in seen:
-        assert rows == n * K + held * GROUP_ALIGN == sizes.sum()
-        assert not (sizes % GROUP_ALIGN).any() and (sizes >= load).all()
+        assert rows == CHUNK_ROWS >= sizes.sum()
+        assert not (sizes % GROUP_ALIGN).any()
+    for dot in range(3):
+        np.testing.assert_array_equal(
+            sum(sizes for _, sizes in seen[dot::3]), padded)
+
+
+@pytest.mark.parametrize("over", [0, 1])
+def test_one_row_over_a_chunk_boundary_is_one_more_trip(over, monkeypatch):
+    """Work grows with the rows in steps of one chunk and no larger: a held
+    expert sent exactly two chunks' rows is walked in two trips, one row
+    more in three."""
+    n, sent = 4 * CHUNK_ROWS, 2 * CHUNK_ROWS + over
+    p, _ = _params(6)
+    x = jax.random.normal(jax.random.key(4), (n, C))
+    logits = jnp.zeros((n, E)).at[:, 0].set(5.0).at[:, 1].set(4.0)
+    logits = logits.at[:sent, 5].set(9.0)
+    seen = _watch_ragged_dot(monkeypatch)
+    _, aux = hvd.moe_ffn_dropless(x, _share(p, 5, 1), experts_per_token=K,
+                                  first_expert=5, router_logits=logits)
+    jax.effects_barrier()
+    assert int(aux.load[5]) == sent
+    assert len(seen) == 3 * (2 + over)
 
 
 def _jaxprs(eqn):
@@ -161,10 +221,11 @@ def _jaxprs(eqn):
 
 
 def test_one_program_takes_any_routing():
-    """No branch on the load: the differentiated layer holds no ``cond``,
-    and its grouped matmuls run over a buffer of all N * K token-choices
-    and a tile a held expert, the most the held experts can be sent
-    (buffers sized by the observed load are ROADMAP S13)."""
+    """No branch on the load and no buffer sized by it: the differentiated
+    layer is one jaxpr with no ``cond``; its grouped matmuls sit in loops
+    (forward and backward) whose trips are a value of the step, each over
+    one chunk of rows, and the most they can walk is the buffer of all
+    N * K token-choices and a tile a held expert."""
     n = 1024
     p, _ = _params(6)
     x = jax.random.normal(jax.random.key(1), (n, C))
@@ -174,20 +235,104 @@ def test_one_program_takes_any_routing():
                                     first_expert=5)
         return (y ** 2).sum()
 
-    names, rows = [], set()
+    names, rows = [], []
 
-    def walk(jaxpr):
+    def walk(jaxpr, loops):
         for e in jaxpr.eqns:
             names.append(e.primitive.name)
             if e.primitive.name.startswith("ragged_dot"):
-                rows.add(e.invars[0].aval.shape[0])
+                rows.append((e.invars[0].aval.shape[0], loops))
             for j in _jaxprs(e):
-                walk(j)
+                walk(j, loops + (e.primitive.name == "while"))
 
+    chunks = counter("moe.row_chunks")
+    before = chunks.value
     walk(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
-        x, _share(p, 5, 1)).jaxpr)
-    assert "cond" not in names and "while" not in names
-    assert rows == {n * K + GROUP_ALIGN}      # one held expert
+        x, _share(p, 5, 1)).jaxpr, 0)
+    assert "cond" not in names and names.count("while") == 2
+    assert len(rows) >= 8 and set(rows) == {(CHUNK_ROWS, 1)}
+    assert (chunks.value - before) * CHUNK_ROWS >= n * K + GROUP_ALIGN
+
+
+class _Remat(nn.Module):
+    first: int
+
+    @nn.compact
+    def __call__(self, x, share):
+        return hvd.moe_ffn_dropless(x, share, experts_per_token=K,
+                                    first_expert=self.first)[0]
+
+
+@pytest.mark.parametrize("wrapped", [False, True],
+                         ids=["plain", "remat_jit"])
+@pytest.mark.parametrize("routing", ROUTINGS)
+def test_value_and_gradients_under_every_routing(routing, wrapped):
+    """Value and gradients in x, the three expert weights AND the router
+    kernel (through the gates) are the plain loop's under each routing,
+    also inside ``nn.remat`` under ``jax.jit``, where the forward walk runs
+    again before the backward one."""
+    x, share, first, held = _routed(routing)
+    ct = jax.random.normal(jax.random.key(7), x.shape)
+
+    def got(x, share):
+        if wrapped:
+            y = nn.remat(_Remat)(first).apply({}, x, share)
+        else:
+            y = hvd.moe_ffn_dropless(x, share, experts_per_token=K,
+                                     first_expert=first)[0]
+        return (y * ct).sum()
+
+    def want(x, share):
+        experts, gates = ref.route(x, share["router"], K, MM)
+        return (ref.moe_share(x, share, experts, gates, first, MM)
+                * ct).sum()
+
+    with jax.default_matmul_precision("highest"):
+        f = jax.value_and_grad(got, argnums=(0, 1))
+        v_got, g_got = (jax.jit(f) if wrapped else f)(x, share)
+        v_want, g_want = jax.value_and_grad(want, argnums=(0, 1))(x, share)
+    np.testing.assert_allclose(float(v_got), float(v_want), rtol=2e-5,
+                               atol=1e-4)
+    got_leaves = {"x": g_got[0], **g_got[1]}
+    want_leaves = {"x": g_want[0], **g_want[1]}
+    assert set(got_leaves) == {"x", "router", "w1", "w3", "w2"}
+    for name, b in want_leaves.items():
+        np.testing.assert_allclose(
+            np.asarray(got_leaves[name]), np.asarray(b), err_msg=name,
+            atol=2e-5 * max(float(jnp.abs(b).max()), 1e-3))
+    # (A first choice pushed this hard saturates its gate: "skewed" moves
+    # the router by next to nothing, and in the reference too.)
+    if routing != "skewed":
+        moved = routing != "none_held"
+        assert (float(jnp.abs(got_leaves["router"]).max()) > 0) == moved
+
+
+def test_the_backward_walk_carries_the_scope():
+    """The scope is opened outside the ``custom_vjp``, so the backward
+    walk's ops carry it (``moe_ffn.ms`` reads both directions by it), and
+    its grouped matmuls are still ``ragged_dot``s by name (the structure
+    row ``grouped_matmuls_in_program`` counts that text)."""
+    import re
+
+    from horovod_tpu.monitor.span_audit import DEVICE_SCOPES
+
+    assert "hvd.moe_ffn" in DEVICE_SCOPES
+    x, share, first, _ = _routed("even")
+    traced = jax.jit(jax.grad(lambda x, share: (hvd.moe_ffn_dropless(
+        x, share, experts_per_token=K, first_expert=first)[0] ** 2).sum(),
+        argnums=(0, 1))).trace(x, share)
+    # As the TPU is handed it: grouped matmuls by name, three forward and
+    # five backward (the hidden rows again, dh, dx's two).
+    assert traced.lower(lowering_platforms=("tpu",)).as_text().count(
+        '"chlo.ragged_dot"') >= 8
+    lowered = traced.lower()
+    where = "\n".join(line for line in lowered.as_text(
+        debug_info=True).splitlines() if "ragged_dot" in line)
+    assert "/jvp(hvd.moe_ffn)/while/body/ragged_dot" in where
+    assert "/transpose(jvp(hvd.moe_ffn))/while/body/ragged_dot" in where
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    backward = [n for n in names if "transpose(" in n and "/while/" in n]
+    assert backward and all("hvd.moe_ffn" in n for n in backward)
 
 
 def test_gradients_are_the_references():
