@@ -21,8 +21,9 @@ import functools
 from benchmarks.builders import gpt_decoder
 from benchmarks.lib import flops_sparse_moe, reference_sparse_moe, traffic
 
-KERNELS = ("hvd_index_select", "hvd_sparse_attn_fwd",
-           "hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv")
+# Named kernels the compiled step has to hold, once a layer at the least;
+# the backward by its prefix, however many kernels it is made of.
+KERNELS = ("hvd_index_select", "hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd")
 
 
 class Session(gpt_decoder.Session):
@@ -51,7 +52,7 @@ class Session(gpt_decoder.Session):
             batch=self.per_chip_batch, seq=self.seq_len, heads=s["heads"],
             kv_heads=s["kv_heads"], head_dim=s["head_dim"],
             topk=s["idx_topk"], idx_heads=s["idx_heads"],
-            idx_dim=s["idx_dim"], act_bytes=2)}
+            idx_dim=s["idx_dim"], act_bytes=2, calls_per_step=s["layers"])}
 
         hvd.shutdown()
         hvd.init(devices=self.devices, mesh_shape=(1, len(self.devices)))

@@ -1,30 +1,43 @@
-"""Device trace: the sparse-attention backward's share of its roofline, the
-dq and the dk/dv kernels together. Least time for one backward over the
-SELECTED pairs only (benchmarks/lib/kernels_sparse.py) over the mean
-measured time of one ``hvd_sparse_attn_bwd_dq`` event plus one
-``hvd_sparse_attn_bwd_dkv`` event on the first device."""
+"""Device trace: the sparse-attention backward's share of its roofline,
+whatever kernels make it up. Least time for one backward over the SELECTED
+pairs only (benchmarks/lib/kernels_sparse.py) over the measured time of one:
+the time of every event on the first device whose kernel name begins
+``hvd_sparse_attn_bwd`` (today ``_dq`` and ``_dkv``; one fused kernel of
+that name reads the same way), inside the traced steps, over the backward
+calls those steps made (``calls_per_step`` of the builder's
+``kernel_shapes`` entry)."""
 
-from benchmarks.lib import kernels, kernels_sparse, manifest as mf, scopes
+import re
+
+from benchmarks.lib import kernels, kernels_sparse, scopes
 
 NAME, UNIT = "sparse_attn_bwd_roofline", "%"
 LAYER, MOVES = "Kernels", "tokens_per_s_per_chip"
-KERNELS = ("hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv")
+PREFIX = "hvd_sparse_attn_bwd"
+# The op's HLO text begins with the kernel's own name (an op that merely
+# consumes a kernel's result holds the name further on and is not one).
+KERNEL = re.compile(rf"^%?({PREFIX}\w*?)(\.\d+)? ")
 
 
 def read(run):
     shape = dict(run.kernel_shapes.get("sparse_attention") or {})
     scoped = scopes.of(run)
-    if scoped is None or run.peak is None or not shape:
+    calls = shape.get("calls_per_step")
+    if scoped is None or run.peak is None or not calls or not scoped.steps:
         return None
-    kernel_seconds = mf.load_module(
-        "layers", "sparse_attn_fwd_roofline").kernel_seconds
-    parts = [kernel_seconds(scoped, k) for k in KERNELS]
-    if not all(parts):
+    by_kernel: dict = {}
+    for text, start, seconds, _ in scoped.ops:
+        m = KERNEL.match(text)
+        if m and any(a <= start < b for a, b in scoped.steps):
+            by_kernel.setdefault(m.group(1), []).append(seconds)
+    if not by_kernel:
         return None
     least, bound = kernels.roofline(
         *kernels_sparse.sparse_attn_bwd_cost(**shape), run.peak)
-    mean = sum(sum(p) / len(p) for p in parts)
-    run.note(f"{NAME}: {[len(p) for p in parts]} calls, mean dq + dkv "
-             f"{mean * 1e6:.1f} us, least {least * 1e6:.1f} us over the "
-             f"selected pairs, bound by {bound}")
+    backwards = len(scoped.steps) * calls
+    mean = sum(map(sum, by_kernel.values())) / backwards
+    run.note(f"{NAME}: {backwards} backward calls, events "
+             f"{ {k: len(v) for k, v in sorted(by_kernel.items())} }, mean "
+             f"{mean * 1e6:.1f} us a call, least {least * 1e6:.1f} us over "
+             f"the selected pairs, bound by {bound}")
     return 100.0 * least / mean

@@ -52,6 +52,30 @@ def test_per_layer_metric_has_its_reader(name):
     assert callable(mod.read)
 
 
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_every_cell_reports_step_p90_on_one_side(cell_name):
+    """Bounded end to end where a step does not follow its routing, as
+    ``step.interval_p90_ms`` of the traced window where it does (PERF.md,
+    PR 35): each cell has one of the two, and a rate either way."""
+    e2e = {m["name"] for m in mf.metrics_for(MANIFEST, "end_to_end",
+                                             cell_name)}
+    layers = {m["name"] for m in mf.metrics_for(MANIFEST, "per_layer",
+                                                cell_name)}
+    assert ("step_ms_p90" in e2e) != ("step.interval_p90_ms" in layers)
+    assert {"tokens_per_s_per_chip", "setup_s"} <= e2e
+
+
+@pytest.mark.parametrize("count, expect", [(9, None), (11, 109.0)])
+def test_interval_p90_reader(count, expect):
+    class Run:
+        def intervals(self):
+            return [0.1 + 0.001 * i for i in range(count)]
+
+    value = mf.load_module("layers", "step.interval_p90_ms").read(Run())
+    assert value == (expect if expect is None
+                     else pytest.approx(expect, rel=1e-9))
+
+
 def test_every_reader_file_is_in_the_manifest():
     for kind, names in (("end_to_end", E2E), ("layers", PER_LAYER)):
         files = {f[:-3] for f in os.listdir(os.path.join(mf.BENCH, kind))
@@ -81,7 +105,7 @@ BREACHES = {
     "five_chips": lambda m: m["workloads"][0].update(chips=5),
     "extra_top_level_key": lambda m: m.update(notes="x"),
     "command_outside_paths": lambda m: m.update(
-        command=["python3", "bench.py"]),
+        command=["python3", "chip_smoke.py"]),
     "run_seconds_too_long": lambda m: m.update(run_seconds=52),
 }
 

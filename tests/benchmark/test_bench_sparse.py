@@ -47,8 +47,8 @@ def test_sound_run_is_correct(session_mesh_restored, tmp_path, seed):
     for name in compare.NUMBERS + ("non_finite_losses",
                                    "compilations_in_window"):
         assert " limit " in _row(lines, name) and "ok" in _row(lines, name)
-    for kernel in ("hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd_dq",
-                   "hvd_sparse_attn_bwd_dkv", "hvd_index_select"):
+    for kernel in ("hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd",
+                   "hvd_index_select"):
         assert "ok" in _row(lines, f"{kernel}_in_program")
 
 
@@ -205,7 +205,7 @@ NEW_READERS = ("sparse_attention.ms", "sparse_indexer.ms", "moe_ffn.ms",
                "sparse_attn_fwd_roofline", "sparse_attn_bwd_roofline")
 SHAPES = {"sparse_attention": dict(
     batch=1, seq=16384, heads=32, kv_heads=4, head_dim=128, topk=2048,
-    idx_heads=16, idx_dim=64, act_bytes=2)}
+    idx_heads=16, idx_dim=64, act_bytes=2, calls_per_step=1)}
 
 
 def _reader_run(ops=None):
@@ -235,7 +235,7 @@ def test_reader_is_declared_and_reads_nothing_where_nothing_is(name):
     entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
     assert (entry["unit"], entry["layer"], entry["moves"]) == (
         reader.UNIT, reader.LAYER, reader.MOVES)
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
     assert reader.read(_reader_run()) is None
     plain = [("%fusion.1 = f32[8] fusion(%p)", 0.1, 0.2,
               "jit(spmd)/hvd.grad/jvp(GPT)/h0/attn/hvd.flash_attention/mul")]
@@ -279,3 +279,51 @@ def test_readers_read_scopes_and_kernels_by_name():
     assert read["sparse_attn_fwd_roofline"] == pytest.approx(2.616, rel=1e-3)
     assert read["sparse_attn_bwd_roofline"] == pytest.approx(
         100 * 5.2326e-3 / 0.12, rel=1e-3)
+
+
+BACK = ("jit(spmd)/hvd.grad/transpose(jvp(SparseMoEDecoder))/h0/attn/"
+        "hvd.sparse_attention/")
+
+
+def _backward(name, start, seconds):
+    return (f"%{name} = (bf16[4]) custom-call(%q)", start, seconds,
+            BACK + name.split(".")[0] + "/pallas_call")
+
+
+@pytest.mark.parametrize("kernels_of_a_backward", [
+    [("hvd_sparse_attn_bwd_dq.1", 0.05), ("hvd_sparse_attn_bwd_dkv.1", 0.07)],
+    [("hvd_sparse_attn_bwd.4", 0.12)],
+    [("hvd_sparse_attn_bwd_dq", 0.03), ("hvd_sparse_attn_bwd_dk.2", 0.04),
+     ("hvd_sparse_attn_bwd_dv.2", 0.05)],
+], ids=["dq_and_dkv", "one_kernel", "three_kernels"])
+@pytest.mark.parametrize("calls", [1, 3])
+def test_backward_roofline_counts_by_prefix_over_the_calls_a_step(
+        kernels_of_a_backward, calls):
+    """The backward is every kernel whose name begins
+    ``hvd_sparse_attn_bwd``: 0.12 s a call whether two kernels make it up,
+    one or three, over ``calls_per_step`` calls a step; the forward kernel,
+    a consumer of a backward kernel's result and an event outside the
+    traced steps are not part of it."""
+    ops = [("%hvd_sparse_attn_fwd.2 = (bf16[4]) custom-call(%q)", 0.0, 0.01,
+            BACK + "hvd_sparse_attn_fwd/pallas_call")]
+    for call in range(calls):
+        at = 0.02 + 0.3 * call
+        for name, seconds in kernels_of_a_backward:
+            ops.append(_backward(name, at, seconds))
+            at += seconds
+        ops.append((f"%copy.{call} = bf16[4] copy(%hvd_sparse_attn_bwd_dq.1)",
+                    at, 0.01, BACK + "transpose"))
+    run = _reader_run(ops)
+    run.kernel_shapes = {"sparse_attention": dict(
+        SHAPES["sparse_attention"], calls_per_step=calls)}
+    run.scoped_ops.ops.append(_backward("hvd_sparse_attn_bwd_dq.9", 2.5, 9.0))
+    reader = mf.load_module("layers", "sparse_attn_bwd_roofline")
+    assert reader.read(run) == pytest.approx(100 * 5.2326e-3 / 0.12,
+                                             rel=1e-3)
+    assert f"{2 * calls} backward calls" in run.notes[-1]
+    # a builder that does not say how many calls a step makes: nothing
+    run.kernel_shapes = SHAPES | {"sparse_attention": {
+        k: v for k, v in SHAPES["sparse_attention"].items()
+        if k != "calls_per_step"}}
+    assert reader.read(run) is None
+
