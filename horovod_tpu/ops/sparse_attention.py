@@ -17,8 +17,8 @@ kernels are):
   all kept. Operands as given (bfloat16 in the model), accumulation in
   float32.
 * :func:`masked_attention` (``hvd_sparse_attn_fwd``,
-  ``hvd_sparse_attn_bwd_dq``, ``hvd_sparse_attn_bwd_dkv``): the flash
-  schedule with the selection applied as a mask inside each score tile.
+  ``hvd_sparse_attn_bwd``): the flash schedule with the selection applied
+  as a mask inside each score tile.
   A grid cell holds one KV head's block of keys against ALL the query
   heads that share it (``[G, bq, D]``), so K, V and the mask tile are
   fetched once for the group; cells wholly in the causal future are
@@ -27,7 +27,13 @@ kernels are):
   A tile none of whose entries is selected is NOT skipped: with a
   selection as scattered as an untrained indexer's every (512, 512) tile
   holds selected pairs, so the test would cost and save nothing
-  (ROADMAP "Speed").
+  (ROADMAP "Speed"). The backward is ONE kernel that computes a tile's
+  ``p`` and ``ds`` once for dq, dk and dv (5 matmuls a tile and a head),
+  with a KV head's float32 dk and dv, ``8 * T * D`` bytes, resident in
+  VMEM; where those pass ``_FUSED_BWD_BUDGET`` (T = 32k at D = 128) two
+  kernels run in its place, ``hvd_sparse_attn_bwd_dq`` and
+  ``hvd_sparse_attn_bwd_dkv`` (3 + 4 matmuls: the tile computed twice).
+  The shape alone decides (:func:`_fused_bwd_fits`).
 
 :func:`sparse_attention` composes them under the scopes
 ``hvd.sparse_indexer`` and ``hvd.sparse_attention``. No gradient reaches
@@ -47,8 +53,10 @@ holds neither kernel: what feeds only a saved value is dead there.
 Trace-time counters (monitor registry): ``sparse_attn.topk``,
 ``sparse_attn.pairs_required`` (sum over queries of min(t + 1, topk), per
 query head) and ``sparse_attn.pairs_computed`` (entries of the score tiles
-a kernel runs), label ``kernel`` = ``index`` | ``fwd`` | ``bwd_dq`` |
-``bwd_dkv``; ``sparse_attn.selection_bytes`` (the packed selection a
+a kernel runs), label ``kernel`` = ``index`` | ``fwd`` | ``bwd`` (the
+fused backward) or ``bwd_dq`` | ``bwd_dkv``; ``sparse_attn.bwd_path``
+(label ``path`` = ``fused`` | ``split``: one a differentiated call, by
+the shape); ``sparse_attn.selection_bytes`` (the packed selection a
 differentiated call names, ``B * T * T / 8``).
 """
 
@@ -80,7 +88,14 @@ _INDEX_BLOCK_Q = 128
 _INDEX_CHUNK = 1024
 _BLOCK_Q = 1024
 _BLOCK_K = 1024
+# The fused backward holds four [bq, bk] float32 intermediates a head where
+# the forward holds two: at (1024, 1024) it ran 39.1 ms a call on the chip,
+# at (512, 1024) 32.2 (the two backward kernels 45.0; PERF.md, PR 36).
+_BWD_BLOCK_Q = 512
+_BWD_BLOCK_K = 1024
 _VMEM_LIMIT = 96 * 1024 * 1024
+# What the fused backward may spend of it on a KV head's dk / dv.
+_FUSED_BWD_BUDGET = _VMEM_LIMIT // 4
 
 
 def _params(*semantics):
@@ -399,6 +414,68 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
+                scale, G, bq, bk, nq):
+    """The whole backward in one query-major walk: a score tile's ``p``
+    and ``ds`` are computed once and feed dq (a scratch a query block, as
+    in ``_bwd_dq_kernel``) and dk / dv, whose float32 ``[T, D]``
+    accumulators of the KV head stay in VMEM across both inner axes. A key
+    block's contributions arrive as in ``_bwd_dkv_kernel`` (query blocks
+    ascending, heads inside), so all three results are that pair's to the
+    bit. The last row of cells sees every key block and writes it out."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    last = _last_k(i, bq, bk)
+
+    @pl.when((i == 0) & (j == 0))
+    def _init_kv():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(j == 0)
+    def _init_q():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
+
+    @pl.when(j <= last)
+    def _cell():
+        sel = mask_ref[0].astype(jnp.float32) != 0.0          # [bq, bk]
+        k, v = k_ref[0], v_ref[0]
+        dk, dv = dk_scr[rows, :], dv_scr[rows, :]
+        for g in range(G):
+            q = q_ref[0, g] * scale
+            do = do_ref[0, g]
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            p = jnp.exp(jnp.where(sel, s, _NEG_INF) - lse_ref[0, g][:, :1])
+            # In this order (dv's matmul before dp's): with dp first the
+            # call read 32.37 ms for 32.17 on the chip (PERF.md, PR 36).
+            dv = dv + lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [bk, D]
+            dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[0, g][:, :1])).astype(k.dtype)
+            dq_scr[g] = dq_scr[g] + lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk = dk + lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        dk_scr[rows, :], dv_scr[rows, :] = dk, dv
+
+    @pl.when(j == last)
+    def _finish_q():
+        for g in range(G):
+            dq_ref[0, g] = (dq_scr[g] * scale).astype(dq_ref.dtype)
+
+    @pl.when(i == nq - 1)
+    def _finish_kv():
+        dk_ref[0] = dk_scr[rows, :].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[rows, :].astype(dv_ref.dtype)
+
+
 _traced_once = functools.partial(
     jax.jit, inline=True,
     static_argnames=("scale", "hkv", "bq", "bk", "interpret"))
@@ -491,6 +568,34 @@ def _bwd_dkv_call(q, k, v, mask, do, lse, delta, *, scale, hkv, bq, bk,
     )(q, k, v, mask, do, lse, delta)
 
 
+@_traced_once
+def _bwd_call(q, k, v, mask, do, lse, delta, *, scale, hkv, bq, bk,
+              interpret):
+    BHk, G, T, D = q.shape
+    nq = T // bq
+    qs, ks, ms, rs = _specs(hkv, G, bq, bk, D, q_major=True)
+    # A key block leaves VMEM from the last row of cells, which sees them
+    # all in turn; before it the output block stays put and is not written.
+    out = pl.BlockSpec((1, bk, D),
+                       lambda b, i, j: (b, jnp.where(i == nq - 1, j, 0), 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, G=G, bq=bq, bk=bk,
+                          nq=nq),
+        grid=(BHk, nq, T // bk),
+        in_specs=[qs, ks, ks, ms, qs, rs, rs],
+        out_specs=[qs, out, out],
+        out_shape=[_fa._out_struct(q.shape, q.dtype, q, k, v, mask, do),
+                   _fa._out_struct(k.shape, k.dtype, q, k, v, mask, do),
+                   _fa._out_struct(v.shape, v.dtype, q, k, v, mask, do)],
+        scratch_shapes=[pltpu.VMEM((G, bq, D), jnp.float32),
+                        pltpu.VMEM((T, D), jnp.float32),
+                        pltpu.VMEM((T, D), jnp.float32)],
+        compiler_params=_params("parallel", "arbitrary", "arbitrary"),
+        interpret=interpret,
+        name="hvd_sparse_attn_bwd",
+    )(q, k, v, mask, do, lse, delta)
+
+
 def _count_pairs(kernel, q, topk_pairs, bq, bk):
     BHk, G, T, _ = q.shape
     cells = sum(_last_k(i, bq, bk) + 1 for i in range(T // bq))
@@ -499,10 +604,20 @@ def _count_pairs(kernel, q, topk_pairs, bq, bk):
         _count("pairs_required", BHk * G * topk_pairs, kernel=kernel)
 
 
-def _kw(q, scale, hkv):
+def _kw(q, scale, hkv, fused_bwd=False):
     T = q.shape[2]
-    return dict(scale=scale, hkv=hkv, bq=_fa._pick_block(T, _BLOCK_Q),
-                bk=_fa._pick_block(T, _BLOCK_K), interpret=_fa._interpret())
+    bq, bk = (_BWD_BLOCK_Q, _BWD_BLOCK_K) if fused_bwd else (_BLOCK_Q,
+                                                             _BLOCK_K)
+    return dict(scale=scale, hkv=hkv, bq=_fa._pick_block(T, bq),
+                bk=_fa._pick_block(T, bk), interpret=_fa._interpret())
+
+
+def _fused_bwd_fits(T, D):
+    """Whether one KV head's float32 dk and dv (``8 * T * D`` bytes) may
+    stay in VMEM beside the fused backward's blocks: 16 MB of the budget's
+    24 at the benchmark's T = 16k, D = 128; from T = 32k on the two
+    kernels run, which hold a key block's accumulators alone."""
+    return 8 * T * D <= _FUSED_BWD_BUDGET
 
 
 def _forward(q, k, v, mask, scale, hkv, required):
@@ -537,14 +652,20 @@ def _masked_bwd(scale, hkv, required, res, do):
     # peak in the benchmark's model). It waits for the cotangent.
     selection, do = lax.optimization_barrier((selection, do))
     mask = unpack_selection(selection)
-    kw = _kw(q, scale, hkv)
+    fused = _fused_bwd_fits(*q.shape[2:])
+    kw = _kw(q, scale, hkv, fused)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     lse, delta = (jnp.broadcast_to(x[..., None], (*x.shape, 8))
                   for x in (lse, delta))
-    _count_pairs("bwd_dq", q, required, kw["bq"], kw["bk"])
-    _count_pairs("bwd_dkv", q, required, kw["bq"], kw["bk"])
-    dq = _bwd_dq_call(q, k, v, mask, do, lse, delta, **kw)
-    dk, dv = _bwd_dkv_call(q, k, v, mask, do, lse, delta, **kw)
+    _count("bwd_path", 1, path="fused" if fused else "split")
+    if fused:
+        _count_pairs("bwd", q, required, kw["bq"], kw["bk"])
+        dq, dk, dv = _bwd_call(q, k, v, mask, do, lse, delta, **kw)
+    else:
+        _count_pairs("bwd_dq", q, required, kw["bq"], kw["bk"])
+        _count_pairs("bwd_dkv", q, required, kw["bq"], kw["bk"])
+        dq = _bwd_dq_call(q, k, v, mask, do, lse, delta, **kw)
+        dk, dv = _bwd_dkv_call(q, k, v, mask, do, lse, delta, **kw)
     return dq, dk, dv, np.zeros(mask.shape, jax.dtypes.float0)
 
 

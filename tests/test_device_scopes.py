@@ -36,8 +36,8 @@ KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv",
            "hvd_flash_bwd_dkv_win",
            "hvd_xent_fwd", "hvd_xent_bwd_dx", "hvd_xent_bwd_dw",
            "hvd_ln_fwd", "hvd_ln_bwd", "hvd_index_select",
-           "hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd_dq",
-           "hvd_sparse_attn_bwd_dkv")
+           "hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd",
+           "hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv")
 # The tiny GPT step runs the unfused LayerNorm, as every cell does today,
 # and none of the sparse decoder's work: that has a tiny step of its own.
 SPARSE_STEP = {"hvd.sparse_attention", "hvd.sparse_indexer", "hvd.moe_ffn"}
@@ -122,6 +122,27 @@ def layer_norm_names():
         x, g).compile().as_text())
 
 
+def _sparse_attention_loss():
+    """(loss, its argument) of one ``sparse_attention`` call at toy
+    widths."""
+    q = jnp.ones((1, 128, 2, 64), jnp.bfloat16)
+    idx = (jnp.ones((1, 128, 2, 8)), jnp.ones((1, 128, 8)),
+           jnp.ones((1, 128, 2)))
+    return (lambda q: spa.sparse_attention(q, q, q, *idx, topk=16).astype(
+        jnp.float32).sum()), q
+
+
+@pytest.fixture()
+def sparse_split_names(monkeypatch):
+    """The op_names of one differentiated ``sparse_attention`` call whose
+    backward is ``_dq`` + ``_dkv``: the path of a sequence whose dk and dv
+    do not fit in VMEM, taken here by a budget of nothing (the tiny
+    step's shape takes the fused kernel)."""
+    monkeypatch.setattr(spa, "_FUSED_BWD_BUDGET", 0)
+    loss, q = _sparse_attention_loss()
+    return _op_names(jax.jit(jax.grad(loss)).lower(q).compile().as_text())
+
+
 @pytest.mark.parametrize("scope", DEVICE_SCOPES)
 def test_scope_reaches_the_compiled_program(scope, step_names,
                                             layer_norm_names,
@@ -151,22 +172,28 @@ def test_scope_reaches_the_compiled_program(scope, step_names,
     ("hvd_flash_bwd_dq", "hvd.flash_attention"),
     ("hvd_flash_bwd_dkv", "hvd.flash_attention"),
     ("hvd_ln_bwd", "hvd.layer_norm"),
-    ("hvd_sparse_attn_bwd_dq", "hvd.sparse_attention"),
-    ("hvd_sparse_attn_bwd_dkv", "hvd.sparse_attention"),
+    ("hvd_sparse_attn_bwd", "hvd.sparse_attention"),
+    ("hvd_sparse_attn_bwd_dq", "hvd.sparse_attention.split"),
+    ("hvd_sparse_attn_bwd_dkv", "hvd.sparse_attention.split"),
     ("hvd_flash_bwd_dq_win", "hvd.flash_window"),
     ("hvd_flash_bwd_dkv_win", "hvd.flash_window")])
 def test_custom_vjp_backward_inherits_the_scope(kernel, scope, step_names,
                                                 layer_norm_names,
                                                 sparse_step_names,
+                                                sparse_split_names,
                                                 afmoe_step_names):
     """The trap: a scope opened inside the custom_vjp's forward function
     would not reach the backward. In interpret mode a kernel's body is
-    traced into the program under its ``name=``."""
+    traced into the program under its ``name=``. The tiny sparse step
+    takes the fused backward kernel (and holds neither of the two);
+    ``.split`` is one call on the path of the two."""
     names = {"hvd.layer_norm": layer_norm_names,
              "hvd.sparse_attention": sparse_step_names,
+             "hvd.sparse_attention.split": sparse_split_names,
              "hvd.flash_window": afmoe_step_names}.get(
         scope, step_names[4])
-    body = [n for n in names if kernel in n]
+    scope = scope.removesuffix(".split")
+    body = [n for n in names if f"{kernel}/" in n or n.endswith(kernel)]
     assert body, f"no op of {kernel} in the program"
     assert all(scope in n and "transpose(" in n for n in body)
 
@@ -189,21 +216,23 @@ def kernel_names():
     w = jnp.ones((256, 64), jnp.bfloat16)
     lab = jnp.zeros((128,), jnp.int32)
     g = jnp.ones((64,), jnp.float32)
-    idx = (jnp.ones((1, 128, 2, 8)), jnp.ones((1, 128, 8)),
-           jnp.ones((1, 128, 2)))
     programs = [
         (lambda q: fa.flash_attention(q, q, q).astype(jnp.float32).sum(),
          q),
         (lambda q: fa.flash_attention(q, q[:, :, :1], q[:, :, :1],
                                       window=32).astype(jnp.float32).sum(),
          q),
-        (lambda q: spa.sparse_attention(q, q, q, *idx, topk=16).astype(
-            jnp.float32).sum(), q),
+        _sparse_attention_loss(),
         (lambda x: sx.linear_cross_entropy(x, w, lab).sum(), x),
         (lambda x: ln.ln_residual(x, x, g, g)[0].astype(
             jnp.float32).sum(), x)]
     found: set = set()
     for fn, arg in programs:
+        _pallas_names(jax.make_jaxpr(jax.grad(fn))(arg).jaxpr, found)
+    # and the sparse backward of a sequence too long for the fused kernel
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(spa, "_FUSED_BWD_BUDGET", 0)
+        fn, arg = _sparse_attention_loss()
         _pallas_names(jax.make_jaxpr(jax.grad(fn))(arg).jaxpr, found)
     return found
 

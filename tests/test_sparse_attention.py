@@ -132,7 +132,8 @@ def test_many_blocks_agree_with_one(monkeypatch):
         return mask, tau, o, grads
 
     one = run()
-    for name in ("_BLOCK_Q", "_BLOCK_K", "_INDEX_BLOCK_Q", "_INDEX_CHUNK"):
+    for name in ("_BLOCK_Q", "_BLOCK_K", "_BWD_BLOCK_Q", "_BWD_BLOCK_K",
+                 "_INDEX_BLOCK_Q", "_INDEX_CHUNK"):
         monkeypatch.setattr(sa, name, 128)
     many = run()
     np.testing.assert_array_equal(np.asarray(one[0]), np.asarray(many[0]))
@@ -268,33 +269,180 @@ def test_batch_and_bfloat16():
                                atol=0.05)
 
 
-def test_trace_time_counters():
+def _dense_grads(q, k, v, sel, ct):
+    """Gradients of the plain float32 reference's attention over ``sel``
+    [T, T] in q [T, H, D], k, v [T, Hk, D], cotangent ``ct``."""
+    T_ = q.shape[0]
+    none = jnp.zeros((T_, 1))
+
+    def loss(q, k, v):
+        return (ref.attention(q, k, v, none, none, none, none[:, 0], MM, 64,
+                              selected=sel) * ct).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _one_key_rows(T_):
+    """A selection whose rows of the second half keep ONE key each (an
+    early one: whole tiles beside it hold nothing of the row), the first
+    half a full lower triangle."""
+    m = np.tril(np.ones((T_, T_), np.int8))
+    m[T_ // 2:] = 0
+    m[np.arange(T_ // 2, T_), np.arange(T_ // 2, T_) % 7] = 1
+    return jnp.asarray(m)[None]
+
+
+# name -> T -> mask [1, T, T]
+_BACKWARD_MASKS = {
+    "ties": lambda T_: jnp.asarray(np.tril(np.ones((1, T_, T_), np.int8))),
+    "empty_upper_triangle": lambda T_: _selected(3, T_),
+    "a_row_with_one_key": _one_key_rows,
+}
+
+
+@pytest.mark.parametrize("mask_name", sorted(_BACKWARD_MASKS))
+@pytest.mark.parametrize("T_,G,bq,bk", [
+    (256, 1, 128, 128), (256, 8, 128, 128), (256, 8, 128, 256),
+    (384, 1, 128, 128), (384, 8, 128, 128), (256, 8, 256, 128)])
+def test_fused_backward_is_the_two_kernels_to_the_bit(monkeypatch, mask_name,
+                                                      T_, G, bq, bk):
+    """One ``hvd_sparse_attn_bwd`` call gives the dq, dk and dv of
+    ``hvd_sparse_attn_bwd_dq`` + ``_dkv`` BIT FOR BIT (a key block's
+    contributions arrive in the same order: query blocks ascending, heads
+    inside; a query block's likewise), and the dense float32 reference's
+    gradients to float32 rounding; G = 1 and G = 8 query heads a KV head,
+    square and oblong tiles, a grid of 2 x 2, 3 x 3, 2 x 1 and 1 x 2."""
+    hk = 2
+    ks = jax.random.split(jax.random.key(T_ + G), 4)
+    q = jax.random.normal(ks[0], (T_, hk * G, D))
+    k, v = (jax.random.normal(key, (T_, hk, D)) for key in ks[1:3])
+    ct = jax.random.normal(ks[3], (T_, hk * G, D))
+    mask = _BACKWARD_MASKS[mask_name](T_)
+    for name in ("_BLOCK_Q", "_BWD_BLOCK_Q"):   # the same tiles on both
+        monkeypatch.setattr(sa, name, bq)       # paths: the same order
+    for name in ("_BLOCK_K", "_BWD_BLOCK_K"):   # of every sum
+        monkeypatch.setattr(sa, name, bk)
+
+    def grads():
+        return jax.grad(lambda q, k, v: (hvd.masked_attention(
+            q[None], k[None], v[None], mask)[0] * ct).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    fused = grads()
+    monkeypatch.setattr(sa, "_FUSED_BWD_BUDGET", 0)
+    split = grads()
+    for a, b, c in zip(fused, split, _dense_grads(q, k, v, mask[0] != 0,
+                                                  ct)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   atol=1e-5 * float(jnp.abs(c).max()))
+
+
+def test_the_fused_backwards_own_blocks():
+    """As shipped the fused backward walks (512, 1024) tiles (a 2 x 1 grid
+    at T = 1024) where the forward and the two kernels walk (1024, 1024):
+    its gradients are the dense reference's, and the two kernels' to
+    float32 rounding (a key block's sum is cut in two)."""
+    assert (sa._BWD_BLOCK_Q, sa._BWD_BLOCK_K) == (512, 1024)
+    assert (sa._BLOCK_Q, sa._BLOCK_K) == (1024, 1024)
+    T_ = 1024
+    ks = jax.random.split(jax.random.key(5), 4)
+    q = jax.random.normal(ks[0], (T_, 2, D))
+    k, v = (jax.random.normal(key, (T_, 1, D)) for key in ks[1:3])
+    ct = jax.random.normal(ks[3], (T_, 2, D))
+    mask = _random_mask(0, 2, (1, T_, T_)) | jnp.eye(T_, dtype=jnp.int8)
+    mask = jnp.tril(mask)
+    fused = jax.grad(lambda q, k, v: (hvd.masked_attention(
+        q[None], k[None], v[None], mask)[0] * ct).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, c in zip(fused, _dense_grads(q, k, v, mask[0] != 0, ct)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   atol=1e-5 * float(jnp.abs(c).max()))
+
+
+def _backward_kernels(T_, D_):
+    """The names of the ``pallas_call``s in the gradient's jaxpr at
+    ``[1, T, 2 / 1 heads, D]``; nothing runs."""
+    found = set()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.add(eqn.params["name"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    q, kv = (jax.ShapeDtypeStruct((1, T_, h, D_), jnp.bfloat16)
+             for h in (2, 1))
+    mask = jax.ShapeDtypeStruct((1, T_, T_), jnp.int8)
+    walk(jax.make_jaxpr(jax.grad(lambda q, k, v, m: hvd.masked_attention(
+        q, k, v, m).astype(jnp.float32).sum(), argnums=(0, 1, 2)))(
+            q, kv, kv, mask).jaxpr)
+    return found - {"hvd_sparse_attn_fwd"}
+
+
+@pytest.mark.parametrize("T_,D_,fused", [
+    (16384, 128, True),    # the benchmark's: 16 MB of dk / dv a KV head
+    (24576, 128, True),    # the budget to the byte (24 MB of the 96)
+    (32768, 128, False),   # 32 MB: the two kernels
+    (32768, 64, True),     # half the head, twice the sequence
+    (65536, 64, False),
+])
+def test_the_backward_path_goes_by_the_shape(T_, D_, fused):
+    """``8 * T * D`` bytes of float32 dk and dv a KV head against a quarter
+    of the kernels' VMEM limit decide the path: no option, no name."""
+    assert sa._FUSED_BWD_BUDGET == sa._VMEM_LIMIT // 4
+    assert sa._fused_bwd_fits(T_, D_) == fused
+    paths = {p: counter("sparse_attn.bwd_path", path=p)
+             for p in ("fused", "split")}
+    before = {p: c.value for p, c in paths.items()}
+    assert _backward_kernels(T_, D_) == (
+        {"hvd_sparse_attn_bwd"} if fused else
+        {"hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv"})
+    assert {p: c.value - before[p] for p, c in paths.items()} == {
+        "fused": int(fused), "split": int(not fused)}
+
+
+@pytest.mark.parametrize("path,bwd_kernels", [
+    ("fused", ("bwd",)), ("split", ("bwd_dq", "bwd_dkv"))])
+def test_trace_time_counters(monkeypatch, path, bwd_kernels):
     """``sparse_attn.pairs_required`` / ``pairs_computed`` by kernel and
     ``sparse_attn.topk`` count at trace time what the call needs and what
-    its tiles compute (one (128, 128) tile here: every pair)."""
+    its tiles compute (one (128, 128) tile here: every pair). The fused
+    backward runs a tile once (``kernel="bwd"``): half of what the two
+    kernels of the other path count between them; ``sparse_attn.bwd_path``
+    says once a differentiated call which of the two it took."""
+    if path == "split":
+        monkeypatch.setattr(sa, "_FUSED_BWD_BUDGET", 0)
     x = _inputs(8, 128)
     names = [("topk", "index"), ("pairs_required", "index"),
              ("pairs_computed", "index")] + [
-        (n, k) for k in ("fwd", "bwd_dq", "bwd_dkv")
+        (n, k) for k in ("fwd", "bwd", "bwd_dq", "bwd_dkv")
         for n in ("pairs_required", "pairs_computed")]
 
     def read():
-        return {nk: counter(f"sparse_attn.{nk[0]}", kernel=nk[1]).value
-                for nk in names}
+        got = {nk: counter(f"sparse_attn.{nk[0]}", kernel=nk[1]).value
+               for nk in names}
+        got.update({p: counter("sparse_attn.bwd_path", path=p).value
+                    for p in ("fused", "split")})
+        return got
 
     before = read()
     jax.grad(lambda q: hvd.sparse_attention(
         q[None], x["k"][None], x["v"][None], x["qi"][None], x["ki"][None],
         x["w"][None], topk=TOPK).sum())(x["q"])
-    got = {nk: read()[nk] - before[nk] for nk in names}
+    got = {nk: v - before[nk] for nk, v in read().items()}
     required = sum(min(t + 1, TOPK) for t in range(128))
     assert sa.pairs_required(128, TOPK) == required
     assert got[("topk", "index")] == TOPK
     assert got[("pairs_required", "index")] == required
     assert got[("pairs_computed", "index")] == 128 * 128
-    for kern in ("fwd", "bwd_dq", "bwd_dkv"):
-        assert got[("pairs_required", kern)] == H * required
-        assert got[("pairs_computed", kern)] == H * 128 * 128
+    for kern in ("fwd", "bwd", "bwd_dq", "bwd_dkv"):
+        ran = kern == "fwd" or kern in bwd_kernels
+        assert got[("pairs_required", kern)] == ran * H * required
+        assert got[("pairs_computed", kern)] == ran * H * 128 * 128
+    assert {p: got[p] for p in ("fused", "split")} == {
+        "fused": path == "fused", "split": path == "split"}
 
 
 @pytest.mark.parametrize("B,T_", [(1, 128), (2, 256)])

@@ -160,30 +160,38 @@ def _kernel_calls(jaxpr, counts=None):
     return counts
 
 
+@pytest.mark.parametrize("backward", ["fused", "split"])
 @pytest.mark.parametrize("kept,index_calls", [
     ("the_models_policy", CFG["layers"]),
     ("the_attention_output_alone", 2 * CFG["layers"]),
 ])
 def test_recomputed_forward_holds_no_index_kernel(monkeypatch, kept,
-                                                  index_calls):
+                                                  index_calls, backward):
     """The blocks keep the packed selection beside the attention kernel's
     output, so the differentiated model selects once a layer: what feeds
     only a saved name is dropped from the recomputed forward. With the
     selection's name out of the policy (the model of before) every layer
-    selects twice. The attention kernels run once a layer either way."""
+    selects twice. The attention kernels run once a layer either way: the
+    backward as ONE ``hvd_sparse_attn_bwd`` equation a layer and none
+    named ``_dq`` / ``_dkv``, or, where a KV head's dk and dv would not
+    fit in VMEM, as those two."""
     import horovod_tpu.models.sparse_moe_decoder as module
+    from horovod_tpu.ops import sparse_attention as sa
 
     if kept == "the_attention_output_alone":
         monkeypatch.setattr(module, "SELECTION_NAME", "kept_by_no_value")
+    if backward == "split":
+        monkeypatch.setattr(sa, "_FUSED_BWD_BUDGET", 0)
     params = jax.eval_shape(functools.partial(ref.make_params, s=SIZES),
                             jax.ShapeDtypeStruct((), jnp.uint32))
     model = SparseMoEDecoder(SparseMoEConfig.from_dict(CFG))
     calls = _kernel_calls(jax.make_jaxpr(jax.grad(
         _program_loss(model, _tokens(4))))(params).jaxpr)
+    bwd = (("hvd_sparse_attn_bwd",) if backward == "fused" else
+           ("hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv"))
     assert calls == {"hvd_index_select": index_calls,
                      "hvd_sparse_attn_fwd": CFG["layers"],
-                     "hvd_sparse_attn_bwd_dq": CFG["layers"],
-                     "hvd_sparse_attn_bwd_dkv": CFG["layers"]}
+                     **{name: CFG["layers"] for name in bwd}}
 
 
 def test_return_hidden_feeds_the_untied_head():

@@ -84,6 +84,14 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def _custom_calls(text: str, name: str) -> int:
+    """How many custom calls of the compiled ``text`` are the kernel
+    ``name`` (and not one whose name goes on, as ``_dq`` after ``_bwd``)."""
+    import re
+
+    return len(re.findall(rf"%{name}[.\d]* = [^\n]*custom-call\(", text))
+
+
 @pytest.mark.parametrize("B,T,H,D,causal", [
     (8, 1024, 16, 64, True),    # GPT-350M train step (chip_smoke.py)
     (16, 1024, 12, 64, True),   # GPT-124M bench shape
@@ -170,14 +178,20 @@ def _sparse_shapes(tpu, T):
             tpu.shape((1, T, 16), jnp.float32))
 
 
-@pytest.mark.parametrize("T", [
-    16384,   # keye-vl2-30b-a3b.train-16k-1chip: [128, T] index keys (8 MB)
-             # and (1024, 1024) score tiles x 8 query heads in VMEM
-    2048,    # one attention block a row, two index chunks
+@pytest.mark.parametrize("T,backward", [
+    # keye-vl2-30b-a3b.train-16k-1chip: [128, T] index keys (8 MB), (1024,
+    # 1024) score tiles x 8 query heads forward, (512, 1024) backward
+    # beside a KV head's 16 MB of float32 dk and dv, all in VMEM
+    (16384, ("hvd_sparse_attn_bwd",)),
+    # one attention block a row, two index chunks
+    (2048, ("hvd_sparse_attn_bwd",)),
+    # dk and dv of a KV head (32 MB) over the fused kernel's budget
+    (32768, ("hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv")),
 ])
-def test_sparse_attention_compiles(tpu, real_kernels, T):
-    """The index kernel and the three masked-attention kernels at the
-    benchmark's widths: 32/4 heads of 128, indexer 16 x 64, topk 2048."""
+def test_sparse_attention_compiles(tpu, real_kernels, T, backward):
+    """The index kernel and the masked-attention kernels at the
+    benchmark's widths: 32/4 heads of 128, indexer 16 x 64, topk 2048; the
+    backward is the one kernel or the two, by the sequence's length."""
     from horovod_tpu.ops.sparse_attention import sparse_attention
 
     def f(q, k, v, qi, ki, w):
@@ -186,9 +200,40 @@ def test_sparse_attention_compiles(tpu, real_kernels, T):
             argnums=(0, 1, 2))(q, k, v)
 
     text = tpu.compile(f, *_sparse_shapes(tpu, T)).as_text()
-    for name in ("hvd_index_select", "hvd_sparse_attn_fwd",
-                 "hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv"):
-        assert name in text
+    for name in ("hvd_index_select", "hvd_sparse_attn_fwd", *backward):
+        assert _custom_calls(text, name) == 1, name
+    for name in {"hvd_sparse_attn_bwd", "hvd_sparse_attn_bwd_dq",
+                 "hvd_sparse_attn_bwd_dkv"} - set(backward):
+        assert _custom_calls(text, name) == 0, name
+
+
+@pytest.mark.parametrize("limit_mb", [
+    96,   # the kernels' limit
+    72,   # a quarter of it stays free beside the compiler's own use in a
+          # whole step (the call was taken at 64 MB and refused at 56)
+])
+def test_fused_sparse_backward_fits_its_vmem(tpu, real_kernels, monkeypatch,
+                                             limit_mb):
+    """``hvd_sparse_attn_bwd`` at the benchmark's ``[4 KV heads, 8 query
+    heads each, 16384, 128]`` with its own blocks: one custom call gives
+    dq, dk and dv inside the VMEM limit."""
+    from horovod_tpu.ops import sparse_attention as sa
+
+    monkeypatch.setattr(sa, "_VMEM_LIMIT", limit_mb * 1024 * 1024)
+    T = 16384
+    q = tpu.shape((4, 8, T, 128), jnp.bfloat16)
+    kv = tpu.shape((4, T, 128), jnp.bfloat16)
+    row = tpu.shape((4, 8, T, 8), jnp.float32)
+    assert sa._fused_bwd_fits(T, 128)
+
+    def f(q, k, v, mask, do, lse, delta):
+        return sa._bwd_call(q, k, v, mask, do, lse, delta,
+                            **sa._kw(q, 128 ** -0.5, 4, fused_bwd=True))
+
+    text = tpu.compile(f, q, kv, kv, tpu.shape((1, T, T), jnp.int8), q,
+                       row, row).as_text()
+    assert _custom_calls(text, "hvd_sparse_attn_bwd") == 1
+    assert "hvd_sparse_attn_bwd_d" not in text
 
 
 @pytest.mark.parametrize("kept,index_calls", [
@@ -202,8 +247,6 @@ def test_saved_selection_leaves_one_index_kernel(tpu, real_kernels, kept,
     is packed and unpacked in passes that put no second ``[T, T]`` array
     into HBM (the whole mask compared before it is cut, or the bytes
     broadcast before they are shifted)."""
-    import re
-
     from horovod_tpu.ops import sparse_attention as sa
 
     T = 2048
@@ -221,13 +264,11 @@ def test_saved_selection_leaves_one_index_kernel(tpu, real_kernels, kept,
 
     text = tpu.compile(f, *_sparse_shapes(tpu, T)).as_text()
 
-    def calls(name):
-        return len(re.findall(rf"%{name}[.\d]* = [^\n]*custom-call\(", text))
-
-    assert calls("hvd_index_select") == index_calls
-    for name in ("hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd_dq",
-                 "hvd_sparse_attn_bwd_dkv"):
-        assert calls(name) == 1
+    assert _custom_calls(text, "hvd_index_select") == index_calls
+    for name in ("hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd"):
+        assert _custom_calls(text, name) == 1
+    for name in ("hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv"):
+        assert _custom_calls(text, name) == 0
     if "SELECTION_NAME" in kept:
         assert f"u8[1,{T // 8},{T}]" in text
         for whole in (f"u8[1,{T},{T}]", f"u8[8,{T // 8},{T}]"):
