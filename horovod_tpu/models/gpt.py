@@ -23,15 +23,29 @@ offset by the chip's shard index automatically.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..common.basics import LOCAL_AXIS
 from ..parallel import sequence as seqpar
+
+
+def _layer_norm(cfg, name: str, x):
+    """``nn.LayerNorm`` under the device scope ``hvd.norm``."""
+    with jax.named_scope("hvd.norm"):
+        return nn.LayerNorm(dtype=cfg.dtype, name=name)(x)
+
+
+def _dense_mlp(cfg, x):
+    """The block's dense MLP under the device scope ``hvd.mlp``."""
+    with jax.named_scope("hvd.mlp"):
+        return _MLP(cfg, name="mlp")(x)
 
 
 def _tp_size(cfg) -> int:
@@ -128,15 +142,23 @@ class _Attention(nn.Module):
                     f"{cfg.attention!r}; use disjoint mesh axes")
         H = cfg.num_heads // tp   # local heads (column-parallel qkv)
         D = C // cfg.num_heads
-        qkv = nn.Dense(3 * H * D, dtype=cfg.dtype, name="qkv",
-                       kernel_init=nn.initializers.normal(0.02))(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        with jax.named_scope("hvd.attn_proj"):
+            qkv = nn.Dense(3 * H * D, dtype=cfg.dtype, name="qkv",
+                           kernel_init=nn.initializers.normal(0.02))(x)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
         # The 4-D views are free: ``flash_attention`` folds them back and
         # its kernels index [B, T, H * D] as it lies (in the compiled step
         # the split's three slices are the forward kernel's operands).
         q = q.reshape(B, T, H, D)
         k = k.reshape(B, T, H, D)
         v = v.reshape(B, T, H, D)
+
+        def project(out):
+            with jax.named_scope("hvd.attn_proj"):
+                return nn.Dense(C, dtype=cfg.dtype, name="proj",
+                                kernel_init=nn.initializers.normal(
+                                    0.02 / (2 * cfg.num_layers) ** 0.5))(out)
+
         if decode is not None:
             # Paged single-token decode (serve/kv_cache.py): append this
             # step's k/v to the layer's page pool, attend over the slot's
@@ -157,10 +179,7 @@ class _Attention(nn.Module):
             out = kvlib.paged_attention(
                 q, cache.k[layer], cache.v[layer], cache.page_table,
                 meta.attend_len, ring_axis=cfg.kv_ring_axis)
-            out = out.reshape(B, T, H * D)
-            out = nn.Dense(C, dtype=cfg.dtype, name="proj",
-                           kernel_init=nn.initializers.normal(
-                               0.02 / (2 * cfg.num_layers) ** 0.5))(out)
+            out = project(out.reshape(B, T, H * D))
             out = lax.psum(out, cfg.tp_axis) if tp > 1 else out
             return out, cache
         if cfg.attention == "ring":
@@ -188,10 +207,7 @@ class _Attention(nn.Module):
             raise ValueError(
                 f"unknown attention {cfg.attention!r}; expected "
                 f"dense | flash | ring | flash_ring | ulysses")
-        out = out.reshape(B, T, H * D)
-        out = nn.Dense(C, dtype=cfg.dtype, name="proj",
-                       kernel_init=nn.initializers.normal(
-                           0.02 / (2 * cfg.num_layers) ** 0.5))(out)
+        out = project(out.reshape(B, T, H * D))
         # Row-parallel: each rank holds the rows for its heads; partial
         # results sum across the tp axis (biases are sliced 1/tp so the
         # psum restores the dense model's single bias).
@@ -254,7 +270,7 @@ class _Block(nn.Module):
             # T=1 decode never pays the Pallas call.
             cache, meta, layer = decode
             attn_out, cache = _Attention(cfg, name="attn")(
-                nn.LayerNorm(dtype=cfg.dtype, name="ln1")(x),
+                _layer_norm(cfg, "ln1", x),
                 decode=(cache, meta, layer))
             x = x + attn_out
             if cfg.moe_experts:
@@ -268,11 +284,11 @@ class _Block(nn.Module):
                     pair_capacity_factor=cfg.moe_pair_capacity_factor,
                     name="moe")
             else:
-                ffn = _MLP(cfg, name="mlp")
-            x = x + ffn(nn.LayerNorm(dtype=cfg.dtype, name="ln2")(x))
+                ffn = functools.partial(_dense_mlp, cfg)
+            x = x + ffn(_layer_norm(cfg, "ln2", x))
             return x, cache
         attn_out = _Attention(cfg, name="attn")(
-            nn.LayerNorm(dtype=cfg.dtype, name="ln1")(x))
+            _layer_norm(cfg, "ln1", x))
         if not cfg.fused_ln:
             x = x + attn_out
         if cfg.moe_experts:
@@ -285,13 +301,13 @@ class _Block(nn.Module):
                             pair_capacity_factor=cfg.moe_pair_capacity_factor,
                             name="moe")
         else:
-            ffn = _MLP(cfg, name="mlp")
+            ffn = functools.partial(_dense_mlp, cfg)
         if cfg.fused_ln:
             # One pass: h = x + attn_out (the stream continues through
             # h), m = ln2(h) — the Pallas kernel's HBM saving.
             m, h = _FusedLNAdd(cfg, name="ln2")(x, attn_out)
             return h + ffn(m)
-        x = x + ffn(nn.LayerNorm(dtype=cfg.dtype, name="ln2")(x))
+        x = x + ffn(_layer_norm(cfg, "ln2", x))
         return x
 
 
@@ -339,13 +355,14 @@ class GPT(nn.Module):
             raise ValueError(
                 f"global sequence length {T_local * n_shards} exceeds "
                 f"max_seq_len={cfg.max_seq_len}")
-        x = (wte[tokens] + wpe[pos][None]).astype(cfg.dtype)
+        with jax.named_scope("hvd.embed"):
+            x = (wte[tokens] + wpe[pos][None]).astype(cfg.dtype)
         block = _Block
         if cfg.remat:
             block = nn.remat(_Block)
         for i in range(cfg.num_layers):
             x = block(cfg, name=f"h{i}")(x)
-        x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
+        x = _layer_norm(cfg, "ln_f", x)
         if cfg.return_hidden:
             return x
         # Tied embedding head. Inputs in the compute dtype (bf16 feeds the
@@ -392,20 +409,22 @@ class GPT(nn.Module):
         meta = kvlib.step_meta(cache, active,
                                page_size=int(cache.k.shape[2]),
                                ring_axis=cfg.kv_ring_axis)
-        if windowed:
-            W = tokens.shape[1]
-            pos = jnp.clip(cache.seq_lens[:, None] + jnp.arange(W)[None],
-                           0, cfg.max_seq_len - 1)
-            x = (wte[tokens] + wpe[pos]).astype(cfg.dtype)
-        else:
-            pos = jnp.clip(cache.seq_lens, 0, cfg.max_seq_len - 1)
-            x = (wte[tokens] + wpe[pos]).astype(cfg.dtype)[:, None, :]
+        with jax.named_scope("hvd.embed"):
+            if windowed:
+                W = tokens.shape[1]
+                pos = jnp.clip(
+                    cache.seq_lens[:, None] + jnp.arange(W)[None],
+                    0, cfg.max_seq_len - 1)
+                x = (wte[tokens] + wpe[pos]).astype(cfg.dtype)
+            else:
+                pos = jnp.clip(cache.seq_lens, 0, cfg.max_seq_len - 1)
+                x = (wte[tokens] + wpe[pos]).astype(cfg.dtype)[:, None, :]
         block = _Block
         if cfg.remat:
             block = nn.remat(_Block)
         for i in range(cfg.num_layers):
             x, cache = block(cfg, name=f"h{i}")(x, decode=(cache, meta, i))
-        x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
+        x = _layer_norm(cfg, "ln_f", x)
         if windowed:
             logits = jnp.einsum("swc,vc->swv", x, wte.astype(cfg.dtype),
                                 preferred_element_type=jnp.float32)

@@ -154,21 +154,24 @@ class SparseMoEConfig:
 
 
 def rms_norm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (y * scale).astype(x.dtype)
+    with jax.named_scope("hvd.norm"):
+        x32 = x.astype(jnp.float32)
+        y = x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+        return (y * scale).astype(x.dtype)
 
 
 def rope(x, theta: float):
     """Rotary embedding over the last dim of x [B, T, heads, D], positions
     0..T-1, halves rotated; float32 angles."""
     T, D = x.shape[1], x.shape[-1]
-    inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None]
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
+    with jax.named_scope("hvd.rotary"):
+        inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+        ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None]
+        cos = jnp.cos(ang)[None, :, None, :]
+        sin = jnp.sin(ang)[None, :, None, :]
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               -1).astype(x.dtype)
 
 
 class _Scale(nn.Module):
@@ -197,7 +200,8 @@ class _Indexer(nn.Module):
 
         def proj(name, n):
             w = self.param(name, init, (d, n), jnp.float32)
-            return u @ lax.stop_gradient(w).astype(cfg.dtype)
+            with jax.named_scope("hvd.attn_proj"):
+                return u @ lax.stop_gradient(w).astype(cfg.dtype)
 
         qi = rope(proj("wq", Hi * Di).reshape(B, T, Hi, Di), cfg.rope_theta)
         ki = rope(proj("wk", Di)[:, :, None, :], cfg.rope_theta)[:, :, 0]
@@ -221,17 +225,19 @@ class _Attention(nn.Module):
                     cfg.head_dim)
         init = nn.initializers.normal(cfg.initializer_range)
 
-        def w(name, *shape):
-            return self.param(name, init, shape, jnp.float32).astype(
-                cfg.dtype)
+        def proj(x, name, *shape):
+            w = self.param(name, init, shape, jnp.float32)
+            with jax.named_scope("hvd.attn_proj"):
+                return x @ w.astype(cfg.dtype)
 
         def head_norm(name, x):
             scale = self.param(name, nn.initializers.ones, (D,), jnp.float32)
             return rms_norm(x, scale, cfg.rms_norm_eps)
 
-        q = head_norm("q_norm", (u @ w("wq", d, H * D)).reshape(B, T, H, D))
-        k = head_norm("k_norm", (u @ w("wk", d, Hk * D)).reshape(B, T, Hk, D))
-        v = (u @ w("wv", d, Hk * D)).reshape(B, T, Hk, D)
+        q = head_norm("q_norm", proj(u, "wq", d, H * D).reshape(B, T, H, D))
+        k = head_norm("k_norm",
+                      proj(u, "wk", d, Hk * D).reshape(B, T, Hk, D))
+        v = proj(u, "wv", d, Hk * D).reshape(B, T, Hk, D)
         if cfg.rope_layers == "all" or kind == SLIDING:
             q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
         if kind == SPARSE:
@@ -242,8 +248,8 @@ class _Attention(nn.Module):
                 window=cfg.sliding_window if kind == SLIDING else None)
         o = o.reshape(B, T, H * D)
         if cfg.attention_gate:
-            o = _output_gate(o, u @ w("wg", d, H * D))
-        return o @ w("wo", H * D, d)
+            o = _output_gate(o, proj(u, "wg", d, H * D))
+        return proj(o, "wo", H * D, d)
 
 
 class _GatedMLP(nn.Module):
@@ -324,7 +330,9 @@ class _Block(nn.Module):
             u, *index))
         z = norm("ln2", h)
         if i < cfg.num_dense_layers:
-            m, load = _GatedMLP(cfg, cfg.intermediate_size, name="mlp")(z), None
+            with jax.named_scope("hvd.mlp"):
+                m = _GatedMLP(cfg, cfg.intermediate_size, name="mlp")(z)
+            load = None
         else:
             m, load = _MoE(cfg, name="moe")(z)
         return h + after("ln2_post", m), load
@@ -348,9 +356,10 @@ class SparseMoEDecoder(nn.Module):
                            (cfg.vocab_size, cfg.hidden_size), jnp.float32)
         head = self.param("head", init,
                           (cfg.vocab_size, cfg.hidden_size), jnp.float32)
-        x = embed.astype(cfg.dtype)[tokens]
-        if cfg.embed_scale != 1.0:
-            x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
+        with jax.named_scope("hvd.embed"):
+            x = embed.astype(cfg.dtype)[tokens]
+            if cfg.embed_scale != 1.0:
+                x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
         block = nn.remat(
             _Block, policy=jax.checkpoint_policies.save_only_these_names(
                 OUT_NAME, SELECTION_NAME, _flash.OUT_NAME))

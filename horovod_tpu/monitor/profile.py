@@ -91,7 +91,6 @@ def profile_window(num_steps: int, logdir: Optional[str] = None):
         tl.begin("profile", "PROFILE:WINDOW")
         tl.instant("PROFILE:START", tid="profile",
                    args={"logdir": logdir, "num_steps": num_steps})
-    _registry.counter("profile.windows").inc()
     jax.profiler.start_trace(logdir)
     try:
         yield win

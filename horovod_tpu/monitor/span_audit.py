@@ -45,7 +45,7 @@ KNOWN_PREFIXES = frozenset({
 })
 
 #: The ``jax.named_scope`` vocabulary of the compiled step: each name is
-#: opened at one place in ``parallel/`` or ``ops/`` and lands in the
+#: opened at one place in ``parallel/``, ``ops/`` or a model and lands in the
 #: ``op_name`` of every HLO op traced under it, which the device trace
 #: carries (docs/observability.md "Scopes in the device trace"). Scopes
 #: nest; JAX adds the direction (``jvp(`` / ``transpose(``) itself. The
@@ -60,6 +60,11 @@ DEVICE_SCOPES = (
     "hvd.sparse_indexer",    # ops/sparse_attention.py: scores + selection
     "hvd.moe_ffn",           # moe/layer.py: dropless router..combine
     "hvd.shared_expert",     # models/sparse_moe_decoder.py: beside moe_ffn
+    "hvd.norm",              # models/: every norm outside ops/layer_norm.py
+    "hvd.rotary",            # models/sparse_moe_decoder.py: rope
+    "hvd.attn_proj",         # models/: q / k / v / gate / output matmuls
+    "hvd.mlp",               # models/: a block's dense MLP
+    "hvd.embed",             # models/: token (+ position) lookup
     "hvd.router_bias_update",  # moe/layer.py: the balancing bias, a step
     "hvd.allreduce_grads",   # parallel/optimizer.py, tape.py: grad exchange
     "hvd.bucket_pack",       # ops/fusion.py: leaves -> flat bucket

@@ -186,9 +186,9 @@ def _acct(kind: str, wire_bytes: float, fp_bytes: Optional[float] = None):
 def bench_gbps() -> tuple:
     """(ici, dcn, pod) modeled link bandwidths in GB/s — the
     HOROVOD_BENCH_{ICI,DCN,POD}_GBPS knobs behind every modeled-time
-    number (``modeled_wire_ms``, the per-bucket latency histograms,
-    the cost model's static defaults: docs/cost-model.md). The pod knob defaults to the DCN value, so 2-level
-    meshes and unset-knob runs behave exactly as before."""
+    number (``modeled_wire_ms``, the cost model's static defaults:
+    docs/cost-model.md). The pod knob defaults to the DCN value, so
+    2-level meshes and unset-knob runs behave exactly as before."""
     ici = float(os.environ.get("HOROVOD_BENCH_ICI_GBPS", "100"))
     dcn = float(os.environ.get("HOROVOD_BENCH_DCN_GBPS", "25"))
     pod = float(os.environ.get("HOROVOD_BENCH_POD_GBPS", str(dcn)))
@@ -310,11 +310,6 @@ def overlap_stream(kind: str, bucket_id):
             r = _metrics.default_registry()
             r.counter("comm.streamed_buckets", kind=kind).inc()
             r.histogram("comm.bucket.bytes").observe(delta)
-            # µs, not ms: the log2 buckets need the resolution (a small
-            # bucket's modeled transfer is far under a millisecond).
-            r.histogram("comm.bucket.latency_us").observe(
-                modeled_wire_ms(own.ici_bytes, own.dcn_bytes,
-                                own.pod_bytes) * 1e3)
         if tl is not None:
             tl.end(tid, activity)
 
@@ -367,8 +362,6 @@ def _acct_kv(hop: str, wire_bytes: float,
     _acct(hop, wire_bytes, fp_bytes)
     if _metrics.metrics_enabled():
         _metrics.counter("comm.kv.bytes", hop=hop).inc(wire_bytes)
-        if transfers:
-            _metrics.counter("comm.kv.transfers", hop=hop).inc(transfers)
     for ws in _wire_recorders:
         ws.kv_bytes += wire_bytes
         ws.kv_bytes_fp += wire_bytes if fp_bytes is None else fp_bytes

@@ -190,7 +190,6 @@ class Supervisor:
         decision = self.engine.record_failure(
             _policy.CLASS_PREEMPTION, key=source)
         reg = self._registry
-        reg.counter("resilience.preempt.notices").inc()
         saved_step = None
         committed = False
         if (self._snapshot_provider is not None
@@ -242,7 +241,6 @@ class Supervisor:
         reg.counter("resilience.preempt.snapshots",
                     verdict=("deadline_met" if deadline_met
                              else "deadline_missed")).inc()
-        reg.gauge("resilience.preempt.snapshot_ms").set(elapsed_ms)
         _timeline_instant("RESILIENCE:PREEMPT", event)
         with self._lock:
             self._preempt_log.append(event)
@@ -268,8 +266,6 @@ class Supervisor:
             self._restarts += 1
             n = self._restarts
         self._registry.counter("resilience.restarts").inc()
-        self._registry.gauge("resilience.restart_budget_left").set(
-            max(0, self.restart_budget - n))
         _timeline_instant("RESILIENCE:RESTART",
                           {"restored_step": restored_step, "count": n,
                            "budget": self.restart_budget})

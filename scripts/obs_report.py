@@ -195,7 +195,6 @@ def build_report(timeline_path, metrics_path, flight_dir=None):
                       "pod_gbps": pod_gbps},
         },
         "streamed_buckets": gauges.get("comm.wire.streamed_buckets", 0.0),
-        "bucket_latency_hist": hists.get("comm.bucket.latency_us"),
         "step_time_hist": hists.get("step.time_ms"),
         "eager_calls": {k: v for k, v in counters.items()
                         if k.startswith("comm.eager.calls")},

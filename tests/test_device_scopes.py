@@ -16,6 +16,7 @@ import pytest
 
 import horovod_tpu as hvd
 from horovod_tpu.compile.cache import persistent_cache_disabled
+from horovod_tpu.monitor import hlo_owners
 from horovod_tpu.monitor.span_audit import DEVICE_SCOPES
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import layer_norm as ln
@@ -46,10 +47,17 @@ SPARSE_STEP = {"hvd.sparse_attention", "hvd.sparse_indexer", "hvd.moe_ffn"}
 AFMOE_STEP = {"hvd.flash_window", "hvd.shared_expert",
               "hvd.router_bias_update"}
 OFF_STEP = {"hvd.layer_norm"} | SPARSE_STEP | AFMOE_STEP
+# The decoder block's names (models/): the programs that hold each. Only
+# the mixture decoder rotates, and the tiny sparse step has no dense layer.
+BLOCK_STEPS = {"hvd.norm": ("gpt", "sparse", "afmoe"),
+               "hvd.attn_proj": ("gpt", "sparse", "afmoe"),
+               "hvd.embed": ("gpt", "sparse", "afmoe"),
+               "hvd.mlp": ("gpt", "afmoe"),
+               "hvd.rotary": ("sparse", "afmoe")}
 # The indexer is forward only: no gradient reaches it.
 DIFFERENTIATED = {"hvd.grad", "hvd.lm_head_loss", "hvd.flash_attention",
                   "hvd.layer_norm", "hvd.sparse_attention", "hvd.moe_ffn",
-                  "hvd.flash_window", "hvd.shared_expert"}
+                  "hvd.flash_window", "hvd.shared_expert"} | set(BLOCK_STEPS)
 NESTED_IN = {"hvd.lm_head_loss": "hvd.grad",
              "hvd.flash_attention": "hvd.grad",
              "hvd.sparse_attention": "hvd.grad",
@@ -57,6 +65,7 @@ NESTED_IN = {"hvd.lm_head_loss": "hvd.grad",
              "hvd.moe_ffn": "hvd.grad",
              "hvd.flash_window": "hvd.flash_attention",
              "hvd.shared_expert": "hvd.grad",
+             **{scope: "hvd.grad" for scope in BLOCK_STEPS},
              "hvd.bucket_pack": "hvd.allreduce_grads",
              "hvd.bucket_allreduce": "hvd.allreduce_grads",
              "hvd.bucket_unpack": "hvd.allreduce_grads"}
@@ -66,47 +75,47 @@ def _op_names(text: str) -> list:
     return re.findall(r'op_name="([^"]*)"', text)
 
 
-def _step_text(n_devices: int) -> str:
-    session = gpt_decoder.build(tiny.CONFIG, tiny.JOB,
-                                jax.devices()[:n_devices])
+STEPS = {"gpt": (gpt_decoder, tiny), "sparse": (sparse_moe_decoder,
+                                                tiny_sparse),
+         "afmoe": (afmoe, tiny_afmoe)}
+
+
+def _step_text(step: str, n_devices: int = 1) -> str:
+    builder, module = STEPS[step]
+    session = builder.build(module.CONFIG, module.JOB,
+                            jax.devices()[:n_devices])
     return session.lower(session.abstract_args()).compile().as_text()
 
 
 @pytest.fixture(scope="module")
-def step_names():
-    """{devices: the op_names of the tiny step's compiled text}."""
+def step_texts():
+    """The compiled text of the three tiny steps on one device (the tiny
+    GPT step, tests/benchmark/bench_tiny.py, on four too)."""
     try:
-        yield {n: _op_names(_step_text(n)) for n in (1, 4)}
+        yield {**{step: _step_text(step) for step in STEPS},
+               "gpt4": _step_text("gpt", 4)}
     finally:
         hvd.shutdown()     # the builder owns init/shutdown: hand the
         hvd.init()         # other tests their mesh back
 
 
 @pytest.fixture(scope="module")
-def sparse_step_names():
-    """The op_names of the tiny sparse-attention / routed-experts step
-    (tests/benchmark/bench_tiny_sparse.py), one device."""
-    try:
-        session = sparse_moe_decoder.build(
-            tiny_sparse.CONFIG, tiny_sparse.JOB, jax.devices()[:1])
-        yield _op_names(session.lower(
-            session.abstract_args()).compile().as_text())
-    finally:
-        hvd.shutdown()
-        hvd.init()
+def step_names(step_texts):
+    """{devices: the op_names of the tiny GPT step's compiled text}."""
+    return {1: _op_names(step_texts["gpt"]), 4: _op_names(step_texts["gpt4"])}
 
 
 @pytest.fixture(scope="module")
-def afmoe_step_names():
+def sparse_step_names(step_texts):
+    """The op_names of the tiny sparse-attention / routed-experts step
+    (tests/benchmark/bench_tiny_sparse.py), one device."""
+    return _op_names(step_texts["sparse"])
+
+
+@pytest.fixture(scope="module")
+def afmoe_step_names(step_texts):
     """The op_names of the tiny afmoe step, one device."""
-    try:
-        session = afmoe.build(tiny_afmoe.CONFIG, tiny_afmoe.JOB,
-                              jax.devices()[:1])
-        yield _op_names(session.lower(
-            session.abstract_args()).compile().as_text())
-    finally:
-        hvd.shutdown()
-        hvd.init()
+    return _op_names(step_texts["afmoe"])
 
 
 @pytest.fixture(scope="module")
@@ -148,7 +157,12 @@ def test_scope_reaches_the_compiled_program(scope, step_names,
                                             layer_norm_names,
                                             sparse_step_names,
                                             afmoe_step_names):
-    if scope in SPARSE_STEP:
+    by_step = {"gpt": step_names[1], "sparse": sparse_step_names,
+               "afmoe": afmoe_step_names}
+    if scope in BLOCK_STEPS:
+        programs = {f"{step} decoder": by_step[step]
+                    for step in BLOCK_STEPS[scope]}
+    elif scope in SPARSE_STEP:
         programs = {"sparse decoder": sparse_step_names}
     elif scope in AFMOE_STEP:
         programs = {"afmoe decoder": afmoe_step_names}
@@ -253,20 +267,62 @@ def _stripped(text: str) -> str:
     return re.sub(r"%[A-Za-z_][\w.\-]*", "%", text)
 
 
-def test_scopes_are_metadata_only(monkeypatch):
+@pytest.mark.parametrize("step", sorted(STEPS))
+def test_scopes_are_metadata_only(step, monkeypatch):
     """The optimized program is the same with every scope taken out: the
-    names cost nothing at run time."""
+    names cost nothing at run time. All three tiny steps, so the decoder
+    block's names (models/) are among them."""
     # JAX's persistent cache leaves metadata out of its key: with it on,
     # the second compile would be handed the first one's text.
     try:
         with persistent_cache_disabled():
-            with_scopes = _step_text(1)
+            with_scopes = _step_text(step)
             monkeypatch.setattr(jax, "named_scope",
                                 lambda name: contextlib.nullcontext())
-            without = _step_text(1)
+            without = _step_text(step)
     finally:
         monkeypatch.undo()
         hvd.shutdown()
         hvd.init()
-    assert "hvd.grad" in with_scopes and "hvd.grad" not in without
+    for scope in ("hvd.grad", *(s for s, steps in BLOCK_STEPS.items()
+                                if step in steps)):
+        assert scope in with_scopes and scope not in without, scope
     assert _stripped(with_scopes) == _stripped(without)
+
+
+# (step, scope, direction): each of the decoder block's names owns some
+# instruction of a tiny step in that direction (hlo_owners reads the
+# direction off the path: ``remat`` is the forward nn.remat runs again).
+OWNED = [("gpt", "hvd.norm", "forward"), ("gpt", "hvd.norm", "backward"),
+         ("gpt", "hvd.attn_proj", "forward"),
+         ("gpt", "hvd.attn_proj", "backward"),
+         ("gpt", "hvd.mlp", "forward"), ("gpt", "hvd.mlp", "backward"),
+         ("gpt", "hvd.embed", "forward"), ("gpt", "hvd.embed", "backward"),
+         ("sparse", "hvd.rotary", "forward"),
+         ("sparse", "hvd.rotary", "remat"),
+         ("sparse", "hvd.rotary", "backward"),
+         ("sparse", "hvd.norm", "remat"),
+         ("sparse", "hvd.attn_proj", "remat"),
+         ("sparse", "hvd.embed", "backward"),
+         ("afmoe", "hvd.mlp", "remat"), ("afmoe", "hvd.mlp", "backward"),
+         ("afmoe", "hvd.rotary", "remat"), ("afmoe", "hvd.norm", "remat")]
+
+
+@pytest.fixture(scope="module")
+def step_owners(step_texts):
+    return {step: hlo_owners.owners(step_texts[step]) for step in STEPS}
+
+
+@pytest.mark.parametrize("step, scope, direction", OWNED)
+def test_block_scope_owns_instructions_in_each_direction(
+        step, scope, direction, step_owners):
+    owned = step_owners[step]
+    whole = [name for name, shares in owned.items()
+             if shares == {(scope, direction): 1.0}]
+    assert whole, f"no instruction of the {step} step is {scope}'s, " \
+                  f"{direction}"
+    # the embedding is outside the rematerialised blocks, and no GPT-2
+    # cell rematerialises
+    if step == "gpt" or scope == "hvd.embed":
+        assert not any((scope, "remat") in shares
+                       for shares in owned.values())
