@@ -35,10 +35,24 @@ Two published families are built from their own ``config.json`` keys
 RMSNorm, per-head RMSNorm on q and k, no bias anywhere, an untied head.
 bfloat16 activations and matmul operands with float32 accumulation;
 float32 parameters, norms, rotary angles, softmax statistics and router
-scores. Each block is rematerialised in the backward pass and keeps the
-attention kernel's output and log-sum-exp rows (``jax.checkpoint`` with
-``save_only_these_names``; a sparse layer also its selection at one bit a
-pair): the recomputed forward then runs no attention kernel.
+scores. Each block is rematerialised in the backward pass
+(``jax.checkpoint`` with ``save_only_these_names``). It always keeps its
+input, the attention kernel's output and log-sum-exp rows and (a sparse
+layer) its selection at one bit a pair: the recomputed forward runs no
+attention kernel. Beside them it keeps what is dear to make and cheap to
+hold, in this order while all that the blocks keep, over all layers, stays
+under ``KEEP_SHARE`` of the device's memory (:func:`remat_kept`: the
+configuration, ``B``, ``T`` and the widths decide, nothing else): the
+experts' plan (``PLAN_NAME``: no top-k and no sort again); under sandwich
+norms, whose backward reads them, the MLP's or the mixture's output
+(``MLP_OUT_NAME``: the experts are not walked a third time) and the
+attention block's (``ATTN_OUT_NAME``); the q / k / v projections
+(``QKV_NAME``) and the output gate's (``GATE_NAME``); the two hidden
+projections of a dense MLP and a shared expert (``MLP_HIDDEN_NAME``). What
+is still made again is elementwise (norms, rotation, gates, products, the
+weights' bfloat16 casts) and the router's matmul. Trace-time counters:
+``remat.kept_bytes{value=<name>}`` and ``remat.kept_names``, once a
+block.
 
 Initial weights: normal(``initializer_range``) for every matrix and the
 embedding, ones for every RMSNorm scale, zeros for a router's bias.
@@ -51,6 +65,7 @@ zero gradient (ROADMAP R0).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -58,14 +73,36 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
-from ..moe.layer import moe_ffn_dropless, router_bias_update
+from ..moe.layer import (PLAN_NAME, moe_ffn_dropless, plan_bytes,
+                         router_bias_update)
 from ..ops import flash_attention as _flash
 from ..ops.sparse_attention import (OUT_NAME, SELECTION_NAME,
                                     sparse_attention)
 
 SPARSE, SLIDING, FULL = "sparse", "sliding_attention", "full_attention"
 BIAS_COLLECTION = "router_bias"
+
+# ``checkpoint_name``s of a block's values (``PLAN_NAME`` is the expert
+# layer's own): what :func:`remat_kept` chooses among.
+MLP_OUT_NAME = "hvd_block_mlp_out"
+ATTN_OUT_NAME = "hvd_block_attn_out"
+QKV_NAME = "hvd_block_qkv"
+GATE_NAME = "hvd_block_gate"
+MLP_HIDDEN_NAME = "hvd_block_mlp_hidden"
+
+#: The share of the device's memory that what the blocks keep, over all
+#: layers, may take: what they keep whatever the rule says (input, attention
+#: output, selection) and the candidates in their order. At an eighth
+#: ``trinity-mini`` (0.51 GB kept anyway) keeps all six candidates, 1.43 GB
+#: more, and its compiled step stands at 12.25 GB of 16 (11.56 with none);
+#: the sparse cell (1.42 GB anyway, one 16k sequence) keeps the plan, 14.68
+#: GB, and not its 1.0 GB of q / k / v, with which it would stand at 15.68
+#: (PERF.md, PR 38, has the compiled step at each set).
+KEEP_SHARE = 0.125
+#: A device that reports no memory (the CPU) is taken for a TPU v5e.
+ASSUMED_MEMORY_BYTES = 16 * 2 ** 30
 
 
 @dataclass(frozen=True)
@@ -153,6 +190,67 @@ class SparseMoEConfig:
         return cls(**flat)
 
 
+@functools.lru_cache(maxsize=None)
+def device_memory_bytes() -> int:
+    """The first local device's memory, read once; a device that reports
+    none is taken for ``ASSUMED_MEMORY_BYTES``."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit") or ASSUMED_MEMORY_BYTES)
+
+
+def remat_candidates(cfg: SparseMoEConfig, B: int, T: int) -> dict:
+    """``{name: bytes a layer, one entry a layer}`` of what a block's
+    backward would otherwise make again, dearest per byte first, for
+    ``B x T`` tokens a chip: a name a layer does not hold reads 0 there,
+    and a name no layer's backward would read is left out."""
+    n = B * T
+    row = n * jnp.dtype(cfg.dtype).itemsize          # a unit of width
+    H, Hk, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    routed = [i >= cfg.num_dense_layers for i in range(cfg.layers)]
+    plan = plan_bytes(n * cfg.num_experts_per_tok, cfg.num_local_experts)
+    shared = cfg.num_shared_experts * cfg.moe_intermediate_size
+    # Only a norm after attention and MLP reads what they put out.
+    normed = [row * cfg.hidden_size * cfg.sandwich_norms] * cfg.layers
+    each = {
+        PLAN_NAME: [plan * r for r in routed],
+        MLP_OUT_NAME: normed,
+        ATTN_OUT_NAME: normed,
+        QKV_NAME: [row * (H + 2 * Hk) * D] * cfg.layers,
+        GATE_NAME: [row * H * D * cfg.attention_gate] * cfg.layers,
+        MLP_HIDDEN_NAME: [2 * row * (shared if r else cfg.intermediate_size)
+                          for r in routed],
+    }
+    return {name: tuple(by) for name, by in each.items() if any(by)}
+
+
+def remat_kept_anyway(cfg: SparseMoEConfig, B: int, T: int) -> int:
+    """Bytes the blocks keep whatever :func:`remat_kept` says, over all
+    layers: a block's input, the attention kernel's output with a float32
+    log-sum-exp a query head, a sparse layer's selection at a bit a pair."""
+    n, item = B * T, jnp.dtype(cfg.dtype).itemsize
+    H, D = cfg.num_attention_heads, cfg.head_dim
+    sparse = sum(cfg.attention_kind(i) == SPARSE for i in range(cfg.layers))
+    return (cfg.layers * n * ((cfg.hidden_size + H * D) * item + 4 * H)
+            + sparse * B * T * T // 8)
+
+
+def remat_kept(cfg: SparseMoEConfig, B: int, T: int,
+               memory_bytes: Optional[int] = None) -> dict:
+    """The candidates a block keeps: :func:`remat_candidates` in their
+    order while all that the blocks keep (:func:`remat_kept_anyway` and
+    the candidates so far, over all layers) stays within ``KEEP_SHARE`` of
+    the device's memory (``memory_bytes``, else
+    :func:`device_memory_bytes`). Bytes decide and nothing else does."""
+    budget = KEEP_SHARE * (memory_bytes or device_memory_bytes())
+    kept, total = {}, remat_kept_anyway(cfg, B, T)
+    for name, by_layer in remat_candidates(cfg, B, T).items():
+        total += sum(by_layer)
+        if total > budget:
+            break
+        kept[name] = by_layer
+    return kept
+
+
 def rms_norm(x, scale, eps):
     with jax.named_scope("hvd.norm"):
         x32 = x.astype(jnp.float32)
@@ -234,10 +332,13 @@ class _Attention(nn.Module):
             scale = self.param(name, nn.initializers.ones, (D,), jnp.float32)
             return rms_norm(x, scale, cfg.rms_norm_eps)
 
-        q = head_norm("q_norm", proj(u, "wq", d, H * D).reshape(B, T, H, D))
-        k = head_norm("k_norm",
-                      proj(u, "wk", d, Hk * D).reshape(B, T, Hk, D))
-        v = proj(u, "wv", d, Hk * D).reshape(B, T, Hk, D)
+        def qkv(name, heads):
+            return checkpoint_name(proj(u, name, d, heads * D),
+                                   QKV_NAME).reshape(B, T, heads, D)
+
+        q = head_norm("q_norm", qkv("wq", H))
+        k = head_norm("k_norm", qkv("wk", Hk))
+        v = qkv("wv", Hk)
         if cfg.rope_layers == "all" or kind == SLIDING:
             q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
         if kind == SPARSE:
@@ -248,8 +349,9 @@ class _Attention(nn.Module):
                 window=cfg.sliding_window if kind == SLIDING else None)
         o = o.reshape(B, T, H * D)
         if cfg.attention_gate:
-            o = _output_gate(o, proj(u, "wg", d, H * D))
-        return proj(o, "wo", H * D, d)
+            o = _output_gate(o, checkpoint_name(proj(u, "wg", d, H * D),
+                                                GATE_NAME))
+        return checkpoint_name(proj(o, "wo", H * D, d), ATTN_OUT_NAME)
 
 
 class _GatedMLP(nn.Module):
@@ -264,7 +366,8 @@ class _GatedMLP(nn.Module):
         w1, w3 = (self.param(n, init, (d, f), jnp.float32).astype(cfg.dtype)
                   for n in ("w1", "w3"))
         w2 = self.param("w2", init, (f, d), jnp.float32).astype(cfg.dtype)
-        return (nn.silu(z @ w1) * (z @ w3)) @ w2
+        a, b = (checkpoint_name(z @ w, MLP_HIDDEN_NAME) for w in (w1, w3))
+        return (nn.silu(a) * b) @ w2
 
 
 class _MoE(nn.Module):
@@ -335,7 +438,7 @@ class _Block(nn.Module):
             load = None
         else:
             m, load = _MoE(cfg, name="moe")(z)
-        return h + after("ln2_post", m), load
+        return h + after("ln2_post", checkpoint_name(m, MLP_OUT_NAME)), load
 
 
 class SparseMoEDecoder(nn.Module):
@@ -350,6 +453,8 @@ class SparseMoEDecoder(nn.Module):
 
     @nn.compact
     def __call__(self, tokens):
+        from ..monitor.registry import counter
+
         cfg = self.cfg
         init = nn.initializers.normal(cfg.initializer_range)
         embed = self.param("embed", init,
@@ -360,11 +465,15 @@ class SparseMoEDecoder(nn.Module):
             x = embed.astype(cfg.dtype)[tokens]
             if cfg.embed_scale != 1.0:
                 x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
+        kept = remat_kept(cfg, *tokens.shape)
         block = nn.remat(
             _Block, policy=jax.checkpoint_policies.save_only_these_names(
-                OUT_NAME, SELECTION_NAME, _flash.OUT_NAME))
+                OUT_NAME, SELECTION_NAME, _flash.OUT_NAME, *kept))
         loads = {}
         for i in range(cfg.layers):
+            counter("remat.kept_names").inc(len(kept))
+            for name, by_layer in kept.items():
+                counter("remat.kept_bytes", value=name).inc(by_layer[i])
             x, load = block(cfg, i, name=f"h{i}")(x)
             if load is not None:
                 loads[f"h{i}"] = load

@@ -51,6 +51,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..common import basics
 from ..common.basics import EP_AXIS
@@ -91,6 +92,29 @@ def moe_capacity(n_tokens: int, num_experts: int,
 
 SCORINGS = ("softmax", "sigmoid")
 
+#: The ``checkpoint_name`` of what the dropless walk is laid out by: the
+#: chosen experts and their scores here, ``moe_ffn_dropless``'s ``order``
+#: and ``sizes``. A rematerialised block that keeps the name (a few bytes a
+#: token-choice) runs no top-k, no gather and no sort in its recomputed
+#: forward.
+PLAN_NAME = "hvd_moe_plan"
+
+
+@jax.custom_jvp
+def _read_at(probs, experts, vals):
+    """``vals``, which ARE ``probs`` at ``experts`` (a top-k's two results),
+    as a function of ``probs``: the derivative ``lax.top_k``'s own rule
+    gives its values, read at the indices handed in. That rule reads the
+    indices of a top-k it runs for itself, so a kept plan did not spare a
+    recomputed forward the top-k; this one does."""
+    return vals
+
+
+@_read_at.defjvp
+def _read_at_jvp(primals, tangents):
+    _, experts, vals = primals
+    return vals, jnp.take_along_axis(tangents[0], experts, axis=-1)
+
 
 def moe_router(x, router_kernel, *, topk: int = 2,
                router_logits=None, scoring: str = "softmax",
@@ -126,8 +150,10 @@ def moe_router(x, router_kernel, *, topk: int = 2,
         probs = jax.nn.sigmoid(router_logits)
         chosen_by = probs if bias is None else probs + lax.stop_gradient(
             bias.astype(jnp.float32))
-        _, experts = lax.top_k(chosen_by, topk)              # [N, K]
-        gates = jnp.take_along_axis(probs, experts, axis=-1)
+        experts = checkpoint_name(lax.top_k(chosen_by, topk)[1],
+                                  PLAN_NAME)                 # [N, K]
+        gates = checkpoint_name(
+            jnp.take_along_axis(probs, experts, axis=-1), PLAN_NAME)
         if route_norm:
             gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
         gates = gates * route_scale
@@ -136,7 +162,10 @@ def moe_router(x, router_kernel, *, topk: int = 2,
             raise ValueError("a selection bias, route_norm=False and "
                              "route_scale belong to scoring='sigmoid'")
         probs = jax.nn.softmax(router_logits, axis=-1)
-        gate_vals, experts = lax.top_k(probs, topk)          # [N, K]
+        gate_vals, experts = (checkpoint_name(a, PLAN_NAME)  # [N, K]
+                              for a in lax.top_k(lax.stop_gradient(probs),
+                                                 topk))
+        gate_vals = _read_at(probs, experts, gate_vals)
         gates = gate_vals / jnp.maximum(
             jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
     # Load-balance aux (Switch eq. 4): f_e from the TOP-1 assignment
@@ -343,6 +372,14 @@ def rows_grouped(choices: int, held: int) -> int:
     return (-(-choices // GROUP_ALIGN) + held) * GROUP_ALIGN
 
 
+def plan_bytes(choices: int, held: int) -> int:
+    """Bytes of what carries ``PLAN_NAME`` in one
+    :func:`moe_ffn_dropless` call of ``choices`` (N * K) token-choices:
+    the chosen experts, their scores and ``order``, four bytes a choice
+    each, and the held groups' ``sizes``."""
+    return 4 * (3 * choices + held)
+
+
 def _chunk(c, plan, K):
     """Rows ``[c, c + 1) * CHUNK_ROWS`` of the buffer, ``c`` under
     :func:`_trips`: the group they lie in, the token-choice and the token
@@ -525,8 +562,14 @@ def moe_ffn_dropless(x, params, *, experts_per_token: int,
     is the same walk (the hidden rows made again, ``dx`` added to its
     tokens, the gates' gradient, the weight gradients summed in float32 a
     tile's expert at a time), under the scope ``hvd.moe_ffn`` in both
-    directions. Without ``ep_axis`` bound nothing is exchanged; the
-    exchange across ``hvd_ep`` for this path is not built (ROADMAP R1)."""
+    directions. What lays the walk out (the chosen experts, their scores,
+    ``order``, ``sizes``: :func:`plan_bytes`) carries the
+    ``checkpoint_name`` ``PLAN_NAME``, for a rematerialised caller to keep
+    in place of a second top-k and sort; the result ``y`` carries none (a
+    caller whose backward reads it names it itself:
+    ``models/sparse_moe_decoder.py``). Without ``ep_axis`` bound nothing is
+    exchanged; the exchange across ``hvd_ep`` for this path is not built
+    (ROADMAP R1)."""
     if ep_axis is not None and _axis_size(ep_axis) > 1:
         raise NotImplementedError(
             "moe_ffn_dropless: the expert exchange across hvd_ep is not "
@@ -555,6 +598,8 @@ def moe_ffn_dropless(x, params, *, experts_per_token: int,
         _, order = lax.sort((key, jnp.arange(N * K, dtype=jnp.int32)),
                             num_keys=1)
         sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
+        order, sizes = (checkpoint_name(a, PLAN_NAME)
+                        for a in (order, sizes))
         padded = -(-sizes // GROUP_ALIGN) * GROUP_ALIGN
         plan = (order, sizes, jnp.cumsum(sizes) - sizes,
                 jnp.cumsum(padded) - padded, padded)

@@ -47,8 +47,11 @@ rematerialised block two values carry a ``checkpoint_name``: the forward
 kernel's output and log-sum-exp row (``OUT_NAME``, 0.14 GB a layer against
 18 ms of forward kernel) and the packed selection (``SELECTION_NAME``,
 against 7 ms of index kernel and the indexer's projections).
-``models/sparse_moe_decoder.py`` keeps both, so the recomputed forward
-holds neither kernel: what feeds only a saved value is dead there.
+``models/sparse_moe_decoder.py`` keeps both whatever else its blocks keep
+(its ``remat_kept`` adds the experts' plan and, where the device's memory
+allows, projections and block outputs by their own names), so the
+recomputed forward holds neither kernel: what feeds only a saved value is
+dead there.
 
 Trace-time counters (monitor registry): ``sparse_attn.topk``,
 ``sparse_attn.pairs_required`` (sum over queries of min(t + 1, topk), per

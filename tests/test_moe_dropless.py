@@ -555,3 +555,75 @@ def test_scoring_is_counted():
                          route_scale=SCALE)
     assert {k: counter("moe.scoring", kind=k).value - before[k]
             for k in before} == {"softmax": 1, "sigmoid": 1}
+
+
+# -- the walk's plan under a name (``PLAN_NAME``) -----------------------------
+# What lays the walk out carries a ``checkpoint_name``: a rematerialised
+# caller that keeps it runs no top-k and no sort a second time
+# (models/sparse_moe_decoder.py ``remat_kept``).
+
+from horovod_tpu.moe.layer import PLAN_NAME, plan_bytes  # noqa: E402
+
+ROUTERS = {"softmax": {}, "sigmoid": dict(scoring="sigmoid",
+                                          route_scale=SCALE)}
+
+
+def _equations(jaxpr, recomputed=False):
+    """(primitive name, whether inside a ``jax.checkpoint``'s backward
+    equation, equation) of ``jaxpr`` and every jaxpr nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, recomputed, eqn
+        for sub in _jaxprs(eqn):
+            yield from _equations(
+                sub, recomputed or eqn.primitive.name == "remat2")
+
+
+@pytest.mark.parametrize("scoring", sorted(ROUTERS))
+def test_the_plan_carries_its_name(scoring):
+    """Chosen experts, their scores, ``order`` and ``sizes``, and nothing
+    else, carry ``PLAN_NAME``; ``plan_bytes`` is their size."""
+    p, x = _params(6)
+    share = _share(p, 2, 4)
+    named = [eqn.outvars[0].aval for name, _, eqn in _equations(
+        jax.make_jaxpr(lambda x: hvd.moe_ffn_dropless(
+            x, share, experts_per_token=K, first_expert=2,
+            **ROUTERS[scoring])[0])(x).jaxpr)
+        if name == "name" and eqn.params["name"] == PLAN_NAME]
+    assert sorted((a.shape, str(a.dtype)) for a in named) == sorted([
+        ((N, K), "int32"), ((N, K), "float32"), ((N * K,), "int32"),
+        ((4,), "int32")])
+    assert sum(a.size * a.dtype.itemsize for a in named) == plan_bytes(
+        N * K, 4)
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "not_kept"])
+@pytest.mark.parametrize("scoring", sorted(ROUTERS))
+def test_a_kept_plan_is_not_made_again(scoring, kept):
+    """Under ``jax.checkpoint`` a policy that saves ``PLAN_NAME`` leaves no
+    top-k and no sort in the backward pass, one that saves nothing has
+    both again; value and gradients (x, the weights, the router through
+    the gates) are the same to the bit either way."""
+    p, x = _params(7)
+    share = _share(p, 2, 4)
+    ct = jax.random.normal(jax.random.key(8), x.shape)
+    policies = jax.checkpoint_policies
+
+    def loss(policy):
+        layer = jax.checkpoint(lambda x, share: hvd.moe_ffn_dropless(
+            x, share, experts_per_token=K, first_expert=2,
+            **ROUTERS[scoring])[0], policy=policy)
+        return lambda x, share: (jnp.tanh(layer(x, share)) * ct).sum()
+
+    policy = (policies.save_only_these_names(PLAN_NAME) if kept
+              else policies.nothing_saveable)
+    f = jax.value_and_grad(loss(policy), argnums=(0, 1))
+    again = [name for name, recomputed, _ in _equations(
+        jax.make_jaxpr(f)(x, share).jaxpr)
+        if recomputed and name in ("top_k", "sort")]
+    assert sorted(again) == ([] if kept else ["sort", "top_k"])
+    got = jax.jit(f)(x, share)
+    want = jax.jit(jax.value_and_grad(loss(policies.nothing_saveable),
+                                      argnums=(0, 1)))(x, share)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert float(jnp.abs(got[1][1]["router"]).max()) > 0

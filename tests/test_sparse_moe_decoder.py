@@ -10,8 +10,11 @@ import numpy as np
 import optax
 import pytest
 
+from benchmarks.lib import reference_afmoe as afref
 from benchmarks.lib import reference_sparse_moe as ref
 from horovod_tpu.models import SparseMoEConfig, SparseMoEDecoder
+from horovod_tpu.models import sparse_moe_decoder as decoder
+from test_afmoe_decoder import CFG as AFMOE
 
 CFG = {"layers": 2, "num_hidden_layers": 2, "hidden_size": 64,
        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
@@ -210,3 +213,208 @@ def test_return_hidden_feeds_the_untied_head():
     logp = jax.nn.log_softmax(logits, -1)
     want = -jnp.take_along_axis(logp, toks[:, 1:, None], -1).mean()
     np.testing.assert_allclose(float(loss), float(want), rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# What a rematerialised block keeps (models/sparse_moe_decoder.py
+# ``remat_kept``): both families at this file's size.
+# ---------------------------------------------------------------------------
+
+FAMILIES = {"sparse": (CFG, ref), "afmoe": (AFMOE, afref)}
+THE_THREE_NAMES_OF_BEFORE = "the_three_names_of_before"
+
+
+def _family(family, seed=None, **overrides):
+    """(model, loss of the parameters, parameters: abstract without a
+    ``seed``) of a family at the tests' size; the ``afmoe`` model is applied
+    with its zero router biases."""
+    cfg, lib = FAMILIES[family]
+    sizes = lib.sizes_from_config(cfg)
+    make = functools.partial(lib.make_params, s=sizes)
+    params = (jax.eval_shape(make, jax.ShapeDtypeStruct((), jnp.uint32))
+              if seed is None else jax.jit(make)(jnp.uint32(seed)))
+    model = SparseMoEDecoder(SparseMoEConfig.from_dict(cfg, **overrides))
+    state = ({} if family == "sparse"
+             else {"router_bias": afref.zero_biases(sizes)})
+    toks = _tokens(7)
+
+    def loss(p):
+        logits = model.apply({"params": p, **state}, toks[:, :-1])
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
+        return -jnp.take_along_axis(logp, toks[:, 1:, None], -1).sum()
+    return model, loss, params
+
+
+def _keep(monkeypatch, kept):
+    if kept == THE_THREE_NAMES_OF_BEFORE:
+        monkeypatch.setattr(decoder, "remat_kept", lambda *a, **k: {})
+
+
+def _grouped_matmuls(jaxpr):
+    return sum(eqn.primitive.name.startswith("ragged_dot") + sum(
+        _grouped_matmuls(sub) for sub in jax.core.jaxprs_in_params(
+            eqn.params)) for eqn in jaxpr.eqns)
+
+
+def _recomputed(jaxpr, inside=False, found=None):
+    """Primitive name -> equations inside the ``checkpoint`` equations of
+    ``jaxpr`` (a backward pass: the recomputed forward and the transposes
+    beside it), a loop's body left out; a ``while`` counts as ``walk_fwd``
+    / ``walk_bwd`` by the 3 or 5 grouped matmuls of its body."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        if name == "remat2":
+            name = "checkpoint"
+        if name == "while":
+            name = {3: "walk_fwd", 5: "walk_bwd"}.get(
+                sum(map(_grouped_matmuls, subs)), name)
+            subs = []
+        if inside:
+            found[name] = found.get(name, 0) + 1
+        for sub in subs:
+            _recomputed(sub, inside or name == "checkpoint", found)
+    return found
+
+
+@pytest.mark.parametrize("kept", ["the_rule", THE_THREE_NAMES_OF_BEFORE])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_recomputed_forward_walks_no_expert_and_sorts_nothing(
+        monkeypatch, family, kept):
+    """Under the rule a block's recomputed forward holds no walk of the
+    experts, no top-k, no gather of the chosen scores and no sort: the plan
+    is kept, and under sandwich norms (``afmoe``) the mixture's output,
+    which the post-norm's backward reads. The backward's own walk stays,
+    once a routed layer. With the three names of before, the forward walk,
+    the top-k, the sort and the sigmoid router's gather (the softmax
+    router's scores are the top-k's own) are all there a second time."""
+    _keep(monkeypatch, kept)
+    model, loss, params = _family(family)
+    routed = model.cfg.layers - model.cfg.num_dense_layers
+    found = _recomputed(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    again = 0 if kept == "the_rule" else routed
+    assert found["walk_bwd"] == routed
+    # Without sandwich norms nothing reads the mixture's output and the
+    # walk was never run again (the sparse family).
+    assert {name: found.get(name, 0) for name in
+            ("walk_fwd", "top_k", "sort", "gather", "while")} == {
+        "walk_fwd": again * model.cfg.sandwich_norms, "top_k": again,
+        "sort": again, "gather": again * (model.cfg.scoring == "sigmoid"),
+        "while": 0}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_kept_values_leave_loss_and_gradients_equal(monkeypatch, family,
+                                                    dtype):
+    """A kept value is the value the recomputation would have made: the
+    jitted loss and EVERY gradient leaf under the rule are bit for bit
+    those under the three names of before."""
+    def run():
+        _, loss, params = _family(family, seed=11, dtype=jnp.dtype(dtype))
+        return jax.jit(jax.value_and_grad(loss)).lower(params).compile(
+            compiler_options={"xla_allow_excess_precision": False})(params)
+
+    loss, grads = run()
+    _keep(monkeypatch, THE_THREE_NAMES_OF_BEFORE)
+    want_loss, want = run()
+    assert float(loss) == float(want_loss)
+    got, want = ref.path_dict(grads), ref.path_dict(want)
+    assert set(got) == set(want)
+    differing = [leaf for leaf in want if not np.array_equal(
+        np.asarray(got[leaf]), np.asarray(want[leaf]))]
+    assert differing == []
+
+
+def _cell(name, seq_len):
+    import json
+    import os
+
+    from benchmarks.lib import manifest
+
+    with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        return SparseMoEConfig.from_dict(json.load(f)), 1, seq_len
+
+
+V5E = decoder.ASSUMED_MEMORY_BYTES
+ALL_SIX = (decoder.PLAN_NAME, decoder.MLP_OUT_NAME, decoder.ATTN_OUT_NAME,
+           decoder.QKV_NAME, decoder.GATE_NAME, decoder.MLP_HIDDEN_NAME)
+
+
+@pytest.mark.parametrize("config, seq_len, memory, want, gb", [
+    ("trinity-mini", 8192, V5E, ALL_SIX, 1.429),
+    ("keye-vl2-30b-a3b", 16384, V5E, ALL_SIX[:1], 0.009),
+    ("trinity-mini", 32768, V5E, ALL_SIX[:1], 0.013),
+    ("keye-vl2-30b-a3b", 32768, V5E, (), 0),
+    ("trinity-mini", 8192, V5E // 2, ALL_SIX[:3], 0.339),
+    ("trinity-mini", 8192, V5E // 8, (), 0),
+    ("keye-vl2-30b-a3b", 16384, V5E // 8, (), 0),
+], ids=lambda v: str(v) if isinstance(v, (str, int)) else "")
+def test_the_rule_keeps_by_bytes(config, seq_len, memory, want, gb):
+    """``trinity-mini``'s shape keeps all six candidates and the sparse
+    cell's the plan alone: its blocks keep 1.42 GB whatever the rule says
+    and its q / k / v would be 1.0 GB more (PERF.md, PR 38). Four times /
+    twice the sequence, or a half / an eighth of the memory, keep the
+    shorter prefix that fits ``KEEP_SHARE`` of it, down to nothing."""
+    cell = _cell(config, seq_len)
+    kept = decoder.remat_kept(*cell, memory_bytes=memory)
+    assert tuple(kept) == want
+    total = sum(sum(by_layer) for by_layer in kept.values())
+    assert round(total / 1e9, 3) == gb
+    held = decoder.remat_kept_anyway(*cell) + total
+    assert not kept or held <= decoder.KEEP_SHARE * memory
+    candidates = decoder.remat_candidates(*cell)
+    if len(kept) < len(candidates):
+        following = list(candidates.values())[len(kept)]
+        assert held + sum(following) > decoder.KEEP_SHARE * memory
+
+
+@pytest.mark.parametrize("reported, memory", [
+    (None, V5E), ({}, V5E), ({"bytes_limit": V5E // 8}, V5E // 8)],
+    ids=["no_stats", "no_limit", "an_eighth"])
+def test_the_rule_reads_the_devices_memory_once(monkeypatch, reported,
+                                                memory):
+    """A device that reports no memory (the CPU's ``memory_stats()`` is
+    None) gives the 16 GB answer; one that does is believed, and asked
+    once."""
+    asked = []
+
+    class Device:
+        def memory_stats(self):
+            asked.append(1)
+            return reported
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [Device()])
+    decoder.device_memory_bytes.cache_clear()
+    try:
+        cell = _cell("trinity-mini", 8192)
+        assert decoder.remat_kept(*cell) == decoder.remat_kept(
+            *cell, memory_bytes=memory)
+        assert decoder.device_memory_bytes() == memory and asked == [1]
+    finally:
+        decoder.device_memory_bytes.cache_clear()
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_kept_bytes_are_counted_once_a_block(family):
+    """``remat.kept_bytes`` by value sums to the rule's bytes over a trace's
+    blocks and ``remat.kept_names`` to the names a block; what the policy
+    names is in the traced program under that name."""
+    from horovod_tpu.monitor.registry import counter
+
+    model, loss, params = _family(family)
+    kept = decoder.remat_kept(model.cfg, 1, T)
+    assert len(kept) == {"sparse": 2, "afmoe": 6}[family]   # all, at T = 64
+    counters = {name: counter("remat.kept_bytes", value=name)
+                for name in kept}
+    names = counter("remat.kept_names")
+    before = {name: c.value for name, c in counters.items()}, names.value
+    text = str(jax.make_jaxpr(loss)(params))
+    assert {name: c.value - before[0][name]
+            for name, c in counters.items()} == {
+        name: sum(by_layer) for name, by_layer in kept.items()}
+    assert names.value - before[1] == len(kept) * model.cfg.layers
+    for name in kept:
+        assert f"name={name}" in text
