@@ -8,6 +8,7 @@ from .sparse_moe_decoder import (  # noqa: F401
     SparseMoEDecoder,
     update_router_biases,
 )
+from .sambay import SambaY, SambaYConfig  # noqa: F401
 from .resnet import (  # noqa: F401
     ResNet,
     ResNet18,

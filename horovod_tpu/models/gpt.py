@@ -36,10 +36,11 @@ from ..common.basics import LOCAL_AXIS
 from ..parallel import sequence as seqpar
 
 
-def _layer_norm(cfg, name: str, x):
-    """``nn.LayerNorm`` under the device scope ``hvd.norm``."""
+def _layer_norm(cfg, name: str, x, eps: float = 1e-6):
+    """``nn.LayerNorm`` under the device scope ``hvd.norm`` (``eps``: flax's
+    default unless a family publishes its own)."""
     with jax.named_scope("hvd.norm"):
-        return nn.LayerNorm(dtype=cfg.dtype, name=name)(x)
+        return nn.LayerNorm(epsilon=eps, dtype=cfg.dtype, name=name)(x)
 
 
 def _dense_mlp(cfg, x):

@@ -234,16 +234,16 @@ def remat_kept_anyway(cfg: SparseMoEConfig, B: int, T: int) -> int:
             + sparse * B * T * T // 8)
 
 
-def remat_kept(cfg: SparseMoEConfig, B: int, T: int,
-               memory_bytes: Optional[int] = None) -> dict:
-    """The candidates a block keeps: :func:`remat_candidates` in their
-    order while all that the blocks keep (:func:`remat_kept_anyway` and
-    the candidates so far, over all layers) stays within ``KEEP_SHARE`` of
-    the device's memory (``memory_bytes``, else
-    :func:`device_memory_bytes`). Bytes decide and nothing else does."""
+def kept_within(candidates: dict, anyway: int,
+                memory_bytes: Optional[int] = None) -> dict:
+    """``candidates`` (``{name: bytes a layer}``) in their order while all
+    that the blocks keep (``anyway`` and the candidates so far, over all
+    layers) stays within ``KEEP_SHARE`` of the device's memory
+    (``memory_bytes``, else :func:`device_memory_bytes`). Bytes decide and
+    nothing else does."""
     budget = KEEP_SHARE * (memory_bytes or device_memory_bytes())
-    kept, total = {}, remat_kept_anyway(cfg, B, T)
-    for name, by_layer in remat_candidates(cfg, B, T).items():
+    kept, total = {}, anyway
+    for name, by_layer in candidates.items():
         total += sum(by_layer)
         if total > budget:
             break
@@ -251,11 +251,26 @@ def remat_kept(cfg: SparseMoEConfig, B: int, T: int,
     return kept
 
 
+def remat_kept(cfg: SparseMoEConfig, B: int, T: int,
+               memory_bytes: Optional[int] = None) -> dict:
+    """The candidates a block keeps: :func:`remat_candidates` in their
+    order within :func:`kept_within`'s budget, beside
+    :func:`remat_kept_anyway`."""
+    return kept_within(remat_candidates(cfg, B, T),
+                       remat_kept_anyway(cfg, B, T), memory_bytes)
+
+
+def rms_norm_in_scope(x, scale, eps):
+    """RMSNorm over the last dim in float32, under the caller's device
+    scope."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
 def rms_norm(x, scale, eps):
     with jax.named_scope("hvd.norm"):
-        x32 = x.astype(jnp.float32)
-        y = x32 * lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-        return (y * scale).astype(x.dtype)
+        return rms_norm_in_scope(x, scale, eps)
 
 
 def rope(x, theta: float):
@@ -306,6 +321,18 @@ class _Indexer(nn.Module):
         return qi, ki, proj("ww", Hi).astype(jnp.float32)
 
 
+def causal_attention(q, k, v, window: Optional[int] = None,
+                     scale: Optional[float] = None):
+    """The models' one call of the flash kernels: causal, grouped KV heads,
+    ``window`` keys a query where given. ``scale`` is handed on only where
+    given, so that a caller that gives none makes the call it always made,
+    ``causal`` and ``window`` alone: ``tests/benchmark/test_bench_afmoe.py``
+    puts a stand-in of that signature in the kernels' place."""
+    extra = {} if scale is None else {"scale": scale}
+    return _flash.flash_attention(q, k, v, causal=True, window=window,
+                                  **extra)
+
+
 def _output_gate(o, g):
     """o * sigmoid(g), the sigmoid in float32."""
     return o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(o.dtype)
@@ -344,9 +371,8 @@ class _Attention(nn.Module):
         if kind == SPARSE:
             o = sparse_attention(q, k, v, *index, topk=cfg.topk)
         else:
-            o = _flash.flash_attention(
-                q, k, v, causal=True,
-                window=cfg.sliding_window if kind == SLIDING else None)
+            o = causal_attention(
+                q, k, v, cfg.sliding_window if kind == SLIDING else None)
         o = o.reshape(B, T, H * D)
         if cfg.attention_gate:
             o = _output_gate(o, checkpoint_name(proj(u, "wg", d, H * D),

@@ -60,6 +60,10 @@ DEVICE_SCOPES = (
     "hvd.sparse_indexer",    # ops/sparse_attention.py: scores + selection
     "hvd.moe_ffn",           # moe/layer.py: dropless router..combine
     "hvd.shared_expert",     # models/sparse_moe_decoder.py: beside moe_ffn
+    "hvd.ssm",               # models/sambay.py: a state-space mixer
+    "hvd.selective_scan",    # ops/selective_scan.py: kernels + their layout
+    "hvd.gmu",               # models/sambay.py: a gated memory unit
+    "hvd.diff_attention",    # models/sambay.py: around the flash call
     "hvd.norm",              # models/: every norm outside ops/layer_norm.py
     "hvd.rotary",            # models/sparse_moe_decoder.py: rope
     "hvd.attn_proj",         # models/: q / k / v / gate / output matmuls

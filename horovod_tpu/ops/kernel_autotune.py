@@ -46,6 +46,7 @@ _loaded = False
 _KERNEL_VERSIONS: Dict[str, int] = {
     "flash_attention": 2,   # 2: sub-tiles inside the grid cell (PR 25)
     "linear_xent": 1,
+    "selective_scan": 1,
 }
 
 
@@ -492,3 +493,59 @@ def xent_blocks(N: int, V: int, C: int, dtype,
         return dt
 
     return get_or_tune("linear_xent", sig, cands, bench, default)
+
+
+def scan_blocks(B: int, T: int, Dn: int, N: int, default: Tuple[int, int],
+                candidates: Sequence[Tuple[int, int]],
+                pick_chunk) -> Tuple[int, int]:
+    """Autotuned (chunk, block_d) for a selective scan over [B, T, Dn]
+    with N states, among ``candidates`` deduplicated by the chunk they snap
+    to on T (``pick_chunk``). Forward and backward are timed together: the
+    backward holds a chunk's states in VMEM and is the one a large chunk
+    overflows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sig = f"B{B}.T{T}.D{Dn}.N{N}"
+    if 1.0 * B * T * Dn * N < 1e8:   # a toy scan: don't sweep
+        return default
+    seen, cands = set(), []
+    for chunk, block_d in candidates:
+        eff = (pick_chunk(T, chunk, N), block_d)
+        if eff[0] is None or eff in seen:
+            continue
+        seen.add(eff)
+        cands.append((chunk, block_d))
+    if len(cands) <= 1:
+        return default
+
+    cal = {"chain": None}
+
+    def bench(cand):
+        chunk, block_d = cand
+        from .selective_scan import selective_scan
+
+        rs = np.random.RandomState(0)
+        x = jnp.asarray(rs.randn(B, T, Dn), jnp.float32)
+        dt = jnp.asarray(rs.uniform(1e-3, 1e-1, (B, T, Dn)), jnp.float32)
+        A = -jnp.asarray(np.tile(np.arange(1.0, N + 1), (Dn, 1)),
+                         jnp.float32)
+        Bm = jnp.asarray(rs.randn(B, T, N), jnp.float32)
+        Cm = jnp.asarray(rs.randn(B, T, N), jnp.float32)
+        skip = jnp.ones((Dn,), jnp.float32)
+
+        def step(x, dt, A, Bm, Cm, skip):
+            g = jax.grad(lambda *ops: selective_scan(
+                *ops, chunk=chunk, block_d=block_d).sum(),
+                argnums=tuple(range(6)))(x, dt, A, Bm, Cm, skip)
+            # Every gradient into the carry (see flash_blocks): one that
+            # nothing reads would be dropped with the work that makes it.
+            rest = g[1] + g[2].sum() + g[3].sum() + g[4].sum() + g[5]
+            return x + 1e-8 * (g[0] + rest)
+
+        dt_s, cal["chain"] = _timed_chain(step, (x, dt, A, Bm, Cm, skip),
+                                          chain=cal["chain"])
+        return dt_s
+
+    return get_or_tune("selective_scan", sig, cands, bench, default)

@@ -26,10 +26,12 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "benchmark"))
 import bench_tiny as tiny  # noqa: E402
 import bench_tiny_afmoe as tiny_afmoe  # noqa: E402
+import bench_tiny_sambay as tiny_sambay  # noqa: E402
 import bench_tiny_sparse as tiny_sparse  # noqa: E402
 
-from benchmarks.builders import (afmoe, gpt_decoder,  # noqa: E402
+from benchmarks.builders import (afmoe, gpt_decoder, sambay,  # noqa: E402
                                  sparse_moe_decoder)
+from horovod_tpu.ops import selective_scan as scan  # noqa: E402
 from horovod_tpu.ops import sparse_attention as spa  # noqa: E402
 
 KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv",
@@ -38,7 +40,8 @@ KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv",
            "hvd_xent_fwd", "hvd_xent_bwd_dx", "hvd_xent_bwd_dw",
            "hvd_ln_fwd", "hvd_ln_bwd", "hvd_index_select",
            "hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd",
-           "hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv")
+           "hvd_sparse_attn_bwd_dq", "hvd_sparse_attn_bwd_dkv",
+           "hvd_selective_scan_fwd", "hvd_selective_scan_bwd")
 # The tiny GPT step runs the unfused LayerNorm, as every cell does today,
 # and none of the sparse decoder's work: that has a tiny step of its own.
 SPARSE_STEP = {"hvd.sparse_attention", "hvd.sparse_indexer", "hvd.moe_ffn"}
@@ -46,18 +49,23 @@ SPARSE_STEP = {"hvd.sparse_attention", "hvd.sparse_indexer", "hvd.moe_ffn"}
 # (tests/benchmark/bench_tiny_afmoe.py).
 AFMOE_STEP = {"hvd.flash_window", "hvd.shared_expert",
               "hvd.router_bias_update"}
-OFF_STEP = {"hvd.layer_norm"} | SPARSE_STEP | AFMOE_STEP
+# Nor a state-space layer, a gated memory unit or differential attention:
+# the tiny sambay step's (tests/benchmark/bench_tiny_sambay.py).
+SAMBAY_STEP = {"hvd.ssm", "hvd.selective_scan", "hvd.gmu",
+               "hvd.diff_attention"}
+OFF_STEP = {"hvd.layer_norm"} | SPARSE_STEP | AFMOE_STEP | SAMBAY_STEP
 # The decoder block's names (models/): the programs that hold each. Only
 # the mixture decoder rotates, and the tiny sparse step has no dense layer.
-BLOCK_STEPS = {"hvd.norm": ("gpt", "sparse", "afmoe"),
-               "hvd.attn_proj": ("gpt", "sparse", "afmoe"),
-               "hvd.embed": ("gpt", "sparse", "afmoe"),
-               "hvd.mlp": ("gpt", "afmoe"),
+BLOCK_STEPS = {"hvd.norm": ("gpt", "sparse", "afmoe", "sambay"),
+               "hvd.attn_proj": ("gpt", "sparse", "afmoe", "sambay"),
+               "hvd.embed": ("gpt", "sparse", "afmoe", "sambay"),
+               "hvd.mlp": ("gpt", "afmoe", "sambay"),
                "hvd.rotary": ("sparse", "afmoe")}
 # The indexer is forward only: no gradient reaches it.
 DIFFERENTIATED = {"hvd.grad", "hvd.lm_head_loss", "hvd.flash_attention",
                   "hvd.layer_norm", "hvd.sparse_attention", "hvd.moe_ffn",
-                  "hvd.flash_window", "hvd.shared_expert"} | set(BLOCK_STEPS)
+                  "hvd.flash_window", "hvd.shared_expert"} | set(
+                      BLOCK_STEPS) | SAMBAY_STEP
 NESTED_IN = {"hvd.lm_head_loss": "hvd.grad",
              "hvd.flash_attention": "hvd.grad",
              "hvd.sparse_attention": "hvd.grad",
@@ -65,6 +73,8 @@ NESTED_IN = {"hvd.lm_head_loss": "hvd.grad",
              "hvd.moe_ffn": "hvd.grad",
              "hvd.flash_window": "hvd.flash_attention",
              "hvd.shared_expert": "hvd.grad",
+             "hvd.ssm": "hvd.grad", "hvd.selective_scan": "hvd.ssm",
+             "hvd.gmu": "hvd.grad", "hvd.diff_attention": "hvd.grad",
              **{scope: "hvd.grad" for scope in BLOCK_STEPS},
              "hvd.bucket_pack": "hvd.allreduce_grads",
              "hvd.bucket_allreduce": "hvd.allreduce_grads",
@@ -77,7 +87,7 @@ def _op_names(text: str) -> list:
 
 STEPS = {"gpt": (gpt_decoder, tiny), "sparse": (sparse_moe_decoder,
                                                 tiny_sparse),
-         "afmoe": (afmoe, tiny_afmoe)}
+         "afmoe": (afmoe, tiny_afmoe), "sambay": (sambay, tiny_sambay)}
 
 
 def _step_text(step: str, n_devices: int = 1) -> str:
@@ -89,7 +99,7 @@ def _step_text(step: str, n_devices: int = 1) -> str:
 
 @pytest.fixture(scope="module")
 def step_texts():
-    """The compiled text of the three tiny steps on one device (the tiny
+    """The compiled text of the four tiny steps on one device (the tiny
     GPT step, tests/benchmark/bench_tiny.py, on four too)."""
     try:
         yield {**{step: _step_text(step) for step in STEPS},
@@ -116,6 +126,12 @@ def sparse_step_names(step_texts):
 def afmoe_step_names(step_texts):
     """The op_names of the tiny afmoe step, one device."""
     return _op_names(step_texts["afmoe"])
+
+
+@pytest.fixture(scope="module")
+def sambay_step_names(step_texts):
+    """The op_names of the tiny sambay step, one device."""
+    return _op_names(step_texts["sambay"])
 
 
 @pytest.fixture(scope="module")
@@ -156,9 +172,10 @@ def sparse_split_names(monkeypatch):
 def test_scope_reaches_the_compiled_program(scope, step_names,
                                             layer_norm_names,
                                             sparse_step_names,
-                                            afmoe_step_names):
+                                            afmoe_step_names,
+                                            sambay_step_names):
     by_step = {"gpt": step_names[1], "sparse": sparse_step_names,
-               "afmoe": afmoe_step_names}
+               "afmoe": afmoe_step_names, "sambay": sambay_step_names}
     if scope in BLOCK_STEPS:
         programs = {f"{step} decoder": by_step[step]
                     for step in BLOCK_STEPS[scope]}
@@ -166,6 +183,8 @@ def test_scope_reaches_the_compiled_program(scope, step_names,
         programs = {"sparse decoder": sparse_step_names}
     elif scope in AFMOE_STEP:
         programs = {"afmoe decoder": afmoe_step_names}
+    elif scope in SAMBAY_STEP:
+        programs = {"sambay decoder": sambay_step_names}
     elif scope in OFF_STEP:
         programs = {"layer_norm": layer_norm_names}
     else:
@@ -239,7 +258,10 @@ def kernel_names():
         _sparse_attention_loss(),
         (lambda x: sx.linear_cross_entropy(x, w, lab).sum(), x),
         (lambda x: ln.ln_residual(x, x, g, g)[0].astype(
-            jnp.float32).sum(), x)]
+            jnp.float32).sum(), x),
+        (lambda x: scan.selective_scan(
+            x, x * 0.1, -jnp.ones((128, 16)), x[..., :16], x[..., :16],
+            g.repeat(2)).sum(), jnp.ones((1, 64, 128), jnp.float32))]
     found: set = set()
     for fn, arg in programs:
         _pallas_names(jax.make_jaxpr(jax.grad(fn))(arg).jaxpr, found)
