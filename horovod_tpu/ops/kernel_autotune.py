@@ -46,7 +46,7 @@ _loaded = False
 _KERNEL_VERSIONS: Dict[str, int] = {
     "flash_attention": 2,   # 2: sub-tiles inside the grid cell (PR 25)
     "linear_xent": 1,
-    "selective_scan": 1,
+    "selective_scan": 2,   # 2: the backward a chunk of all channels (PR 40)
 }
 
 

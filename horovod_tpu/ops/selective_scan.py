@@ -23,28 +23,36 @@ a VMEM scratch across them.
 
 * ``hvd_selective_scan_fwd`` writes ``y`` and the state ENTERING each chunk
   (``T / chunk x Dn x N`` float32: what the backward starts a chunk from).
-* ``hvd_selective_scan_bwd`` walks the chunks in reverse. In a chunk it
-  runs the forward again from the saved state, keeping the chunk's states
-  in VMEM, then the reverse recurrence ``g[t] = C[t] dy[t] + a[t+1] g[t+1]``
-  with ``g`` carried across chunks in a scratch. ``dA`` and ``dDskip``
-  accumulate in output blocks that stay resident over the chunks; the
-  products whose sums over the channels are ``dB[t, n]`` and ``dC[t, n]``
-  are kept for the chunk and reduced at its end by two small matmuls (the
-  MXU is otherwise idle), a channel block's part each, summed outside.
+* ``hvd_selective_scan_bwd`` walks the chunks in reverse, a grid step ALL
+  the channels its VMEM holds (``bwd_registers``: the cell's 5120), one
+  register of 1024 at a time. For a register it runs the forward again
+  from the saved state, keeping the chunk's states in VMEM, then the
+  reverse recurrence ``g[t] = C[t] dy[t] + a[t+1] g[t+1]`` with ``a g``
+  carried across chunks in a scratch. ``dA`` and ``dDskip`` accumulate in
+  the loop's carry and reach their resident output blocks once a chunk;
+  ``B[t, n]`` and ``C[t, n]`` are spread to registers once a grid step.
+  The products whose sums over the channels are ``dB[t, n]`` and
+  ``dC[t, n]`` are added up over the step's registers of channels in VMEM
+  and reduced ONCE a grid step on the otherwise idle MXU (three one-pass
+  matmuls of bfloat16-exact pieces: the float32 sum), a channel block's
+  part each, summed outside. What the compiled schedule showed and what
+  each choice bought: docs/selective_scan.md.
 
-``chunk`` and ``block_d`` (channels a block) come from
-ops/kernel_autotune.py (``scan_blocks``: forward and backward timed
-together) unless the caller gives them. A shape the kernels refuse (no
-chunk divides ``T``, or more states than the registers hold; off-TPU, a
-call inside ``shard_map``) takes :func:`selective_scan_reference`, a plain
-``lax.scan`` with the same float32 arithmetic. Trace-time counters:
-``ssm.scan_path{path=kernel|fallback}``,
-``ssm.scan_chunks``, ``ssm.state_bytes``.
+``chunk`` and ``block_d`` (channels a block of the forward, and what the
+channels are padded to) come from ops/kernel_autotune.py (``scan_blocks``:
+forward and backward timed together) unless the caller gives them. A shape
+the kernels refuse (no chunk divides ``T``, or more states than the
+registers hold; off-TPU, a call inside ``shard_map``) takes
+:func:`selective_scan_reference`, a plain ``lax.scan`` with the same
+float32 arithmetic. Trace-time counters:
+``ssm.scan_path{path=kernel|fallback}``, ``ssm.scan_chunks``,
+``ssm.state_bytes``.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -64,10 +72,16 @@ _SMEM_TILE = 1024                   # words: a 1-D SMEM block's unit
 MAX_STATES = 32                     # states carried as registers of a loop
 #: (chunk, block_d) the kernels take where nothing was swept.
 DEFAULT_BLOCKS = (64, 1024)
-#: What the sweep times: the backward holds 2 x (chunk + 1) x N x block_d
-#: float32 in VMEM (8.4 MB at (64, 1024), N = 16; 33 MB at (256, 1024)).
-CANDIDATES = ((64, 1024), (128, 1024), (256, 1024), (64, 2048), (128, 2048))
+#: What the sweep times. The backward's grid step plans its VMEM itself
+#: (``bwd_registers``: 37 MB at chunk 64, N = 16, Dn = 5120; at chunk 128
+#: one register of channels a step, 48 MB); chunk 256 holds 95 MB of
+#: states, sums and scalars and does not compile.
+CANDIDATES = ((64, 1024), (128, 1024), (64, 2048), (128, 2048))
 _VMEM_LIMIT = 64 * 2 ** 20
+_BWD_VMEM = 48 * 2 ** 20            # what a backward grid step may plan for
+_STATE_GROUP = 16                   # states the backward's loops carry
+_LOG2E, _LN2 = math.log2(math.e), math.log(2.0)
+_UNROLL = 4                         # tokens a trip (a chunk is 8 k tokens)
 
 # Under ``jax.checkpoint`` with ``save_only_these_names(OUT_NAME)`` the scan's
 # output and the chunk-boundary states are kept, and the recomputed forward
@@ -149,84 +163,173 @@ def _fwd_kernel(x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref, y_ref, hs_ref,
         h_ref[n] = h[n]
 
 
+def _bf16_pieces(v):
+    """Three float32 arrays of at most 8 significant bits each that sum to
+    ``v`` exactly: a one-pass (bfloat16) matmul of each against zeros and
+    ones is the float32 sum, at half the six passes ``HIGHEST`` makes."""
+    def top(a):
+        bits = lax.bitcast_convert_type(a, jnp.uint32)
+        return lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                        jnp.float32)
+
+    hi = top(v)
+    mid = top(v - hi)
+    return hi, mid, v - hi - mid
+
+
+def _token_loop(L, body, carry):
+    """``fori_loop`` over a chunk's tokens, ``_UNROLL`` of them a trip: the
+    scheduler packs one trip's instructions and nothing across trips."""
+    def trip(i, carry):
+        for k in range(_UNROLL):
+            carry = body(i * _UNROLL + k, carry)
+        return carry
+
+    return lax.fori_loop(0, L // _UNROLL, trip, carry)
+
+
 def _bwd_kernel(x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref, dy_ref, hs_ref,
                 dx_ref, ddt_ref, da_ref, dd_ref, db_ref, dc_ref,
-                g_ref, h_buf, p_buf, *, L, N, rows):
+                g_ref, a2_ref, b_vec, c_vec, h_buf, p_buf, q_buf,
+                *, L, N, subs):
+    """A grid step: a chunk's tokens of ``subs`` registers of channels (1024
+    each). B and C are spread to registers once; then one register of
+    channels at a time, and in it ``_STATE_GROUP`` states at a time (the
+    loops' carries are what the register file holds); then the sums of the
+    products over the step's channels."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         g_ref[...] = jnp.zeros_like(g_ref)
+        # exp(dt A) = 2 ** (dt (A log2 e)): the chip's exponential is a
+        # power of two behind a product, made here once a sequence.
+        a2_ref[...] = a_ref[...] * _LOG2E
         da_ref[...] = jnp.zeros_like(da_ref)
         dd_ref[...] = jnp.zeros_like(dd_ref)
 
-    # The chunk's states again, from the state that entered it:
-    # h_buf[n, t + 1] = h[t], h_buf[n, 0] = the state before the chunk.
-    def again(t, h):
-        h, _ = _token_forward(x_ref[0, t], dt_ref[0, t], a_ref, b_ref,
-                              c_ref, t, h, N)
+    # A token's scalars B[t, n] and C[t, n] as registers, once a grid step
+    # for all its channels: in the loops they are loads, not broadcasts.
+    def spread(t, _):
         for n in range(N):
-            h_buf[n, t + 1] = h[n]
-        return h
+            for ref, vec in ((b_ref, b_vec), (c_ref, c_vec)):
+                vec[t, n] = jnp.full((_SUBLANES, _LANES), ref[t * N + n])
 
-    # The loop starts from the scratch's copy: under ``shard_map`` a value
-    # read from an operand carries the operands' varying mesh axes in its
-    # type and a value computed inside a kernel (or read from a scratch)
-    # carries none, and a loop's carry must keep one type.
-    for n in range(N):
-        h_buf[n, 0] = hs_ref[0, 0, n]
-    h_last = lax.fori_loop(0, L, again,
-                           tuple(h_buf[n, 0] for n in range(N)))
-    skip = d_ref[...]
+    lax.fori_loop(0, L, spread, None)
 
-    def back(i, carry):
-        ga, h_t = carry                  # a[t+1] * g[t+1]; h[t]
-        t = L - 1 - i
-        x, dt, dy = x_ref[0, t], dt_ref[0, t], dy_ref[0, t]
-        dtx = dt * x
-        s = jnp.zeros_like(x)
-        ddt = jnp.zeros_like(x)
-        ga_new, h_prev = [], []
-        for n in range(N):
-            a_n = a_ref[n]
-            a = jnp.exp(dt * a_n)
-            g = dy * c_ref[t * N + n] + ga[n]
-            s = s + g * b_ref[t * N + n]
-            hp = h_buf[n, t]
-            w = g * hp * a
-            ddt = ddt + w * a_n
-            da_ref[0, n] += w * dt
-            ga_new.append(g * a)
-            h_prev.append(hp)
-            p_buf[n, t] = g * dtx            # sums to dB[t, n]
-            h_buf[n, t + 1] = dy * h_t[n]    # sums to dC[t, n]; h[t] is spent
-        dx_ref[0, t] = dy * skip + dt * s
-        ddt_ref[0, t] = x * s + ddt
-        dd_ref[0] += dy * x
-        return tuple(ga_new), tuple(h_prev)
+    def scan(r, ns, first_sub, first_group):
+        """The chunk's two loops for the channels ``r`` (8 rows of 128) and
+        the states ``ns``, eight states' registers as one ``[8, 8, 128]``
+        value (a wider one keeps more alive than the register file holds).
+        The products whose sums over ALL the grid step's channels are
+        dB[t, n] and dC[t, n] are added up register by register in
+        ``p_buf`` / ``q_buf`` (the first one writes)."""
+        # (the eight states among all N, the same among the group's)
+        eights = [(pl.ds(k, min(_SUBLANES, ns.stop - k)),
+                   pl.ds(k - ns.start, min(_SUBLANES, ns.stop - k)))
+                  for k in range(ns.start, ns.stop, _SUBLANES)]
 
-    ga, _ = lax.fori_loop(
-        0, L, back, (tuple(g_ref[n] for n in range(N)), h_last))
-    for n in range(N):
-        g_ref[n] = ga[n]
+        def keep(buf, of_all, t, products):
+            # One load and one store of eight registers: a load placed
+            # after a store to the same scratch waits for it.
+            buf[of_all, t] = (products if first_sub
+                              else buf[of_all, t] + products)
 
-    # The products' sums over a block's channels, on the MXU: over the
-    # lanes ([8, 128] . [L * rows, 128]^T), then over a token's ``rows``
-    # sublanes, which now lie side by side ([8, L * rows] @ [L * rows, L]).
-    ones = jnp.ones((_SUBLANES, _LANES), jnp.float32)
-    token = lax.broadcasted_iota(jnp.int32, (L * rows, L), 0) // rows
-    fold = (token == lax.broadcasted_iota(jnp.int32, (L * rows, L), 1)
+        # The chunk's states again, from the state that entered it:
+        # h_buf[:, t + 1] = h[t], h_buf[:, 0] = the state before the chunk.
+        def again(t, hs):
+            x, dt, dy = x_ref[0, t, r], dt_ref[0, t, r], dy_ref[0, t, r]
+            new = []
+            for (of_all, of_group), h in zip(eights, hs):
+                h = (jnp.exp2(dt * a2_ref[of_all, r]) * h
+                     + (dt * x) * b_vec[t, of_all])
+                h_buf[of_group, t + 1] = h
+                keep(q_buf, of_all, t, dy * h)   # sums to dC[t, n]
+                new.append(h)
+            return tuple(new)
+
+        # The loop starts from the scratch's copy: under ``shard_map`` a
+        # value read from an operand carries the operands' varying mesh
+        # axes in its type and a value computed inside a kernel (or read
+        # from a scratch) carries none, and a loop's carry must keep one
+        # type.
+        for of_all, of_group in eights:
+            h_buf[of_group, 0] = hs_ref[0, 0, of_all, r]
+        _token_loop(L, again, tuple(h_buf[of_group, 0]
+                                    for _, of_group in eights))
+        skip = d_ref[r]
+
+        def back(i, carry):
+            gas, das, dd = carry         # a[t+1] * g[t+1]; dA, dDskip so far
+            t = L - 1 - i
+            x, dt, dy = x_ref[0, t, r], dt_ref[0, t, r], dy_ref[0, t, r]
+            s = ddt = jnp.zeros_like(x)
+            gas_new, das_new = [], []
+            for (of_all, of_group), ga, da in zip(eights, gas, das):
+                a2 = a2_ref[of_all, r]
+                g = dy * c_vec[t, of_all] + ga
+                s = s + (g * b_vec[t, of_all]).sum(0)
+                ag = g * jnp.exp2(dt * a2)
+                w = ag * h_buf[of_group, t]      # h[t - 1]
+                ddt = ddt + (w * a2).sum(0)
+                keep(p_buf, of_all, t, g * (dt * x))     # sums to dB[t, n]
+                gas_new.append(ag)
+                das_new.append(da + w * dt)
+            dx, ddt = dt * s, x * s + ddt * _LN2
+            if first_group:              # and the skip's part, once
+                dx_ref[0, t, r], ddt_ref[0, t, r] = dx + dy * skip, ddt
+                dd = dd + dy * x
+            else:
+                dx_ref[0, t, r] += dx
+                ddt_ref[0, t, r] += ddt
+            return tuple(gas_new), tuple(das_new), dd
+
+        gas = tuple(g_ref[of_all, r] for of_all, _ in eights)
+        gas, das, dd = _token_loop(
+            L, back, (gas, tuple(jnp.zeros_like(ga) for ga in gas),
+                      jnp.zeros((_SUBLANES, _LANES), jnp.float32)))
+        for (of_all, _), ga, da in zip(eights, gas, das):
+            g_ref[of_all, r] = ga
+            da_ref[0, of_all, r] += da
+        if first_group:
+            dd_ref[0, r] += dd
+
+    def register(s, first_sub):
+        r = pl.ds(pl.multiple_of(s * _SUBLANES, _SUBLANES), _SUBLANES)
+        for g0 in range(0, N, _STATE_GROUP):
+            scan(r, range(g0, min(g0 + _STATE_GROUP, N)), first_sub, g0 == 0)
+
+    register(0, True)
+    if subs > 1:
+        lax.fori_loop(1, subs, lambda s, _: register(s, False), None)
+
+    # The products' sums over the grid step's channels, on the MXU: over
+    # the lanes, eight states at a time (state n's products meet a matrix
+    # whose row n % 8 is ones, so that the eight sums land on eight
+    # sublanes), then over a token's 8 sublanes, which now lie side by side
+    # ([2N, L * 8] @ [L * 8, L]).
+    row = lax.broadcasted_iota(jnp.int32, (_SUBLANES, _LANES), 0)
+    token = lax.broadcasted_iota(jnp.int32, (L * _SUBLANES, L), 0) // _SUBLANES
+    fold = (token == lax.broadcasted_iota(jnp.int32, (L * _SUBLANES, L), 1)
             ).astype(jnp.float32)
 
-    def channel_sum(p):                      # [L, rows, 128] -> [1, L]
-        lanes = lax.dot_general(
-            ones, p.reshape(L * rows, _LANES), (((1,), (1,)), ((), ())),
-            precision=lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-        return jnp.dot(lanes, fold, precision=lax.Precision.HIGHEST,
-                       preferred_element_type=jnp.float32)[:1]
+    def lane_sums(buf, g0):                  # 8 states: -> [8, L * 8]
+        out = None
+        for n in range(g0, min(g0 + _SUBLANES, N)):
+            ones = (row == n - g0).astype(jnp.float32)
+            for piece in _bf16_pieces(buf[n].reshape(L * _SUBLANES, _LANES)):
+                part = lax.dot_general(
+                    ones, piece, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                out = part if out is None else out + part
+        return out
 
-    for n in range(N):
-        db_ref[0, 0, 0, pl.ds(n, 1), :] = channel_sum(p_buf[n])
-        dc_ref[0, 0, 0, pl.ds(n, 1), :] = channel_sum(h_buf[n, pl.ds(1, L)])
+    groups = range(0, N, _SUBLANES)
+    lanes = jnp.concatenate([lane_sums(buf, g0) for buf in (p_buf, q_buf)
+                             for g0 in groups], axis=0)
+    sums = jnp.dot(lanes, fold, precision=lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+    half = len(groups) * _SUBLANES
+    db_ref[0, 0, 0] = sums[:N]
+    dc_ref[0, 0, 0] = sums[half:half + N]
 
 
 def _specs(L, N, rows, reverse_of=None):
@@ -286,13 +389,31 @@ def _fwd_call(x, dt, At, Dskip, Bf, Cf, *, L, rows, interpret):
     )(*ops)
 
 
-@functools.partial(jax.jit, inline=True,
-                   static_argnames=("L", "rows", "interpret"))
-def _bwd_call(x, dt, At, Dskip, Bf, Cf, dy, hs, *, L, rows, interpret):
+def bwd_registers(R: int, L: int, N: int) -> int:
+    """Registers of channels (8 rows of 128) a grid step of the backward
+    takes: the most that divide the ``R`` rows and keep the step's VMEM
+    within ``_BWD_VMEM``. In [8, 128] float32 tiles: five token blocks,
+    double-buffered; A, dA and the boundary state likewise and ``a g``
+    once; a group's states of a chunk; the two products' sums and the
+    chunk's scalars B and C as registers."""
+    total = R // _SUBLANES
+    group = min(N, _STATE_GROUP)
+    for subs in range(total, 1, -1):
+        tiles = (10 * L + 8 * N) * subs + (L + 1) * group + 4 * N * L
+        if total % subs == 0 and tiles * 4 * _VREG <= _BWD_VMEM:
+            return subs
+    return 1
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("L", "interpret"))
+def _bwd_call(x, dt, At, Dskip, Bf, Cf, dy, hs, *, L, interpret):
     """-> (dx, ddt [B, T, R, 128]; dAt [B, N, R, 128]; dDskip [B, R, 128];
-    dB, dC [B, R / rows, T / L, N, L]: a channel block's part each)."""
+    dB, dC [B, channel blocks, T / L, N, L]: a channel block's part each)."""
     B, T, R, _ = x.shape
-    N, n_chunks, n_blocks = At.shape[0], T // L, R // rows
+    N, n_chunks = At.shape[0], T // L
+    subs = bwd_registers(R, L, N)
+    rows = subs * _SUBLANES
+    n_blocks = R // rows
     tokens, states, channels, scalars, boundary = _specs(
         L, N, rows, reverse_of=n_chunks)
     ops = (x, dt, At, Dskip, Bf, Cf, dy, hs)
@@ -300,8 +421,9 @@ def _bwd_call(x, dt, At, Dskip, Bf, Cf, dy, hs, *, L, rows, interpret):
                         lambda b, c, j: (b, c, n_chunks - 1 - j, 0, 0))
     part_shape = _out_struct((B, n_blocks, n_chunks, N, L), jnp.float32,
                              *ops)
+    tile = (_SUBLANES, _LANES)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, L=L, N=N, rows=rows),
+        functools.partial(_bwd_kernel, L=L, N=N, subs=subs),
         grid=(B, n_blocks, n_chunks),
         in_specs=[tokens(), tokens(), states(), channels(),
                   scalars(n_chunks), scalars(n_chunks), tokens(),
@@ -317,9 +439,14 @@ def _bwd_call(x, dt, At, Dskip, Bf, Cf, dy, hs, *, L, rows, interpret):
                    _out_struct((B, R, _LANES), jnp.float32, *ops),
                    part_shape, part_shape],
         scratch_shapes=[
-            pltpu.VMEM((N, rows, _LANES), jnp.float32),         # a * g
-            pltpu.VMEM((N, L + 1, rows, _LANES), jnp.float32),  # h, then dC's
-            pltpu.VMEM((N, L, rows, _LANES), jnp.float32),      # dB's
+            pltpu.VMEM((N, rows, _LANES), jnp.float32),             # a * g
+            pltpu.VMEM((N, rows, _LANES), jnp.float32),             # A log2 e
+            pltpu.VMEM((L, N) + tile, jnp.float32),                 # B[t, n]
+            pltpu.VMEM((L, N) + tile, jnp.float32),                 # C[t, n]
+            pltpu.VMEM((min(N, _STATE_GROUP), L + 1) + tile,
+                       jnp.float32),                                # h
+            pltpu.VMEM((N, L) + tile, jnp.float32),                 # dB's
+            pltpu.VMEM((N, L) + tile, jnp.float32),                 # dC's
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=_SEMANTICS, vmem_limit_bytes=_VMEM_LIMIT),
@@ -371,8 +498,7 @@ def _scan_bwd(L, block_d, out_dtype, res, dy):
     N = A.shape[1]
     dx, ddt, dAt, dD, dBp, dCp = _bwd_call(
         *_kernel_operands(x, dt, A, Bm, Cm, Dskip, block_d),
-        _channel_tiles(dy, block_d), hs, L=L, rows=block_d // _LANES,
-        interpret=_interpret())
+        _channel_tiles(dy, block_d), hs, L=L, interpret=_interpret())
 
     def tokens(g, like):
         return g.reshape(B, T, -1)[..., :Dn].astype(like.dtype)

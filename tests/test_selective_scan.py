@@ -64,21 +64,6 @@ def test_values_are_the_token_by_token_scans(shape):
     assert rel_gap(S.selective_scan_reference(*ops), want) < 1e-5
 
 
-@pytest.mark.parametrize("shape", SHAPES, ids=str)
-def test_all_six_gradients_are_the_scans(shape):
-    *sizes, chunk = shape
-    ops = operands(*sizes, seed=1)
-    w = jnp.asarray(np.random.RandomState(2).randn(*ops[0].shape),
-                    jnp.float32)
-    every = tuple(range(6))
-    got = jax.grad(weighted(hvd.selective_scan, w, chunk=chunk,
-                            block_d=1024), every)(*ops)
-    want = jax.grad(weighted(S.selective_scan_reference, w), every)(*ops)
-    for name, a, b in zip(NAMES, got, want):
-        assert a.shape == b.shape and a.dtype == b.dtype, name
-        assert rel_gap(a, b) < 2e-5, name
-
-
 def test_the_state_crosses_a_chunk_boundary():
     """One chunk of 128 and two of 64 give the same output, and the second
     half depends on the first half's inputs."""
@@ -212,3 +197,72 @@ def test_the_sweep_times_legal_blockings_once_each(monkeypatch):
     kernel_autotune.scan_blocks(1, 64, 128, 16, S.DEFAULT_BLOCKS,
                                 S.CANDIDATES, S.pick_chunk)
     assert not seen
+
+
+def _gradient_cases():
+    """(B, T, Dn, N, chunk, block_d): ``SHAPES``; then, for the backward
+    kernel, every candidate blocking that divides T = 512 at 16 states, 8
+    and 32 states, three registers of channels: B = 2, the channels fill no
+    block, and a sequence is at least four chunks, so that ``a g`` crosses
+    chunk boundaries and dA / dDskip are added to more than once."""
+    T = 512
+    return ([(*shape, 1024) for shape in SHAPES]
+            + [(2, T, 1300, 16, chunk, block_d)
+               for chunk, block_d in S.CANDIDATES
+               if S.pick_chunk(T, chunk, 16) == chunk]
+            + [(2, T, 1300, 8, 128, 1024), (2, 256, 1300, 32, 64, 1024),
+               (2, 256, 2100, 16, 64, 1024)])
+
+
+@pytest.mark.parametrize("case", _gradient_cases(), ids=str)
+def test_all_six_gradients_are_the_scans(case):
+    *sizes, chunk, block_d = case
+    ops = operands(*sizes, seed=1)
+    w = jnp.asarray(np.random.RandomState(2).randn(*ops[0].shape),
+                    jnp.float32)
+    every = tuple(range(6))
+    got = jax.jit(jax.grad(weighted(hvd.selective_scan, w, chunk=chunk,
+                                    block_d=block_d), every))(*ops)
+    want = jax.jit(jax.grad(weighted(S.selective_scan_reference, w),
+                            every))(*ops)
+    for name, a, b in zip(NAMES, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert rel_gap(a, b) < 1e-5, name
+
+
+def test_three_bfloat16_pieces_are_the_float32():
+    """What the backward's one-pass matmuls sum: each piece survives a
+    rounding to bfloat16 and the three add up to the value, bit for bit."""
+    v = jnp.asarray(np.random.RandomState(11).randn(8, 128) * 1e3,
+                    jnp.float32)
+    pieces = S._bf16_pieces(v)
+    for piece in pieces:
+        np.testing.assert_array_equal(
+            piece, piece.astype(jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_array_equal(pieces[0] + pieces[1] + pieces[2], v)
+
+
+def test_the_differentiated_program_has_one_backward_kernel_a_scan():
+    """Whatever the backward does a chunk, it is ONE kernel under the name
+    the benchmark's reader takes events by (a second one under another
+    name would put part of the time outside the roofline's share)."""
+    ops = operands(1, 128, 2100, 16, seed=10)
+
+    def two(*o):
+        y = hvd.selective_scan(*o, chunk=64, block_d=1024)
+        return hvd.selective_scan(y, *o[1:], chunk=64, block_d=1024).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(two, tuple(range(6))))(*ops))
+    assert text.count("name=hvd_selective_scan_bwd") == 2
+    assert text.count("name=hvd_selective_scan_fwd") == 2
+    assert text.count("pallas_call[") == 4
+
+
+def test_a_backward_grid_step_takes_the_channels_its_vmem_holds():
+    """All of the cell's 40 rows of channels a step at chunk 64; one
+    register where a chunk's sums leave no room; a divisor of the rows."""
+    assert S.bwd_registers(40, 64, 16) == 5
+    assert S.bwd_registers(40, 128, 16) == 1
+    assert S.bwd_registers(8, 64, 16) == 1
+    assert S.bwd_registers(128, 64, 16) == 8
+    assert S.bwd_registers(24, 64, 32) == 3

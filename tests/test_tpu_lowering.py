@@ -23,6 +23,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 import horovod_tpu as hvd
+from horovod_tpu.ops.selective_scan import CANDIDATES as SCAN_CANDIDATES
 
 
 class _DescribedTpu:
@@ -205,6 +206,37 @@ def test_sparse_attention_compiles(tpu, real_kernels, T, backward):
     for name in {"hvd_sparse_attn_bwd", "hvd_sparse_attn_bwd_dq",
                  "hvd_sparse_attn_bwd_dkv"} - set(backward):
         assert _custom_calls(text, name) == 0, name
+
+
+@pytest.mark.parametrize("T,Dn,N,chunk,block_d", [
+    # phi-4-mini-flash.train-8k-1chip at every blocking its sweep times: the
+    # backward takes all 40 rows of channels a grid step at chunk 64 (37 MB
+    # of VMEM by its own plan), one register of them at chunk 128
+    *((8192, 5120, 16, chunk, block_d)
+      for chunk, block_d in SCAN_CANDIDATES),
+    # two groups of 16 states (dx and ddt added to a second time a token),
+    # channels that fill no block
+    (2048, 2100, 32, 64, 1024),
+    (2048, 1300, 8, 128, 2048),
+])
+def test_selective_scan_compiles(tpu, real_kernels, T, Dn, N, chunk,
+                                 block_d):
+    """The scan's two kernels, forward once and ONE backward kernel."""
+    from horovod_tpu.ops.selective_scan import selective_scan
+
+    tokens = tpu.shape((1, T, Dn), jnp.bfloat16)
+    states = tpu.shape((1, T, N), jnp.bfloat16)
+
+    def f(*ops):
+        return jax.grad(lambda *o: selective_scan(
+            *o, chunk=chunk, block_d=block_d).sum(),
+            argnums=tuple(range(6)))(*ops)
+
+    text = tpu.compile(f, tokens, tpu.shape((1, T, Dn), jnp.float32),
+                       tpu.shape((Dn, N), jnp.float32), states, states,
+                       tpu.shape((Dn,), jnp.float32)).as_text()
+    assert _custom_calls(text, "hvd_selective_scan_fwd") == 1
+    assert _custom_calls(text, "hvd_selective_scan_bwd") == 1
 
 
 @pytest.mark.parametrize("limit_mb", [
