@@ -125,6 +125,10 @@ from .ops.flash_attention import (  # noqa: F401
     flash_attention,
     flash_ring_attention,
 )
+from .ops.block_diffusion import (  # noqa: F401
+    block_diffusion_loss,
+    block_diffusion_noise,
+)
 from .ops.selective_scan import selective_scan  # noqa: F401
 from .ops.sparse_attention import (  # noqa: F401
     index_select,

@@ -8,15 +8,17 @@ layer, and the block is written once.
   ``topk`` keys its indexer scores highest (:func:`hvd.sparse_attention`);
   ``"sliding_attention"`` — causal attention over the last
   ``sliding_window`` keys; ``"full_attention"`` — causal attention over all
-  of them. The last two run :func:`hvd.flash_attention` with grouped KV
-  heads (``window=`` on a sliding layer).
+  of them; ``"block_diffusion"`` — attention under the block-diffusion
+  objective's mask over ``[noised ; clean]`` rows (docs/block_diffusion.md).
+  The last three run :func:`hvd.flash_attention` with grouped KV heads
+  (``window=`` on a sliding layer, ``block_diffusion=`` on the last kind).
 * MLP kind: a dense gated MLP on the first ``num_dense_layers`` layers,
   else routed experts through :func:`hvd.moe_ffn_dropless` (told which
   experts this chip holds), beside ``num_shared_experts`` shared ones that
   every token passes (scope ``hvd.shared_expert``, outside
   ``hvd.moe_ffn``).
 
-Two published families are built from their own ``config.json`` keys
+Three published families are built from their own ``config.json`` keys
 (:meth:`SparseMoEConfig.from_dict`, by ``model_type``):
 
 * Keye-VL-2.0's language model (``sa_config`` present): every layer
@@ -31,6 +33,16 @@ Two published families are built from their own ``config.json`` keys
   carries it beside parameters and optimizer state, and
   :func:`update_router_biases` moves it once a step from the step's expert
   counts (``cfg.return_load`` hands them out).
+
+* ``sdar_moe`` (SDAR): Qwen3-MoE's block (the first family's without the
+  indexer) trained under the block-diffusion objective: every layer
+  ``block_diffusion``, ``block_length`` from the configuration. The model
+  is handed ``[B, 2 L]`` tokens, a noised copy of every sequence and then
+  the clean one (:func:`hvd.block_diffusion_noise` makes them); both
+  halves are rotated by positions ``0 .. L - 1``, every layer runs on all
+  ``2 L`` rows, and the final norm and what follows it see the noised
+  half alone: the hidden states or logits that come out are ``[B, L, ..]``
+  (:func:`hvd.block_diffusion_loss` takes them).
 
 RMSNorm, per-head RMSNorm on q and k, no bias anywhere, an untied head.
 bfloat16 activations and matmul operands with float32 accumulation;
@@ -82,6 +94,7 @@ from ..ops.sparse_attention import (OUT_NAME, SELECTION_NAME,
                                     sparse_attention)
 
 SPARSE, SLIDING, FULL = "sparse", "sliding_attention", "full_attention"
+BLOCK_DIFFUSION = "block_diffusion"
 BIAS_COLLECTION = "router_bias"
 
 # ``checkpoint_name``s of a block's values (``PLAN_NAME`` is the expert
@@ -128,6 +141,7 @@ class SparseMoEConfig:
     # A layer's kinds. ``layer_types`` None: every layer ``sparse``.
     layer_types: Optional[Tuple[str, ...]] = None
     sliding_window: Optional[int] = None
+    block_length: Optional[int] = None   # of ``block_diffusion`` layers
     num_dense_layers: int = 0         # leading layers with a dense MLP ...
     intermediate_size: int = 0        # ... of this width
     num_shared_experts: int = 0
@@ -157,7 +171,9 @@ class SparseMoEConfig:
         """From a ``config.json`` as published; ``layers`` is the depth to
         build where given, else ``num_hidden_layers``. The family is told
         by its keys: a nested ``sa_config`` (the learned indexer) or
-        ``model_type`` ``afmoe``."""
+        ``model_type`` ``afmoe`` or ``sdar_moe`` (``block_length`` is the
+        release's generation setting, not a key of its ``config.json``:
+        the configuration that is built states it)."""
         flat = {k: cfg[k] for k in cls.__dataclass_fields__ if k in cfg}
         flat.setdefault("layers", cfg.get("num_hidden_layers"))
         flat["rope_theta"] = float(cfg["rope_theta"])
@@ -183,9 +199,14 @@ class SparseMoEConfig:
                 rope_layers="sliding",
                 embed_scale=(cfg["hidden_size"] ** 0.5
                              if cfg.get("mup_enabled") else 1.0))
+        elif cfg.get("model_type") == "sdar_moe":
+            for key in ("sliding_window", "intermediate_size"):
+                flat.pop(key, None)   # published, and unused by this family
+            flat.update(layer_types=(BLOCK_DIFFUSION,) * flat["layers"],
+                        block_length=int(cfg["block_length"]))
         else:
-            raise ValueError("neither an sa_config nor model_type afmoe: "
-                             "a family this decoder does not know")
+            raise ValueError("neither an sa_config nor model_type afmoe or "
+                             "sdar_moe: a family this decoder does not know")
         flat.update(overrides)
         return cls(**flat)
 
@@ -273,13 +294,16 @@ def rms_norm(x, scale, eps):
         return rms_norm_in_scope(x, scale, eps)
 
 
-def rope(x, theta: float):
+def rope(x, theta: float, positions=None):
     """Rotary embedding over the last dim of x [B, T, heads, D], positions
-    0..T-1, halves rotated; float32 angles."""
+    0..T-1 or the ``positions [T]`` given, halves rotated; float32
+    angles."""
     T, D = x.shape[1], x.shape[-1]
     with jax.named_scope("hvd.rotary"):
         inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-        ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None]
+        at = (jnp.arange(T, dtype=jnp.float32) if positions is None
+              else positions.astype(jnp.float32))
+        ang = at[:, None] * inv[None]
         cos = jnp.cos(ang)[None, :, None, :]
         sin = jnp.sin(ang)[None, :, None, :]
         x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -333,6 +357,12 @@ def causal_attention(q, k, v, window: Optional[int] = None,
                                   **extra)
 
 
+def block_diffusion_attention(q, k, v, block_length: int):
+    """The flash kernels under the block-diffusion mask: q, k, v hold the
+    noised rows of every sequence and then its clean rows."""
+    return _flash.flash_attention(q, k, v, block_diffusion=block_length)
+
+
 def _output_gate(o, g):
     """o * sigmoid(g), the sigmoid in float32."""
     return o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(o.dtype)
@@ -343,7 +373,7 @@ class _Attention(nn.Module):
     kind: str = SPARSE
 
     @nn.compact
-    def __call__(self, u, index=None):
+    def __call__(self, u, index=None, positions=None):
         cfg, kind = self.cfg, self.kind
         B, T, d = u.shape
         H, Hk, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -367,9 +397,11 @@ class _Attention(nn.Module):
         k = head_norm("k_norm", qkv("wk", Hk))
         v = qkv("wv", Hk)
         if cfg.rope_layers == "all" or kind == SLIDING:
-            q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
+            q, k = (rope(x, cfg.rope_theta, positions) for x in (q, k))
         if kind == SPARSE:
             o = sparse_attention(q, k, v, *index, topk=cfg.topk)
+        elif kind == BLOCK_DIFFUSION:
+            o = block_diffusion_attention(q, k, v, cfg.block_length)
         else:
             o = causal_attention(
                 q, k, v, cfg.sliding_window if kind == SLIDING else None)
@@ -442,7 +474,7 @@ class _Block(nn.Module):
     index: int = 0
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         """(y, the layer's token-choices per expert or None)."""
         cfg, i = self.cfg, self.index
         kind = cfg.attention_kind(i)
@@ -456,7 +488,7 @@ class _Block(nn.Module):
         u = norm("ln1", x)
         index = (_Indexer(cfg, name="indexer")(u),) if kind == SPARSE else ()
         h = x + after("ln1_post", _Attention(cfg, kind, name="attn")(
-            u, *index))
+            u, *index, positions=positions))
         z = norm("ln2", h)
         if i < cfg.num_dense_layers:
             with jax.named_scope("hvd.mlp"):
@@ -474,7 +506,9 @@ class SparseMoEDecoder(nn.Module):
     With ``cfg.return_load`` a pair: that, and ``{layer name: token-choices
     per expert [E]}`` of the routed layers (what
     :func:`update_router_biases` reads). A family with a router bias is
-    applied with its ``router_bias`` collection beside ``params``."""
+    applied with its ``router_bias`` collection beside ``params``. With
+    ``cfg.block_length`` the tokens are ``[B, 2 L]``, noised rows then
+    clean ones, and what comes out is the noised half's, ``[B, L, ..]``."""
     cfg: SparseMoEConfig
 
     @nn.compact
@@ -491,6 +525,14 @@ class SparseMoEDecoder(nn.Module):
             x = embed.astype(cfg.dtype)[tokens]
             if cfg.embed_scale != 1.0:
                 x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
+        positions = None
+        if cfg.block_length is not None:
+            if tokens.shape[1] % (2 * cfg.block_length):
+                raise ValueError(
+                    f"{tokens.shape[1]} rows are not a noised and a clean "
+                    f"copy of whole blocks of {cfg.block_length}")
+            half = tokens.shape[1] // 2
+            positions = jnp.arange(2 * half) % half
         kept = remat_kept(cfg, *tokens.shape)
         block = nn.remat(
             _Block, policy=jax.checkpoint_policies.save_only_these_names(
@@ -500,9 +542,11 @@ class SparseMoEDecoder(nn.Module):
             counter("remat.kept_names").inc(len(kept))
             for name, by_layer in kept.items():
                 counter("remat.kept_bytes", value=name).inc(by_layer[i])
-            x, load = block(cfg, i, name=f"h{i}")(x)
+            x, load = block(cfg, i, name=f"h{i}")(x, positions)
             if load is not None:
                 loads[f"h{i}"] = load
+        if cfg.block_length is not None:
+            x = x[:, :half]    # the head reads the noised half alone
         x = _Scale(cfg.rms_norm_eps, name="ln_f")(x)
         if not cfg.return_hidden:
             x = jnp.einsum("btc,vc->btv", x, head.astype(cfg.dtype),

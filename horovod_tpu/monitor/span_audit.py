@@ -55,6 +55,9 @@ DEVICE_SCOPES = (
     "hvd.lm_head_loss",      # ops/softmax_xent.py: head matmul + xent
     "hvd.flash_attention",   # ops/flash_attention.py: kernels + layout
     "hvd.flash_window",      # ops/flash_attention.py: a windowed call, inside
+    "hvd.flash_block_diffusion",  # ops/flash_attention.py: a call under
+    #                          the block-diffusion mask, inside
+    "hvd.block_diffusion_noise",  # ops/block_diffusion.py: a step's noise
     "hvd.layer_norm",        # ops/layer_norm.py: fused residual + LN
     "hvd.sparse_attention",  # ops/sparse_attention.py: kernels + layout
     "hvd.sparse_indexer",    # ops/sparse_attention.py: scores + selection

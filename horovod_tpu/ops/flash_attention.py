@@ -80,6 +80,21 @@ kernels (guide: /opt/skills/guides/pallas_guide.md):
   static sizes. The three kernels of a windowed call are named
   ``hvd_flash_*_win``. ``window=None`` with one head count compiles what
   this file compiled before it knew either (docs/flash_window.md).
+* the block-diffusion mask (``block_diffusion=B``: the ``2 L`` rows are a
+  noised copy of a sequence and then its clean copy; a clean query sees
+  the clean keys to the END of its own block of ``B`` positions, a noised
+  one the clean keys to the end of the PREVIOUS block and the noised keys
+  of its own block; docs/block_diffusion.md) makes the inner grid axis
+  walk the cells the mask touches and no other (``_bd_k_block``,
+  ``_bd_q_block``): ``L^2 + L B`` pairs a head of the square's
+  ``4 L^2``. ``B`` divides a sub-tile, so an edge runs only through the
+  sub-tiles ON the diagonal of a cell on the diagonal (``_Edge``), whose
+  mask is one compare against 0 of a hoisted difference of BLOCK indices
+  (``row >> log2 B`` minus ``column >> log2 B``); every other cell is
+  bare (``_FULL``) or never visited. A noised row meets its own block
+  first, so its running maximum is finite before any tile it sees nothing
+  of. The kernels are named ``hvd_flash_*_bd``. ``block_diffusion=None``
+  compiles what this file compiled before it knew the mask.
 * :func:`flash_ring_attention` composes the kernels with sequence
   parallelism: K/V blocks rotate around the mesh axis via
   ``lax.ppermute`` while each ring step runs the flash kernel with
@@ -97,8 +112,8 @@ kernel call adds to ``flash.tiles_total`` / ``flash.tiles_computed`` /
 ``bwd_dkv``): the sub-tiles of one head's grid, how many are computed and
 how many of those are masked (16 / 10 / 4 at the benchmark cells' shape,
 in either layout); a windowed call counts under one more label,
-``window``. ``flash.kv_group`` adds the query heads a KV head of every
-grouped call.
+``window``, a block-diffusion call under ``block``. ``flash.kv_group``
+adds the query heads a KV head of every grouped call.
 
 Everything is static-shaped; block sizes adapt to divide the sequence
 (see ``_pick_block`` — a whole-sequence block covers anything <= the
@@ -157,7 +172,8 @@ _DEF_BLOCK_Q = _block_knob("HOROVOD_FLASH_BLOCK_Q", 1024)
 _DEF_BLOCK_K = _block_knob("HOROVOD_FLASH_BLOCK_K", 1024)
 
 
-def _resolve_blocks(B, Tq, Tk, H, D, dtype, causal, window=None, Hkv=None):
+def _resolve_blocks(B, Tq, Tk, H, D, dtype, causal, window=None, Hkv=None,
+                    block_diffusion=None):
     """Block sizes for a flash call that pinned neither block: env knobs
     win; otherwise the kernel autotuner's cached/swept choice (TPU,
     single-process); otherwise the hand-tuned defaults. Multi-process
@@ -179,7 +195,8 @@ def _resolve_blocks(B, Tq, Tk, H, D, dtype, causal, window=None, Hkv=None):
     return kernel_autotune.flash_blocks(
         B, Tq, Tk, H, D, dtype, causal,
         (_DEF_BLOCK_Q, _DEF_BLOCK_K), _pick_block, window=window,
-        kv_heads=None if Hkv in (None, H) else Hkv)
+        kv_heads=None if Hkv in (None, H) else Hkv,
+        block_diffusion=block_diffusion)
 
 
 def _interpret() -> bool:
@@ -291,7 +308,7 @@ def _sub_tiles(mode: str, bq: int, bk: int, sub_tile):
     ``mode``. Only a cell that can skip is cut up: every cut costs each
     kernel a column of row statistics per sub-tile ([tq, 1]: as many vregs
     as half a [tq, 256] pass), which only skipped sub-tiles pay back."""
-    if mode != _SKIP and not isinstance(mode, _Band):
+    if mode != _SKIP and not isinstance(mode, (_Band, _Edge)):
         return bq, bk
     return _sub_tile(bq, sub_tile[0]), _sub_tile(bk, sub_tile[1])
 
@@ -419,10 +436,92 @@ def _band_cells(window: int, b: int, nkb: int):
     return cells
 
 
+class _Edge(NamedTuple):
+    """A grid cell of a BLOCK-DIFFUSION call that a block edge crosses
+    (docs/block_diffusion.md). Rows are ``[noised ; clean]``, ``L`` of
+    each, position ``p`` in block ``p // B``; blocks and sub-tiles are
+    square and ``B`` divides a sub-tile, so only the sub-tiles ON the
+    cell's diagonal hold an edge, and what the others do is known when
+    the kernel is traced, as in a band. ``edge`` says which cell:
+
+    * ``"b"``: clean queries on the clean keys of their own positions:
+      visible where ``blk(query) >= blk(key)`` (block-causal: the diagonal
+      moved up to the end of the query's block);
+    * ``"s"``: noised queries on the clean keys of their own positions:
+      ``blk(query) > blk(key)`` (the diagonal moved down to the end of the
+      PREVIOUS block); sub-tiles below the diagonal are bare in both;
+    * ``"e"``: noised queries on the noised keys of their own positions:
+      ``blk(query) == blk(key)``, the diagonal's sub-tiles and no other.
+    """
+    edge: str
+
+    def kind(self, a_minus_c: int, tq: int, tk: int):
+        """As :meth:`_Band.kind`: ``None`` never computed, ``""`` bare,
+        else the edge as the mask a diagonal sub-tile builds."""
+        if a_minus_c == 0:
+            return self.edge
+        return "" if a_minus_c > 0 and self.edge != "e" else None
+
+
+def _pick(cond, a, b):
+    """``a if cond else b``, on Python numbers (``_tile_counts``) and on
+    program ids alike."""
+    if isinstance(cond, (bool, int)):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+def _bd_k_block(i, j, n):
+    """Forward and dq kernels of a block-diffusion call with ``n`` blocks a
+    half: the k block that step ``j`` of q block ``i`` reads. A noised q
+    block (``i < n``) reads its own noised k block FIRST (every row sees
+    itself there, so the running maximum is finite from the first tile on
+    and a row that sees nothing of a later tile needs no guard), then the
+    clean blocks ``0 .. i``; a clean q block ``n + p`` the clean blocks
+    ``0 .. p``. Past its last the index stays where it is: no fetch."""
+    noised = i < n
+    last = _pick(noised, i, i - n)
+    c = j - _pick(noised, 1, 0)
+    c = _pick(c < last, c, last)
+    return _pick(noised & (j == 0), i, n + c)
+
+
+def _bd_q_block(j, step, n):
+    """The dk/dv kernel's side: the q block that ``step`` of k block ``j``
+    reads. A noised k block is seen by the noised q block of its own
+    positions alone; a clean one ``n + p`` by the noised q blocks
+    ``p .. n - 1`` and then the clean ones ``n + p .. 2n - 1``."""
+    p = j - n
+    q = _pick(step < n - p, p + step, 2 * p + step)
+    q = _pick(q < 2 * n - 1, q, 2 * n - 1)
+    return _pick(j >= n, q, j)
+
+
+def _bd_cells(x, step, n, kv_major=False):
+    """``[(mode, is this cell one)]`` of a block-diffusion call: the copies
+    of the body a kernel holds and the grid steps each runs at. ``x`` is
+    the q block (forward, dq; ``_bd_k_block`` has the order of its steps)
+    or, ``kv_major``, the k block (``_bd_q_block``). A step that is none of
+    them runs nothing."""
+    if kv_major:
+        clean, m = x >= n, 2 * n - x      # m: q blocks a half from it on
+        return [(_Edge("e"), (x < n) & (step == 0)),
+                (_Edge("s"), clean & (step == 0)),
+                (_FULL, clean & (step > 0) & (step < 2 * m) & (step != m)),
+                (_Edge("b"), clean & (step == m))]
+    noised = x < n
+    p = _pick(noised, x, x - n)
+    c = step - _pick(noised, 1, 0)        # the clean k block of this step
+    return [(_Edge("e"), noised & (step == 0)),
+            (_FULL, (c >= 0) & (c < p)),
+            (_Edge("s"), noised & (c == p)),
+            (_Edge("b"), (x >= n) & (c == p))]
+
+
 def _k_plan(mode, a, tq, bk, tk):
     """``[(c, kind)]``: the k sub-tiles q sub-tile ``a`` computes, in
     order, each with the masks it needs (``_Band.kind``)."""
-    if isinstance(mode, _Band):
+    if isinstance(mode, (_Band, _Edge)):
         return [(c, kind) for c in range(bk // tk)
                 if (kind := mode.kind(a * tq - c * tk, tq, tk)) is not None]
     n_full, hi = _k_tiles(mode, a, tq, bk, tk)
@@ -431,7 +530,7 @@ def _k_plan(mode, a, tq, bk, tk):
 
 def _q_plan(mode, c, tk, bq, tq):
     """The same from k sub-tile ``c``'s side: ``[(a, kind)]``."""
-    if isinstance(mode, _Band):
+    if isinstance(mode, (_Band, _Edge)):
         return [(a, kind) for a in range(bq // tq)
                 if (kind := mode.kind(a * tq - c * tk, tq, tk)) is not None]
     lo, lo_full = _q_tiles(mode, c, tk, bq, tq)
@@ -446,6 +545,16 @@ def _visible(s, diag, kind, off, window, transposed=False):
     window's far edge is the same compare against a second scalar."""
     if not kind:
         return s
+    if kind in ("b", "s", "e"):
+        # Block-diffusion: ``diag`` is the difference of the BLOCK indices
+        # (``_block_diagonal``), of a sub-tile on its cell's diagonal.
+        if kind == "e":
+            seen = diag == 0
+        elif transposed:
+            seen = diag <= 0 if kind == "b" else diag < 0
+        else:
+            seen = diag >= 0 if kind == "b" else diag > 0
+        return jnp.where(seen, s, _NEG_INF)
     if transposed:
         seen = diag <= off if "c" in kind else None
         if "w" in kind:
@@ -466,6 +575,27 @@ def _diagonal(tq, tk):
             - jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1))
 
 
+def _block_diagonal(tq, tk, block):
+    """The row's block minus the column's over a sub-tile that starts on a
+    block edge both ways: the three masks of a block-diffusion call are
+    one compare of it against 0 (``_visible``)."""
+    shift = block.bit_length() - 1
+    return (jnp.right_shift(jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0),
+                            shift)
+            - jnp.right_shift(jax.lax.broadcasted_iota(jnp.int32, (tq, tk),
+                                                       1), shift))
+
+
+def _mask_index(mode, tq, tk, bd):
+    """What a masked sub-tile of ``mode`` compares: nothing in a cell
+    without a mask."""
+    if mode == _FULL:
+        return None
+    if isinstance(mode, _Edge):
+        return _block_diagonal(tq, tk, bd[0])
+    return _diagonal(tq, tk)
+
+
 def _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode):
     """GLOBAL position of the block's first query minus its first key's.
     Offsets arrive as operands (see ``_scalar_spec``) so ring/sharded
@@ -479,9 +609,12 @@ def _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode):
             - koff_ref[...][0, 0, 0] - j * bk)
 
 
-def _last_k_block(causal, static_skip, i, bq, bk, nk, band=None):
+def _last_k_block(causal, static_skip, i, bq, bk, nk, band=None, bd=None):
     """The last k block that q block ``i`` runs (``_cell_is``); in a
-    windowed call the band's last, the diagonal's."""
+    windowed call the band's last, the diagonal's; in a block-diffusion
+    call the last step of ``_bd_k_block``."""
+    if bd:
+        return jnp.where(i < bd[1], i + 1, i - bd[1])
     if band:
         return band[1] - 1
     if causal and static_skip:
@@ -490,12 +623,16 @@ def _last_k_block(causal, static_skip, i, bq, bk, nk, band=None):
 
 
 def _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk, band=None,
-               valid=None):
+               valid=None, cells=None):
     """One copy of ``body`` per kind of cell the call can meet, each under
     its ``pl.when``. In a windowed call (``band`` = (window, blocks a
     band)) ``j`` is the cell's offset inside the band and ``valid`` says
     whether the band has a block there (it sticks out of the sequence at
-    its ends)."""
+    its ends). A block-diffusion call hands its ``cells`` (``_bd_cells``)."""
+    if cells is not None:
+        for mode, here in cells:
+            pl.when(here)(functools.partial(body, mode))
+        return
     if band is None:
         for mode in _cell_modes(causal, static_skip, nq, nk, bq, bk):
             pl.when(_cell_is(mode, causal, static_skip, i, j, bq, bk))(
@@ -506,13 +643,23 @@ def _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk, band=None,
         pl.when(valid & here)(functools.partial(body, mode))
 
 
-def _tile_counts(causal, static_skip, nq, nk, bq, bk, window=None):
+def _tile_counts(causal, static_skip, nq, nk, bq, bk, window=None,
+                 block=None):
     """(total, computed, masked) sub-tiles of one head, over the grid; a
     cell that never runs counts at the lattice of the mode it is nearest
     to (the last one: a future cell of a causal call is ``_SKIP``'s). A
     windowed call's grid holds the band's cells alone; the cells outside
-    it count to the total, at the cut-up lattice."""
-    if window is None:
+    it count to the total, at the cut-up lattice; so do the cells a
+    block-diffusion call (``block`` its block length) never visits."""
+    if block is not None:
+        n = nq // 2
+        cell_modes = {(i, j): [] for i in range(nq) for j in range(nk)}
+        for i in range(nq):
+            for step in range(n + 1):
+                for mode, here in _bd_cells(i, step, n):
+                    if here:
+                        cell_modes[i, _bd_k_block(i, step, n)] = [mode]
+    elif window is None:
         modes = _cell_modes(causal, static_skip, nq, nk, bq, bk)
         cell_modes = {(i, j): [m for m in modes if _cell_is(
             m, causal, static_skip, i, j, bq, bk)]
@@ -537,18 +684,21 @@ def _tile_counts(causal, static_skip, nq, nk, bq, bk, window=None):
     return total, computed, masked
 
 
-def _count_tiles(kernel, *args, window=None):
+def _count_tiles(kernel, *args, window=None, block=None):
     """Trace-time counters of how often the in-cell tiling engages, per
     head and kernel call (monitor registry, as plan/accounting.py keeps
     trace-time wire bytes): nothing of this runs on the device. A windowed
-    call counts under a label of its own, ``window`` = its width."""
+    call counts under a label of its own, ``window`` = its width, a
+    block-diffusion call under ``block`` = its block length."""
     from ..monitor.registry import counter
 
     labels = dict(kernel=kernel)
     if window is not None:
         labels["window"] = str(window)
+    if block is not None:
+        labels["block"] = str(block)
     for name, n in zip(("total", "computed", "masked"),
-                       _tile_counts(*args, window=window)):
+                       _tile_counts(*args, window=window, block=block)):
         counter(f"flash.tiles_{name}", **labels).inc(n)
 
 
@@ -595,12 +745,13 @@ def _fwd_out(m, l, acc, o_dtype):
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr,
                 *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile, G,
-                band=None):
+                band=None, bd=None):
     i = pl.program_id(2)   # q block
     g = pl.program_id(3)   # head inside the lane block (``_specs``)
     j = pl.program_id(4)   # k block (innermost: scratch carries across j);
-    #                        windowed: its offset inside the band
-    window, inner = band if band else (None, nk)
+    #                        windowed: its offset inside the band;
+    #                        block diffusion: a step of ``_bd_k_block``
+    window, inner = band if band else (None, bd[1] + 1 if bd else nk)
     carried = inner > 1    # else a q sub-tile finishes inside this cell
 
     if carried:
@@ -613,7 +764,7 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     def body(mode):
         tq, tk = _sub_tiles(mode, bq, bk, sub_tile)
         d0 = _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode)
-        diag = _diagonal(tq, tk) if mode != _FULL else None
+        diag = _mask_index(mode, tq, tk, bd)
         qscale = _on_head(g, G, q_ref, scale)
 
         def tile(a, c, q, carry, kind):
@@ -660,11 +811,11 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 _put(o_ref, (0, rows), o, g, G)
 
     _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk, band,
-               band and j >= inner - 1 - i)
+               band and j >= inner - 1 - i, bd and _bd_cells(i, j, bd[1]))
 
     if carried:
         @pl.when(j == _last_k_block(causal, static_skip, i, bq, bk, nk,
-                                    band))
+                                    band, bd))
         def _finish():
             o, lse_ref[0] = _fwd_out(
                 m_scr[:], l_scr[:], acc_scr[:], o_ref.dtype)
@@ -672,16 +823,23 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _statics(scale, causal, bq, bk, static_skip, heads, window=None,
-             group=1):
+             group=1, block=None):
     """What a kernel call is specialised on, read where the call is made."""
-    if window is not None and not (causal and static_skip and bq == bk):
+    if (window is not None or block is not None) and not (
+            causal and static_skip and bq == bk):
         raise ValueError(
-            "a windowed flash call is causal, has zero offsets (no ring "
-            f"partial) and square blocks, got causal={causal} "
-            f"static_skip={static_skip} blocks ({bq}, {bk})")
+            "a windowed or block-diffusion flash call is causal, has zero "
+            "offsets (no ring partial) and square blocks, got "
+            f"causal={causal} static_skip={static_skip} blocks ({bq}, {bk})")
+    if block is not None and (window is not None
+                              or _sub_tile(bq, _SUB_TILE[0]) % block):
+        raise ValueError(
+            f"a block-diffusion call has no window and its block length "
+            f"({block}) divides a sub-tile of its blocks ({bq})")
     return dict(scale=scale, causal=causal, bq=bq, bk=bk,
                 static_skip=static_skip, heads=heads, sub_tile=_SUB_TILE,
-                interpret=_interpret(), window=window, group=group)
+                interpret=_interpret(), window=window, group=group,
+                block=block)
 
 
 # The three pallas_calls are traced once per (operand shapes, statics) and
@@ -690,11 +848,11 @@ def _statics(scale, causal, bq, bk, static_skip, heads, window=None,
 _traced_once = functools.partial(
     jax.jit, inline=True,
     static_argnames=("scale", "causal", "bq", "bk", "static_skip", "heads",
-                     "sub_tile", "interpret", "window", "group"))
+                     "sub_tile", "interpret", "window", "group", "block"))
 
 
 def _specs(heads, width, bq, bk, kv_major=False, group=1, band=None,
-           nq=None):
+           nq=None, bd=None):
     """``(P, G, lanes, q_rows, k_rows, stats)`` of one kernel call over
     operands ``[N, T, width]`` with ``heads`` heads side by side. How the
     last dimension is cut: ``lanes`` a block, ``P`` blocks a row, ``G``
@@ -728,7 +886,13 @@ def _specs(heads, width, bq, bk, kv_major=False, group=1, band=None,
     k block ``j`` in the dk/dv kernel. Where the band sticks out of the
     sequence the index stays on the edge block (already there, or next to
     come: no fetch of its own) and the kernel skips the cell. No block
-    outside the band is fetched."""
+    outside the band is fetched.
+
+    Block diffusion (``bd`` = blocks a half; rows ``[noised ; clean]``):
+    the inner axis walks the steps of ``_bd_k_block`` (``bd + 1`` of them)
+    or, in the dk/dv kernel, of ``_bd_q_block`` (``2 * bd``): the cells the
+    mask touches in the order they are taken, the index at rest past the
+    last."""
     if heads == 1:
         lanes, P, G = width, 1, 1
     else:
@@ -743,11 +907,15 @@ def _specs(heads, width, bq, bk, kv_major=False, group=1, band=None,
         qi, ki = 2, 4
 
     def q_block(ids):
+        if bd and kv_major:
+            return _bd_q_block(ids[ki], ids[qi], bd)
         if band and kv_major:
             return jnp.minimum(ids[ki] + ids[qi], nq - 1)
         return ids[qi]
 
     def k_block(ids):
+        if bd and not kv_major:
+            return _bd_k_block(ids[qi], ids[ki], bd)
         if band and not kv_major:
             return jnp.maximum(ids[qi] - (band - 1) + ids[ki], 0)
         return ids[ki]
@@ -782,6 +950,8 @@ def _specs(heads, width, bq, bk, kv_major=False, group=1, band=None,
 # matches an op's whole name tells them from the full calls, one that looks
 # for the full calls' names as substrings takes all six for kernels.
 _WIN = "_win"
+# ... and a block-diffusion call's, for the same reason.
+_BD = "_bd"
 
 
 def _band(window, b, nk):
@@ -789,12 +959,18 @@ def _band(window, b, nk):
     return None if window is None else (window, _band_blocks(window, b, nk))
 
 
+def _halves(block, nq):
+    """(block length, blocks a half) of a block-diffusion call, else
+    None."""
+    return None if block is None else (block, nq // 2)
+
+
 # The head axis revisits the output block, the innermost one accumulates.
 _SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary", "arbitrary")
 
 
 def _flash_fwd(q, k, v, scale, causal, bq, bk, q_off=0, k_off=0,
-               static_skip=True, heads=1, window=None, group=1):
+               static_skip=True, heads=1, window=None, group=1, block=None):
     """q,k,v: [N, T, heads * D] → (o [N, Tq, heads * D], lse [N * heads,
     1, Tq] f32): ``heads`` = 1 is the packed layout ([B * H, T, D]), more
     the projections' own ([B, T, H * D], ``_reads_in_place``).
@@ -803,30 +979,41 @@ def _flash_fwd(q, k, v, scale, causal, bq, bk, q_off=0, k_off=0,
     (may be traced, e.g. ``lax.axis_index(...) * T_local`` under a ring);
     pass ``static_skip=False`` whenever they can be nonzero. ``window``:
     query t sees keys t - window + 1 .. t. ``group``: query heads a KV
-    head (k, v hold ``heads / group`` heads, or N / group rows packed)."""
+    head (k, v hold ``heads / group`` heads, or N / group rows packed).
+    ``block``: the rows are ``[noised ; clean]`` under the block-diffusion
+    mask of that block length."""
     _count_tiles("fwd", causal, static_skip, q.shape[1] // bq,
-                 k.shape[1] // bk, bq, bk, window=window)
+                 k.shape[1] // bk, bq, bk, window=window, block=block)
     return _fwd_call(q_off, k_off, q, k, v,
                      **_statics(scale, causal, bq, bk, static_skip, heads,
-                                window, group))
+                                window, group, block))
+
+
+def _inner_steps(band, bd, n, kv_major=False):
+    """Length of a kernel's innermost grid axis: the band's blocks, the
+    steps of a block-diffusion call, else all ``n`` blocks."""
+    if bd:
+        return 2 * bd[1] if kv_major else bd[1] + 1
+    return band[1] if band else n
 
 
 @_traced_once
 def _fwd_call(q_off, k_off, q, k, v, *, scale, causal, bq, bk, static_skip,
-              heads, sub_tile, interpret, window, group):
+              heads, sub_tile, interpret, window, group, block):
     N, Tq, W = q.shape
     Tk = k.shape[1]
     nq, nk = Tq // bq, Tk // bk
-    band = _band(window, bq, nk)
+    band, bd = _band(window, bq, nk), _halves(block, nq)
     P, G, lanes, q_rows, k_rows, stats = _specs(
-        heads, W, bq, bk, group=group, band=band and band[1], nq=nq)
+        heads, W, bq, bk, group=group, band=band and band[1], nq=nq,
+        bd=bd and bd[1])
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                bq=bq, bk=bk, nq=nq, nk=nk,
                                static_skip=static_skip, sub_tile=sub_tile,
-                               G=G, band=band)
+                               G=G, band=band, bd=bd)
     return pl.pallas_call(
         kernel,
-        grid=(N, P, nq, G, band[1] if band else nk),
+        grid=(N, P, nq, G, _inner_steps(band, bd, nk)),
         in_specs=[_scalar_spec(), _scalar_spec(),
                   q_rows(), k_rows(), k_rows()],
         out_specs=[q_rows(), stats()],
@@ -842,7 +1029,7 @@ def _fwd_call(q_off, k_off, q, k, v, *, scale, causal, bq, bk, static_skip,
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
-        name="hvd_flash_fwd" + _WIN * bool(band),
+        name="hvd_flash_fwd" + _WIN * bool(band) + _BD * bool(bd),
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v)
 
 
@@ -864,11 +1051,12 @@ def _probs(s, lse, static_skip):
 def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, acc_scr,
                    *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile,
-                   G, band=None):
+                   G, band=None, bd=None):
     i = pl.program_id(2)
     g = pl.program_id(3)
-    j = pl.program_id(4)   # windowed: the cell's offset inside the band
-    window, inner = band if band else (None, nk)
+    j = pl.program_id(4)   # windowed: the cell's offset inside the band;
+    #                        block diffusion: a step of ``_bd_k_block``
+    window, inner = band if band else (None, bd[1] + 1 if bd else nk)
     carried = inner > 1
 
     if carried:
@@ -879,7 +1067,7 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def body(mode):
         tq, tk = _sub_tiles(mode, bq, bk, sub_tile)
         d0 = _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode)
-        diag = _diagonal(tq, tk) if mode != _FULL else None
+        diag = _mask_index(mode, tq, tk, bd)
         qscale = _on_head(g, G, q_ref, scale)
         own = _on_head(g, G, do_ref, 1.0)
 
@@ -914,11 +1102,11 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                      g, G)
 
     _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk, band,
-               band and j >= inner - 1 - i)
+               band and j >= inner - 1 - i, bd and _bd_cells(i, j, bd[1]))
 
     if carried:
         @pl.when(j == _last_k_block(causal, static_skip, i, bq, bk, nk,
-                                    band))
+                                    band, bd))
         def _finish():
             _put(dq_ref, (0,), (acc_scr[:] * scale).astype(dq_ref.dtype),
                  g, G)
@@ -927,13 +1115,14 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr,
                     *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile,
-                    G, band=None, group=1):
+                    G, band=None, group=1, bd=None):
     j = pl.program_id(2)   # k block
     g = pl.program_id(3)   # head inside the lane block; with grouped KV
     #                        heads (one head a block then) the query head
     #                        inside the group: dk, dv sum over it here
     i = pl.program_id(4)   # q block (innermost: scratch carries across i);
-    #                        windowed: how many blocks past the k block
+    #                        windowed: how many blocks past the k block;
+    #                        block diffusion: a step of ``_bd_q_block``
     window, inner = band if band else (None, nq)
     carried = inner > 1 or group > 1
 
@@ -955,7 +1144,7 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         # the transpose unit.
         tq, tk = _sub_tiles(mode, bq, bk, sub_tile)
         d0 = _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode)
-        diag = _diagonal(tk, tq) if mode != _FULL else None
+        diag = _mask_index(mode, tk, tq, bd)
         nqs = bq // tq
         kscale = _on_head(g, G, k_ref, scale)
         own = _on_head(g, G, v_ref, 1.0)
@@ -999,7 +1188,10 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                      g, G)
                 _put(dv_ref, (0, cols), dv.astype(dv_ref.dtype), g, G)
 
-    if band:
+    if bd:
+        _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk,
+                   cells=_bd_cells(j, i, bd[1], kv_major=True))
+    elif band:
         # The q block ``i`` blocks past k block ``j`` sees it as its band's
         # cell ``inner - 1 - i`` (offset 0 is a q block's farthest).
         _each_mode(body, causal, static_skip, i, inner - 1 - i, bq, bk, nq,
@@ -1041,29 +1233,30 @@ def _prep_residuals(o, do, heads=1):
 
 def _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, bq, bk,
                   q_off=0, k_off=0, static_skip=True, heads=1, window=None,
-                  group=1):
+                  group=1, block=None):
     _count_tiles("bwd_dq", causal, static_skip, q.shape[1] // bq,
-                 k.shape[1] // bk, bq, bk, window=window)
+                 k.shape[1] // bk, bq, bk, window=window, block=block)
     return _bwd_dq_call(q_off, k_off, q, k, v, do, lse, delta,
                         **_statics(scale, causal, bq, bk, static_skip,
-                                   heads, window, group))
+                                   heads, window, group, block))
 
 
 @_traced_once
 def _bwd_dq_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
                  bq, bk, static_skip, heads, sub_tile, interpret, window,
-                 group):
+                 group, block):
     N, Tq, W = q.shape
     nq, nk = Tq // bq, k.shape[1] // bk
-    band = _band(window, bq, nk)
+    band, bd = _band(window, bq, nk), _halves(block, nq)
     P, G, lanes, q_rows, k_rows, stats = _specs(
-        heads, W, bq, bk, group=group, band=band and band[1], nq=nq)
+        heads, W, bq, bk, group=group, band=band and band[1], nq=nq,
+        bd=bd and bd[1])
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, nk=nk,
                           static_skip=static_skip, sub_tile=sub_tile, G=G,
-                          band=band),
-        grid=(N, P, nq, G, band[1] if band else nk),
+                          band=band, bd=bd),
+        grid=(N, P, nq, G, _inner_steps(band, bd, nk)),
         in_specs=[_scalar_spec(), _scalar_spec(),
                   q_rows(), k_rows(), k_rows(),       # q, k, v
                   q_rows(), stats(), stats()],        # do, lse, delta
@@ -1073,36 +1266,37 @@ def _bwd_dq_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
         scratch_shapes=[pltpu.VMEM((bq, lanes), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
-        name="hvd_flash_bwd_dq" + _WIN * bool(band),
+        name="hvd_flash_bwd_dq" + _WIN * bool(band) + _BD * bool(bd),
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v, do, lse, delta)
 
 
 def _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, bq, bk,
                    q_off=0, k_off=0, static_skip=True, heads=1, window=None,
-                   group=1):
+                   group=1, block=None):
     _count_tiles("bwd_dkv", causal, static_skip, q.shape[1] // bq,
-                 k.shape[1] // bk, bq, bk, window=window)
+                 k.shape[1] // bk, bq, bk, window=window, block=block)
     return _bwd_dkv_call(q_off, k_off, q, k, v, do, lse, delta,
                          **_statics(scale, causal, bq, bk, static_skip,
-                                    heads, window, group))
+                                    heads, window, group, block))
 
 
 @_traced_once
 def _bwd_dkv_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
                   bq, bk, static_skip, heads, sub_tile, interpret, window,
-                  group):
+                  group, block):
     N, Tk, W = k.shape
     nq, nk = q.shape[1] // bq, Tk // bk
-    band = _band(window, bq, nk)
+    band, bd = _band(window, bq, nk), _halves(block, nq)
     P, G, lanes, q_rows, k_rows, stats = _specs(
         heads, q.shape[2], bq, bk, kv_major=True, group=group,
-        band=band and band[1], nq=nq)
+        band=band and band[1], nq=nq, bd=bd and bd[1])
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, nk=nk,
                           static_skip=static_skip, sub_tile=sub_tile, G=G,
-                          band=band, group=group),
-        grid=(N, P, nk, max(G, group), band[1] if band else nq),
+                          band=band, group=group, bd=bd),
+        grid=(N, P, nk, max(G, group),
+              _inner_steps(band, bd, nq, kv_major=True)),
         in_specs=[_scalar_spec(), _scalar_spec(),
                   q_rows(), k_rows(), k_rows(),       # q, k, v
                   q_rows(), stats(), stats()],        # do, lse, delta
@@ -1119,17 +1313,18 @@ def _bwd_dkv_call(q_off, k_off, q, k, v, do, lse, delta, *, scale, causal,
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,
-        name="hvd_flash_bwd_dkv" + _WIN * bool(band),
+        name="hvd_flash_bwd_dkv" + _WIN * bool(band) + _BD * bool(bd),
     )(_as_scalar(q_off), _as_scalar(k_off), q, k, v, do, lse, delta)
 
 
 def _flash_bwd(q, k, v, o, lse, do, heads, scale, causal, bq, bk,
-               window=None, group=1):
+               window=None, group=1, block=None):
     delta = _prep_residuals(o, do, heads)
     dq = _flash_bwd_dq(q, k, v, do, lse, delta, scale, causal, bq, bk,
-                       heads=heads, window=window, group=group)
+                       heads=heads, window=window, group=group, block=block)
     dk, dv = _flash_bwd_dkv(q, k, v, do, lse, delta, scale, causal, bq, bk,
-                            heads=heads, window=window, group=group)
+                            heads=heads, window=window, group=group,
+                            block=block)
     return dq, dk, dv
 
 
@@ -1154,10 +1349,11 @@ def _pick_block(T: int, preferred: int) -> Optional[int]:
     return None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash(q, k, v, heads, scale, causal, bq, bk, window, group):
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, heads, scale, causal, bq, bk, window, group, block):
     o, _ = _flash_fwd(q, k, v, scale, causal, bq, bk, heads=heads,
-                      window=window, group=group)
+                      window=window, group=group, block=block)
     return o
 
 
@@ -1167,17 +1363,19 @@ def _flash(q, k, v, heads, scale, causal, bq, bk, window, group):
 OUT_NAME = "hvd_flash_out"
 
 
-def _flash_vjp_fwd(q, k, v, heads, scale, causal, bq, bk, window, group):
+def _flash_vjp_fwd(q, k, v, heads, scale, causal, bq, bk, window, group,
+                   block):
     o, lse = (checkpoint_name(x, OUT_NAME) for x in _flash_fwd(
         q, k, v, scale, causal, bq, bk, heads=heads, window=window,
-        group=group))
+        group=group, block=block))
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(heads, scale, causal, bq, bk, window, group, res, g):
+def _flash_vjp_bwd(heads, scale, causal, bq, bk, window, group, block, res,
+                   g):
     q, k, v, o, lse = res
     return _flash_bwd(q, k, v, o, lse, g, heads, scale, causal, bq, bk,
-                      window, group)
+                      window, group, block)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -1400,22 +1598,42 @@ def flash_ring_attention(q, k, v, *, axis, causal: bool = True,
         return _unpack(o, B, H)
 
 
-def _dense_fallback(q, k, v, causal, window, scale):
+def block_diffusion_mask(L: int, block: int):
+    """``[2L, 2L]`` bool: may row r (a query) see row c (a key)? Rows are
+    ``[noised ; clean]``, position p of either half in block ``p // block``.
+    A clean query sees the clean keys of its own and earlier blocks; a
+    noised query the clean keys of EARLIER blocks and the noised keys of
+    its own block; nothing else (BD3-LM's training mask, Arriola et al.
+    2025). ``L^2 + L * block`` pairs; every row sees itself."""
+    blk = jnp.arange(L) // block
+    clean_clean = blk[:, None] >= blk[None, :]
+    noised_clean = blk[:, None] > blk[None, :]
+    noised_noised = blk[:, None] == blk[None, :]
+    return jnp.block([[noised_noised, noised_clean],
+                      [jnp.zeros((L, L), bool), clean_clean]])
+
+
+def _dense_fallback(q, k, v, causal, window, scale, block=None):
     """The dense path for a sequence no block divides, with grouped KV
-    heads and a window where the call has them."""
+    heads and a window or the block-diffusion mask where the call has
+    them."""
     from ..parallel.sequence import dense_attention
 
     group = q.shape[2] // k.shape[2]
     if group > 1:
         k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
-    if window is None:
+    if window is None and block is None:
         return dense_attention(q, k, v, causal=causal, scale=scale)
     T, D = q.shape[1], q.shape[3]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * (
         D ** -0.5 if scale is None else scale)
-    d = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
-    s = jnp.where((d >= 0) & (d < window), s, _NEG_INF)
+    if block is not None:
+        seen = block_diffusion_mask(T // 2, block)
+    else:
+        d = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+        seen = (d >= 0) & (d < window)
+    s = jnp.where(seen, s, _NEG_INF)
     return jnp.einsum("bhqk,bkhd->bqhd",
                       jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
 
@@ -1429,6 +1647,7 @@ def _in_place(H: int, Hkv: int, D: int) -> bool:
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
+                    block_diffusion: Optional[int] = None,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None):
@@ -1440,6 +1659,18 @@ def flash_attention(q, k, v, *, causal: bool = True,
     (its own token is one of the ``window``); needs ``causal``. A window
     the sequence fits in is no window. ``window=None`` and ``H == Hkv``
     compile the kernels they always did (docs/flash_window.md).
+
+    ``block_diffusion=B`` (a power of two): the ``T = 2 L`` rows are a
+    noised copy of a sequence and then its clean copy, and the mask is the
+    block-diffusion objective's (:func:`block_diffusion_mask`,
+    docs/block_diffusion.md): a clean query sees the clean keys to the end
+    of its own block of ``B`` positions, a noised query the clean keys of
+    earlier blocks and the noised keys of its own. Blocks are square and
+    divide ``L``; only the cells the mask touches are fetched or computed
+    (``L^2 + L B`` pairs a head of the square's ``4 L^2``), in kernels
+    named ``hvd_flash_*_bd`` under scope ``hvd.flash_block_diffusion``.
+    ``block_diffusion=None`` compiles what the file compiled before it
+    knew the mask, kernel for kernel.
 
     Differentiable (custom VJP with Pallas backward kernels). Block sizes
     shrink to a divisor of the sequence when needed (a single whole-sequence
@@ -1467,9 +1698,19 @@ def flash_attention(q, k, v, *, causal: bool = True,
             raise ValueError("a window is causal and holds the query's own "
                              f"token, got causal={causal} window={window}")
         window = None if window >= Tk else int(window)
+    bd = block_diffusion
+    if bd is not None:
+        bd = int(bd)
+        if (not causal or window is not None or Tq != Tk or bd < 1
+                or bd & (bd - 1) or Tq % (2 * bd)):
+            raise ValueError(
+                "a block-diffusion call takes [noised ; clean] rows of one "
+                "length, a whole number of blocks of a power of two each, "
+                f"and no window: got {Tq} queries, {Tk} keys, block {bd}, "
+                f"window {window}, causal={causal}")
     if block_q is None and block_k is None:
         block_q, block_k = _resolve_blocks(B, Tq, Tk, H, D, q.dtype,
-                                           causal, window, Hkv)
+                                           causal, window, Hkv, bd)
     else:
         block_q = _DEF_BLOCK_Q if block_q is None else block_q
         block_k = _DEF_BLOCK_K if block_k is None else block_k
@@ -1477,11 +1718,17 @@ def flash_attention(q, k, v, *, causal: bool = True,
         raise ValueError(
             f"block_q/block_k must be >= 128 (MXU/lane tile), got "
             f"{block_q}/{block_k}")
-    if window is not None:
+    if window is not None or bd is not None:
         block_q = block_k = min(block_q, block_k)
-    bq, bk = _pick_block(Tq, block_q), _pick_block(Tk, block_k)
+    if bd is None:
+        bq, bk = _pick_block(Tq, block_q), _pick_block(Tk, block_k)
+    else:   # the blocks divide a HALF, and a sub-tile holds whole blocks
+        bq = bk = _pick_block(Tq // 2, block_q)
+        if bq is not None and (_sub_tile(bq, _SUB_TILE[0]) % bd or (
+                bq % _LANES and not _interpret())):
+            bq = None
     if bq is None or bk is None:
-        return _dense_fallback(q, k, v, causal, window, scale)
+        return _dense_fallback(q, k, v, causal, window, scale, bd)
     scale = float(scale) if scale is not None else D ** -0.5
 
     from ..monitor.registry import counter
@@ -1495,19 +1742,23 @@ def flash_attention(q, k, v, *, causal: bool = True,
         counter("flash.kv_group").inc(group)
     # Outside the custom_vjp call, so that the backward kernels and the
     # packed path's [B, T, H, D] <-> [BH, T, D] traffic carry the scope too.
-    with jax.named_scope("hvd.flash_attention"), _window_scope(window):
+    with jax.named_scope("hvd.flash_attention"), _kind_scope(window, bd):
         if in_place:
             qp, kp, vp = _harmonize_vma(*(
                 x.reshape(B, x.shape[1], x.shape[2] * D) for x in (q, k, v)))
-            o = _flash(qp, kp, vp, H, scale, causal, bq, bk, window, group)
+            o = _flash(qp, kp, vp, H, scale, causal, bq, bk, window, group,
+                       bd)
             return o.reshape(B, Tq, H, D)
         qp, kp, vp = _harmonize_vma(_pack(q), _pack(k), _pack(v))
         return _unpack(_flash(qp, kp, vp, 1, scale, causal, bq, bk, window,
-                              group), B, H)
+                              group, bd), B, H)
 
 
-def _window_scope(window):
+def _kind_scope(window, block=None):
     """``hvd.flash_window`` inside ``hvd.flash_attention`` around a
-    windowed call (forward and backward), nothing around any other."""
+    windowed call (forward and backward), ``hvd.flash_block_diffusion``
+    around a block-diffusion call, nothing around any other."""
+    if block is not None:
+        return jax.named_scope("hvd.flash_block_diffusion")
     return (contextlib.nullcontext() if window is None
             else jax.named_scope("hvd.flash_window"))

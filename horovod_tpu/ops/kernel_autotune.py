@@ -356,13 +356,15 @@ def _timed_chain(step_fn, args, target_seconds: float = 0.25,
 def flash_blocks(B: int, Tq: int, Tk: int, H: int, D: int, dtype,
                  causal: bool, default: Tuple[int, int],
                  pick_block, window: Optional[int] = None,
-                 kv_heads: Optional[int] = None) -> Tuple[int, int]:
+                 kv_heads: Optional[int] = None,
+                 block_diffusion: Optional[int] = None) -> Tuple[int, int]:
     """Autotuned (block_q, block_k) for a flash-attention shape. A window
     and grouped KV heads (``kv_heads`` < ``H``) are part of the shape: they
     key the cache and the timed call has them. A windowed call's blocks are
     square, so only square candidates are timed. For such a shape no
     candidate is above the hand-tuned default and the whole backward is
-    timed (dk, dv too): a (1024, 2048) blocking won the forward-and-dq
+    timed (dk, dv too); so it is for a block-diffusion call (``Tq`` its
+    ``2 L`` rows; the blocks divide a half). A (1024, 2048) blocking won the forward-and-dq
     sweep of the full grouped call at T = 8192 by 5% and its dk/dv kernel
     then overflowed scoped VMEM inside the step (PERF.md, PR 30), which a
     sweep standing alone cannot see. Ungrouped calls without a window keep
@@ -377,6 +379,8 @@ def flash_blocks(B: int, Tq: int, Tk: int, H: int, D: int, dtype,
         sig += f".w{window}"
     if kv_heads is not None:
         sig += f".kv{kv_heads}"
+    if block_diffusion is not None:
+        sig += f".bd{block_diffusion}"
 
     # Too-small workloads (e.g. the B=1 model.init trace) neither benefit
     # from tuning nor time reliably — keep the default, don't sweep.
@@ -386,14 +390,16 @@ def flash_blocks(B: int, Tq: int, Tk: int, H: int, D: int, dtype,
     # Candidate grid, deduplicated by the EFFECTIVE blocking after the
     # legality shrink (different preferences can collapse to one choice).
     # Grouped or windowed: the whole backward, nothing above the default.
-    bounded = window is not None or kv_heads is not None
+    square = window is not None or block_diffusion is not None
+    bounded = square or kv_heads is not None
     grid = [(bq, bk) for bq in (512, 1024, 2048) for bk in (512, 1024,
                                                             2048)
-            if (window is None or bq == bk)
+            if (not square or bq == bk)
             and not (bounded and max(bq, bk) > max(default))]
+    halves = 1 if block_diffusion is None else 2
     seen, cands = set(), []
     for bq, bk in grid:
-        eff = (pick_block(Tq, bq), pick_block(Tk, bk))
+        eff = (pick_block(Tq // halves, bq), pick_block(Tk // halves, bk))
         if None in eff or eff in seen:
             continue
         seen.add(eff)
@@ -415,7 +421,8 @@ def flash_blocks(B: int, Tq: int, Tk: int, H: int, D: int, dtype,
         def step(q, k, v):
             g = jax.grad(lambda q, k, v: flash_attention(
                 q, k, v, causal=causal, window=window, block_q=bq,
-                block_k=bk).astype(jnp.float32).sum(),
+                block_k=bk, block_diffusion=block_diffusion,
+            ).astype(jnp.float32).sum(),
                 argnums=(0, 1, 2) if bounded else 0)(q, k, v)
             if bounded:   # dk, dv [.., Hkv, D] into the carry as well
                 g = g[0] + sum(jnp.repeat(x, H // x.shape[2], axis=2)

@@ -17,7 +17,9 @@ us of it). A shape is ``BxTxHxD`` + ``c`` (causal) or ``f`` (full), then
 ``.kvN`` for N KV heads under the H query heads and ``.wN`` for a window of
 N keys (``1x8192x32x128c.kv4.w2048``: a windowed call's events carry the
 names ``hvd_flash_*_win``, so one windowed and one grouped call are timed
-alone against the full call ``1x8192x32x128c``); a
+alone against the full call ``1x8192x32x128c``) and ``.bdN`` for the
+block-diffusion mask of block length N over T = 2 L rows
+(``1x16384x32x128c.kv4.bd4``: the ``hvd_flash_*_bd`` kernels); a
 sub-tile ``TQxTK`` (the rule's own choice when the list is empty). ``--module FILE`` times another
 copy of ``ops/flash_attention.py`` (e.g. the parent commit's) in the same
 process; such a copy ignores ``--subtiles`` unless it has ``_SUB_TILE``.
@@ -39,7 +41,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv",
            "hvd_flash_fwd_win", "hvd_flash_bwd_dq_win",
-           "hvd_flash_bwd_dkv_win")
+           "hvd_flash_bwd_dkv_win", "hvd_flash_fwd_bd",
+           "hvd_flash_bwd_dq_bd", "hvd_flash_bwd_dkv_bd")
 
 
 def load_module(path):
@@ -91,13 +94,15 @@ def time_one(mod, shape, block, steps):
     import jax.numpy as jnp
     import numpy as np
 
-    B, T, H, D, causal, kv_heads, window = shape
+    B, T, H, D, causal, kv_heads, window, bd = shape
     rs = np.random.RandomState(0)
     q, k, v = (jnp.asarray(rs.randn(B, T, n * D), jnp.bfloat16) * 0.3
                for n in (H, kv_heads, kv_heads))
     # Only what the shape asks for: a copy from before the window (the
     # parent's, by --module) is still called as it was.
     extra = {} if window is None else {"window": window}
+    if bd is not None:
+        extra["block_diffusion"] = bd
 
     @jax.jit
     def f(q, k, v):
@@ -117,19 +122,21 @@ def time_one(mod, shape, block, steps):
 
 
 def parse_shape(text: str):
-    """``BxTxHxD{c|f}[.kvN][.wN]`` -> (B, T, H, D, causal, KV heads,
-    window or None)."""
+    """``BxTxHxD{c|f}[.kvN][.wN][.bdN]`` -> (B, T, H, D, causal, KV heads,
+    window or None, block-diffusion block length or None)."""
     base, *options = text.split(".")
     B, T, H, D = map(int, base[:-1].split("x"))
-    kv_heads, window = H, None
+    kv_heads, window, bd = H, None, None
     for opt in options:
         if opt.startswith("kv"):
             kv_heads = int(opt[2:])
+        elif opt.startswith("bd"):
+            bd = int(opt[2:])
         elif opt.startswith("w"):
             window = int(opt[1:])
         else:
             raise ValueError(f"shape option {opt!r} in {text!r}")
-    return B, T, H, D, base[-1] == "c", kv_heads, window
+    return B, T, H, D, base[-1] == "c", kv_heads, window, bd
 
 
 def main(argv=None) -> int:

@@ -27,16 +27,18 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 import bench_tiny as tiny  # noqa: E402
 import bench_tiny_afmoe as tiny_afmoe  # noqa: E402
 import bench_tiny_sambay as tiny_sambay  # noqa: E402
+import bench_tiny_sdar as tiny_sdar  # noqa: E402
 import bench_tiny_sparse as tiny_sparse  # noqa: E402
 
 from benchmarks.builders import (afmoe, gpt_decoder, sambay,  # noqa: E402
-                                 sparse_moe_decoder)
+                                 sdar_moe, sparse_moe_decoder)
 from horovod_tpu.ops import selective_scan as scan  # noqa: E402
 from horovod_tpu.ops import sparse_attention as spa  # noqa: E402
 
 KERNELS = ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv",
            "hvd_flash_fwd_win", "hvd_flash_bwd_dq_win",
-           "hvd_flash_bwd_dkv_win",
+           "hvd_flash_bwd_dkv_win", "hvd_flash_fwd_bd",
+           "hvd_flash_bwd_dq_bd", "hvd_flash_bwd_dkv_bd",
            "hvd_xent_fwd", "hvd_xent_bwd_dx", "hvd_xent_bwd_dw",
            "hvd_ln_fwd", "hvd_ln_bwd", "hvd_index_select",
            "hvd_sparse_attn_fwd", "hvd_sparse_attn_bwd",
@@ -53,7 +55,11 @@ AFMOE_STEP = {"hvd.flash_window", "hvd.shared_expert",
 # the tiny sambay step's (tests/benchmark/bench_tiny_sambay.py).
 SAMBAY_STEP = {"hvd.ssm", "hvd.selective_scan", "hvd.gmu",
                "hvd.diff_attention"}
-OFF_STEP = {"hvd.layer_norm"} | SPARSE_STEP | AFMOE_STEP | SAMBAY_STEP
+# Nor the block-diffusion objective: the tiny sdar step's
+# (tests/benchmark/bench_tiny_sdar.py).
+SDAR_STEP = {"hvd.flash_block_diffusion", "hvd.block_diffusion_noise"}
+OFF_STEP = ({"hvd.layer_norm"} | SPARSE_STEP | AFMOE_STEP | SAMBAY_STEP
+            | SDAR_STEP)
 # The decoder block's names (models/): the programs that hold each. Only
 # the mixture decoder rotates, and the tiny sparse step has no dense layer.
 BLOCK_STEPS = {"hvd.norm": ("gpt", "sparse", "afmoe", "sambay"),
@@ -64,7 +70,8 @@ BLOCK_STEPS = {"hvd.norm": ("gpt", "sparse", "afmoe", "sambay"),
 # The indexer is forward only: no gradient reaches it.
 DIFFERENTIATED = {"hvd.grad", "hvd.lm_head_loss", "hvd.flash_attention",
                   "hvd.layer_norm", "hvd.sparse_attention", "hvd.moe_ffn",
-                  "hvd.flash_window", "hvd.shared_expert"} | set(
+                  "hvd.flash_window", "hvd.shared_expert",
+                  "hvd.flash_block_diffusion"} | set(
                       BLOCK_STEPS) | SAMBAY_STEP
 NESTED_IN = {"hvd.lm_head_loss": "hvd.grad",
              "hvd.flash_attention": "hvd.grad",
@@ -72,6 +79,7 @@ NESTED_IN = {"hvd.lm_head_loss": "hvd.grad",
              "hvd.sparse_indexer": "hvd.grad",
              "hvd.moe_ffn": "hvd.grad",
              "hvd.flash_window": "hvd.flash_attention",
+             "hvd.flash_block_diffusion": "hvd.flash_attention",
              "hvd.shared_expert": "hvd.grad",
              "hvd.ssm": "hvd.grad", "hvd.selective_scan": "hvd.ssm",
              "hvd.gmu": "hvd.grad", "hvd.diff_attention": "hvd.grad",
@@ -87,7 +95,8 @@ def _op_names(text: str) -> list:
 
 STEPS = {"gpt": (gpt_decoder, tiny), "sparse": (sparse_moe_decoder,
                                                 tiny_sparse),
-         "afmoe": (afmoe, tiny_afmoe), "sambay": (sambay, tiny_sambay)}
+         "afmoe": (afmoe, tiny_afmoe), "sambay": (sambay, tiny_sambay),
+         "sdar": (sdar_moe, tiny_sdar)}
 
 
 def _step_text(step: str, n_devices: int = 1) -> str:
@@ -99,7 +108,7 @@ def _step_text(step: str, n_devices: int = 1) -> str:
 
 @pytest.fixture(scope="module")
 def step_texts():
-    """The compiled text of the four tiny steps on one device (the tiny
+    """The compiled text of the five tiny steps on one device (the tiny
     GPT step, tests/benchmark/bench_tiny.py, on four too)."""
     try:
         yield {**{step: _step_text(step) for step in STEPS},
@@ -132,6 +141,12 @@ def afmoe_step_names(step_texts):
 def sambay_step_names(step_texts):
     """The op_names of the tiny sambay step, one device."""
     return _op_names(step_texts["sambay"])
+
+
+@pytest.fixture(scope="module")
+def sdar_step_names(step_texts):
+    """The op_names of the tiny sdar step, one device."""
+    return _op_names(step_texts["sdar"])
 
 
 @pytest.fixture(scope="module")
@@ -173,7 +188,8 @@ def test_scope_reaches_the_compiled_program(scope, step_names,
                                             layer_norm_names,
                                             sparse_step_names,
                                             afmoe_step_names,
-                                            sambay_step_names):
+                                            sambay_step_names,
+                                            sdar_step_names):
     by_step = {"gpt": step_names[1], "sparse": sparse_step_names,
                "afmoe": afmoe_step_names, "sambay": sambay_step_names}
     if scope in BLOCK_STEPS:
@@ -185,6 +201,8 @@ def test_scope_reaches_the_compiled_program(scope, step_names,
         programs = {"afmoe decoder": afmoe_step_names}
     elif scope in SAMBAY_STEP:
         programs = {"sambay decoder": sambay_step_names}
+    elif scope in SDAR_STEP:
+        programs = {"sdar decoder": sdar_step_names}
     elif scope in OFF_STEP:
         programs = {"layer_norm": layer_norm_names}
     else:
@@ -209,12 +227,15 @@ def test_scope_reaches_the_compiled_program(scope, step_names,
     ("hvd_sparse_attn_bwd_dq", "hvd.sparse_attention.split"),
     ("hvd_sparse_attn_bwd_dkv", "hvd.sparse_attention.split"),
     ("hvd_flash_bwd_dq_win", "hvd.flash_window"),
-    ("hvd_flash_bwd_dkv_win", "hvd.flash_window")])
+    ("hvd_flash_bwd_dkv_win", "hvd.flash_window"),
+    ("hvd_flash_bwd_dq_bd", "hvd.flash_block_diffusion"),
+    ("hvd_flash_bwd_dkv_bd", "hvd.flash_block_diffusion")])
 def test_custom_vjp_backward_inherits_the_scope(kernel, scope, step_names,
                                                 layer_norm_names,
                                                 sparse_step_names,
                                                 sparse_split_names,
-                                                afmoe_step_names):
+                                                afmoe_step_names,
+                                                sdar_step_names):
     """The trap: a scope opened inside the custom_vjp's forward function
     would not reach the backward. In interpret mode a kernel's body is
     traced into the program under its ``name=``. The tiny sparse step
@@ -223,7 +244,8 @@ def test_custom_vjp_backward_inherits_the_scope(kernel, scope, step_names,
     names = {"hvd.layer_norm": layer_norm_names,
              "hvd.sparse_attention": sparse_step_names,
              "hvd.sparse_attention.split": sparse_split_names,
-             "hvd.flash_window": afmoe_step_names}.get(
+             "hvd.flash_window": afmoe_step_names,
+             "hvd.flash_block_diffusion": sdar_step_names}.get(
         scope, step_names[4])
     scope = scope.removesuffix(".split")
     body = [n for n in names if f"{kernel}/" in n or n.endswith(kernel)]
@@ -255,6 +277,9 @@ def kernel_names():
         (lambda q: fa.flash_attention(q, q[:, :, :1], q[:, :, :1],
                                       window=32).astype(jnp.float32).sum(),
          q),
+        (lambda q: fa.flash_attention(q, q[:, :, :1], q[:, :, :1],
+                                      block_diffusion=4).astype(
+            jnp.float32).sum(), q),
         _sparse_attention_loss(),
         (lambda x: sx.linear_cross_entropy(x, w, lab).sum(), x),
         (lambda x: ln.ln_residual(x, x, g, g)[0].astype(

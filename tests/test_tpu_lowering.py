@@ -169,6 +169,42 @@ def test_flash_window_and_grouped_heads_compile(tpu, real_kernels, T, H,
                 assert not used & kv_sized, line[:200]
 
 
+@pytest.mark.parametrize("L,H,Hkv,D,B,block", [
+    (8192, 32, 4, 128, 4, 1024),   # sdar-30b-a3b.train-8k-1chip: 16,384
+                                   # rows, 8 blocks a half
+    (8192, 32, 4, 128, 4, 512),    # the sweep's other candidate
+    (2048, 8, 8, 64, 8, 1024),     # a pair of heads a block
+    (1024, 6, 2, 64, 4, 1024),     # grouped at D = 64: packed, one block
+])
+def test_flash_block_diffusion_compiles(tpu, real_kernels, L, H, Hkv, D, B,
+                                        block):
+    """The kernels under the block-diffusion mask at the cell's widths:
+    their own names and no plain or windowed call beside them, no score
+    array over the rows, gradients in k's and v's shapes."""
+    import re
+
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    q = tpu.shape((1, 2 * L, H, D), jnp.bfloat16)
+    kv = tpu.shape((1, 2 * L, Hkv, D), jnp.bfloat16)
+
+    def f(q, k, v):
+        return jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, block_diffusion=B, block_q=block,
+            block_k=block).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    compiled = tpu.compile(f, q, kv, kv)
+    text = compiled.as_text()
+    for name in ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"):
+        assert _custom_calls(text, name + "_bd") >= 1, name
+        assert _custom_calls(text, name) == 0, name
+        assert _custom_calls(text, name + "_win") == 0, name
+    assert not re.search(rf"[\[,]({L}|{2 * L}),{2 * L}\]", text)
+    dq, dk, dv = compiled.out_info
+    assert (dq.shape, dk.shape, dv.shape) == (q.shape, kv.shape, kv.shape)
+
+
 def _sparse_shapes(tpu, T):
     """(q, k, v, index_q, index_k, index_w) at the benchmark's widths:
     32/4 heads of 128, indexer 16 x 64."""
