@@ -14,7 +14,11 @@ kernels (guide: /opt/skills/guides/pallas_guide.md):
 * forward: one grid cell per (head, q block, k block); the k-block axis is
   innermost, so the per-q-block running max ``m``, normalizer ``l`` and
   output accumulator live in VMEM scratch across k-steps; scores never
-  leave VMEM. Emits the logsumexp residual for the backward pass.
+  leave VMEM. Emits the logsumexp residual for the backward pass. A cell
+  is walked as STRIPS of queries, each against the keys it sees of the
+  cell as one wide tile: one pair of matmuls, one softmax pass and one
+  update of the row's state a strip, the strips issued as a pipeline
+  (``_fwd_kernel``; docs/flash_window.md "The forward's walk").
 * layout: the kernels read q, k, v, dO and write o, dq, dk, dv as the
   projections produce and consume them, ``[B, T, H·D]``, wherever whole
   heads fill whole 128-lane blocks: ``128 % D == 0 and (H·D) % 128 == 0``
@@ -50,8 +54,11 @@ kernels (guide: /opt/skills/guides/pallas_guide.md):
   matmul, no softmax pass: 6 of 16 at T = 1024), only the sub-tiles the
   diagonal crosses build a mask (4 of the other 10), and the rest run
   bare. A cell wholly below the diagonal, a non-causal cell and a ring
-  partial stay one tile: nothing to skip there, and every cut costs a
-  column of row statistics per sub-tile.
+  partial stay one tile of that lattice: nothing to skip there, and every
+  cut along the keys costs the backward kernels a column of row statistics
+  per sub-tile. (The forward cuts along the queries alone, which costs
+  none: its strips take the bare sub-tiles of a row and the crossed ones at
+  their ends as one tile.)
 * what is per row or per operand is done there and not on the score
   tile: ``scale`` is folded into q (into k in the dK/dV kernel) once per
   sub-tile row, the mask is one compare of a hoisted iota difference
@@ -112,8 +119,10 @@ kernel call adds to ``flash.tiles_total`` / ``flash.tiles_computed`` /
 ``bwd_dkv``): the sub-tiles of one head's grid, how many are computed and
 how many of those are masked (16 / 10 / 4 at the benchmark cells' shape,
 in either layout); a windowed call counts under one more label,
-``window``, a block-diffusion call under ``block``. ``flash.kv_group``
-adds the query heads a KV head of every grouped call.
+``window``, a block-diffusion call under ``block``. The forward also
+counts its walk, ``flash.strips`` and ``flash.softmax_updates`` (4 / 4 at
+that shape: ``_count_tiles``). ``flash.kv_group`` adds the query heads a
+KV head of every grouped call.
 
 Everything is static-shaped; block sizes adapt to divide the sequence
 (see ``_pick_block`` — a whole-sequence block covers anything <= the
@@ -151,10 +160,12 @@ _NEG_INF = -1e30  # finite: keeps running-max arithmetic NaN-free
 #   1024        128           351   440    507
 #   512         256          1001   680    796   the finer grid
 #
-# A [1024, 1024] f32 score tile is 4 MB of VMEM; the kernels keep one (a
-# non-causal cell, a cell below the diagonal, a ring partial) or a few
-# 256 KB ones. Tunable like the other HOROVOD_* knobs (e.g. for other
-# chip generations' VMEM sizes).
+# A [1024, 1024] f32 score tile is 4 MB of VMEM; the backward kernels keep
+# one (a non-causal cell, a cell below the diagonal, a ring partial) or a
+# few 256 KB ones, the forward two strips' ([128 or 256, <= 1024]: the
+# table's forward column is PR 25's walk, PR 42's is 270 us at the default).
+# Tunable like the other HOROVOD_* knobs (e.g. for other chip generations'
+# VMEM sizes).
 
 
 def _block_knob(name: str, default: int) -> int:
@@ -304,11 +315,16 @@ def _sub_tile(block: int, preferred: int) -> int:
 
 
 def _sub_tiles(mode: str, bq: int, bk: int, sub_tile):
-    """(tq, tk): the sub-tiles a kernel walks inside a (bq, bk) cell of
-    ``mode``. Only a cell that can skip is cut up: every cut costs each
-    kernel a column of row statistics per sub-tile ([tq, 1]: as many vregs
-    as half a [tq, 256] pass), which only skipped sub-tiles pay back."""
-    if mode != _SKIP and not isinstance(mode, (_Band, _Edge)):
+    """(tq, tk): the lattice of sub-tiles inside a (bq, bk) cell of
+    ``mode``: what ``flash.tiles_*`` count and what decides which pairs a
+    kernel computes. A cell that can skip (``_cuts``) has the (256, 256)
+    lattice, any other is one tile. The two BACKWARD kernels walk the
+    lattice tile by tile: a cut along the KEYS costs them a column of row
+    statistics per sub-tile ([tq, 1]: as many vregs as half a [tq, 256]
+    pass), which only skipped sub-tiles pay back. The forward cuts every
+    cell along the QUERIES alone (``_fwd_tiles``): rows are independent,
+    each with its own statistics, and a cut there costs no update."""
+    if not _cuts(mode):
         return bq, bk
     return _sub_tile(bq, sub_tile[0]), _sub_tile(bk, sub_tile[1])
 
@@ -327,10 +343,24 @@ _MASKED = "masked"  # crossed off the sub-tile lattice (bq != bk), or
                     # runtime offsets: every sub-tile, masked
 
 
-# (tq, tk) inside a cell that skips. Measured on v5e at D = 64, bf16, block
-# (1024, 1024), all three kernels (PERF.md, PR 25): 256 x 256 is the best
-# or within 2% of it for each; 128 x 128 and 512 x 512 lose 8-9% overall.
+# (tq, tk): the lattice inside a cell that skips. Measured on v5e at D = 64,
+# bf16, block (1024, 1024), all three kernels (PERF.md, PR 25): 256 x 256 is
+# the best or within 2% of it for each; 128 x 128 and 512 x 512 lose 8-9%
+# overall. The backward kernels walk it tile by tile; the forward walks its
+# ROWS as strips and meets the keys of a strip as one wide tile (PR 42).
 _SUB_TILE = (256, 256)
+# Rows of a forward strip in a cell with nothing to skip. The strips of a
+# cell are a pipeline (strip a + 1's score matmul under strip a's softmax),
+# which runs at half the MXUs while it fills and drains: the finer the
+# strips, the shorter both. By the compiled schedule a (1024, 1024) cell at
+# D = 128 takes 4,469 cycles at 128 rows, 4,800 at 256, 5,042 at 512 where
+# its matmuls need 4,096 (PERF.md, PR 42).
+_STRIP = 128
+
+
+def _cuts(mode) -> bool:
+    """Can a cell of ``mode`` skip sub-tiles?"""
+    return mode == _SKIP or isinstance(mode, (_Band, _Edge))
 
 
 def _cell_modes(causal, static_skip, nq, nk, bq, bk):
@@ -537,6 +567,32 @@ def _q_plan(mode, c, tk, bq, tq):
     return [(a, "c" if a < lo_full else "") for a in range(lo, bq // tq)]
 
 
+def _fwd_tiles(mode, bq: int, bk: int, sub_tile):
+    """(tq, tk) of the FORWARD's walk of a cell: strips of ``tq`` queries,
+    and ``tk`` the width of a sub-tile an edge crosses. A cell that can
+    skip keeps its lattice (the pairs computed are the lattice's); any
+    other is cut along the queries alone."""
+    if _cuts(mode):
+        return _sub_tiles(mode, bq, bk, sub_tile)
+    return _sub_tile(bq, _STRIP), bk
+
+
+def _strip_plan(mode, a, tq, bk, tk):
+    """``(lo, hi, [(c0, kind)])``: the keys ``lo .. hi - 1`` of the cell
+    that q strip ``a`` meets, as ONE tile, and the sub-tiles inside it that
+    an edge crosses (first key ``c0``, ``tk`` wide, ``_Band.kind``); None
+    for a strip that sees nothing of the cell. The sub-tiles a strip
+    computes lie side by side (``_k_plan``: what is skipped lies past the
+    diagonal or past the window, at the ends)."""
+    plan = _k_plan(mode, a, tq, bk, tk)
+    if not plan:
+        return None
+    (first, _), (last, _) = plan[0], plan[-1]
+    assert [c for c, _ in plan] == list(range(first, last + 1)), plan
+    return (first * tk, (last + 1) * tk,
+            [(c * tk, kind) for c, kind in plan if kind])
+
+
 def _visible(s, diag, kind, off, window, transposed=False):
     """Scores with the invisible entries of a masked sub-tile at -1e30.
     ``diag`` is the hoisted row minus column index; ``off`` the scalar the
@@ -643,6 +699,37 @@ def _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk, band=None,
         pl.when(valid & here)(functools.partial(body, mode))
 
 
+def _grid_cells(causal, static_skip, nq, nk, bq, bk, window=None,
+                block=None):
+    """``{(i, j): mode or None}``: what each (q block, k block) of one
+    head's square is to a call: the mode of the cell that runs there, None
+    where nothing runs (a causal call's future, a windowed call's cells
+    outside the band, the cells a block-diffusion call never visits)."""
+    cells = {(i, j): None for i in range(nq) for j in range(nk)}
+    if block is not None:
+        n = nq // 2
+        for i in range(nq):
+            for step in range(n + 1):
+                for mode, here in _bd_cells(i, step, n):
+                    if here:
+                        cells[i, _bd_k_block(i, step, n)] = mode
+    elif window is None:
+        modes = _cell_modes(causal, static_skip, nq, nk, bq, bk)
+        for i, j in cells:
+            for mode in modes:
+                if _cell_is(mode, causal, static_skip, i, j, bq, bk):
+                    cells[i, j] = mode
+                    break
+    else:
+        nkb = _band_blocks(window, bq, nk)
+        for mode, lo, hi in _band_cells(window, bq, nkb):
+            for i in range(nq):
+                for jj in range(lo, hi + 1):
+                    if i - (nkb - 1) + jj >= 0:
+                        cells[i, i - (nkb - 1) + jj] = mode
+    return cells
+
+
 def _tile_counts(causal, static_skip, nq, nk, bq, bk, window=None,
                  block=None):
     """(total, computed, masked) sub-tiles of one head, over the grid; a
@@ -651,37 +738,32 @@ def _tile_counts(causal, static_skip, nq, nk, bq, bk, window=None,
     windowed call's grid holds the band's cells alone; the cells outside
     it count to the total, at the cut-up lattice; so do the cells a
     block-diffusion call (``block`` its block length) never visits."""
-    if block is not None:
-        n = nq // 2
-        cell_modes = {(i, j): [] for i in range(nq) for j in range(nk)}
-        for i in range(nq):
-            for step in range(n + 1):
-                for mode, here in _bd_cells(i, step, n):
-                    if here:
-                        cell_modes[i, _bd_k_block(i, step, n)] = [mode]
-    elif window is None:
-        modes = _cell_modes(causal, static_skip, nq, nk, bq, bk)
-        cell_modes = {(i, j): [m for m in modes if _cell_is(
-            m, causal, static_skip, i, j, bq, bk)]
-            for i in range(nq) for j in range(nk)}
-    else:
-        nkb = _band_blocks(window, bq, nk)
-        cell_modes = {(i, j): [] for i in range(nq) for j in range(nk)}
-        for mode, lo, hi in _band_cells(window, bq, nkb):
-            for i in range(nq):
-                for jj in range(lo, hi + 1):
-                    if i - (nkb - 1) + jj >= 0:
-                        cell_modes[i, i - (nkb - 1) + jj] = [mode]
     total = computed = masked = 0
-    for mine in cell_modes.values():
-        mode = mine[0] if mine else _SKIP
-        tq, tk = _sub_tiles(mode, bq, bk, _SUB_TILE)
+    for mode in _grid_cells(causal, static_skip, nq, nk, bq, bk, window,
+                            block).values():
+        tq, tk = _sub_tiles(_SKIP if mode is None else mode, bq, bk,
+                            _SUB_TILE)
         total += (bq // tq) * (bk // tk)
-        for a in range(bq // tq if mine else 0):
+        for a in range(0 if mode is None else bq // tq):
             plan = _k_plan(mode, a, tq, bk, tk)
             computed += len(plan)
             masked += sum(1 for _, kind in plan if kind)
     return total, computed, masked
+
+
+def _strip_count(causal, static_skip, nq, nk, bq, bk, window=None,
+                 block=None):
+    """The q strips the FORWARD walks over one head's grid: a strip of a
+    cell that sees something of it. Each makes one online-softmax update
+    (``_fwd_kernel``): its keys are one tile."""
+    strips = 0
+    for mode in _grid_cells(causal, static_skip, nq, nk, bq, bk, window,
+                            block).values():
+        if mode is not None:
+            tq, tk = _fwd_tiles(mode, bq, bk, _SUB_TILE)
+            strips += sum(_strip_plan(mode, a, tq, bk, tk) is not None
+                          for a in range(bq // tq))
+    return strips
 
 
 def _count_tiles(kernel, *args, window=None, block=None):
@@ -689,7 +771,11 @@ def _count_tiles(kernel, *args, window=None, block=None):
     head and kernel call (monitor registry, as plan/accounting.py keeps
     trace-time wire bytes): nothing of this runs on the device. A windowed
     call counts under a label of its own, ``window`` = its width, a
-    block-diffusion call under ``block`` = its block length."""
+    block-diffusion call under ``block`` = its block length. The forward
+    also counts its walk: ``flash.strips``, the q strips it walks, and
+    ``flash.softmax_updates``, the times it brings a strip's running
+    maximum, normalizer and accumulator up to date: one a strip, where a
+    walk sub-tile by sub-tile made one a computed sub-tile."""
     from ..monitor.registry import counter
 
     labels = dict(kernel=kernel)
@@ -700,15 +786,20 @@ def _count_tiles(kernel, *args, window=None, block=None):
     for name, n in zip(("total", "computed", "masked"),
                        _tile_counts(*args, window=window, block=block)):
         counter(f"flash.tiles_{name}", **labels).inc(n)
+    if kernel == "fwd":
+        strips = _strip_count(*args, window=window, block=block)
+        counter("flash.strips", **labels).inc(strips)
+        counter("flash.softmax_updates", **labels).inc(strips)
 
 
 def _as_row(col):
     """[t, 1] → [1, t]: a per-query statistic laid along the lanes, as the
     statistics lie in HBM and as the dk/dv kernel's transposed score tiles
-    want them."""
+    want them. Also from [t, 128], the statistic on every lane of its row
+    (the forward's scratch)."""
     t = col.shape[0]
     if t % 128:
-        return col.T
+        return col[:, :1].T
     return jnp.broadcast_to(col, (t, 128)).T[0:1, :]
 
 
@@ -730,6 +821,32 @@ def _as_cols(*rows):
     return tuple(cols[:, n:n + 1] for n in range(len(rows)))
 
 
+def _per_row(stat, like):
+    """A row statistic as a factor of ``like``: the forward's scratch keeps
+    a statistic on all 128 lanes of its rows (``_fwd_call``), a reduction
+    gives one column; either multiplies a 128-lane tile as it is."""
+    if stat.shape[1] in (1, like.shape[1]):
+        return stat
+    return stat[:, :1]
+
+
+def _less_row_stat(s, stat):
+    """``s - stat`` for a tile ``s`` [t, W] and a statistic of its rows: a
+    [t, 1] column, or on every lane of [t, 128] (the forward's state). The
+    latter is taken off 128 lanes at a time, register by register: cut
+    down to a column first it would go through a lane broadcast on the
+    XLU, which made an uncut cell 5% and a band's far cell 67% slower by
+    the compiled schedule (PERF.md, PR 42)."""
+    if stat.shape[1] == 1:
+        return s - stat
+    w = min(s.shape[1], stat.shape[1])
+    stat = stat[:, :w]
+    if s.shape[1] == w:
+        return s - stat
+    return jnp.concatenate([s[:, c:c + w] - stat
+                            for c in range(0, s.shape[1], w)], axis=1)
+
+
 def _fwd_out(m, l, acc, o_dtype):
     """(o, lse [1, t]) of finished rows. Fully-masked rows have l == 0:
     emit o = 0 and lse = -inf-like so a ring merge weights them out.
@@ -739,20 +856,31 @@ def _fwd_out(m, l, acc, o_dtype):
     its size, and the kernels' largest operand."""
     safe_l = jnp.maximum(l, 1e-30)
     lse = jnp.where(l > 0, m + jnp.log(safe_l), _NEG_INF)
-    return (acc / safe_l).astype(o_dtype), _as_row(lse)
+    return (acc / _per_row(safe_l, acc)).astype(o_dtype), _as_row(lse)
 
 
 def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr,
                 *, scale, causal, bq, bk, nq, nk, static_skip, sub_tile, G,
                 band=None, bd=None):
+    """A cell is walked as STRIPS of queries, each against the keys it
+    sees of the cell as ONE tile (``_strip_plan``): one pair of matmuls,
+    one pass of maximum, exponential and sum and one update of the rows'
+    state a strip, whatever lies under it (a run of bare sub-tiles with a
+    crossed one at either end). The strips of a cell are independent
+    chains, and they are issued as a pipeline: strip a + 1's score matmul
+    stands in the source before strip a's softmax, since the compiler
+    assigns the two kinds of matmul to two MXUs each and schedules close
+    to source order: only with both kinds in flight are the four busy
+    (PERF.md, PR 42). The arithmetic of an update is what it was; where a
+    strip's tile is one sub-tile, so are its bits."""
     i = pl.program_id(2)   # q block
     g = pl.program_id(3)   # head inside the lane block (``_specs``)
     j = pl.program_id(4)   # k block (innermost: scratch carries across j);
     #                        windowed: its offset inside the band;
     #                        block diffusion: a step of ``_bd_k_block``
     window, inner = band if band else (None, bd[1] + 1 if bd else nk)
-    carried = inner > 1    # else a q sub-tile finishes inside this cell
+    carried = inner > 1    # else a q strip finishes inside this cell
 
     if carried:
         @pl.when(j == 0)
@@ -762,53 +890,75 @@ def _fwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def body(mode):
-        tq, tk = _sub_tiles(mode, bq, bk, sub_tile)
+        tq, tk = _fwd_tiles(mode, bq, bk, sub_tile)
         d0 = _first_q_minus_k(qoff_ref, koff_ref, i, j, bq, bk, mode)
         diag = _mask_index(mode, tq, tk, bd)
         qscale = _on_head(g, G, q_ref, scale)
+        walk = [(a, plan) for a in range(bq // tq)
+                if (plan := _strip_plan(mode, a, tq, bk, tk))]
 
-        def tile(a, c, q, carry, kind):
-            m_prev, l_prev, acc = carry
-            cols = pl.ds(c * tk, tk)
+        def scores(a, plan):
+            """Strip ``a``'s scaled queries against its keys, [tq, hi - lo]
+            float32, the sub-tiles an edge crosses masked in place."""
+            lo, hi, crossed = plan
+            q = q_ref[0, pl.ds(a * tq, tq), :] * qscale     # [tq, D], once
             s = jax.lax.dot_general(
-                q, k_ref[0, cols, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [tq, tk]
-            s = _visible(s, diag, kind, c * tk - a * tq - d0, window)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
+                q, k_ref[0, pl.ds(lo, hi - lo), :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if not crossed:
+                return s
+            parts, done = [], lo
+            for c0, kind in crossed:
+                if c0 > done:
+                    parts.append(s[:, done - lo:c0 - lo])
+                parts.append(_visible(s[:, c0 - lo:c0 - lo + tk], diag, kind,
+                                      c0 - a * tq - d0, window))
+                done = c0 + tk
+            if done < hi:
+                parts.append(s[:, done - lo:])
+            return parts[0] if len(parts) == 1 else jnp.concatenate(
+                parts, axis=1)
+
+        def finish(a, plan, s):
+            """The strip's softmax over its tile and ``p v``: the one update
+            of its rows' state, or, where a q block has this one cell, its
+            output."""
+            lo, hi, crossed = plan
+            rows = pl.ds(a * tq, tq)
+            m_new = jnp.max(s, axis=1, keepdims=True)
+            if carried:
+                m_prev = m_scr[rows, :]
+                m_new = jnp.maximum(m_prev, m_new)
             m_sub = m_new
-            if not static_skip or "w" in kind:
+            if not static_skip or any("w" in kind for _, kind in crossed):
                 # Fully-masked rows (a ring partial that sees a k block
                 # entirely in its causal future; a row whose window ends
-                # before this sub-tile, the first its q block meets):
-                # m_new stays at _NEG_INF and s - m_new == 0 would wrongly
-                # give p = 1. Subtracting 0 there instead gives
+                # before this tile, the first its q block meets): m_new
+                # stays at _NEG_INF and s - m_new == 0 would wrongly give
+                # p = 1. Subtracting 0 there instead gives
                 # p = exp(-1e30) = 0.
                 m_sub = jnp.where(m_new > _NEG_INF / 2, m_new, 0.0)
-            p = jnp.exp(s - m_sub)
-            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc = acc * alpha + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0, cols, :],
+            p = jnp.exp(_less_row_stat(s, m_sub))
+            l = jnp.sum(p, axis=1, keepdims=True)
+            acc = jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, pl.ds(lo, hi - lo), :],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            return m_new, l_new, acc
-
-        for a in range(bq // tq):
-            rows = pl.ds(a * tq, tq)
-            q = q_ref[0, rows, :] * qscale              # [tq, D], once
             if carried:
-                carry = m_scr[rows, :], l_scr[rows, :], acc_scr[rows, :]
+                alpha = jnp.exp(m_prev - m_new)
+                m_scr[rows, :] = m_new
+                l_scr[rows, :] = l_scr[rows, :] * alpha + l
+                acc_scr[rows, :] = (acc_scr[rows, :] * _per_row(alpha, acc)
+                                    + acc)
             else:
-                carry = (jnp.full((tq, 1), _NEG_INF, jnp.float32),
-                         jnp.zeros((tq, 1), jnp.float32),
-                         jnp.zeros((tq, q.shape[1]), jnp.float32))
-            for c, kind in _k_plan(mode, a, tq, bk, tk):
-                carry = tile(a, c, q, carry, kind)
-            if carried:
-                m_scr[rows, :], l_scr[rows, :], acc_scr[rows, :] = carry
-            else:
-                o, lse_ref[0, :, rows] = _fwd_out(*carry, o_ref.dtype)
+                o, lse_ref[0, :, rows] = _fwd_out(m_new, l, acc, o_ref.dtype)
                 _put(o_ref, (0, rows), o, g, G)
+
+        s = scores(*walk[0])
+        for n, (a, plan) in enumerate(walk):
+            ahead = scores(*walk[n + 1]) if n + 1 < len(walk) else None
+            finish(a, plan, s)
+            s = ahead
 
     _each_mode(body, causal, static_skip, i, j, bq, bk, nq, nk, band,
                band and j >= inner - 1 - i, bd and _bd_cells(i, j, bd[1]))
@@ -1022,10 +1172,14 @@ def _fwd_call(q_off, k_off, q, k, v, *, scale, causal, bq, bk, static_skip,
             _out_struct((N * heads, 1, Tq), jnp.float32, q, k, v, q_off,
                         k_off),
         ],
+        # The running max m and normalizer l of a row on ALL the lanes of
+        # its scratch row: a [bq, 1] column takes as many registers and a
+        # lane broadcast (XLU) on every load; at D = 128 the accumulator is
+        # rescaled by the loaded register as it is.
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),     # running max m
-            pltpu.VMEM((bq, 1), jnp.float32),     # running normalizer l
-            pltpu.VMEM((bq, lanes), jnp.float32),  # output accumulator
+            pltpu.VMEM((bq, _LANES), jnp.float32),   # running max m
+            pltpu.VMEM((bq, _LANES), jnp.float32),   # running normalizer l
+            pltpu.VMEM((bq, lanes), jnp.float32),    # output accumulator
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interpret,

@@ -44,7 +44,8 @@ _loaded = False
 # returned before any legality/sweep logic runs). The candidate grid is
 # additionally hashed into the key, so grid edits self-invalidate.
 _KERNEL_VERSIONS: Dict[str, int] = {
-    "flash_attention": 2,   # 2: sub-tiles inside the grid cell (PR 25)
+    "flash_attention": 3,   # 2: sub-tiles inside the grid cell (PR 25);
+                            # 3: the forward walks by strips (PR 42)
     "linear_xent": 1,
     "selective_scan": 2,   # 2: the backward a chunk of all channels (PR 40)
 }

@@ -23,7 +23,14 @@ block-diffusion mask of block length N over T = 2 L rows
 sub-tile ``TQxTK`` (the rule's own choice when the list is empty). ``--module FILE`` times another
 copy of ``ops/flash_attention.py`` (e.g. the parent commit's) in the same
 process; such a copy ignores ``--subtiles`` unless it has ``_SUB_TILE``.
-Needs a TPU (anything else: exit 2). Results also go to
+Beside each kernel's time stands ``mxu_share``: the share of it that the
+matmuls the kernel EXECUTES need at the MXU's peak (the sub-tiles it visits,
+``flash.tiles_computed``'s, x 2 / 3 / 4 matmuls a tile forward / dq / dk-dv
+x 128 lanes, a head's width on the MXU in place, D packed), so that the
+forward's rate a matmul reads against the backward's in one line
+(PERF.md section 5, PR 42: the default ``--shapes`` are its table;
+``phi-4-mini-flash``'s 40 / 20 heads of 64 reach the kernels as 40 / 10 of
+128, ``.kv10``: differential attention is one 128-wide call). Needs a TPU (anything else: exit 2). Results also go to
 ``chiprun_out/flash_kernel_times.jsonl``.
 """
 
@@ -89,6 +96,54 @@ def kernel_us(trace_dir, kernels=KERNELS, calls=0):
     return us
 
 
+MATMULS = {"fwd": 2, "bwd_dq": 3, "bwd_dkv": 4}   # a score tile
+# One call of each cell's kind (PERF.md section 5): the GPT-2 cells',
+# trinity-mini's full and windowed, phi-4-mini-flash's, sdar's.
+TABLE = ("8x1024x16x64c,1x8192x32x128c.kv4,1x8192x32x128c.kv4.w2048,"
+         "1x8192x40x128c.kv20,1x8192x40x128c.kv20.w512,"
+         "1x16384x32x128c.kv4.bd4")
+
+
+def executed_matmul_us(shape, block, peak_flops):
+    """{``fwd`` | ``bwd_dq`` | ``bwd_dkv``: us} the matmuls a kernel of the
+    call executes need at ``peak_flops``: the pairs of the sub-tiles it
+    visits (the tree's own lattice: a copy given by ``--module`` visits the
+    same pairs) x 2 x the head's width on the MXU x matmuls a tile."""
+    from horovod_tpu.ops import flash_attention as F
+
+    B, T, H, D, causal, kv_heads, window, bd = shape
+    b = F._pick_block(T // (2 if bd else 1), block)
+    n = T // b
+    pairs = 0
+    for mode in F._grid_cells(causal, True, n, n, b, b, window, bd).values():
+        if mode is not None:
+            tq, tk = F._sub_tiles(mode, b, b, F._SUB_TILE)
+            pairs += tq * tk * sum(len(F._k_plan(mode, a, tq, b, tk))
+                                   for a in range(b // tq))
+    width = 128 if F._in_place(H, kv_heads, D) else D
+    flops = 2.0 * pairs * width * B * H
+    return {k: m * flops / peak_flops * 1e6 for k, m in MATMULS.items()}
+
+
+def mxu_shares(us, shape, block, peak_flops):
+    """{kernel name: executed matmuls' us / measured us} of one row."""
+    need = executed_matmul_us(shape, block, peak_flops)
+    return {name: round(need_us / mean_us, 4)
+            for name, (mean_us, _) in us.items()
+            for kind, need_us in need.items()
+            if name.startswith("hvd_flash_" + kind)}
+
+
+def peak_flops():
+    """The bf16 peak of this process's chip (``benchmarks/lib/peaks.json``:
+    a chip that is not in the table is an error, not a default)."""
+    import jax
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "lib", "peaks.json")) as f:
+        return json.load(f)[jax.devices()[0].device_kind]["bf16_flops_per_s"]
+
+
 def time_one(mod, shape, block, steps):
     import jax
     import jax.numpy as jnp
@@ -141,7 +196,8 @@ def parse_shape(text: str):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--shapes", default="8x1024x16x64c")
+    ap.add_argument("--shapes", default=TABLE,
+                    help="comma-separated; default: PERF.md section 5's table")
     ap.add_argument("--subtiles", default="")
     ap.add_argument("--blocks", default="1024",
                     help="grid blocks (bq = bk), comma-separated")
@@ -155,6 +211,7 @@ def main(argv=None) -> int:
         print("flash_kernel_times: needs a TPU", file=sys.stderr)
         return 2
     mod = load_module(args.module)
+    peak = peak_flops()
     shapes = [parse_shape(s) for s in args.shapes.split(",")]
     subs = [tuple(map(int, s.split("x")))
             for s in args.subtiles.split(",") if s] or [None]
@@ -171,6 +228,8 @@ def main(argv=None) -> int:
                         us = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
                     row = {"module": args.module or "tree", "shape": shape,
                            "block": block, "subtile": sub, "us": us}
+                    if "error" not in us:
+                        row["mxu_share"] = mxu_shares(us, shape, block, peak)
                     print(json.dumps(row), flush=True)
                     log.write(json.dumps(row) + "\n")
     return 0

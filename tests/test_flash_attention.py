@@ -568,19 +568,36 @@ class TestInPlaceLayout:
                                        rtol=2e-3, atol=1e-6)
 
 
+def _seen(T, *, causal=True, window=None, block=None, q_off=0, k_off=0):
+    """[T, T] bool: which (query, key) pairs a call sees, from the masks'
+    definitions."""
+    from horovod_tpu.ops.flash_attention import block_diffusion_mask
+
+    if block is not None:
+        return np.asarray(block_diffusion_mask(T // 2, block))
+    ahead = (q_off + np.arange(T))[:, None] - (k_off + np.arange(T))[None, :]
+    seen = ahead >= 0 if causal else np.ones((T, T), bool)
+    if window is not None:
+        seen &= ahead < window
+    return seen
+
+
+def _dense_o_lse(q, k, v, seen):
+    """q [B, T, H, D], k / v [B, T, Hkv, D] -> (o [B, T, H, D], lse
+    [B * H, T]): plain attention and its log-sum-exp under ``seen``."""
+    B, T, H, D = q.shape
+    k, v = (jnp.repeat(x, H // k.shape[2], axis=2) for x in (k, v))
+    s = jnp.where(seen, jnp.einsum("bqhd,bkhd->bhqk", q, k) * D ** -0.5,
+                  -1e30)
+    p = jnp.where(seen, jax.nn.softmax(s, axis=-1), 0.0)
+    return (jnp.einsum("bhqk,bkhd->bqhd", p, v),
+            jax.nn.logsumexp(s, axis=-1).reshape(B * H, T))
+
+
 def _dense_window(q, k, v, window):
     """Plain attention with grouped KV heads and a window: query t sees
     keys t - window + 1 .. t."""
-    B, T, H, D = q.shape
-    group = H // k.shape[2]
-    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * D ** -0.5
-    ahead = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
-    seen = ahead >= 0
-    if window is not None:
-        seen &= ahead < window
-    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    return _dense_o_lse(q, k, v, _seen(q.shape[1], window=window))[0]
 
 
 def _grouped_qkv(T, H, Hkv, D, seed=0):
@@ -720,3 +737,125 @@ class TestWindowAndGroupedHeads:
         # The dk/dv kernel's second axis counts KV heads, its fourth the
         # query heads of a group: dk, dv are summed over them in scratch.
         assert grids["hvd_flash_bwd_dkv_win"] == (1, 1, 4, 2, 2)
+
+
+# (T, H, Hkv, D, bq, bk, layout, the call's mask). What each walks:
+_WALKS = {
+    # one (512, 512) cell a head, T = bq: two strips, the second a
+    # (256, 512) tile whose last sub-tile the diagonal crosses; the state
+    # never leaves the registers
+    "causal_T_is_bq": (512, 2, 2, 64, 512, 512, "in_place", {}),
+    # 2 x 2 cells: a whole cell below the diagonal is four strips of 128
+    # against all 512 keys, the state carried in scratch
+    "causal_full_cells": (1024, 2, 2, 64, 512, 512, "in_place", {}),
+    # the band of cells [far, whole, diagonal] as window 2048 at blocks of
+    # 1024 has it
+    "window_two_blocks": (2048, 1, 1, 128, 512, 512, "in_place",
+                          dict(window=1024)),
+    "window_half_a_block": (1024, 1, 1, 128, 512, 512, "in_place",
+                            dict(window=256)),
+    # off the lattice: both edges inside one sub-tile row
+    "window_300": (1024, 2, 2, 64, 512, 512, "in_place", dict(window=300)),
+    "grouped_kv_heads": (1024, 4, 1, 128, 512, 512, "in_place", {}),
+    "grouped_kv_heads_window": (1024, 4, 2, 128, 512, 512, "in_place",
+                                dict(window=640)),
+    "block_diffusion": (1024, 2, 1, 128, 256, 256, "in_place",
+                        dict(block=4)),
+    "packed": (1024, 3, 3, 64, 512, 512, "packed", {}),
+    "packed_grouped_window": (1024, 4, 2, 64, 512, 512, "packed",
+                              dict(window=384)),
+    "not_causal": (512, 2, 2, 64, 256, 256, "in_place", dict(causal=False)),
+    # cells the diagonal crosses off the lattice: one masked tile a strip
+    "masked_bq_is_not_bk": (1024, 2, 2, 64, 512, 256, "in_place", {}),
+    # a ring partial: runtime offsets, every strip masked, rows that see
+    # nothing
+    "masked_runtime_offsets": (512, 2, 2, 64, 256, 256, "in_place",
+                               dict(q_off=128, k_off=384)),
+    "masked_runtime_offsets_packed": (512, 1, 1, 32, 512, 512, "packed",
+                                      dict(q_off=256, k_off=256)),
+}
+
+
+class TestStripWalk:
+    """The forward walks every cell as strips of queries, each against the
+    keys it sees of the cell as one tile (``_fwd_kernel``; PR 42)."""
+
+    @pytest.mark.parametrize("case", sorted(_WALKS))
+    def test_output_and_lse_are_the_dense_ones(self, case):
+        from horovod_tpu.ops import flash_attention as F
+
+        T, H, Hkv, D, bq, bk, layout, mask = _WALKS[case]
+        mask = dict(mask)
+        causal = mask.pop("causal", True)
+        offsets = {k: jnp.int32(mask.pop(k)) for k in ("q_off", "k_off")
+                   if k in mask}
+        rs = np.random.RandomState(len(case))
+        q, k, v = (jnp.asarray(rs.randn(1, T, n, D), jnp.float32)
+                   for n in (H, Hkv, Hkv))
+        seen = _seen(T, causal=causal, **mask,
+                     **{k: int(x) for k, x in offsets.items()})
+        o_d, lse_d = _dense_o_lse(q, k, v, seen)
+        if layout == "packed":
+            args, heads = [F._pack(x) for x in (q, k, v)], 1
+        else:
+            args, heads = [x.reshape(1, T, -1) for x in (q, k, v)], H
+        o, lse = F._flash_fwd(
+            *args, D ** -0.5, causal, bq, bk, heads=heads, group=H // Hkv,
+            static_skip=not offsets, **offsets, **mask)
+        o = F._unpack(o, 1, H) if layout == "packed" else o.reshape(q.shape)
+        rows = seen.any(axis=1)
+        np.testing.assert_allclose(np.asarray(o)[:, rows],
+                                   np.asarray(o_d)[:, rows],
+                                   rtol=1e-5, atol=2e-6)
+        np.testing.assert_allclose(np.asarray(lse)[:, 0][:, rows],
+                                   np.asarray(lse_d)[:, rows],
+                                   rtol=1e-5, atol=2e-6)
+        assert not np.asarray(o)[:, ~rows].any()
+        assert (np.asarray(lse)[:, 0][:, ~rows] == -1e30).all()
+
+    @pytest.mark.parametrize("T,bq,bk,kw,tiles,strips", [
+        # the GPT-2 cells: a head is one cell that skips, four strips
+        (1024, 1024, 1024, {}, (16, 10, 4), 4),
+        # trinity-mini's full call: 28 whole cells of eight strips, eight
+        # on the diagonal of four
+        (8192, 1024, 1024, {}, (604, 108, 32), 256),
+        (8192, 1024, 1024, dict(window=2048), (919, 147, 56), 112),
+        # phi-4-mini-flash's sliding call at either blocking
+        (8192, 1024, 1024, dict(window=512), (1024, 93, 62), 46),
+        (8192, 512, 512, dict(window=512), (1024, 93, 62), 62),
+        # sdar: the block-diffusion grid over 16,384 rows
+        (16384, 1024, 1024, dict(block=4), (3256, 248, 96), 544),
+        (2048, 1024, 512, {}, (36, 18, 6), 40),
+        (1024, 512, 512, {}, (13, 7, 4), 8),
+        # a ring partial: one masked tile a strip of 128
+        (1024, 1024, 1024, dict(q_off=jnp.int32(1024), k_off=jnp.int32(0),
+                                static_skip=False), (1, 1, 1), 8),
+    ])
+    def test_counters(self, T, bq, bk, kw, tiles, strips):
+        """``flash.strips`` and ``flash.softmax_updates`` of the forward:
+        one update a strip, fewer than one a computed sub-tile wherever
+        the call's cells are cut; ``flash.tiles_*`` read what they read
+        before the forward walked by strips (the pairs computed are the
+        same)."""
+        from horovod_tpu.ops import flash_attention as F
+
+        labels = dict(kernel="fwd", **{k: str(kw[k]) for k in (
+            "window", "block") if k in kw})
+        names = ("flash.tiles_total", "flash.tiles_computed",
+                 "flash.tiles_masked", "flash.strips",
+                 "flash.softmax_updates")
+        read = lambda: [counter(n, **labels).value for n in names]
+        before = read()
+        x = jax.ShapeDtypeStruct((1, T, 128), jnp.bfloat16)
+        jax.eval_shape(lambda q: F._flash_fwd(
+            q, q, q, 0.125, True, bq, bk, **kw), x)
+        got = [a - b for a, b in zip(read(), before)]
+        assert tuple(got[:3]) == tiles
+        assert got[3] == got[4] == strips
+        cut = [F._cuts(mode) for mode in F._grid_cells(
+            True, kw.get("static_skip", True), T // bq, T // bk, bq, bk,
+            kw.get("window"), kw.get("block")).values() if mode is not None]
+        if all(cut):
+            assert strips < tiles[1]
+        # ... and the backward kernels count no walk of their own.
+        assert not counter("flash.strips", kernel="bwd_dq").value

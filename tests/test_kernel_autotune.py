@@ -60,16 +60,16 @@ class TestGetOrTune:
 
     def test_entry_of_an_older_kernel_version_is_not_read_back(
             self, fresh_cache, monkeypatch):
-        """Blocks swept against the version-1 flash kernels (one score
-        square a grid cell) say nothing about version 2: the sweep runs
-        again and stores beside the stale entry."""
+        """Blocks swept against the version-2 flash kernels (a forward that
+        walks a cell sub-tile by sub-tile) say nothing about version 3: the
+        sweep runs again and stores beside the stale entry."""
         import jax
 
         monkeypatch.setattr(at, "enabled", lambda: True)
-        assert at._KERNEL_VERSIONS["flash_attention"] == 2
+        assert at._KERNEL_VERSIONS["flash_attention"] == 3
         cands = [(512, 512), (1024, 1024)]
         chip = getattr(jax.devices()[0], "device_kind", "tpu")
-        stale = (f"flash_attention|{chip}|sigF|v1."
+        stale = (f"flash_attention|{chip}|sigF|v2."
                  f"g{at._grid_token(cands)}")
         fresh_cache.write_text(json.dumps({stale: {"blocks": [512, 512]}}))
         calls = []
@@ -83,7 +83,7 @@ class TestGetOrTune:
         assert sorted(calls) == cands
         disk = json.loads(fresh_cache.read_text())
         assert disk[stale]["blocks"] == [512, 512]
-        assert disk[stale.replace("|v1.", "|v2.")]["blocks"] == [1024, 1024]
+        assert disk[stale.replace("|v2.", "|v3.")]["blocks"] == [1024, 1024]
 
     def test_failing_candidates_skipped(self, fresh_cache, monkeypatch):
         monkeypatch.setattr(at, "enabled", lambda: True)
