@@ -10,10 +10,9 @@ A module beside ``sparse_moe_decoder.py`` and not new kinds of its block:
 that block is RMSNorm, rotary position, per-head q / k norms and an untied
 head around attention and experts, and none of it is here. What the two
 share is imported, not copied: the call of the flash kernels
-(``causal_attention``), the gated MLP (``_GatedMLP``), the RMS norm
-(``rms_norm_in_scope``), the rule for what a rematerialised block keeps
-(``kept_within`` with its value names) and GPT-2's LayerNorm
-(``gpt._layer_norm``).
+(``causal_attention``), the gated MLP (``_GatedMLP``), the rule for what a
+rematerialised block keeps (``kept_within`` with its value names) and
+GPT-2's LayerNorm (``gpt._layer_norm``).
 
 With ``u = LayerNorm(x)`` (weight and bias), every layer is
 ``h = x + Mixer(u)``, ``y = h + W2 (silu(W1 z) * W3 z)``,
@@ -48,7 +47,10 @@ query head ``2p + e`` is its 64 values in half ``e`` of a 128-wide head with
 zeros in the other half, so ``q . [k1 | k2]`` is ``q1 . k1`` (``e = 0``) or
 ``q2 . k2`` (``e = 1``) and the head's output is ``P_e [v1 | v2]``: 40
 query heads on 10 KV heads, scale 1/8. The zeros cost no matmul time on a
-128-wide MXU and 84 MB of q at 8k.
+128-wide MXU and 84 MB of q at 8k. The two elementwise halves around that
+call, the query into its half and the pair's difference with its 128-wide
+norm, are ``ops/diff_attention.py``'s ``lay_in_halves`` and
+``diff_combine``: one pass each way over ``[B, T, H * 128]`` as it lies.
 
 Each block is rematerialised in the backward pass and takes ``m``, ``k``
 and ``v`` as extra inputs and outputs: a block's inputs are kept, so what
@@ -78,12 +80,12 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ..ops import diff_attention as _diff
 from ..ops import flash_attention as _flash
 from ..ops import selective_scan as _scan
 from .gpt import _layer_norm
 from .sparse_moe_decoder import (MLP_HIDDEN_NAME, QKV_NAME, _GatedMLP,
-                                 causal_attention, kept_within,
-                                 rms_norm_in_scope)
+                                 causal_attention, kept_within)
 
 MAMBA, SLIDING, FULL = "mamba", "sliding_attention", "full_attention"
 GMU, CROSS = "gmu", "cross_attention"
@@ -307,9 +309,7 @@ class _DiffAttention(nn.Module):
             q = checkpoint_name(proj(u, "wq", d, H * D), QKV_NAME)
         with jax.named_scope("hvd.diff_attention"):
             # Head 2p + e: its 64 values in half e of a 128-wide head.
-            q = q.reshape(B, T, H // 2, 2, 1, D)
-            q = (q * jnp.eye(2, dtype=q.dtype)[:, :, None]).reshape(
-                B, T, H, 2 * D)
+            q = _diff.lay_in_halves(q, D).reshape(B, T, H, 2 * D)
         o = causal_attention(
             q, *kv, window=cfg.sliding_window if kind == SLIDING else None,
             scale=D ** -0.5)
@@ -318,12 +318,10 @@ class _DiffAttention(nn.Module):
                                   for n in ("lq1", "lk1", "lq2", "lk2"))
             lam0 = 0.8 - 0.6 * math.exp(-0.3 * cfg.layers[self.index])
             lam = jnp.exp(lq1 @ lk1) - jnp.exp(lq2 @ lk2) + lam0
-            o = o.reshape(B, T, H // 2, 2, 2 * D).astype(f32)
-            a = o[:, :, :, 0] - lam * o[:, :, :, 1]
             scale = self.param("subln", nn.initializers.ones, (2 * D,), f32)
-            a = rms_norm_in_scope(a, scale * (1.0 - lam0),
-                                  cfg.layer_norm_eps).astype(cfg.dtype)
-        return proj(a.reshape(B, T, H * D), "wo", H * D, d), kv
+            a = _diff.diff_combine(o.reshape(B, T, H * 2 * D), lam,
+                                   scale * (1.0 - lam0), cfg.layer_norm_eps)
+        return proj(a, "wo", H * D, d), kv
 
 
 class _GMU(nn.Module):

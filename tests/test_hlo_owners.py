@@ -337,3 +337,55 @@ def test_fusions_of_a_tiny_step_are_read_by_what_is_inside(step, tiny_texts,
                        if ho.key_of(i.path)[0] == ho.UNOWNED]
     assert any("hvd.optimizer_update" in {o for o, _ in owned[i.name]}
                for i in by_root_unowned)
+
+
+# -- scripts/scope_bytes.py: the same text, counted by bytes -------------------
+
+BYTES_TEXT = f"""HloModule jit_spmd, is_scheduled=true
+
+%fused_mm (p.0: bf16[8,16], p.1: bf16[16,4]) -> bf16[8,4] {{
+  %p.0 = bf16[8,16]{{1,0}} parameter(0)
+  %p.1 = bf16[16,4]{{1,0}} parameter(1)
+  ROOT %dot.1 = bf16[8,4]{{1,0}} dot(%p.0, %p.1), lhs_contracting_dims={{1}}, rhs_contracting_dims={{0}}{_md(G + "/hvd.attn_proj/dot_general")}
+}}
+
+%fused_mul (p.2: bf16[8,16]) -> f32[8,16] {{
+  %p.2 = bf16[8,16]{{1,0}} parameter(0)
+  %c.2 = f32[8,16]{{1,0}} convert(%p.2){_md(G + "/hvd.diff_attention/convert")}
+  ROOT %m.2 = f32[8,16]{{1,0}} multiply(%c.2, %c.2){_md(G + "/hvd.diff_attention/mul")}
+}}
+
+ENTRY %main (a: bf16[8,16], w: bf16[16,4]) -> (bf16[8,4], bf16[8,32]) {{
+  %a = bf16[8,16]{{1,0:T(8,128)(2,1)}} parameter(0)
+  %w = bf16[16,4]{{1,0}} parameter(1)
+  %view = bf16[128]{{0}} bitcast(%a)
+  %wide = f32[8,16]{{1,0:T(8,128)}} fusion(%a), kind=kLoop, calls=%fused_mul{_md(G + "/hvd.diff_attention/mul")}
+  %again = f32[8,16]{{1,0}} copy(%wide){_md(R + "/hvd.diff_attention/copy")}
+  %mm = bf16[8,4]{{1,0}} fusion(%a, %w), kind=kOutput, calls=%fused_mm{_md(G + "/hvd.attn_proj/dot_general")}
+  %kernel = bf16[8,32]{{1,0}} custom-call(%a), custom_call_target="tpu_custom_call"{_md(B + "/hvd.diff_attention/hvd_diff_lay_fwd/pallas_call")}
+  ROOT %out = (bf16[8,4]{{1,0}}, bf16[8,32]{{1,0}}) tuple(%mm, %kernel)
+}}
+"""
+
+
+def test_scope_bytes_counts_operands_and_results_by_scope():
+    """``scripts/scope_bytes.py``: an entry instruction moves its operands'
+    and its result's bytes, goes to its own path's innermost scope and
+    direction, and matmul fusions and custom calls stand apart."""
+    scripts = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts")
+    sys.path.insert(0, scripts)
+    try:
+        import scope_bytes as sb
+    finally:
+        sys.path.remove(scripts)
+    rows = sb.moved(BYTES_TEXT)
+    assert [r[0].name for r in rows] == ["wide", "again", "mm", "kernel"]
+    table = sb.by_scope(rows)
+    diff = "hvd.diff_attention"
+    assert table[(diff, ho.FORWARD)][sb.ELEMENTWISE] == [1, 256 + 512]
+    assert table[(diff, ho.REMAT)][sb.ELEMENTWISE] == [1, 512 + 512]
+    assert table[(diff, ho.BACKWARD)][sb.KERNEL] == [1, 256 + 512]
+    assert table[("hvd.attn_proj", ho.FORWARD)][sb.MATMUL] == [
+        1, 256 + 128 + 64]
+    assert sb.ELEMENTWISE not in table[("hvd.attn_proj", ho.FORWARD)]

@@ -23,6 +23,7 @@ from benchmarks.lib import reference_sambay as ref
 from horovod_tpu.models import SambaY, SambaYConfig
 from horovod_tpu.models import sambay
 from horovod_tpu.monitor.registry import counter
+from test_diff_attention import parent_combine, parent_lay
 
 CFG = {"model_type": "phi4flash", "num_hidden_layers": 32,
        "hidden_size": 64, "num_attention_heads": 4,
@@ -225,6 +226,26 @@ def test_differential_attention_is_two_explicit_softmax_maps(weights):
     assert float(moved[at + 511]) > 1e-6
     assert float(moved[at + 512:].max()) == 0.0
     assert float(moved[:at].max()) == 0.0
+
+
+@pytest.mark.parametrize("dtype, rtol", [(jnp.float32, 2e-4),
+                                         (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+def test_loss_and_gradients_are_the_expressions_they_replaced(
+        monkeypatch, weights, dtype, rtol):
+    """``ops/diff_attention.py``'s two functions with their hand-written
+    backwards against the expressions ``_DiffAttention`` held before them,
+    differentiated by JAX: the model's loss and every gradient leaf."""
+    toks = _tokens(9)[0]
+    model = SambaY(SambaYConfig.from_dict(CFG, dtype=dtype))
+    got_l, got_g = jax.value_and_grad(_program_loss(model, toks))(weights)
+
+    monkeypatch.setattr(sambay._diff, "lay_in_halves", parent_lay)
+    monkeypatch.setattr(sambay._diff, "diff_combine", parent_combine)
+    want_l, want_g = jax.value_and_grad(_program_loss(model, toks))(weights)
+    assert abs(float(got_l) - float(want_l)) < 0.5 * rtol * abs(float(want_l))
+    _close(got_g, want_g, rtol)
+    assert float(jnp.abs(want_g["h1"]["mixer"]["subln"]).max()) > 0
 
 
 def test_one_adamw_step(weights):

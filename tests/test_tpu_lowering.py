@@ -275,6 +275,41 @@ def test_selective_scan_compiles(tpu, real_kernels, T, Dn, N, chunk,
     assert _custom_calls(text, "hvd_selective_scan_bwd") == 1
 
 
+@pytest.mark.parametrize("T,H,D,dtype", [
+    (8192, 40, 64, jnp.bfloat16),   # phi-4-mini-flash.train-8k-1chip
+    (8192, 40, 64, jnp.float32),    # its blocks at twice the bytes
+    (1000, 6, 128, jnp.bfloat16),   # a last block the rows do not fill,
+                                    # 256-lane heads, three pairs
+])
+def test_diff_attention_kernels_compile(tpu, real_kernels, T, H, D, dtype):
+    """Differential attention's two elementwise halves, a kernel each
+    direction, over the arrays as they lie."""
+    from horovod_tpu.ops.diff_attention import diff_combine, lay_in_halves
+
+    def f(q, lam, scale):
+        def loss(q, lam, scale):
+            o = lay_in_halves(q, D)        # stands in for the flash call
+            return (diff_combine(o, lam, scale, 1e-5).astype(
+                jnp.float32) ** 2).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, lam, scale)
+
+    compiled = tpu.compile(f, tpu.shape((1, T, H * D), dtype),
+                           tpu.shape((), jnp.float32),
+                           tpu.shape((2 * D,), jnp.float32))
+    text = compiled.as_text()
+    import re
+
+    for name in ("hvd_diff_lay_fwd", "hvd_diff_lay_bwd",
+                 "hvd_diff_combine_fwd", "hvd_diff_combine_bwd"):
+        # outside a named scope the call is ``%jvp_<name>_.1``
+        assert len(re.findall(rf"%\w*{name}[\w.]* = [^\n]*custom-call\(",
+                              text)) == 1, name
+    # no float32 copy of the wide array, no view with the pair as a
+    # dimension beside the lanes
+    assert f"f32[1,{T},{H * 2 * D}]" not in text or dtype == jnp.float32
+    assert f",2,{2 * D}]" not in text
+
+
 @pytest.mark.parametrize("limit_mb", [
     96,   # the kernels' limit
     72,   # a quarter of it stays free beside the compiler's own use in a
