@@ -135,10 +135,6 @@ from .ops.sparse_attention import (  # noqa: F401
     masked_attention,
     sparse_attention,
 )
-from .ops.fused_collective import (  # noqa: F401
-    fused_all_gather_matmul,
-    fused_matmul_reduce_scatter,
-)
 from .ops.softmax_xent import (  # noqa: F401
     linear_cross_entropy,
     lm_head_loss,

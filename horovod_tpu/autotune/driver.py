@@ -39,10 +39,8 @@ log = logging.getLogger("horovod_tpu.autotune")
 #     leg order | per-hop dtype | stream placement) stored alongside the
 #     knobs — the GP now searches plan space (docs/wire-plan.md);
 #     from_dict/load stay tolerant of v3/v4 entries.
-# v6: + the fused Pallas kernel backend knob (docs/fused-kernels.md) —
-#     the plan encoding gains the trailing `|pl` segment and TunedParams
-#     the `fused` field; from_dict/load stay tolerant of v5 entries
-#     (fused defaults False, the exact pre-v6 wire).
+# v6: a `fused` kernel-backend knob that is gone again; from_dict takes
+#     what it knows by key, so an entry that still carries it loads.
 # v7: cost-model-driven warm start (docs/cost-model.md) — the cache key
 #     carries the full geometry fingerprint (mesh shape x world x device
 #     kind, basics.mesh_geometry: a winner tuned on one chip kind never
@@ -188,7 +186,7 @@ def _store_cached_params(key: str, params: TunedParams, *,
 def _priced_seeds(payload_bytes: float, k: int, *, initial: TunedParams,
                   quantized: bool, tune_hierarchical: bool,
                   tune_zero: bool, tune_overlap: bool,
-                  tune_fused: bool, tune_pp: bool = False,
+                  tune_pp: bool = False,
                   pp_stages: int = 0, pp_max_interleave: int = 1,
                   tune_moe: bool = False, moe_experts: int = 0):
     """Top-``k`` cost-model-priced candidates for this session's search
@@ -202,7 +200,7 @@ def _priced_seeds(payload_bytes: float, k: int, *, initial: TunedParams,
     return _wire_planner.shortlist(
         payload_bytes, quantized=quantized, k=k,
         tune_hierarchical=tune_hierarchical, tune_zero=tune_zero,
-        tune_overlap=tune_overlap, tune_fused=tune_fused,
+        tune_overlap=tune_overlap,
         tune_pp=tune_pp, pp_stages=pp_stages,
         pp_max_interleave=pp_max_interleave,
         tune_moe=tune_moe, moe_experts=moe_experts,
@@ -272,7 +270,6 @@ def autotune_session(
     tune_hierarchical: bool = True,
     tune_zero: bool = False,
     tune_overlap: bool = False,
-    tune_fused: bool = False,
     tune_pp: bool = False,
     pp_stages: int = 0,
     pp_max_interleave: int = 1,
@@ -315,13 +312,9 @@ def autotune_session(
     would silently score a config it never ran. ``tune_overlap`` gates
     the ``overlap`` + ``num_comm_streams`` pair the same way (overlap ×
     ``backward_passes_per_step`` restructures the accumulation state,
-    docs/overlap.md). ``tune_fused`` adds the fused Pallas kernel
-    backend (docs/fused-kernels.md) to the search — only meaningful on
-    a quantized wire, where the int8 legs have a kernel lowering; on an
-    unquantized wire canonicalization collapses the dimension to one
-    trial. ``tune_pp`` (with ``pp_stages`` = the mesh's stage count and
-    ``pp_max_interleave`` = the deepest virtual-stage split the model's
-    layer count allows) adds the pipeline schedule pair —
+    docs/overlap.md). ``tune_pp`` (with ``pp_stages`` = the mesh's stage
+    count and ``pp_max_interleave`` = the deepest virtual-stage split the
+    model's layer count allows) adds the pipeline schedule pair —
     ``pp_microbatches`` (pow2, snapped to a stage-count multiple) and
     ``pp_interleave`` (pow2) — gated exactly like zero/overlap: both
     restructure the traced schedule, so only a step builder that
@@ -421,8 +414,7 @@ def autotune_session(
                 quantized=bool(tune_quant_block),
                 tune_hierarchical=tune_hierarchical,
                 tune_zero=tune_zero, tune_overlap=tune_overlap,
-                tune_fused=tune_fused, tune_pp=tune_pp,
-                pp_stages=pp_stages,
+                tune_pp=tune_pp, pp_stages=pp_stages,
                 pp_max_interleave=pp_max_interleave,
                 tune_moe=tune_moe, moe_experts=moe_experts)
             seeds = [pp.params for pp in ranked]
@@ -445,7 +437,6 @@ def autotune_session(
         tune_hierarchical=tune_hierarchical,
         tune_zero=tune_zero,
         tune_overlap=tune_overlap,
-        tune_fused=tune_fused,
         tune_pp=tune_pp,
         pp_stages=pp_stages,
         pp_max_interleave=pp_max_interleave,

@@ -58,8 +58,6 @@ _MAX_STREAMS_LOG = 2.0  # 2^2  = 4 bucket collectives in flight
 # settings that compile to the SAME wire plan (e.g. hierarchical under
 # ZeRO, or a stream count with overlap off) collapse to one trial
 # instead of costing two recompiles.
-# v6 adds the fused-kernel backend dimension (docs/fused-kernels.md):
-# dead on an unquantized wire, where canonicalization collapses it.
 # v8 adds the pipeline schedule pair (docs/pipeline.md): pp_microbatches
 # (pow2, snapped to a multiple of the stage count) and pp_interleave
 # (pow2 virtual-stage degree) — both gated by tune_pp and dead (0 / 1)
@@ -80,7 +78,7 @@ _MAX_STREAMS_LOG = 2.0  # 2^2  = 4 bucket collectives in flight
 # gated by tune_pp like the v8 pair and dead ("interleaved_1f1b") when
 # the session's step is not pipelined, where canonicalization
 # collapses it to one trial.
-_DIMS = 14  # fusion, qblock, tree, zero, overlap, streams, fused,
+_DIMS = 13  # fusion, qblock, tree, zero, overlap, streams,
 #             ppM, ppV, moeCap, moeQ, svK, svQ, ppZb
 
 _MIN_PPM_LOG = 1.0   # 2^1 = 2 microbatches
@@ -96,8 +94,9 @@ _MAX_SPEC_K = 4      # speculative draft-window search box (0..4)
 # window score; same layout here with the compiled-path knob set).
 # zero_sharding (= zero_stage > 0) stays a column for log compatibility;
 # zero_stage carries the actual level. v5 appends the canonical `plan`
-# encoding column; v6 the `fused` kernel-backend knob. read_log stays
-# tolerant of v3/v4/v5 logs lacking the newer columns.
+# encoding column. read_log stays tolerant of v3/v4 logs lacking the
+# newer columns, and takes what it knows by name: the `fused` column of
+# a v6..v12 log (a knob that is gone) is ignored.
 # v8 appends the pipeline pair; read_log stays tolerant of v3..v7 logs
 # lacking the newer columns.
 # v9 appends the MoE pair; read_log stays tolerant of v3..v8 logs
@@ -113,7 +112,7 @@ _MAX_SPEC_K = 4      # speculative draft-window search box (0..4)
 # read_log stays tolerant of v3..v11 logs lacking the newer columns.
 CSV_FIELDS = ("sample", "fusion_threshold_bytes", "quant_block",
               "hierarchical_allreduce", "zero_sharding", "zero_stage",
-              "overlap", "num_comm_streams", "fused",
+              "overlap", "num_comm_streams",
               "pp_microbatches", "pp_interleave",
               "moe_capacity_factor", "moe_quantized",
               "spec_draft_k", "kv_migrate_quantized",
@@ -135,7 +134,6 @@ class TunedParams:
     zero_stage: int = 0
     overlap: bool = False
     num_comm_streams: int = 1
-    fused: bool = False
     # Pipeline schedule pair (docs/pipeline.md): 0 / 1 = "not a
     # pipelined step" — the canonical dead-knob values. pp_schedule
     # picks the table family ("interleaved_1f1b" vs the zero-bubble
@@ -168,7 +166,6 @@ class TunedParams:
             "zero_stage": int(self.zero_stage),
             "overlap": bool(self.overlap),
             "num_comm_streams": int(self.num_comm_streams),
-            "fused": bool(self.fused),
             "pp_microbatches": int(self.pp_microbatches),
             "pp_interleave": int(self.pp_interleave),
             "pp_schedule": str(self.pp_schedule),
@@ -194,7 +191,6 @@ class TunedParams:
             zero_stage=int(stage),
             overlap=bool(d.get("overlap", False)),
             num_comm_streams=int(d.get("num_comm_streams", 1)),
-            fused=bool(d.get("fused", False)),
             pp_microbatches=int(d.get("pp_microbatches", 0) or 0),
             pp_interleave=int(d.get("pp_interleave", 1) or 1),
             pp_schedule=str(d.get("pp_schedule", "interleaved_1f1b")
@@ -222,7 +218,6 @@ class TunedParams:
             zero_stage=stage,
             overlap=getattr(config, "overlap", False),
             num_comm_streams=getattr(config, "num_comm_streams", 1),
-            fused=getattr(config, "fused_kernels", False),
             pp_microbatches=getattr(config, "pp_microbatches", 0) or 0,
             pp_interleave=getattr(config, "pp_interleave", 1) or 1,
             pp_schedule=str(getattr(config, "pp_schedule",
@@ -282,7 +277,6 @@ class ParameterManager:
         tune_hierarchical: bool = True,
         tune_zero: bool = False,
         tune_overlap: bool = False,
-        tune_fused: bool = False,
         tune_pp: bool = False,
         pp_stages: int = 0,
         pp_max_interleave: int = 1,
@@ -315,10 +309,6 @@ class ParameterManager:
         # num_comm_streams rides the same gate — it only means anything
         # with overlap on.
         self.tune_overlap = tune_overlap
-        # The fused-kernel backend only changes the wire when an int8 leg
-        # exists (quantized); with quantized off, encode_tuned drops the
-        # dimension and canonicalization dedups the trials away.
-        self.tune_fused = tune_fused
         # The pipeline pair restructures the WHOLE training schedule
         # (microbatch count + virtual-stage interleave are trace-time
         # schedule geometry), so like zero/overlap it is searched only
@@ -402,7 +392,6 @@ class ParameterManager:
             (min(p.zero_stage, 2) + 0.5) / 3.0,
             0.75 if p.overlap else 0.25,
             s / _MAX_STREAMS_LOG,
-            0.75 if p.fused else 0.25,
             (ppm - _MIN_PPM_LOG) / (_MAX_PPM_LOG - _MIN_PPM_LOG),
             ppv / _MAX_PPV_LOG,
             (cap - _MIN_MOE_CAP) / (_MAX_MOE_CAP - _MIN_MOE_CAP),
@@ -437,23 +426,22 @@ class ParameterManager:
         else:
             ov = self.initial.overlap
             ns = self.initial.num_comm_streams
-        fz = (u[6] >= 0.5 if self.tune_fused else self.initial.fused)
         if self.tune_pp:
             # pow2 snap, then round up to a multiple of the stage count
             # (the interleaved grouping needs M % stages == 0).
-            ppm_l = _MIN_PPM_LOG + u[7] * (_MAX_PPM_LOG - _MIN_PPM_LOG)
+            ppm_l = _MIN_PPM_LOG + u[6] * (_MAX_PPM_LOG - _MIN_PPM_LOG)
             ppm = 1 << max(int(_MIN_PPM_LOG),
                            min(int(_MAX_PPM_LOG), round(ppm_l)))
             if self.pp_stages > 1:
                 ppm = max(ppm, self.pp_stages)
                 ppm += (-ppm) % self.pp_stages
             ppv = 1 << max(0, min(int(_MAX_PPV_LOG),
-                                  round(u[8] * _MAX_PPV_LOG)))
+                                  round(u[7] * _MAX_PPV_LOG)))
             ppv = min(ppv, self.pp_max_interleave)
             # Schedule family (v11): a relaxed boolean at the tail so
             # pre-v11 unit tuples stay valid coordinates.
-            u13 = u[13] if len(u) > 13 else 0.25
-            pps = "zb1" if u13 >= 0.5 else "interleaved_1f1b"
+            u12 = u[12] if len(u) > 12 else 0.25
+            pps = "zb1" if u12 >= 0.5 else "interleaved_1f1b"
         else:
             ppm = self.initial.pp_microbatches
             ppv = self.initial.pp_interleave
@@ -464,12 +452,12 @@ class ParameterManager:
             # discrete (finer steps cannot change the padded capacity
             # by more than rounding). Tolerant of pre-v9 unit tuples
             # lacking the trailing dims.
+            u8 = u[8] if len(u) > 8 else 0.25
             u9 = u[9] if len(u) > 9 else 0.25
-            u10 = u[10] if len(u) > 10 else 0.25
-            cap = _MIN_MOE_CAP + u9 * (_MAX_MOE_CAP - _MIN_MOE_CAP)
+            cap = _MIN_MOE_CAP + u8 * (_MAX_MOE_CAP - _MIN_MOE_CAP)
             cap = round(cap * 4) / 4.0
             moe_cap = min(_MAX_MOE_CAP, max(_MIN_MOE_CAP, cap))
-            moe_q = u10 >= 0.5
+            moe_q = u9 >= 0.5
         else:
             moe_cap = self.initial.moe_capacity_factor
             moe_q = self.initial.moe_quantized
@@ -478,10 +466,10 @@ class ParameterManager:
             # (the window W = k+1 is trace-time geometry — the space IS
             # discrete). Tolerant of pre-v10 unit tuples lacking the
             # trailing dims.
-            u11 = u[11] if len(u) > 11 else 0.0
-            u12 = u[12] if len(u) > 12 else 0.25
-            sv_k = max(0, min(_MAX_SPEC_K, round(u11 * _MAX_SPEC_K)))
-            sv_q = u12 >= 0.5
+            u10 = u[10] if len(u) > 10 else 0.0
+            u11 = u[11] if len(u) > 11 else 0.25
+            sv_k = max(0, min(_MAX_SPEC_K, round(u10 * _MAX_SPEC_K)))
+            sv_q = u11 >= 0.5
         else:
             sv_k = self.initial.spec_draft_k
             sv_q = self.initial.kv_migrate_quantized
@@ -492,7 +480,6 @@ class ParameterManager:
             zero_stage=stage,
             overlap=ov,
             num_comm_streams=ns,
-            fused=fz,
             pp_microbatches=ppm,
             pp_interleave=ppv,
             pp_schedule=pps,
@@ -522,7 +509,6 @@ class ParameterManager:
             zero_stage=d["zero_stage"],
             overlap=d["overlap"],
             num_comm_streams=d["num_comm_streams"],
-            fused=d.get("fused", False),
             quant_block=d.get("quant_block", p.quant_block),
             pp_microbatches=d.get("pp_microbatches", 0),
             pp_interleave=d.get("pp_interleave", 1),
@@ -607,7 +593,6 @@ class ParameterManager:
                             int(p.zero_stage),
                             int(p.overlap),
                             int(p.num_comm_streams),
-                            int(p.fused),
                             int(p.pp_microbatches),
                             int(p.pp_interleave),
                             f"{p.moe_capacity_factor:g}",
@@ -628,12 +613,11 @@ class ParameterManager:
         log.info(
             "autotune converged after %d samples: fusion_threshold=%d "
             "quant_block=%d hierarchical=%s zero_stage=%d overlap=%s "
-            "streams=%d fused=%s (best %.3f steps/sec)",
+            "streams=%d (best %.3f steps/sec)",
             len(self.history), self.best.fusion_threshold_bytes,
             self.best.quant_block, self.best.hierarchical_allreduce,
             self.best.zero_stage, self.best.overlap,
-            self.best.num_comm_streams, self.best.fused,
-            self.best_score)
+            self.best.num_comm_streams, self.best_score)
 
     def _sample_unit(self) -> Tuple[float, ...]:
         # The v11 tail dim (pp_schedule) draws from the stream only
@@ -648,17 +632,15 @@ class ParameterManager:
         if not self.tune_overlap:
             u[4] = 0.25
             u[5] = 0.0
-        if not self.tune_fused:
-            u[6] = 0.25
         if not self.tune_pp:
+            u[6] = 0.0
             u[7] = 0.0
-            u[8] = 0.0
         if not self.tune_moe:
+            u[8] = 0.25
             u[9] = 0.25
-            u[10] = 0.25
         if not self.tune_serve:
-            u[11] = 0.0
-            u[12] = 0.25
+            u[10] = 0.0
+            u[11] = 0.25
         return tuple(u)
 
     def _propose_next(self) -> TunedParams:
@@ -744,7 +726,6 @@ def read_log(path: str) -> List[dict]:
                 "overlap": bool(int(rec.get("overlap", 0) or 0)),
                 "num_comm_streams": int(rec.get("num_comm_streams", 1)
                                         or 1),
-                "fused": bool(int(rec.get("fused", 0) or 0)),
                 "pp_microbatches": int(rec.get("pp_microbatches", 0)
                                        or 0),
                 "pp_interleave": int(rec.get("pp_interleave", 1) or 1),

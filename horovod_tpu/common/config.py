@@ -84,10 +84,6 @@ class Config:
     overlap: bool = False
     num_comm_streams: int = 1  # bucket collectives in flight (pow2 1-4)
 
-    # --- fused compute-collective Pallas kernels (docs/fused-kernels.md):
-    #     kernel-eligible wire-plan legs (int8 quantize/dequant, matmul
-    #     prologue/epilogue) lower through the Pallas backend ---
-    fused_kernels: bool = False
     # 3-level tree plans: ride the pod hop as the blockwise-int8 rs+ag
     # pair instead of the exact psum (docs/wire-plan.md)
     quantized_pod: bool = False
@@ -204,7 +200,6 @@ def from_env() -> Config:
         zero_stage=_env_int("HOROVOD_ZERO_STAGE", 0),
         overlap=_env_bool("HOROVOD_OVERLAP", False),
         num_comm_streams=_env_int("HOROVOD_NUM_COMM_STREAMS", 1),
-        fused_kernels=_env_bool("HOROVOD_FUSED_KERNELS", False),
         quantized_pod=_env_bool("HOROVOD_QUANTIZED_POD", False),
         pp_stages=_env_int("HOROVOD_PP_STAGES", 0),
         pp_microbatches=_env_int("HOROVOD_PP_MICROBATCHES", 0),

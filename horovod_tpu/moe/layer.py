@@ -223,8 +223,7 @@ def _exchange(buf, plan, axis, residual, kind):
 
 def default_a2a_plan(axis=None, *, quantized: bool = False,
                      block: Optional[int] = None,
-                     error_feedback: Optional[bool] = None,
-                     fused: Optional[bool] = None):
+                     error_feedback: Optional[bool] = None):
     """The a2a plan of an hvd_ep hop (docs/moe.md): the leg's level is
     the slowest link class one ep hop crosses — the ep axis leads the
     mesh, so it jumps a whole data mesh (``ep_a2a_level``); a custom
@@ -246,8 +245,7 @@ def default_a2a_plan(axis=None, *, quantized: bool = False,
     q = bool(quantized) and level != _planner.ICI
     ef = q if error_feedback is None else (error_feedback and q)
     return _planner.a2a_plan(level, quantized=q, block=block,
-                             error_feedback=ef,
-                             fused=bool(fused) and q)
+                             error_feedback=ef)
 
 
 def moe_ffn(x, params, *, topk: int = 2, capacity_factor: float = 1.25,

@@ -35,7 +35,6 @@ KNOWN_PREFIXES = frozenset({
     "PROFILE",     # hvd.profile_window brackets (monitor/profile.py)
     "CYCLE_START",  # HOROVOD_TIMELINE_MARK_CYCLES (reference parity)
     "CKPT",        # async checkpoint lifecycle (docs/checkpoint.md)
-    "FUSED",       # fused Pallas kernel spans (docs/fused-kernels.md)
     "PP",          # pipeline sends + schedule slots (docs/pipeline.md)
     "MOE",         # expert dispatch/combine exchanges (docs/moe.md)
     "STRAGGLER",   # skew / link-health diagnoses (monitor/straggler.py)

@@ -224,7 +224,6 @@ def reduce_scatter(
     axes=None,
     quantized: Optional[bool] = None,
     block: Optional[int] = None,
-    fused: Optional[bool] = None,
     plan=None,
     _presummed: bool = False,
 ):
@@ -307,8 +306,7 @@ def reduce_scatter(
     eff_plan = _resolve_plan(
         plan, lambda: _planner.derive_reduce_scatter(
             levels=_planner.levels_of(axes_t), quantized=quantized,
-            error_feedback=residual is not None, block=block,
-            fused=fused))
+            error_feedback=residual is not None, block=block))
     shard, new_res = _plan_compiler.lower_reduce_scatter(
         eff_plan, flat, residual=residual,
         block=_quant_block_size(block), axes=axes_t, world=world)
@@ -324,7 +322,6 @@ def all_gather(
     axes=None,
     quantized: Optional[bool] = None,
     block: Optional[int] = None,
-    fused: Optional[bool] = None,
     plan=None,
 ):
     """Concatenate per-rank flat shards in rank-major order into the full
@@ -378,7 +375,7 @@ def all_gather(
         plan, lambda: _planner.derive_all_gather(
             levels=_planner.levels_of(axes_t) if use_quant else None,
             quantized=use_quant, error_feedback=residual is not None,
-            block=block, fused=fused))
+            block=block))
     if eff_plan.is_quantized and not use_quant:
         # An explicit quantized plan on a mesh with no DCN hop (or
         # custom axes) has no int8 leg to lower — fall back exact.
@@ -519,12 +516,12 @@ def _reduce_replicated(x, op: ReduceOp, axes: Tuple[str, ...],
 
 
 def _reduce_in_jit(x, op: ReduceOp, axes: Tuple[str, ...],
-                   hierarchical: bool, plan=None, fused=None):
+                   hierarchical: bool, plan=None):
     if op in (ReduceOp.AVERAGE, ReduceOp.SUM, ReduceOp.ADASUM):
         eff_plan = _resolve_plan(
             plan, lambda: _planner.derive_allreduce(
                 levels=_planner.levels_of(axes), quantized=False,
-                hierarchical=bool(hierarchical), fused=fused))
+                hierarchical=bool(hierarchical)))
         red = _plan_compiler.lower_psum(eff_plan, x, axes)
         if op == ReduceOp.AVERAGE:
             n = _world_size(axes)
@@ -567,7 +564,6 @@ def allreduce(
     hierarchical: Optional[bool] = None,
     quantized: Optional[bool] = None,
     block: Optional[int] = None,
-    fused: Optional[bool] = None,
     plan=None,
     _presummed: bool = False,
 ):
@@ -605,8 +601,8 @@ def allreduce(
         tensor, op=op, prescale_factor=prescale_factor,
         postscale_factor=postscale_factor, compression=compression,
         name=name, axes=axes, hierarchical=hierarchical,
-        quantized=quantized, residual=None, block=block, fused=fused,
-        plan=plan, _presummed=_presummed)
+        quantized=quantized, residual=None, block=block, plan=plan,
+        _presummed=_presummed)
     return out
 
 
@@ -621,7 +617,6 @@ def quantized_allreduce(
     name: Optional[str] = None,
     axes=None,
     block: Optional[int] = None,
-    fused: Optional[bool] = None,
     plan=None,
 ):
     """Quantized allreduce with explicit error-feedback state.
@@ -643,8 +638,7 @@ def quantized_allreduce(
         tensor, op=op, prescale_factor=prescale_factor,
         postscale_factor=postscale_factor, compression=compression,
         name=name, axes=axes, hierarchical=None, quantized=True,
-        residual=residual, block=block, fused=fused, plan=plan,
-        _presummed=False)
+        residual=residual, block=block, plan=plan, _presummed=False)
 
 
 def _allreduce_impl(
@@ -660,7 +654,6 @@ def _allreduce_impl(
     quantized: Optional[bool],
     residual,
     block: Optional[int] = None,
-    fused: Optional[bool] = None,
     plan=None,
     _presummed: bool = False,
 ):
@@ -720,8 +713,7 @@ def _allreduce_impl(
                     else None,
                     lambda: _planner.quantized_allreduce_plan(
                         block=block,
-                        error_feedback=residual is not None,
-                        fused=_planner._resolve_fused(fused)))
+                        error_feedback=residual is not None))
                 red, new_residual = \
                     _plan_compiler.lower_quantized_allreduce(
                         eff_plan, compressed, residual=residual,
@@ -758,8 +750,7 @@ def _allreduce_impl(
                               and plan.collective == "allreduce"
                               and not plan.is_dcn_quantized else None)
                 red = _reduce_in_jit(compressed, op, axes_t,
-                                     bool(hierarchical), plan=exact_plan,
-                                     fused=fused)
+                                     bool(hierarchical), plan=exact_plan)
     else:
         # hierarchical=False matches what the eager data plane does (flat
         # rings), so only an explicit True is an unsatisfiable request —

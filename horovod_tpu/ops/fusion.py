@@ -288,7 +288,6 @@ def allreduce_pytree(
     tuned_params=None,
     overlap: Optional[bool] = None,
     num_comm_streams: Optional[int] = None,
-    fused: Optional[bool] = None,
     plan=None,
 ):
     """Allreduce every leaf of a pytree with tensor fusion.
@@ -362,11 +361,6 @@ def allreduce_pytree(
             hierarchical = tuned_params.hierarchical_allreduce
         if block is None:
             block = tuned_params.quant_block
-        if fused is None:
-            # Same resolution DistributedOptimizer applies: the tuned
-            # kernel-backend knob steers the wire wherever the caller
-            # left it unset (docs/fused-kernels.md).
-            fused = getattr(tuned_params, "fused", None)
     leaves, treedef = jax.tree.flatten(tree)
     if error_feedback is not None:
         quantized = True if quantized is None else quantized
@@ -389,7 +383,7 @@ def allreduce_pytree(
                 leaf, op=op, compression=compression, axes=axes,
                 hierarchical=hierarchical, prescale_factor=prescale_factor,
                 postscale_factor=postscale_factor, quantized=quantized,
-                block=block, fused=fused, plan=plan, _presummed=presummed)
+                block=block, plan=plan, _presummed=presummed)
         else:
             varying_idx.append(i)
 
@@ -419,13 +413,13 @@ def allreduce_pytree(
                                 compression=compression, axes=axes,
                                 prescale_factor=prescale_factor,
                                 postscale_factor=postscale_factor,
-                                block=block, fused=fused, plan=plan)
+                                block=block, plan=plan)
                         else:
                             red, rnew = C.quantized_allreduce(
                                 buf, rbuf, op=op, compression=compression,
                                 axes=axes, prescale_factor=prescale_factor,
                                 postscale_factor=postscale_factor,
-                                block=block, fused=fused, plan=plan)
+                                block=block, plan=plan)
                     else:
                         rnew = None
                         kw = dict(op=op, compression=compression, axes=axes,
@@ -433,7 +427,7 @@ def allreduce_pytree(
                                   prescale_factor=prescale_factor,
                                   postscale_factor=postscale_factor,
                                   quantized=quantized, block=block,
-                                  fused=fused, plan=plan)
+                                  plan=plan)
                         red = (C.allreduce_stream(buf, bucket_id=j, **kw)
                                if overlap_on else C.allreduce(buf, **kw))
                 issued.append((j, red, rnew))

@@ -369,7 +369,6 @@ def DistributedOptimizer(
     zero_stage: Optional[int] = None,
     overlap: Optional[bool] = None,
     num_comm_streams: Optional[int] = None,
-    fused: Optional[bool] = None,
     axes=None,
     tuned_params=None,
     plan=None,
@@ -435,16 +434,6 @@ def DistributedOptimizer(
     order, so it is bit-identical to off; ``hvd.init`` arms the XLA
     async-collective/latency-hiding flags on TPU (graceful no-op
     elsewhere).
-
-    ``fused`` (default: the ``HOROVOD_FUSED_KERNELS`` knob) lowers the
-    kernel-eligible legs of the gradient wire through the fused Pallas
-    backend (docs/fused-kernels.md): with ``quantized`` on, the
-    blockwise int8 quantize/dequant-accumulate of the DCN legs runs as
-    one VMEM kernel pass instead of separate XLA ops round-tripping the
-    payload + scales through HBM. The wire format and bytes are
-    identical; values agree to the last ulp of the scale division
-    (tests/test_fused_collective.py pins the parity matrix). On an
-    unquantized wire the knob is a no-op (no kernel-eligible leg).
 
     ``tuned_params`` (an ``autotune.TunedParams``, e.g. the winner of
     :func:`horovod_tpu.autotune_session`) overrides the fusion threshold,
@@ -513,8 +502,6 @@ def DistributedOptimizer(
             num_comm_streams = step_plan.num_comm_streams
         if hierarchical is None:
             hierarchical = step_plan.hierarchical
-        if fused is None:
-            fused = step_plan.fused
         if fusion_threshold_bytes is None:
             fusion_threshold_bytes = step_plan.fusion_threshold_bytes
         if step_plan.quantized:
@@ -534,8 +521,6 @@ def DistributedOptimizer(
             overlap = tuned_params.overlap
         if num_comm_streams is None:
             num_comm_streams = tuned_params.num_comm_streams
-        if fused is None:
-            fused = getattr(tuned_params, "fused", None)
     if quantized is None:
         quantized = (basics.config().quantized_allreduce
                      if basics.is_initialized()
@@ -569,7 +554,6 @@ def DistributedOptimizer(
             quant_block=quant_block,
             overlap=bool(overlap),
             num_comm_streams=num_comm_streams,
-            fused=fused,
             axes=axes,
             stage=zero_stage,
         ), axes)
@@ -608,7 +592,6 @@ def DistributedOptimizer(
                 block=quant_block,
                 overlap=overlap,
                 num_comm_streams=num_comm_streams,
-                fused=fused,
                 plan=grad_plan,
             )
 
@@ -966,7 +949,6 @@ def _build_zero_transform(
     axes,
     overlap: bool = False,
     num_comm_streams: int = 1,
-    fused=None,
     stage: int = 2,
 ) -> optax.GradientTransformation:
     """The ZeRO optax wrapper: reduce-scatter → shard update → (stages
@@ -1185,8 +1167,7 @@ def _build_zero_transform(
                            else _res_read(state.residual[i], in_trace))
                     rs_kw = dict(op=reduce_op, prescale_factor=prescale,
                                  postscale_factor=postscale,
-                                 block=quant_block, fused=fused,
-                                 _presummed=True)
+                                 block=quant_block, _presummed=True)
                     if res is not None:
                         if overlap:
                             shard, nres = C.reduce_scatter_stream(
@@ -1307,11 +1288,10 @@ def _build_zero_transform(
                     if overlap:
                         full, nres = C.all_gather_stream(
                             wire, res, bucket_id=i, quantized=True,
-                            block=quant_block, fused=fused)
+                            block=quant_block)
                     else:
                         full, nres = C.all_gather(
-                            wire, res, quantized=True, block=quant_block,
-                            fused=fused)
+                            wire, res, quantized=True, block=quant_block)
                     new_ag[i] = _res_write(state.gather_residual[i], nres,
                                            in_trace)
                 else:
@@ -1319,11 +1299,11 @@ def _build_zero_transform(
                         full = C.all_gather_stream(
                             wire, bucket_id=i,
                             quantized=use_quant and is_float,
-                            block=quant_block, fused=fused)
+                            block=quant_block)
                     else:
                         full = C.all_gather(
                             wire, quantized=use_quant and is_float,
-                            block=quant_block, fused=fused)
+                            block=quant_block)
                     new_ag[i] = (None if state.gather_residual is None
                                  else state.gather_residual[i])
                 issued.append((i, full, ctx))

@@ -73,7 +73,6 @@ def allreduce_gradients(
     tuned_params=None,
     overlap: Optional[bool] = None,
     num_comm_streams: Optional[int] = None,
-    fused: Optional[bool] = None,
     plan=None,
 ):
     """Allreduce a gradient pytree (reference: _make_allreduce_grads_fn,
@@ -106,7 +105,7 @@ def allreduce_gradients(
             hierarchical=hierarchical, presummed=True,
             quantized=quantized, error_feedback=error_feedback,
             tuned_params=tuned_params, overlap=overlap,
-            num_comm_streams=num_comm_streams, fused=fused, plan=plan)
+            num_comm_streams=num_comm_streams, plan=plan)
 
 
 def value_and_grad(

@@ -33,18 +33,15 @@ autotuner warm-starts from (``autotune_session(warm_start=K)``).
 from .ir import (  # noqa: F401
     ALL_GATHER,
     ALL_TO_ALL,
-    BACKENDS,
     DCN,
     FLAT,
     ICI,
     INT8,
-    PALLAS,
     PAYLOAD,
     POD,
     PSUM,
     REDUCE_SCATTER,
     SEND,
-    XLA,
     Leg,
     PlanError,
     WirePlan,
@@ -52,7 +49,6 @@ from .ir import (  # noqa: F401
 from .accounting import (  # noqa: F401
     WireStats,
     bench_gbps,
-    fused_span,
     kv_span,
     modeled_wire_ms,
     moe_span,
@@ -72,8 +68,6 @@ from .planner import (  # noqa: F401
     enumerate_tuned,
     ep_a2a_level,
     flat_plan,
-    fused_ag_matmul_plan,
-    fused_matmul_rs_plan,
     derive_kv_migrate,
     derive_send,
     kv_migrate_level,
@@ -82,7 +76,6 @@ from .planner import (  # noqa: F401
     pp_send_level,
     predict_a2a_bytes,
     predict_kv_migrate_bytes,
-    predict_fused_hbm_saved,
     predict_leg_bytes,
     quantized_allreduce_plan,
     send_plan,

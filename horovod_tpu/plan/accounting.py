@@ -52,11 +52,6 @@ class WireStats:
         # latency-hiding scheduler can run it under independent compute.
         self.overlap_bytes = 0.0
         self.streamed_buckets = 0
-        # HBM round-trip bytes the fused Pallas kernels avoided vs the
-        # separate-op lowering (docs/fused-kernels.md), plus how many
-        # fused kernel calls the traced program contains.
-        self.fused_hbm_saved_bytes = 0.0
-        self.fused_calls = 0
         # Pipeline wire (docs/pipeline.md): bytes moved by send legs —
         # the inter-stage activation/activation-grad ppermutes of the
         # hvd_pp axis. Counted ON TOP of the per-hop ici/dcn/pod totals
@@ -151,7 +146,6 @@ def _publish_wire_stats(ws: "WireStats") -> None:
     r.gauge("comm.wire.overlap_bytes").set(ws.overlap_bytes)
     r.gauge("comm.wire.streamed_buckets").set(ws.streamed_buckets)
     r.gauge("comm.wire.hidden_fraction").set(ws.hidden_fraction)
-    r.gauge("comm.wire.fused_hbm_saved_bytes").set(ws.fused_hbm_saved_bytes)
     r.gauge("comm.wire.pp_bytes").set(ws.pp_bytes)
     r.gauge("comm.wire.pp_sends").set(ws.pp_sends)
     r.gauge("comm.wire.bubble_hidden_bytes").set(ws.bubble_hidden_bytes)
@@ -419,39 +413,3 @@ def pp_span(kind: str, tid: str = "pp"):
     finally:
         if tl is not None:
             tl.end(tid, activity)
-
-
-# ---------------------------------------------------------------------------
-# Fused-kernel instrumentation (docs/fused-kernels.md): every fused
-# Pallas kernel call brackets itself in a FUSED:* span at trace time and
-# accounts the HBM round-trip it avoided vs the separate-op lowering.
-# Like the wire accounting this is trace-time-only — a compiled step
-# re-executes with zero instrumentation cost.
-# ---------------------------------------------------------------------------
-
-
-@contextlib.contextmanager
-def fused_span(kind: str, hbm_saved_bytes: float = 0.0):
-    """Bracket one fused kernel call: emit a ``FUSED:<kind>`` timeline
-    span (kinds: ``MATMUL_RS``, ``AG_MATMUL``, ``QUANT``, ``DEQUANT``),
-    bump the ``comm.fused.*`` metrics, and credit ``hbm_saved_bytes``
-    (the modeled HBM round-trip the fusion avoids — the epilogue/
-    prologue's intermediate that never materializes) to every active
-    :func:`record_wire_stats` recorder."""
-    tl = basics._state.timeline if basics.is_initialized() else None
-    activity = f"FUSED:{kind}"
-    if tl is not None:
-        tl.begin("fused", activity)
-    try:
-        yield
-    finally:
-        if _metrics.metrics_enabled():
-            r = _metrics.default_registry()
-            r.counter("comm.fused.calls", kind=kind).inc()
-            r.counter("comm.fused.hbm_saved_bytes", kind=kind).inc(
-                float(hbm_saved_bytes))
-        for ws in _wire_recorders:
-            ws.fused_calls += 1
-            ws.fused_hbm_saved_bytes += float(hbm_saved_bytes)
-        if tl is not None:
-            tl.end("fused", activity)

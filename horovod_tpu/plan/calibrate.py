@@ -192,13 +192,11 @@ def _sweep_level(axis: str, sizes: Sequence[int],
 def _sweep_quant(sizes: Sequence[int],
                  reps: int) -> List[Tuple[float, float]]:
     """(fp bytes, seconds) of the blockwise int8 quantize +
-    dequant-accumulate kernel pair (the XLA composition — the rate the
-    cost model charges; the Pallas backend is modeled at 2x it)."""
+    dequant-accumulate pair (the rate the cost model charges)."""
     import jax
     import jax.numpy as jnp
 
     from .compiler import _dequant_accumulate, _quantize_blocks
-    from . import ir as _ir
 
     blk = 256
     pts: List[Tuple[float, float]] = []
@@ -207,8 +205,8 @@ def _sweep_quant(sizes: Sequence[int],
         x = jnp.arange(nb * blk, dtype=jnp.float32).reshape(1, nb, blk)
 
         def pair(blocks):
-            q, scales, _ = _quantize_blocks(blocks, _ir.XLA)
-            return _dequant_accumulate(q, scales, _ir.XLA)
+            q, scales, _ = _quantize_blocks(blocks)
+            return _dequant_accumulate(q, scales)
 
         fn = jax.jit(pair)
         pts.append((float(nb * blk) * 4.0, _time_call(fn, x, reps=reps)))

@@ -113,7 +113,7 @@ def _lower_tree_psum(plan: ir.WirePlan, x, axes: Tuple[str, ...]):
     local_axis, cross_axis = LOCAL_AXIS, CROSS_AXIS
     cross_levels = [l.level for l in plan.legs
                     if l.primitive == ir.PSUM and l.level != ir.FLAT]
-    # Quantized pod hop (docs/fused-kernels.md): the pod level spelled as
+    # Quantized pod hop (docs/wire-plan.md): the pod level spelled as
     # the rs[int8] > ag[int8] pair instead of the exact psum.
     qpod = [l for l in plan.legs
             if l.level == ir.POD and l.wire_dtype == ir.INT8]
@@ -155,10 +155,8 @@ def _lower_tree_psum(plan: ir.WirePlan, x, axes: Tuple[str, ...]):
                 seg = sn // npod
                 shape = shard.shape
                 segs = shard.reshape(npod, seg).astype(jnp.float32)
-                red, _ = _leg_quant_rs(segs, blk, POD_AXIS,
-                                       backend=qpod[0].backend)
-                vals, _ = _leg_quant_ag(red, blk, POD_AXIS,
-                                        backend=qpod[-1].backend)
+                red, _ = _leg_quant_rs(segs, blk, POD_AXIS)
+                vals, _ = _leg_quant_ag(red, blk, POD_AXIS)
                 shard = vals.reshape(shape).astype(x.dtype)
             else:
                 shard = lax.psum(shard, POD_AXIS)
@@ -188,16 +186,9 @@ def _lower_tree_psum(plan: ir.WirePlan, x, axes: Tuple[str, ...]):
 # ---------------------------------------------------------------------------
 
 
-def _quantize_blocks(blocks, backend: str):
+def _quantize_blocks(blocks):
     """Blockwise int8 quantize of ``blocks [rows, nb, blk]`` →
-    ``(q, scales, err)``. The ``pallas`` backend runs the fused one-pass
-    VMEM kernel (ops/fused_collective.py — interpret mode off-TPU);
-    ``xla`` is the original separate-op composition. Same wire format
-    either way; values agree to the last ulp of the scale division."""
-    if backend == ir.PALLAS:
-        from ..ops import fused_collective as _fused
-
-        return _fused.quantize_blockwise(blocks.astype(jnp.float32))
+    ``(q, scales, err)``."""
     scales = _compression._block_scales(blocks)
     q = jnp.clip(jnp.round(blocks / scales[..., None]),
                  -127, 127).astype(jnp.int8)
@@ -205,26 +196,19 @@ def _quantize_blocks(blocks, backend: str):
     return q, scales, err
 
 
-def _dequant_accumulate(qT, sT, backend: str):
-    """``sum_r qT[r] * sT[r]`` over the contributor axis — the fused
-    kernel never expands the int8 payload to fp32 in HBM."""
-    if backend == ir.PALLAS:
-        from ..ops import fused_collective as _fused
-
-        return _fused.dequantize_accumulate(qT, sT)
+def _dequant_accumulate(qT, sT):
+    """``sum_r qT[r] * sT[r]`` over the contributor axis."""
     return jnp.sum(qT.astype(jnp.float32) * sT[..., None], axis=0)
 
 
-def _leg_quant_rs(segs, blk: int, cross_axis, backend: str = ir.XLA):
+def _leg_quant_rs(segs, blk: int, cross_axis):
     """Quantized DCN reduce-scatter leg: ``segs`` is this rank's
     ICI-scattered shard viewed ``[nc, seg]`` in fp32, row ``j`` destined
     to cross rank ``j``. Each row quantizes to int8 with one fp32 scale
     per ``blk`` elements, a tiled ``all_to_all`` moves int8 + scales,
     receivers dequantize-accumulate in fp32. Returns
     ``(reduced_seg [seg] fp32, err [nc, seg] fp32)`` where ``err`` is
-    this rank's quantization error on everything it sent. ``backend``
-    selects the quantize/dequant lowering (``pallas`` = fused kernels,
-    docs/fused-kernels.md); the wire composition is identical."""
+    this rank's quantization error on everything it sent."""
     nc, seg = segs.shape
     pad = (-seg) % blk
     if pad:
@@ -232,17 +216,17 @@ def _leg_quant_rs(segs, blk: int, cross_axis, backend: str = ir.XLA):
             [segs, jnp.zeros((nc, pad), jnp.float32)], axis=1)
     nb = segs.shape[1] // blk
     blocks = segs.reshape(nc, nb, blk)
-    q, scales, err = _quantize_blocks(blocks, backend)
+    q, scales, err = _quantize_blocks(blocks)
     qT = lax.all_to_all(q, cross_axis, split_axis=0, concat_axis=0,
                         tiled=True)
     sT = lax.all_to_all(scales, cross_axis, split_axis=0, concat_axis=0,
                         tiled=True)
-    acc = _dequant_accumulate(qT, sT, backend)
+    acc = _dequant_accumulate(qT, sT)
     return (acc.reshape(nb * blk)[:seg],
             err.reshape(nc, nb * blk)[:, :seg])
 
 
-def _leg_quant_ag(seg_vals, blk: int, cross_axis, backend: str = ir.XLA):
+def _leg_quant_ag(seg_vals, blk: int, cross_axis):
     """Quantized DCN all-gather leg: quantize this rank's owned segment
     ``[seg]`` (fp32) and rebroadcast it as a masked int8 psum — disjoint
     support makes the sum exact and the result replicated over
@@ -254,7 +238,7 @@ def _leg_quant_ag(seg_vals, blk: int, cross_axis, backend: str = ir.XLA):
     padded = (jnp.concatenate([seg_vals, jnp.zeros((pad,), jnp.float32)])
               if pad else seg_vals)
     nb = padded.shape[0] // blk
-    q3, s2, e3 = _quantize_blocks(padded.reshape(1, nb, blk), backend)
+    q3, s2, e3 = _quantize_blocks(padded.reshape(1, nb, blk))
     q2, s2, err = q3[0], s2[0], e3[0]
     err = err.reshape(nb * blk)[:seg]
     ci = lax.axis_index(cross_axis)
@@ -267,15 +251,6 @@ def _leg_quant_ag(seg_vals, blk: int, cross_axis, backend: str = ir.XLA):
     vals = (qg.astype(jnp.float32) * sg[..., None]).reshape(
         nc, nb * blk)[:, :seg]
     return vals, err
-
-
-def _int8_leg_backend(plan: ir.WirePlan, primitive: str) -> str:
-    """Backend of the first int8 leg with ``primitive`` (xla when the
-    plan has none — the exact fallback paths)."""
-    for leg in plan.legs:
-        if leg.wire_dtype == ir.INT8 and leg.primitive == primitive:
-            return leg.backend
-    return ir.XLA
 
 
 def _leg_ici_gather(shard_flat, n: int, offset, local_axis=LOCAL_AXIS):
@@ -333,8 +308,7 @@ def lower_send(plan: ir.WirePlan, x, *, axis, perm, residual=None,
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), jnp.float32)])
     nb = flat.shape[0] // blk
-    q, scales, err = _quantize_blocks(flat.reshape(1, nb, blk),
-                                      backend=ir.XLA)
+    q, scales, err = _quantize_blocks(flat.reshape(1, nb, blk))
     if _acct_enabled():
         wire = quant_wire_bytes(n, blk)
         _acct_pp(hop, wire * frac * repeats,
@@ -475,14 +449,12 @@ def lower_a2a(plan: ir.WirePlan, x, *, axis, residual=None,
         rows = jnp.concatenate(
             [rows, jnp.zeros((k, pad), jnp.float32)], axis=1)
     nb = rows.shape[1] // blk
-    backend = leg.backend
-
     def _exchange_int8(blocks):
         """One int8 row exchange of ``blocks [k, nb, blk]``; returns
         ``(vals, err)`` — dequantized received blocks (a permutation,
         not a reduction: each block scales back independently) and this
         rank's quantization error on what it sent."""
-        q, scales, err = _quantize_blocks(blocks, backend)
+        q, scales, err = _quantize_blocks(blocks)
         if _acct_enabled():
             _acct_a2a(hop, quant_wire_bytes(seg, blk) * (k - 1),
                       float(seg) * (k - 1) * isz)
@@ -582,22 +554,17 @@ def lower_quantized_allreduce(plan: ir.WirePlan, x, *, residual=None,
               2.0 * float(sn) * (nc - 1) / nc * isz)
         _acct("ici", 2.0 * n * (nl - 1) / nl * isz)        # ICI gather leg
 
-    rs_backend = _int8_leg_backend(plan, ir.REDUCE_SCATTER)
-    ag_backend = _int8_leg_backend(plan, ir.ALL_GATHER)
-
     # Leg 1 — ICI reduce-scatter in the payload dtype.
     shard = lax.psum_scatter(flat, local_axis, scatter_dimension=0,
                              tiled=True)
 
     # Leg 2 — quantized DCN reduce-scatter (all_to_all of int8 + scales).
     segs = shard.reshape(nc, seg).astype(jnp.float32)
-    red_seg, err1 = _leg_quant_rs(segs, blk, cross_axis,
-                                  backend=rs_backend)     # [seg], [nc, seg]
+    red_seg, err1 = _leg_quant_rs(segs, blk, cross_axis)  # [seg], [nc, seg]
 
     # Leg 3 — requantize the reduced segment; masked int8 psum gathers the
     # shard with replication by construction (disjoint segment support).
-    vals, err2 = _leg_quant_ag(red_seg, blk, cross_axis,
-                               backend=ag_backend)        # [nc, seg], [seg]
+    vals, err2 = _leg_quant_ag(red_seg, blk, cross_axis)  # [nc, seg], [seg]
     shard_red = vals.reshape(sn).astype(x.dtype)
 
     # Leg 4 — ICI gather (psum of disjointly-placed shards).
@@ -688,9 +655,7 @@ def lower_reduce_scatter(plan: ir.WirePlan, flat, *, residual=None,
         if residual is not None:
             new_res = jnp.zeros_like(residual)
     elif quantized:
-        red, err = _leg_quant_rs(
-            h.astype(jnp.float32), blk, CROSS_AXIS,
-            backend=_int8_leg_backend(plan, ir.REDUCE_SCATTER))
+        red, err = _leg_quant_rs(h.astype(jnp.float32), blk, CROSS_AXIS)
         shard = red.astype(flat.dtype)
         if residual is not None:
             new_res = err.reshape(sn).astype(residual.dtype)
@@ -735,9 +700,7 @@ def lower_all_gather(plan: ir.WirePlan, shard, *, residual=None,
                     f"all_gather residual must match the shard [{seg}], "
                     f"got {residual.shape}")
             x = x + residual.astype(jnp.float32)
-        vals, err = _leg_quant_ag(
-            x, blk, CROSS_AXIS,
-            backend=_int8_leg_backend(plan, ir.ALL_GATHER))  # [nc, seg]
+        vals, err = _leg_quant_ag(x, blk, CROSS_AXIS)  # [nc, seg]
         if residual is not None:
             new_res = err.astype(residual.dtype)
         # ICI leg: place this rank's cross-gathered column at local index

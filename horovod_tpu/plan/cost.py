@@ -25,9 +25,7 @@ planner.StepPlan` into predicted milliseconds:
   more alphas) amortized over the overlap flight width;
 * **quant term** — blockwise int8 quantize + dequant-accumulate kernel
   time on the fp-equivalent payload of every int8 leg at the link's
-  ``quant_rate_gbps``; the fused Pallas backend halves it (one-pass VMEM
-  kernels never round-trip the expansion through HBM,
-  docs/fused-kernels.md);
+  ``quant_rate_gbps``;
 * **overlap credit** — an overlap-scheduled plan hides its streamed wire
   under backward compute except the final flight's tail
   (``1/buckets`` of the wire, the PR-5 streaming machinery's exposed
@@ -301,13 +299,9 @@ def price_plan(plan: ir.WirePlan, n: int, itemsize: float, mesh_shape,
         quant_ms = 0.0
         if r["leg"].wire_dtype == ir.INT8:
             # Quantize + dequant-accumulate on the fp-equivalent payload
-            # of this hop; the fused one-pass VMEM kernels skip the HBM
-            # round-trip of the int8/fp32 expansion — half the cost
-            # (docs/fused-kernels.md).
+            # of this hop.
             rate = lk.quant_rate_gbps * 1e9
             quant_ms = float(r["fp_bytes"]) / rate * 1e3
-            if r["leg"].backend == ir.PALLAS:
-                quant_ms *= 0.5
         legs.append(LegCost(r["leg"], hop, b, modeled_ms, wire_ms,
                             alpha_ms, quant_ms))
     return PlanCost(plan, tuple(legs))

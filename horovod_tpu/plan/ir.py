@@ -74,17 +74,6 @@ WIRE_DTYPES = (PAYLOAD, BF16, INT8)
 # them — its accounting/pricing rows carry the hop explicitly.
 LEVEL_HOP = {ICI: "ici", DCN: "dcn", POD: "pod"}
 
-# Leg backends. ``xla`` lowers through the stock jax primitives; ``pallas``
-# lowers the leg's local compute (blockwise quantize/dequant-accumulate,
-# matmul prologue/epilogue tiles) through the fused Pallas TPU kernels of
-# ``ops/fused_collective.py`` so it never round-trips HBM between the
-# producing op and the wire (docs/fused-kernels.md). The WIRE composition
-# is identical either way — backend is an execution attribute, like
-# ``stream``.
-XLA = "xla"
-PALLAS = "pallas"
-BACKENDS = (XLA, PALLAS)
-
 _REDUCE_PRIMS = (REDUCE_SCATTER, PSUM)
 _GATHER_PRIMS = (ALL_GATHER,)
 
@@ -114,8 +103,6 @@ class Leg:
     rank sent, re-injected next step). ``stream`` is the comm-stream
     slot the leg's bucket collective is issued on when the plan is
     overlap-scheduled (0-based, < :attr:`WirePlan.streams`).
-    ``backend`` selects the lowering of the leg's local compute:
-    ``xla`` (default) or ``pallas`` (fused kernel, docs/fused-kernels.md).
     """
 
     level: str
@@ -124,7 +111,6 @@ class Leg:
     block: Optional[int] = None
     error_feedback: bool = False
     stream: int = 0
-    backend: str = XLA
 
     def describe(self) -> str:
         d = self.wire_dtype
@@ -132,8 +118,7 @@ class Leg:
             d = f"int8/{self.block}"
         if self.error_feedback:
             d += "+ef"
-        tail = "@pl" if self.backend == PALLAS else ""
-        return f"{self.level}.{self.primitive}[{d}]{tail}"
+        return f"{self.level}.{self.primitive}[{d}]"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,19 +230,7 @@ class WirePlan:
                     f"addition, so the exact psum has no quantized "
                     f"lowering; spell a quantized hop as the "
                     f"reduce_scatter[int8] > all_gather[int8] pair "
-                    f"(the quantized pod hop, docs/fused-kernels.md)")
-            if leg.backend not in BACKENDS:
-                raise PlanError(
-                    f"{where}: unknown backend {leg.backend!r} — "
-                    f"backends are {BACKENDS} (xla = stock primitives, "
-                    f"pallas = fused kernels, docs/fused-kernels.md)")
-            if leg.backend == PALLAS and leg.level == FLAT:
-                raise PlanError(
-                    f"{where}: backend='pallas' on a flat leg — the "
-                    f"flat plan is one XLA-decomposed collective with "
-                    f"no leg-local compute to fuse a kernel into; "
-                    f"kernel-backed legs live on the per-level "
-                    f"compositions (docs/fused-kernels.md)")
+                    f"(the quantized pod hop, docs/wire-plan.md)")
             if ((leg.primitive == SEND)
                     != (self.collective in _SEND_COLLECTIVES)):
                 if leg.primitive == SEND:
@@ -276,12 +249,6 @@ class WirePlan:
                     f"{where}: a send leg names the LINK CLASS the "
                     f"pipeline hop crosses (ici/dcn/pod) — there is no "
                     f"flat decomposition of a point-to-point hop")
-            if leg.primitive == SEND and leg.backend == PALLAS:
-                raise PlanError(
-                    f"{where}: backend='pallas' on a send leg — the "
-                    f"pipeline hop has no leg-local compute to fuse "
-                    f"beyond the int8 quantize pair, which the compiler "
-                    f"places itself (docs/pipeline.md)")
             if (leg.primitive == ALL_TO_ALL) != (self.collective == "a2a"):
                 if leg.primitive == ALL_TO_ALL:
                     raise PlanError(
@@ -299,20 +266,6 @@ class WirePlan:
                     f"expert-parallel hop crosses (ici/dcn/pod) — there "
                     f"is no flat decomposition of the hvd_ep row "
                     f"exchange (docs/moe.md)")
-            if (leg.primitive == ALL_TO_ALL and leg.backend == PALLAS
-                    and leg.wire_dtype != INT8):
-                raise PlanError(
-                    f"{where}: backend='pallas' on a payload-dtype a2a "
-                    f"leg — an exact exchange has no leg-local compute; "
-                    f"the fused kernels back the blockwise int8 "
-                    f"quantize/dequant pair only (docs/fused-kernels.md)")
-            if leg.backend == PALLAS and leg.primitive == PSUM:
-                raise PlanError(
-                    f"{where}: backend='pallas' on a psum leg — the "
-                    f"exact psum has no kernel body; the fused kernels "
-                    f"back the quantize/dequant rs/ag legs and the "
-                    f"matmul prologue/epilogue legs "
-                    f"(docs/fused-kernels.md)")
             if leg.error_feedback and leg.level not in (DCN, POD):
                 raise PlanError(
                     f"{where}: error-feedback slot on a non-DCN hop — "

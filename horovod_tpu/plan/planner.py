@@ -48,23 +48,12 @@ from typing import List, Optional, Tuple
 
 from ..common import basics
 from ..common.config import _env_bool, _env_int
-from .ir import (ALL_GATHER, ALL_TO_ALL, DCN, FLAT, ICI, INT8, PALLAS,
-                 PAYLOAD, POD, PSUM, REDUCE_SCATTER, SEND, XLA, Leg,
-                 PlanError, WirePlan)
+from .ir import (ALL_GATHER, ALL_TO_ALL, DCN, FLAT, ICI, INT8, PAYLOAD,
+                 POD, PSUM, REDUCE_SCATTER, SEND, Leg, PlanError,
+                 WirePlan)
 
 _AXIS_LEVEL = {basics.LOCAL_AXIS: ICI, basics.CROSS_AXIS: DCN,
                basics.POD_AXIS: POD}
-
-
-def _resolve_fused(fused: Optional[bool]) -> bool:
-    """Per-call arg > init-time Config > HOROVOD_FUSED_KERNELS env —
-    whether kernel-eligible legs lower through the fused Pallas backend
-    (docs/fused-kernels.md)."""
-    if fused is not None:
-        return bool(fused)
-    cfg = basics.config() if basics.is_initialized() else None
-    return (cfg.fused_kernels if cfg is not None
-            else _env_bool("HOROVOD_FUSED_KERNELS", False))
 
 
 def _resolve_quantized_pod(quantized_pod: Optional[bool]) -> bool:
@@ -76,10 +65,6 @@ def _resolve_quantized_pod(quantized_pod: Optional[bool]) -> bool:
     cfg = basics.config() if basics.is_initialized() else None
     return (cfg.quantized_pod if cfg is not None
             else _env_bool("HOROVOD_QUANTIZED_POD", False))
-
-
-def _backend(fused: bool) -> str:
-    return PALLAS if fused else XLA
 
 
 def levels_of(axes_t) -> Optional[Tuple[str, ...]]:
@@ -107,17 +92,14 @@ def flat_plan(collective: str, *, streams: int = 1,
 def tree_allreduce_plan(*, pod: bool = False, streams: int = 1,
                         overlap: bool = False,
                         quantized_pod: bool = False,
-                        block: Optional[int] = None,
-                        fused: bool = False) -> WirePlan:
+                        block: Optional[int] = None) -> WirePlan:
     legs = [Leg(ICI, REDUCE_SCATTER), Leg(DCN, PSUM)]
     if pod and quantized_pod:
-        # The quantized pod hop (docs/fused-kernels.md): the pod level as
+        # The quantized pod hop (docs/wire-plan.md): the pod level as
         # the int8 rs+ag pair — the EQuARX decomposition on the slowest
         # link of a 3-level mesh — instead of the exact psum.
-        legs.append(Leg(POD, REDUCE_SCATTER, INT8, block=block,
-                        backend=_backend(fused)))
-        legs.append(Leg(POD, ALL_GATHER, INT8, block=block,
-                        backend=_backend(fused)))
+        legs.append(Leg(POD, REDUCE_SCATTER, INT8, block=block))
+        legs.append(Leg(POD, ALL_GATHER, INT8, block=block))
     elif pod:
         legs.append(Leg(POD, PSUM))
     legs.append(Leg(ICI, ALL_GATHER))
@@ -128,14 +110,13 @@ def tree_allreduce_plan(*, pod: bool = False, streams: int = 1,
 def quantized_allreduce_plan(*, block: Optional[int] = None,
                              error_feedback: bool = False,
                              streams: int = 1,
-                             overlap: bool = False,
-                             fused: bool = False) -> WirePlan:
+                             overlap: bool = False) -> WirePlan:
     legs = (
         Leg(ICI, REDUCE_SCATTER),
         Leg(DCN, REDUCE_SCATTER, INT8, block=block,
-            error_feedback=error_feedback, backend=_backend(fused)),
+            error_feedback=error_feedback),
         Leg(DCN, ALL_GATHER, INT8, block=block,
-            error_feedback=error_feedback, backend=_backend(fused)),
+            error_feedback=error_feedback),
         Leg(ICI, ALL_GATHER),
     )
     return WirePlan("allreduce", legs, streams=streams,
@@ -146,13 +127,11 @@ def zero_reduce_scatter_plan(*, quantized: bool = False,
                              block: Optional[int] = None,
                              error_feedback: bool = False,
                              streams: int = 1,
-                             overlap: bool = False,
-                             fused: bool = False) -> WirePlan:
+                             overlap: bool = False) -> WirePlan:
     """The ZeRO gradient wire (the reduce half of the quantized
     allreduce, stopped before the optimizer update)."""
     dcn = (Leg(DCN, REDUCE_SCATTER, INT8, block=block,
-               error_feedback=error_feedback,
-               backend=_backend(fused)) if quantized
+               error_feedback=error_feedback) if quantized
            else Leg(DCN, REDUCE_SCATTER, PAYLOAD,
                     error_feedback=error_feedback))
     return WirePlan("reduce_scatter",
@@ -164,13 +143,11 @@ def zero_all_gather_plan(*, quantized: bool = False,
                          block: Optional[int] = None,
                          error_feedback: bool = False,
                          streams: int = 1,
-                         overlap: bool = False,
-                         fused: bool = False) -> WirePlan:
+                         overlap: bool = False) -> WirePlan:
     """The ZeRO update broadcast (the gather half)."""
     if quantized:
         legs = (Leg(DCN, ALL_GATHER, INT8, block=block,
-                    error_feedback=error_feedback,
-                    backend=_backend(fused)),
+                    error_feedback=error_feedback),
                 Leg(ICI, ALL_GATHER))
         return WirePlan("all_gather", legs, streams=streams,
                         overlap=overlap).validate()
@@ -281,18 +258,15 @@ def predict_kv_migrate_bytes(plan: WirePlan, n: int,
 
 def a2a_plan(level: str = DCN, *, quantized: bool = False,
              block: Optional[int] = None,
-             error_feedback: bool = False,
-             fused: bool = False) -> WirePlan:
+             error_feedback: bool = False) -> WirePlan:
     """The MoE dispatch/combine wire (docs/moe.md): a single tiled
     ``all_to_all`` row exchange on the link class the hvd_ep hop
     crosses. ``quantized`` rides it blockwise-int8 with optional error
     feedback — legal on the DCN/pod hops only (the EQuARX placement
-    rule, exactly like the pipeline send leg); ``fused`` backs the int8
-    quantize/dequant pair with the Pallas kernels."""
+    rule, exactly like the pipeline send leg)."""
     if quantized:
         leg = Leg(level, ALL_TO_ALL, INT8, block=block,
-                  error_feedback=error_feedback,
-                  backend=_backend(fused))
+                  error_feedback=error_feedback)
     else:
         leg = Leg(level, ALL_TO_ALL, PAYLOAD)
     return WirePlan("a2a", (leg,)).validate()
@@ -307,16 +281,14 @@ def ep_a2a_level(mesh_shape) -> str:
 
 def derive_a2a(*, mesh_shape, quantized: bool = False,
                block: Optional[int] = None,
-               error_feedback: Optional[bool] = None,
-               fused: Optional[bool] = None) -> WirePlan:
+               error_feedback: Optional[bool] = None) -> WirePlan:
     """Derive the MoE a2a plan for a data mesh: the level comes from
     :func:`ep_a2a_level`; ``quantized`` is forced off on an ICI hop
     (int8 is illegal there — compression belongs on slow links)."""
     level = ep_a2a_level(mesh_shape)
     q = bool(quantized) and level in (DCN, POD)
     ef = q if error_feedback is None else (error_feedback and q)
-    return a2a_plan(level, quantized=q, block=block, error_feedback=ef,
-                    fused=_resolve_fused(fused) and q)
+    return a2a_plan(level, quantized=q, block=block, error_feedback=ef)
 
 
 def predict_a2a_bytes(plan: WirePlan, n: int, itemsize: float,
@@ -349,28 +321,6 @@ def pp_bubble_bound(stages: int, microbatches: int) -> float:
     return (s - 1) / (m + s - 1) if s > 1 else 0.0
 
 
-def fused_matmul_rs_plan(*, streams: int = 1,
-                         overlap: bool = False) -> WirePlan:
-    """The wire of :func:`~horovod_tpu.ops.fused_collective.
-    fused_matmul_reduce_scatter`: a kernel-backed ring reduce-scatter —
-    same bytes as the per-level rs legs, matmul epilogue riding inside."""
-    return WirePlan("reduce_scatter",
-                    (Leg(ICI, REDUCE_SCATTER, backend=PALLAS),
-                     Leg(DCN, REDUCE_SCATTER, backend=PALLAS)),
-                    streams=streams, overlap=overlap).validate()
-
-
-def fused_ag_matmul_plan(*, streams: int = 1,
-                         overlap: bool = False) -> WirePlan:
-    """The wire of :func:`~horovod_tpu.ops.fused_collective.
-    fused_all_gather_matmul`: a kernel-backed ring all-gather whose
-    arriving shards feed the matmul prologue."""
-    return WirePlan("all_gather",
-                    (Leg(DCN, ALL_GATHER, backend=PALLAS),
-                     Leg(ICI, ALL_GATHER, backend=PALLAS)),
-                    streams=streams, overlap=overlap).validate()
-
-
 # ---------------------------------------------------------------------------
 # Knob → plan derivation (what the entry points call per trace).
 # ---------------------------------------------------------------------------
@@ -380,26 +330,22 @@ def derive_allreduce(*, levels, quantized: bool, hierarchical: bool,
                      block: Optional[int] = None,
                      error_feedback: bool = False,
                      streams: int = 1, overlap: bool = False,
-                     fused: Optional[bool] = None,
                      quantized_pod: Optional[bool] = None) -> WirePlan:
     """Today's allreduce knob combination as a plan. ``levels`` is the
-    bound-axis level tuple (None for custom axes → flat). ``fused``
-    (default: HOROVOD_FUSED_KERNELS) puts the Pallas backend on the
-    kernel-eligible legs; ``quantized_pod`` (HOROVOD_QUANTIZED_POD)
-    rides the 3-level tree plan's pod hop as the int8 rs+ag pair."""
+    bound-axis level tuple (None for custom axes → flat).
+    ``quantized_pod`` (HOROVOD_QUANTIZED_POD) rides the 3-level tree
+    plan's pod hop as the int8 rs+ag pair."""
     lvls = set(levels or ())
-    fused = _resolve_fused(fused)
     if quantized and lvls == {ICI, DCN}:
         return quantized_allreduce_plan(block=block,
                                         error_feedback=error_feedback,
-                                        streams=streams, overlap=overlap,
-                                        fused=fused)
+                                        streams=streams, overlap=overlap)
     if hierarchical and {ICI, DCN} <= lvls:
         return tree_allreduce_plan(
             pod=POD in lvls, streams=streams, overlap=overlap,
             quantized_pod=(POD in lvls
                            and _resolve_quantized_pod(quantized_pod)),
-            block=block, fused=fused)
+            block=block)
     return flat_plan("allreduce", streams=streams, overlap=overlap)
 
 
@@ -407,28 +353,26 @@ def derive_reduce_scatter(*, levels, quantized: bool,
                           error_feedback: bool = False,
                           block: Optional[int] = None,
                           streams: int = 1,
-                          overlap: bool = False,
-                          fused: Optional[bool] = None) -> WirePlan:
+                          overlap: bool = False) -> WirePlan:
     lvls = set(levels or ())
     if lvls == {ICI, DCN} and (quantized or error_feedback):
         return zero_reduce_scatter_plan(
             quantized=quantized, block=block,
             error_feedback=error_feedback, streams=streams,
-            overlap=overlap, fused=_resolve_fused(fused) and quantized)
+            overlap=overlap)
     return flat_plan("reduce_scatter", streams=streams, overlap=overlap)
 
 
 def derive_all_gather(*, levels, quantized: bool,
                       error_feedback: bool = False,
                       block: Optional[int] = None,
-                      streams: int = 1, overlap: bool = False,
-                      fused: Optional[bool] = None) -> WirePlan:
+                      streams: int = 1,
+                      overlap: bool = False) -> WirePlan:
     lvls = set(levels or ())
     if quantized and lvls == {ICI, DCN}:
         return zero_all_gather_plan(
             quantized=True, block=block, error_feedback=error_feedback,
-            streams=streams, overlap=overlap,
-            fused=_resolve_fused(fused))
+            streams=streams, overlap=overlap)
     return flat_plan("all_gather", streams=streams, overlap=overlap)
 
 
@@ -509,22 +453,6 @@ def predict_leg_bytes(plan: WirePlan, n: int, itemsize: int,
             row(leg, "pod", p)
         return rows
 
-    ring = all(l.backend == PALLAS and l.wire_dtype == PAYLOAD
-               for l in plan.legs)
-    if ring and plan.collective in ("reduce_scatter", "all_gather"):
-        # Fused matmul ring (fused_matmul_rs_plan / fused_ag_matmul_plan):
-        # world-1 hops of the 1/world tile = (w-1)/w * n total per device
-        # (a TRUE ring gather — no masked-psum doubling), of which 1/nl
-        # of the directed links cross a host boundary (the same model
-        # ops/fused_collective.py charges at trace time).
-        total = n * (world - 1) / max(1, world) * isz
-        for leg in plan.legs:
-            if leg.level == ICI:
-                row(leg, "ici", total * (1.0 - 1.0 / nl))
-            else:
-                row(leg, "dcn", total / nl)
-        return rows
-
     for leg in plan.legs:
         if leg.level == ICI and leg.primitive == REDUCE_SCATTER:
             row(leg, "ici", n * (nl - 1) / nl * isz)
@@ -572,35 +500,6 @@ def predict_leg_bytes(plan: WirePlan, n: int, itemsize: int,
     return rows
 
 
-def predict_fused_hbm_saved(plan: WirePlan, n: int, itemsize: int,
-                            mesh_shape) -> float:
-    """Predicted HBM round-trip bytes the plan's kernel-backed legs avoid
-    vs their separate-op lowering, for a payload of ``n`` elements — the
-    same model the kernels charge at trace time
-    (ops/fused_collective.py: ``quant_hbm_saved``/``dequant_hbm_saved``),
-    rendered by the plan table's ``fused:`` line."""
-    from ..ops import fused_collective as _fused
-
-    nl, nc, npod = _mesh_sizes(mesh_shape)
-    blk = plan.quant_block or 256
-    sn = n // nl if nl else n
-    saved = 0.0
-    for leg in plan.legs:
-        if leg.backend != PALLAS or leg.wire_dtype != INT8:
-            continue
-        k = npod if leg.level == POD else nc
-        seg = (n // (nl * nc * npod) if plan.collective != "allreduce"
-               and leg.level == DCN else sn // max(1, k))
-        b = leg.block or blk
-        nb = (seg + b - 1) // b
-        if leg.primitive == REDUCE_SCATTER:
-            saved += _fused.quant_hbm_saved(k, nb, b)
-            saved += _fused.dequant_hbm_saved(k, nb, b)
-        elif leg.primitive == ALL_GATHER:
-            saved += _fused.quant_hbm_saved(1, nb, b)
-    return saved
-
-
 # ---------------------------------------------------------------------------
 # StepPlan: the resolved wire plans of one training step + the knob
 # record they were derived from. ``hvd.describe_plan(**knobs)`` builds it.
@@ -629,7 +528,6 @@ class StepPlan:
     fusion_threshold_bytes: int
     gradient: WirePlan
     gather: Optional[WirePlan]
-    fused: bool = False
     quantized_pod: bool = False
     # Pipeline parallelism (docs/pipeline.md): the inter-stage
     # activation wire (a validated send plan; None with pp off) plus the
@@ -701,8 +599,10 @@ class StepPlan:
             f"hierarchical={_onoff(self.hierarchical)} "
             f"streams={self.num_comm_streams} "
             f"fusion_threshold={self.fusion_threshold_bytes} "
-            f"fused={_onoff(self.fused)} "
             f"quantized_pod={_onoff(self.quantized_pod)}",
+            # An int8 leg has one lowering, so `backend` reads `xla`
+            # on every row; the column stays where the golden tables
+            # (tests/test_plan.py) pin it.
             f"{'collective':<16} {'leg':>3} {'level':<5} "
             f"{'primitive':<14} {'wire':<10} {'ef':<3} {'backend':<7} "
             f"{'stream':>6} {'bytes/dev':>12} {'model ms':>9} "
@@ -710,13 +610,10 @@ class StepPlan:
         ]
         tot = {"ici": 0.0, "dcn": 0.0, "pod": 0.0, "fp": 0.0,
                "pod_fp": 0.0}
-        hbm_saved = 0.0
         for plan in self.plans:
             rows = predict_leg_bytes(plan, n, itemsize, self.mesh_shape)
             plan_cost = _cost.price_plan(plan, n, itemsize,
                                          self.mesh_shape, model)
-            hbm_saved += predict_fused_hbm_saved(plan, n, itemsize,
-                                                 self.mesh_shape)
             for r in rows:
                 if r["hop"] in tot:
                     tot[r["hop"]] += r["bytes"]
@@ -734,7 +631,7 @@ class StepPlan:
                     f"{plan.collective:<16} {li:>3} {leg.level:<5} "
                     f"{leg.primitive:<14} {wire:<10} "
                     f"{'yes' if leg.error_feedback else '-':<3} "
-                    f"{leg.backend:<7} "
+                    f"{'xla':<7} "
                     f"{leg.stream:>6} {int(round(b)):>12} "
                     f"{modeled_ms:>9.4f} {pred_ms:>8.4f}")
         if self.send is not None:
@@ -755,7 +652,7 @@ class StepPlan:
                     f"{'send':<16} {li:>3} {leg.level:<5} "
                     f"{leg.primitive:<14} {wire:<10} "
                     f"{'yes' if leg.error_feedback else '-':<3} "
-                    f"{leg.backend:<7} "
+                    f"{'xla':<7} "
                     f"{leg.stream:>6} {int(round(b)):>12} "
                     f"{modeled_ms:>9.4f} {pred_ms:>8.4f}")
         if self.moe is not None:
@@ -778,7 +675,7 @@ class StepPlan:
                     f"{'a2a':<16} {li:>3} {leg.level:<5} "
                     f"{leg.primitive:<14} {wire:<10} "
                     f"{'yes' if leg.error_feedback else '-':<3} "
-                    f"{leg.backend:<7} "
+                    f"{'xla':<7} "
                     f"{leg.stream:>6} {int(round(b)):>12} "
                     f"{modeled_ms:>9.4f} {pred_ms:>8.4f}")
         red = (tot["fp"] / tot["dcn"]) if tot["dcn"] else None
@@ -793,11 +690,6 @@ class StepPlan:
             totline += (f" pod_fp_equiv={int(round(tot['pod_fp']))} "
                         f"pod_reduction={pred:.2f}x")
         lines.append(totline)
-        if hbm_saved:
-            lines.append(
-                f"fused: predicted hbm round-trip saved "
-                f"{int(round(hbm_saved))} bytes/dev vs unfused "
-                f"(docs/fused-kernels.md)")
         if self.send is not None:
             bound = pp_bubble_bound(self.pp_stages, self.pp_microbatches)
             lines.append(
@@ -845,7 +737,6 @@ def describe_plan(
     mesh_shape: Optional[Tuple[int, ...]] = None,
     error_feedback: Optional[bool] = None,
     tuned_params=None,
-    fused: Optional[bool] = None,
     quantized_pod: Optional[bool] = None,
     pp_stages: Optional[int] = None,
     pp_microbatches: Optional[int] = None,
@@ -877,8 +768,6 @@ def describe_plan(
             num_comm_streams = tuned_params.num_comm_streams
         if quant_block is None:
             quant_block = tuned_params.quant_block
-        if fused is None:
-            fused = getattr(tuned_params, "fused", None)
         if pp_microbatches is None:
             pp_microbatches = getattr(tuned_params, "pp_microbatches",
                                       None) or None
@@ -963,7 +852,6 @@ def describe_plan(
     if moe_quantized is None:
         moe_quantized = (cfg.moe_quantized if cfg is not None
                          else _env_bool("HOROVOD_MOE_QUANTIZED", False))
-    fused = _resolve_fused(fused)
     quantized_pod = _resolve_quantized_pod(quantized_pod)
     nl, nc, npod = _mesh_sizes(mesh_shape)
     # The level ladder is structural, not size-gated: a 1-host mesh still
@@ -977,18 +865,18 @@ def describe_plan(
         gradient = derive_reduce_scatter(
             levels=levels, quantized=quantized, error_feedback=ef,
             block=quant_block if quantized else None, streams=streams,
-            overlap=overlap, fused=fused)
+            overlap=overlap)
         gather = derive_all_gather(
             levels=levels, quantized=quantized, error_feedback=ef,
             block=quant_block if quantized else None, streams=streams,
-            overlap=overlap, fused=fused)
+            overlap=overlap)
     else:
         gradient = derive_allreduce(
             levels=levels, quantized=quantized,
             hierarchical=hierarchical,
             block=quant_block if (quantized or quantized_pod) else None,
             error_feedback=ef, streams=streams, overlap=overlap,
-            fused=fused, quantized_pod=quantized_pod)
+            quantized_pod=quantized_pod)
         gather = None
     send = None
     if pp_stages > 1:
@@ -999,8 +887,7 @@ def describe_plan(
     if moe_experts > 1:
         moe = derive_a2a(mesh_shape=mesh_shape,
                          quantized=bool(moe_quantized),
-                         block=quant_block if moe_quantized else None,
-                         fused=fused)
+                         block=quant_block if moe_quantized else None)
     return StepPlan(
         moe=moe,
         moe_experts=moe_experts if moe_experts > 1 else 0,
@@ -1024,7 +911,6 @@ def describe_plan(
         fusion_threshold_bytes=int(fusion_threshold_bytes),
         gradient=gradient,
         gather=gather,
-        fused=bool(fused),
         quantized_pod=bool(quantized_pod),
     )
 
@@ -1038,7 +924,7 @@ def describe_plan(
 _PLAN_RE = re.compile(
     r"^(?P<grad>ar\.flat|ar\.tree|rs\+ag\.z[123])\|"
     r"(?P<wire>fp|int8/\d+)\|s(?P<streams>\d+)\|(?P<sched>sync|ovl)"
-    r"(?P<fused>\|pl)?(\|pp(?P<ppm>\d+)/(?P<ppv>\d+)(?P<ppzb>\|zb1)?)?"
+    r"(\|pp(?P<ppm>\d+)/(?P<ppv>\d+)(?P<ppzb>\|zb1)?)?"
     r"(\|moe(?P<moecap>[0-9.]+)/(?P<moeq>q8|fp))?"
     r"(\|sv(?P<svk>\d+)/(?P<svq>q8|fp))?$")
 
@@ -1047,15 +933,10 @@ def encode_tuned(params, *, quantized: bool = False,
                  pp: bool = False, moe: bool = False,
                  serve: bool = False) -> str:
     """Compact plan encoding of a ``TunedParams``-like knob set: gradient
-    leg order | DCN hop wire dtype | stream count | placement
-    [| kernel backend]. E.g. ``ar.tree|int8/256|s2|ovl`` or
-    ``rs+ag.z2|int8/256|s1|sync|pl`` (schema v6: the trailing ``|pl``
-    marks the fused Pallas backend on the int8 legs; absent for v5
-    readers and for every plan with no kernel-eligible leg). Knob sets
-    that compile to the same wire encode identically (``hierarchical``
-    is dead under ZeRO's rs+ag split; ``fused`` is dead on an
-    unquantized wire — no int8 leg to back with a kernel — and both
-    drop out)."""
+    leg order | DCN hop wire dtype | stream count | placement. E.g.
+    ``ar.tree|int8/256|s2|ovl`` or ``rs+ag.z2|int8/256|s1|sync``. Knob
+    sets that compile to the same wire encode identically
+    (``hierarchical`` is dead under ZeRO's rs+ag split and drops out)."""
     stage = int(getattr(params, "zero_stage", 0) or 0)
     if stage > 0:
         grad = f"rs+ag.z{stage}"
@@ -1070,8 +951,6 @@ def encode_tuned(params, *, quantized: bool = False,
     if sched == "sync":
         streams = 1  # dead knob with overlap off: same wire, one trial
     enc = f"{grad}|{wire}|s{streams}|{sched}"
-    if quantized and getattr(params, "fused", False):
-        enc += "|pl"  # dead knob without an int8 leg: drops out above
     if pp:
         # Schema v8 (docs/pipeline.md): the pipeline schedule knobs —
         # microbatch count / interleave degree — join the plan encoding
@@ -1148,7 +1027,6 @@ def enumerate_tuned(*, quantized: bool = False,
                     tune_hierarchical: bool = True,
                     tune_zero: bool = False,
                     tune_overlap: bool = False,
-                    tune_fused: bool = False,
                     tune_pp: bool = False,
                     pp_stages: int = 0,
                     pp_max_interleave: int = 1,
@@ -1159,8 +1037,8 @@ def enumerate_tuned(*, quantized: bool = False,
                     blocks=None) -> list:
     """Enumerate the legal knob space of one tuning session as
     ``TunedParams`` candidates: leg order (flat/tree vs the ZeRO rs+ag
-    split) x DCN wire dtype scale block x stream split x fused backend x
-    fusion threshold — gated exactly like the autotuner's search
+    split) x DCN wire dtype scale block x stream split x fusion
+    threshold — gated exactly like the autotuner's search
     dimensions (a knob the session's step cannot accept is pinned to the
     initial value), deduplicated on the canonical plan encoding so knob
     sets that compile to the same wire appear once."""
@@ -1230,39 +1108,33 @@ def enumerate_tuned(*, quantized: bool = False,
                             stream_opts = (
                                 max(1, initial.num_comm_streams),)
                         for s in stream_opts:
-                            fz_opts = ((False, True)
-                                       if tune_fused and quantized
-                                       else (initial.fused
-                                             if quantized else False,))
-                            for fz in fz_opts:
-                                for ppm in ppm_opts:
-                                    for ppv in ppv_opts:
-                                        for pps in ppsched_opts:
-                                            for cap in cap_opts:
-                                                for mq in moeq_opts:
-                                                    p = TunedParams(
-                                                        fusion_threshold_bytes=thr,
-                                                        quant_block=blk,
-                                                        hierarchical_allreduce=hier,
-                                                        zero_stage=stage,
-                                                        overlap=ovl,
-                                                        num_comm_streams=s,
-                                                        fused=fz,
-                                                        pp_microbatches=ppm,
-                                                        pp_interleave=ppv,
-                                                        pp_schedule=pps,
-                                                        moe_capacity_factor=cap,
-                                                        moe_quantized=mq)
-                                                    key = (thr, blk,
-                                                           encode_tuned(
-                                                               p,
-                                                               quantized=quantized,
-                                                               pp=tune_pp,
-                                                               moe=tune_moe))
-                                                    if key in seen:
-                                                        continue
-                                                    seen.add(key)
-                                                    out.append(p)
+                            for ppm in ppm_opts:
+                                for ppv in ppv_opts:
+                                    for pps in ppsched_opts:
+                                        for cap in cap_opts:
+                                            for mq in moeq_opts:
+                                                p = TunedParams(
+                                                    fusion_threshold_bytes=thr,
+                                                    quant_block=blk,
+                                                    hierarchical_allreduce=hier,
+                                                    zero_stage=stage,
+                                                    overlap=ovl,
+                                                    num_comm_streams=s,
+                                                    pp_microbatches=ppm,
+                                                    pp_interleave=ppv,
+                                                    pp_schedule=pps,
+                                                    moe_capacity_factor=cap,
+                                                    moe_quantized=mq)
+                                                key = (thr, blk,
+                                                       encode_tuned(
+                                                           p,
+                                                           quantized=quantized,
+                                                           pp=tune_pp,
+                                                           moe=tune_moe))
+                                                if key in seen:
+                                                    continue
+                                                seen.add(key)
+                                                out.append(p)
     return out
 
 
@@ -1270,7 +1142,7 @@ def shortlist(payload_bytes: float, *, itemsize: float = 4.0,
               mesh_shape=None, model=None, compute_ms=None,
               quantized: bool = False, k: Optional[int] = None,
               tune_hierarchical: bool = True, tune_zero: bool = False,
-              tune_overlap: bool = False, tune_fused: bool = False,
+              tune_overlap: bool = False,
               tune_pp: bool = False, pp_stages: int = 0,
               pp_max_interleave: int = 1,
               tune_moe: bool = False, moe_experts: int = 0,
@@ -1299,7 +1171,6 @@ def shortlist(payload_bytes: float, *, itemsize: float = 4.0,
                              tune_hierarchical=tune_hierarchical,
                              tune_zero=tune_zero,
                              tune_overlap=tune_overlap,
-                             tune_fused=tune_fused,
                              tune_pp=tune_pp, pp_stages=pp_stages,
                              pp_max_interleave=pp_max_interleave,
                              tune_moe=tune_moe, moe_experts=moe_experts,
@@ -1346,8 +1217,7 @@ def decode_tuned(encoding: str) -> dict:
     if not m:
         raise PlanError(
             f"unparseable plan encoding {encoding!r} — expected "
-            f"'<ar.flat|ar.tree|rs+ag.zN>|<fp|int8/B>|sK|<sync|ovl>"
-            f"[|pl]'")
+            f"'<ar.flat|ar.tree|rs+ag.zN>|<fp|int8/B>|sK|<sync|ovl>'")
     grad = m.group("grad")
     out = {
         "zero_stage": int(grad[-1]) if grad.startswith("rs+ag") else 0,
@@ -1355,7 +1225,6 @@ def decode_tuned(encoding: str) -> dict:
         "quantized": m.group("wire") != "fp",
         "overlap": m.group("sched") == "ovl",
         "num_comm_streams": int(m.group("streams")),
-        "fused": m.group("fused") is not None,
         "pp_microbatches": int(m.group("ppm") or 0),
         "pp_interleave": int(m.group("ppv") or 1),
         # v11: |zb1 rides the pp segment — absent (or pp off) decodes

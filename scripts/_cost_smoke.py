@@ -60,8 +60,7 @@ def main():
 
     # -- 2. shortlist: nonempty, ranked ascending ----------------------
     shortlist = hvd_planner.shortlist(
-        8 * 1024 * 1024, quantized=True, tune_overlap=True,
-        tune_fused=True, model=model)
+        8 * 1024 * 1024, quantized=True, tune_overlap=True, model=model)
     assert shortlist, "shortlist is empty"
     preds = [pp.predicted_ms for pp in shortlist]
     assert preds == sorted(preds), "shortlist is not ranked"
@@ -91,7 +90,7 @@ def main():
     out_plain = run(
         threshold_bytes=p.fusion_threshold_bytes, block=p.quant_block,
         hierarchical=p.hierarchical_allreduce, overlap=p.overlap,
-        num_comm_streams=p.num_comm_streams, fused=p.fused)
+        num_comm_streams=p.num_comm_streams)
     for k in tree:
         np.testing.assert_array_equal(
             np.asarray(out_priced[k]), np.asarray(out_plain[k]),
@@ -99,9 +98,8 @@ def main():
                     f"unpriced lowering on leaf {k!r}")
     print(f"cost smoke OK: top candidate "
           f"(thr={p.fusion_threshold_bytes >> 20}MiB block="
-          f"{p.quant_block} streams={p.num_comm_streams} "
-          f"fused={p.fused}) lowers bit-identically to the unpriced "
-          f"plan")
+          f"{p.quant_block} streams={p.num_comm_streams}) lowers "
+          f"bit-identically to the unpriced plan")
 
 
 if __name__ == "__main__":

@@ -186,8 +186,6 @@ def build_report(timeline_path, metrics_path, flight_dir=None):
             "dcn_bytes_fp_equiv": dcn_fp,
             "dcn_reduction": (dcn_fp / dcn) if dcn else None,
             "pod_bytes_per_step_device": pod,
-            "fused_hbm_saved_bytes": gauges.get(
-                "comm.wire.fused_hbm_saved_bytes", 0.0),
             "modeled_wire_ms": round(
                 (ici / (ici_gbps * 1e9) + dcn / (dcn_gbps * 1e9)
                  + pod / (pod_gbps * 1e9)) * 1e3, 4),

@@ -517,8 +517,9 @@ class TestCacheSchemaV7:
         monkeypatch.setenv("HOROVOD_AUTOTUNE_CACHE",
                            str(tmp_path / "cache.json"))
         TestSession._reset_kernel_cache()
-        # A v6-era entry: params carry fused, but no geometry /
-        # predicted_ms fields — reads cleanly.
+        # A v6-era entry: params carry the `fused` knob that is gone
+        # (ignored by key), but no geometry / predicted_ms fields —
+        # reads cleanly.
         kernel_autotune.cache_store("legacy|v6", {
             "params": {"fusion_threshold_bytes": 2 * MIB,
                        "quant_block": 256,
@@ -530,7 +531,7 @@ class TestCacheSchemaV7:
         p = load_cached_params("legacy|v6")
         assert p == TunedParams(fusion_threshold_bytes=2 * MIB,
                                 zero_stage=2, overlap=True,
-                                num_comm_streams=2, fused=True)
+                                num_comm_streams=2)
 
     def test_load_tolerant_of_v5_entry(self, tmp_path, monkeypatch):
         from horovod_tpu.ops import kernel_autotune
@@ -538,8 +539,6 @@ class TestCacheSchemaV7:
         monkeypatch.setenv("HOROVOD_AUTOTUNE_CACHE",
                            str(tmp_path / "cache.json"))
         TestSession._reset_kernel_cache()
-        # v5: no fused knob at all — defaults to False (the exact
-        # pre-v6 wire).
         kernel_autotune.cache_store("legacy|v5", {
             "params": {"fusion_threshold_bytes": 4 * MIB,
                        "quant_block": 128,
@@ -552,7 +551,6 @@ class TestCacheSchemaV7:
         assert p == TunedParams(fusion_threshold_bytes=4 * MIB,
                                 quant_block=128,
                                 hierarchical_allreduce=True)
-        assert p.fused is False
 
 
 def _toy_make_step(tuned, sleep_by_threshold=None):

@@ -162,6 +162,40 @@ def test_allgather_hierarchical_flag_from_config(monkeypatch):
         np.asarray(out)[0], x.reshape(N * 2, 3))
 
 
+def _tp_operands():
+    """x [M, K] and W [K, Nc], with x's columns and W's rows cut into N
+    rank slabs: ``xs[r] @ ws[r]`` summed over ranks is ``x @ W``."""
+    rng = np.random.RandomState(4)
+    M, K, Nc = 16, 64, 8
+    x = rng.randn(M, K).astype(np.float32)
+    w = rng.randn(K, Nc).astype(np.float32)
+    return x, w, np.stack(np.split(x, N, axis=1)), np.stack(np.split(w, N))
+
+
+def test_row_parallel_matmul_then_reduce_scatter_is_the_psums_row_shards():
+    # TP row-parallel: y = sum_r x[:, K_r] @ W[K_r, :]. Reduce-scattering
+    # the flat partial product leaves rank r rows [r M/N, (r+1) M/N) of
+    # the sum that lax.psum would have left whole on every rank.
+    x, w, xs, ws = _tp_operands()
+    spec = P(hvd.HVD_AXES)
+    got = spmd(lambda xr, wr: hvd.reduce_scatter(
+        (xr[0] @ wr[0]).ravel(), op=hvd.Sum),
+        in_specs=(spec, spec), out_specs=spec)(xs, ws)
+    ref = spmd(lambda xr, wr: jax.lax.psum(xr[0] @ wr[0], hvd.HVD_AXES),
+               in_specs=(spec, spec))(xs, ws)
+    np.testing.assert_allclose(np.asarray(got).reshape(ref.shape),
+                               np.asarray(ref), rtol=1e-4, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(ref), x @ w, rtol=1e-4, atol=1e-3)
+
+
+def test_all_gather_then_matmul_is_the_full_matmul():
+    # ZeRO-3's forward: rank-major row shards of W gathered, then x @ W.
+    x, w, _, ws = _tp_operands()
+    got = spmd(lambda wr: x @ hvd.all_gather(wr[0].ravel()).reshape(w.shape)
+               )(ws)
+    np.testing.assert_allclose(np.asarray(got), x @ w, rtol=1e-4, atol=1e-3)
+
+
 @pytest.mark.parametrize("root", [0, 3, 7])
 def test_broadcast(root):
     # Each rank holds rank-dependent values; all must end with root's.

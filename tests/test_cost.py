@@ -112,14 +112,6 @@ class TestPricePlan:
         fp = (sn * 0.5 + 2 * sn * 0.5) * 4
         assert q.quant_ms == pytest.approx(fp / 50e9 * 1e3, rel=1e-6)
 
-    def test_pallas_backend_halves_quant_cost(self):
-        xla = price_plan(quantized_allreduce_plan(block=256), self.N, 4,
-                         (2, 4))
-        pl = price_plan(quantized_allreduce_plan(block=256, fused=True),
-                        self.N, 4, (2, 4))
-        assert pl.quant_ms == pytest.approx(xla.quant_ms / 2, rel=1e-9)
-        assert pl.wire_ms == pytest.approx(xla.wire_ms, rel=1e-9)
-
     def test_quantized_wire_cheaper_on_slow_dcn(self):
         # The int8 wire must price below the exact wire once the DCN
         # link is slow enough — EQuARX's premise as a model consequence.
@@ -197,8 +189,7 @@ class TestPriceStep:
 class TestShortlist:
     def test_every_candidate_validates_and_is_ranked(self):
         rows = shortlist(16 * MIB, mesh_shape=(2, 4), quantized=True,
-                         tune_overlap=True, tune_fused=True,
-                         tune_zero=True)
+                         tune_overlap=True, tune_zero=True)
         assert rows
         preds = [r.predicted_ms for r in rows]
         assert preds == sorted(preds)
@@ -218,7 +209,6 @@ class TestShortlist:
         rows = shortlist(16 * MIB, mesh_shape=(2, 4), quantized=False)
         assert all(r.params.zero_stage == 0 for r in rows)
         assert all(not r.params.overlap for r in rows)
-        assert all(not r.params.fused for r in rows)
         zrows = shortlist(16 * MIB, mesh_shape=(2, 4), quantized=False,
                           tune_zero=True)
         assert {r.params.zero_stage for r in zrows} == {0, 1, 2}

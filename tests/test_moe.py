@@ -107,14 +107,6 @@ class TestA2AIR:
         with pytest.raises(PlanError, match="LINK CLASS"):
             WirePlan("a2a", (Leg("flat", ALL_TO_ALL),)).validate()
 
-    def test_pallas_needs_int8(self):
-        with pytest.raises(PlanError, match="payload-dtype a2a"):
-            WirePlan("a2a", (Leg("dcn", ALL_TO_ALL,
-                                 backend="pallas"),)).validate()
-        # int8 + pallas is legal (the fused quantize pair backs it)
-        WirePlan("a2a", (Leg("dcn", ALL_TO_ALL, "int8", block=256,
-                             backend="pallas"),)).validate()
-
     def test_a2a_level_from_mesh(self):
         assert ep_a2a_level((2, 2)) == "dcn"
         assert ep_a2a_level((1, 4)) == "ici"
@@ -639,8 +631,7 @@ class TestGoldenPlan:
                                hierarchical=False, num_comm_streams=1,
                                quant_block=256,
                                fusion_threshold_bytes=64 * 1024 * 1024,
-                               fused=False, quantized_pod=False,
-                               pp_stages=0)
+                               quantized_pod=False, pp_stages=0)
         table = sp.table(payload_bytes=4 * 1024 * 1024)
         assert ("a2a                1 dcn   all_to_all     int8/256   "
                 "yes xla          0") in table
@@ -700,16 +691,15 @@ class TestAutotuneV9:
         pm = ParameterManager(TunedParams(moe_capacity_factor=1.25),
                               warmup_samples=0, max_samples=8,
                               tune_moe=True, moe_experts=4)
-        for u9 in (0.0, 0.3, 0.7, 1.0):
-            p = pm._from_unit((0.5, 0.5, 0.25, 0.25, 0.25, 0.0, 0.25,
-                               0.0, 0.0, u9, 0.9))
+        for u8 in (0.0, 0.3, 0.7, 1.0):
+            p = pm._from_unit((0.5, 0.5, 0.25, 0.25, 0.25, 0.0,
+                               0.0, 0.0, u8, 0.9))
             assert 1.0 <= p.moe_capacity_factor <= 2.0
             assert (p.moe_capacity_factor * 4) == int(
                 p.moe_capacity_factor * 4)       # quarter-snapped
             assert p.moe_quantized
-        # pre-v9 unit tuples (9 dims) still resolve
-        p = pm._from_unit((0.5, 0.5, 0.25, 0.25, 0.25, 0.0, 0.25,
-                           0.0, 0.0))
+        # pre-v9 unit tuples (8 dims) still resolve
+        p = pm._from_unit((0.5, 0.5, 0.25, 0.25, 0.25, 0.0, 0.0, 0.0))
         assert p.moe_capacity_factor >= 1.0
 
     def test_csv_roundtrip_with_moe_columns(self, tmp_path):

@@ -981,8 +981,8 @@ class TestLinkHealth:
 class TestSpanVocabulary:
     def test_known_prefixes_cover_the_documented_table(self):
         for p in ("FAULT", "AUTOTUNE", "OVERLAP", "SERVE", "STALL",
-                  "METRIC", "PROFILE", "CYCLE_START", "CKPT", "FUSED",
-                  "PP", "STRAGGLER", "FLIGHT"):
+                  "METRIC", "PROFILE", "CYCLE_START", "CKPT", "PP",
+                  "STRAGGLER", "FLIGHT"):
             assert p in KNOWN_PREFIXES
 
     def test_event_prefix(self):

@@ -209,7 +209,7 @@ class TestPlanner:
 
 GOLDEN_QUANTIZED_2x4 = """\
 wire plan  mesh=2x4  payload=1048576B (itemsize 4)
-knobs: quantized=on block=256 zero_stage=0 overlap=off hierarchical=off streams=1 fusion_threshold=67108864 fused=off quantized_pod=off
+knobs: quantized=on block=256 zero_stage=0 overlap=off hierarchical=off streams=1 fusion_threshold=67108864 quantized_pod=off
 collective       leg level primitive      wire       ef  backend stream    bytes/dev  model ms  pred ms
 allreduce          1 ici   reduce_scatter payload    -   xla          0       786432    0.0079   0.0109
 allreduce          2 dcn   reduce_scatter int8/256   yes xla          0        33280    0.0013   0.0290
@@ -221,7 +221,7 @@ encoding: allreduce:ici.reduce_scatter[payload]>dcn.reduce_scatter[int8/256+ef]>
 
 GOLDEN_ZERO2_OVERLAP_2x4 = """\
 wire plan  mesh=2x4  payload=1048576B (itemsize 4)
-knobs: quantized=off block=256 zero_stage=2 overlap=on hierarchical=off streams=2 fusion_threshold=67108864 fused=off quantized_pod=off
+knobs: quantized=off block=256 zero_stage=2 overlap=on hierarchical=off streams=2 fusion_threshold=67108864 quantized_pod=off
 collective       leg level primitive      wire       ef  backend stream    bytes/dev  model ms  pred ms
 reduce_scatter     1 flat  reduce_scatter payload    -   xla          0       917504    0.0131   0.0411
 all_gather         1 flat  all_gather     payload    -   xla          0      1835008    0.0262   0.0542

@@ -65,12 +65,9 @@ class TestSendIR:
             WirePlan("send", (Leg("dcn", SEND), Leg("ici", SEND))
                      ).validate()
 
-    def test_flat_and_pallas_send_rejected(self):
+    def test_flat_send_rejected(self):
         with pytest.raises(PlanError, match="LINK CLASS"):
             WirePlan("send", (Leg("flat", SEND),)).validate()
-        with pytest.raises(PlanError, match="pallas"):
-            WirePlan("send", (Leg("dcn", SEND, backend="pallas"),)
-                     ).validate()
 
     def test_send_level_from_axis(self):
         assert _send_plan_for_axis(hvd.LOCAL_AXIS).legs[0].level == "ici"
@@ -673,7 +670,7 @@ class TestGoldenPlan:
                                hierarchical=False, num_comm_streams=1,
                                quant_block=256,
                                fusion_threshold_bytes=64 * 1024 * 1024,
-                               fused=False, quantized_pod=False)
+                               quantized_pod=False)
         table = sp.table(payload_bytes=4 * 1024 * 1024)
         assert ("send               1 dcn   send           int8/256   "
                 "yes xla          0") in table
@@ -733,9 +730,8 @@ class TestAutotuneV8:
                               warmup_samples=0, max_samples=8,
                               tune_pp=True, pp_stages=3,
                               pp_max_interleave=2)
-        for u7 in (0.0, 0.33, 0.7, 1.0):
-            p = pm._from_unit((0.5, 0.5, 0.25, 0.25, 0.25, 0.0, 0.25,
-                               u7, 1.0))
+        for u6 in (0.0, 0.33, 0.7, 1.0):
+            p = pm._from_unit((0.5, 0.5, 0.25, 0.25, 0.25, 0.0, u6, 1.0))
             assert p.pp_microbatches % 3 == 0
             assert p.pp_microbatches >= 3
             assert p.pp_interleave <= 2
@@ -844,21 +840,20 @@ class TestAutotuneV11:
                               warmup_samples=0, max_samples=8,
                               tune_pp=True, pp_stages=4,
                               pp_max_interleave=1)
-        for u13, want in ((0.0, "interleaved_1f1b"),
+        for u12, want in ((0.0, "interleaved_1f1b"),
                           (0.25, "interleaved_1f1b"),
                           (0.75, "zb1"), (1.0, "zb1")):
-            p = pm._from_unit((0.5, 0.5, 0.25, 0.25, 0.25, 0.0, 0.25,
-                               0.5, 0.0, 0.25, 0.25, 0.25, 0.25, u13))
+            p = pm._from_unit((0.5, 0.5, 0.25, 0.25, 0.25, 0.0,
+                               0.5, 0.0, 0.25, 0.25, 0.25, 0.25, u12))
             assert p.pp_schedule == want
             # round trip: _to_unit lands the same side of 0.5
             back = pm._from_unit(pm._to_unit(p))
             assert back.pp_schedule == want
-        # pre-v11 unit tuples (len < 14) still resolve — the zb dim
+        # pre-v11 unit tuples (len < 13) still resolve — the zb dim
         # was appended at the tail precisely so old coordinates stay
         # valid, defaulting to the pre-v11 schedule
-        p9 = pm._from_unit((0.5, 0.5, 0.25, 0.25, 0.25, 0.0, 0.25,
-                            0.5, 0.0))
-        assert p9.pp_schedule == "interleaved_1f1b"
+        p8 = pm._from_unit((0.5, 0.5, 0.25, 0.25, 0.25, 0.0, 0.5, 0.0))
+        assert p8.pp_schedule == "interleaved_1f1b"
 
     def test_csv_roundtrip_with_pp_schedule_column(self, tmp_path):
         from horovod_tpu.autotune.parameter_manager import (

@@ -73,11 +73,10 @@ def real_kernels(monkeypatch):
     """``interpret=False``: the kernels' own backend test sees this
     process's CPU and would hand Mosaic nothing to compile."""
     import horovod_tpu.ops.flash_attention as F
-    import horovod_tpu.ops.fused_collective as FC
     import horovod_tpu.ops.layer_norm as L
     import horovod_tpu.ops.softmax_xent as X
 
-    for mod in (F, FC, L, X):
+    for mod in (F, L, X):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -507,57 +506,3 @@ def test_quantized_allreduce_compiles_over_four_chips(tpu):
     x = tpu.shape((4, 1024), jnp.float32, P(hvd.HVD_AXES))
     text = tpu.compile(f, x, x).as_text()
     assert "all-to-all" in text and "s8[" in text
-
-
-# -- ops/fused_collective.py: opt-in (fused=), never on the default path.
-#    Its kernels keep whole operands in VMEM, so they compile at toy sizes
-#    only; ROADMAP S7 decides whether they are tiled or removed.
-
-
-def _fused_case(tpu, kernel, *dims):
-    import horovod_tpu.ops.fused_collective as FC
-
-    if kernel == "matmul_accumulate":
-        m, K, N = dims
-        return FC._matmul_accumulate, (
-            tpu.shape((m, K), jnp.bfloat16), tpu.shape((K, N), jnp.bfloat16),
-            tpu.shape((m, N), jnp.bfloat16))
-    if kernel == "quantize_blockwise":
-        return FC.quantize_blockwise, (tpu.shape(dims, jnp.float32),)
-    return FC.dequantize_accumulate, (tpu.shape(dims, jnp.int8),
-                                      tpu.shape(dims[:2], jnp.float32))
-
-
-@pytest.mark.parametrize("kernel,dims", [
-    ("matmul_accumulate", (256, 1024, 1024)),
-    ("quantize_blockwise", (4, 1024, 256)),
-    ("dequantize_accumulate", (4, 1024, 256)),
-])
-def test_fused_collective_compiles_at_toy_size(tpu, real_kernels, kernel,
-                                               dims):
-    fn, args = _fused_case(tpu, kernel, *dims)
-    assert _has_kernel(tpu.compile(fn, *args))
-
-
-@pytest.mark.parametrize("kernel,dims", [
-    ("matmul_accumulate", (2048, 1024, 4096)),   # one 350M MLP tile
-    ("quantize_blockwise", (4, 16384, 256)),     # a 64 MiB gradient bucket
-    ("dequantize_accumulate", (4, 16384, 256)),
-])
-def test_fused_collective_refuses_real_sizes_by_name(tpu, real_kernels,
-                                                     kernel, dims):
-    """What the compiler refuses (RESOURCE_EXHAUSTED, after up to 154 s)
-    the opt-in refuses at trace time, saying why."""
-    fn, args = _fused_case(tpu, kernel, *dims)
-    with pytest.raises(ValueError, match=rf"{kernel}.*ROADMAP S7"):
-        tpu.compile(fn, *args)
-
-
-@pytest.mark.xfail(strict=True, raises=ValueError,
-                   reason="ROADMAP S7: _matmul_accumulate holds the whole "
-                          "[m, N] tile + fp32 scratch in VMEM; 2048x1024 @ "
-                          "1024x4096 needs 76 MiB of 16 — tile it or "
-                          "remove fused=")
-def test_fused_matmul_compiles_at_a_real_size(tpu, real_kernels):
-    fn, args = _fused_case(tpu, "matmul_accumulate", 2048, 1024, 4096)
-    tpu.compile(fn, *args)

@@ -310,32 +310,42 @@ def test_quantized_reduce_scatter_error_bounded():
 # --- composition -----------------------------------------------------------
 
 
-def test_zero_quantized_error_feedback_compose():
-    """zero + quantized: training tracks the fp ZeRO run and the EF
-    residuals become (and stay) active."""
+@pytest.mark.parametrize("stage", [1, 2, 3])
+def test_zero_quantized_error_feedback_compose(stage):
+    """zero + quantized, every stage: training tracks the fp ZeRO run of
+    the same stage and the EF residuals become (and stay) active — the
+    reduce-scatter's at every stage, the all-gather's where the step
+    ends in one (stage 3 gathers at the head of the next forward)."""
     rng = np.random.RandomState(8)
     x, y = make_data(rng)
-    tq = hvd.DistributedOptimizer(optax.sgd(0.1), zero=True, quantized=True)
-    tf_ = hvd.DistributedOptimizer(optax.sgd(0.1), zero=True,
+    tq = hvd.DistributedOptimizer(optax.sgd(0.1), zero_stage=stage,
+                                  quantized=True)
+    tf_ = hvd.DistributedOptimizer(optax.sgd(0.1), zero_stage=stage,
                                    quantized=False)
-    pq, sq, lq = train(tq, True, x, y, steps=6)
-    pf, _, lf = train(tf_, True, x, y, steps=6)
+    if stage == 3:
+        pq, _, sq, lq = train3(tq, x, y, steps=6)
+        pf, _, _, _ = train3(tf_, x, y, steps=6)
+    else:
+        pq, sq, lq = train(tq, True, x, y, steps=6)
+        pf, _, _ = train(tf_, True, x, y, steps=6)
     assert lq[-1] < lq[0]  # trains
     for k in pf:
         np.testing.assert_allclose(np.asarray(pq[k]), np.asarray(pf[k]),
                                    rtol=0.05, atol=5e-3)
     assert isinstance(sq, hvd.ZeroState)
     rs = [l for l in jax.tree.leaves(sq.residual) if l is not None]
-    ag = [l for l in jax.tree.leaves(sq.gather_residual) if l is not None]
-    assert rs and ag
-    assert any(float(jnp.abs(l).max()) > 0 for l in rs)
-    assert any(float(jnp.abs(l).max()) > 0 for l in ag)
+    assert rs and any(float(jnp.abs(l).max()) > 0 for l in rs)
     # residuals are shard-local: [world, padded/local] and [world, padded/world]
     plan = fusion.plan_buckets(jax.tree.leaves(init_params()),
                                shard_multiple=N)
     local = hvd.local_size()
     assert {tuple(l.shape) for l in rs} == \
         {(N, b.padded_size // local) for b in plan}
+    if stage == 3:
+        assert sq.gather_residual is None  # no trailing all-gather leg
+        return
+    ag = [l for l in jax.tree.leaves(sq.gather_residual) if l is not None]
+    assert ag and any(float(jnp.abs(l).max()) > 0 for l in ag)
     assert {tuple(l.shape) for l in ag} == \
         {(N, b.padded_size // N) for b in plan}
 
