@@ -171,8 +171,10 @@ from .parallel.expert import (  # noqa: F401
 from . import moe  # noqa: F401  (expert-parallel MoE, docs/moe.md)
 from .moe import (  # noqa: F401
     MoELayer,
+    moe_apply,
     moe_ffn,
     moe_ffn_dropless,
+    moe_route,
     router_bias_update,
 )
 from .parallel.pipeline import (  # noqa: F401

@@ -16,10 +16,15 @@ layer, and the block is written once.
   else routed experts through :func:`hvd.moe_ffn_dropless` (told which
   experts this chip holds), beside ``num_shared_experts`` shared ones that
   every token passes (scope ``hvd.shared_expert``, outside
-  ``hvd.moe_ffn``).
+  ``hvd.moe_ffn``). With ``router_input="block_input"`` the router reads
+  the block's INPUT, before the attention norm: the block calls
+  :func:`hvd.moe_route` on it ahead of attention (scope
+  ``hvd.moe_route``) and :func:`hvd.moe_apply` on the normed stream after
+  it.
 
-Three published families are built from their own ``config.json`` keys
-(:meth:`SparseMoEConfig.from_dict`, by ``model_type``):
+Four published families are built from their own ``config.json`` keys
+(:meth:`SparseMoEConfig.from_dict`, by ``model_type`` or, where the
+release has none, by the keys only it has):
 
 * Keye-VL-2.0's language model (``sa_config`` present): every layer
   ``sparse`` + routed, pre-norm residuals, rotary position on every layer,
@@ -44,7 +49,17 @@ Three published families are built from their own ``config.json`` keys
   half alone: the hidden states or logits that come out are ``[B, L, ..]``
   (:func:`hvd.block_diffusion_loss` takes them).
 
-RMSNorm, per-head RMSNorm on q and k, no bias anywhere, an untied head.
+* SmallThinker (``moe_num_primary_experts`` and ``sliding_window_layout``
+  present; the release names itself in ``model_name``): the layers'
+  kinds from ``sliding_window_layout`` (1 sliding, 0 full; the period is
+  ``[full, sliding, sliding, sliding]``), rotary position where
+  ``rope_layout`` says (the sliding layers; a full layer has none),
+  pre-norm residuals, NO per-head norm on q and k, no gate, a softmax
+  top-k router that reads the block's input, ReLU-gated experts
+  (``W2(relu(W1 m) * W3 m)``), no shared expert and no dense layer.
+
+RMSNorm, per-head RMSNorm on q and k (``qk_norm``; every family but the
+last), no bias anywhere, an untied head.
 bfloat16 activations and matmul operands with float32 accumulation;
 float32 parameters, norms, rotary angles, softmax statistics and router
 scores. Each block is rematerialised in the backward pass
@@ -87,7 +102,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ..moe.layer import (PLAN_NAME, moe_ffn_dropless, plan_bytes,
+from ..moe.layer import (ACTIVATIONS, PLAN_NAME, moe_apply,
+                         moe_ffn_dropless, moe_route, plan_bytes,
                          router_bias_update)
 from ..ops import flash_attention as _flash
 from ..ops.sparse_attention import (OUT_NAME, SELECTION_NAME,
@@ -96,6 +112,9 @@ from ..ops.sparse_attention import (OUT_NAME, SELECTION_NAME,
 SPARSE, SLIDING, FULL = "sparse", "sliding_attention", "full_attention"
 BLOCK_DIFFUSION = "block_diffusion"
 BIAS_COLLECTION = "router_bias"
+#: What a layer's router reads: the normed stream its experts read, after
+#: attention, or the block's input, before the attention norm.
+ROUTER_INPUTS = ("mlp_input", "block_input")
 
 # ``checkpoint_name``s of a block's values (``PLAN_NAME`` is the expert
 # layer's own): what :func:`remat_kept` chooses among.
@@ -150,7 +169,10 @@ class SparseMoEConfig:
     route_norm: bool = True
     route_scale: float = 1.0
     load_balance_coeff: float = 0.0   # > 0: a selection bias as state
+    router_input: str = "mlp_input"   # or "block_input": before attention
+    expert_activation: str = "silu"   # or "relu" (moe/layer.py ACTIVATIONS)
     # The block around them.
+    qk_norm: bool = True              # RMSNorm over every q and k head
     sandwich_norms: bool = False      # a norm after attention and MLP too
     attention_gate: bool = False      # o * sigmoid(u Wg)
     rope_layers: str = "all"          # or "sliding": NoPE on full layers
@@ -159,6 +181,15 @@ class SparseMoEConfig:
     dtype: jnp.dtype = jnp.bfloat16
     return_hidden: bool = False
     return_load: bool = False         # also {layer: token-choices [E]}
+
+    def __post_init__(self):
+        if self.router_input not in ROUTER_INPUTS:
+            raise ValueError(f"router_input is one of {ROUTER_INPUTS}, got "
+                             f"{self.router_input!r}")
+        if self.expert_activation not in ACTIVATIONS:
+            raise ValueError(f"expert_activation is one of "
+                             f"{tuple(ACTIVATIONS)}, got "
+                             f"{self.expert_activation!r}")
 
     def attention_kind(self, i: int) -> str:
         return SPARSE if self.layer_types is None else self.layer_types[i]
@@ -170,10 +201,13 @@ class SparseMoEConfig:
     def from_dict(cls, cfg: dict, **overrides) -> "SparseMoEConfig":
         """From a ``config.json`` as published; ``layers`` is the depth to
         build where given, else ``num_hidden_layers``. The family is told
-        by its keys: a nested ``sa_config`` (the learned indexer) or
+        by its keys: a nested ``sa_config`` (the learned indexer),
         ``model_type`` ``afmoe`` or ``sdar_moe`` (``block_length`` is the
         release's generation setting, not a key of its ``config.json``:
-        the configuration that is built states it)."""
+        the configuration that is built states it), or SmallThinker's own
+        ``moe_num_primary_experts`` and ``sliding_window_layout`` (its
+        ``config.json`` has no ``model_type``; ``num_local_experts``
+        defaults to all of them)."""
         flat = {k: cfg[k] for k in cls.__dataclass_fields__ if k in cfg}
         flat.setdefault("layers", cfg.get("num_hidden_layers"))
         flat["rope_theta"] = float(cfg["rope_theta"])
@@ -204,9 +238,36 @@ class SparseMoEConfig:
                 flat.pop(key, None)   # published, and unused by this family
             flat.update(layer_types=(BLOCK_DIFFUSION,) * flat["layers"],
                         block_length=int(cfg["block_length"]))
+        elif {"moe_num_primary_experts", "sliding_window_layout"} <= set(cfg):
+            n = flat["layers"]
+            sliding, roped = (tuple(cfg[k])[:n] for k in (
+                "sliding_window_layout", "rope_layout"))
+            if len(sliding) != n or set(sliding) - {0, 1}:
+                raise ValueError(f"sliding_window_layout {sliding} for {n} "
+                                 f"layers")
+            if roped not in (sliding, (1,) * n):
+                raise NotImplementedError(
+                    f"rope_layout {roped}: position on the sliding layers "
+                    f"{sliding} or on every layer")
+            if not (cfg.get("moe_primary_router_apply_softmax", True)
+                    and cfg.get("norm_topk_prob", True)):
+                raise NotImplementedError(
+                    "a router without the softmax over its chosen logits")
+            flat.update(
+                layer_types=tuple(SLIDING if w else FULL for w in sliding),
+                rope_layers="all" if roped != sliding else "sliding",
+                sliding_window=cfg["sliding_window_size"],
+                num_experts=cfg["moe_num_primary_experts"],
+                num_experts_per_tok=cfg["moe_num_active_primary_experts"],
+                moe_intermediate_size=cfg["moe_ffn_hidden_size"],
+                qk_norm=False, router_input="block_input",
+                expert_activation="relu")
+            flat.setdefault("num_local_experts", flat["num_experts"])
         else:
-            raise ValueError("neither an sa_config nor model_type afmoe or "
-                             "sdar_moe: a family this decoder does not know")
+            raise ValueError(
+                "no sa_config, no model_type afmoe or sdar_moe, no "
+                "moe_num_primary_experts with a sliding_window_layout: a "
+                "family this decoder does not know")
         flat.update(overrides)
         return cls(**flat)
 
@@ -223,7 +284,10 @@ def remat_candidates(cfg: SparseMoEConfig, B: int, T: int) -> dict:
     """``{name: bytes a layer, one entry a layer}`` of what a block's
     backward would otherwise make again, dearest per byte first, for
     ``B x T`` tokens a chip: a name a layer does not hold reads 0 there,
-    and a name no layer's backward would read is left out."""
+    and a name no layer's backward would read is left out. The plan is
+    the same bytes and stands first wherever the router reads: from the
+    block's input it no longer hangs on the attention's output, and what
+    keeping it spares, a top-k and a sort, is the same."""
     n = B * T
     row = n * jnp.dtype(cfg.dtype).itemsize          # a unit of width
     H, Hk, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -386,6 +450,8 @@ class _Attention(nn.Module):
                 return x @ w.astype(cfg.dtype)
 
         def head_norm(name, x):
+            if not cfg.qk_norm:
+                return x
             scale = self.param(name, nn.initializers.ones, (D,), jnp.float32)
             return rms_norm(x, scale, cfg.rms_norm_eps)
 
@@ -429,44 +495,73 @@ class _GatedMLP(nn.Module):
 
 
 class _MoE(nn.Module):
+    """The routed experts of a layer: ``route`` makes the plan from the
+    tensor the router reads, the call walks the experts' input by it.
+    Where both are the one tensor (``cfg.router_input == "mlp_input"``)
+    the call alone does both, as :func:`hvd.moe_ffn_dropless`."""
     cfg: SparseMoEConfig
 
-    @nn.compact
-    def __call__(self, z):
-        """(y, token-choices per expert [E] of this rank's tokens)."""
+    def setup(self):
+        cfg = self.cfg
+        d, held, f = (cfg.hidden_size, cfg.num_local_experts,
+                      cfg.moe_intermediate_size)
+        init = nn.initializers.normal(cfg.initializer_range)
+        self.router = self.param("router", init, (d, cfg.num_experts),
+                                 jnp.float32)
+        self.w1 = self.param("w1", init, (held, d, f), jnp.float32)
+        self.w3 = self.param("w3", init, (held, d, f), jnp.float32)
+        self.w2 = self.param("w2", init, (held, f, d), jnp.float32)
+        if cfg.has_router_bias():
+            self.bias = self.variable(BIAS_COLLECTION, "bias", jnp.zeros,
+                                      (cfg.num_experts,), jnp.float32)
+        if cfg.num_shared_experts:
+            self.shared = _GatedMLP(cfg, cfg.num_shared_experts * f)
+
+    def _routing(self) -> dict:
+        cfg = self.cfg
+        kw = dict(experts_per_token=cfg.num_experts_per_tok,
+                  first_expert=cfg.first_local_expert)
+        if cfg.scoring != "softmax":
+            kw.update(scoring=cfg.scoring, route_norm=cfg.route_norm,
+                      route_scale=cfg.route_scale)
+        if cfg.has_router_bias():
+            kw["bias"] = self.bias.value
+        return kw
+
+    def route(self, x):
+        """The plan of the block's tokens from ``x [B, T, d]``, the tensor
+        the router reads."""
+        return moe_route(x.reshape(-1, x.shape[-1]), self.router,
+                         held=self.cfg.num_local_experts, **self._routing())
+
+    def __call__(self, z, plan=None):
+        """(y, token-choices per expert [E] of this rank's tokens) of the
+        experts' input ``z [B, T, d]``, routed by ``plan`` where given and
+        from ``z`` itself otherwise."""
         from ..monitor.registry import counter
 
         cfg = self.cfg
         B, T, d = z.shape
-        held, f = cfg.num_local_experts, cfg.moe_intermediate_size
-        init = nn.initializers.normal(cfg.initializer_range)
-        params = {
-            "router": self.param("router", init, (d, cfg.num_experts),
-                                 jnp.float32),
-            "w1": self.param("w1", init, (held, d, f), jnp.float32),
-            "w3": self.param("w3", init, (held, d, f), jnp.float32),
-            "w2": self.param("w2", init, (held, f, d), jnp.float32),
-        }
-        router = {}
-        if cfg.scoring != "softmax":
-            router = dict(scoring=cfg.scoring, route_norm=cfg.route_norm,
-                          route_scale=cfg.route_scale)
-        if cfg.has_router_bias():
-            router["bias"] = self.variable(
-                BIAS_COLLECTION, "bias", jnp.zeros, (cfg.num_experts,),
-                jnp.float32).value
-        y, aux = moe_ffn_dropless(
-            z.reshape(B * T, d), params,
-            experts_per_token=cfg.num_experts_per_tok,
-            first_expert=cfg.first_local_expert, **router)
-        self.sow("intermediates", "moe_expert_load", aux.load)
+        params = {"router": self.router, "w1": self.w1, "w3": self.w3,
+                  "w2": self.w2}
+        counter("moe.router_input", at=cfg.router_input).inc()
+        if plan is None:
+            y, aux = moe_ffn_dropless(
+                z.reshape(B * T, d), params, **self._routing(),
+                activation=cfg.expert_activation)
+            load = aux.load
+        else:
+            y = moe_apply(z.reshape(B * T, d), plan, params,
+                          activation=cfg.expert_activation)
+            load = plan.load
+        self.sow("intermediates", "moe_expert_load", load)
         y = y.reshape(B, T, d)
         if cfg.num_shared_experts:
-            width = cfg.num_shared_experts * f
-            counter("moe.shared_width").inc(width)
+            counter("moe.shared_width").inc(
+                cfg.num_shared_experts * cfg.moe_intermediate_size)
             with jax.named_scope("hvd.shared_expert"):
-                y = y + _GatedMLP(cfg, width, name="shared")(z)
-        return y, aux.load
+                y = y + self.shared(z)
+        return y, load
 
 
 class _Block(nn.Module):
@@ -478,6 +573,7 @@ class _Block(nn.Module):
         """(y, the layer's token-choices per expert or None)."""
         cfg, i = self.cfg, self.index
         kind = cfg.attention_kind(i)
+        routed = i >= cfg.num_dense_layers
 
         def norm(name, t):
             return _Scale(cfg.rms_norm_eps, name=name)(t)
@@ -485,17 +581,22 @@ class _Block(nn.Module):
         def after(name, t):
             return norm(name, t) if cfg.sandwich_norms else t
 
+        moe = _MoE(cfg, name="moe") if routed else None
+        # A router that reads the block's input is run here, ahead of
+        # attention: its plan hangs on nothing attention makes.
+        plan = (moe.route(x) if routed and cfg.router_input == "block_input"
+                else None)
         u = norm("ln1", x)
         index = (_Indexer(cfg, name="indexer")(u),) if kind == SPARSE else ()
         h = x + after("ln1_post", _Attention(cfg, kind, name="attn")(
             u, *index, positions=positions))
         z = norm("ln2", h)
-        if i < cfg.num_dense_layers:
+        if routed:
+            m, load = moe(z, plan)
+        else:
             with jax.named_scope("hvd.mlp"):
                 m = _GatedMLP(cfg, cfg.intermediate_size, name="mlp")(z)
             load = None
-        else:
-            m, load = _MoE(cfg, name="moe")(z)
         return h + after("ln2_post", checkpoint_name(m, MLP_OUT_NAME)), load
 
 

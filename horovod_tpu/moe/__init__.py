@@ -16,15 +16,18 @@ from .layer import (  # noqa: F401
     EXPERT_LEAVES,
     MoEAux,
     MoELayer,
+    MoEPlan,
     default_a2a_plan,
     ep_mean_dense_grads,
     ep_param_pspecs,
     ep_stack_params,
+    moe_apply,
     moe_capacity,
     moe_ef_residuals,
     moe_ffn,
     moe_ffn_dropless,
     moe_positions,
+    moe_route,
     moe_router,
     router_bias_update,
     rows_filled,

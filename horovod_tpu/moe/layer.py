@@ -45,7 +45,8 @@ batch shards over ``hvd_ep``, get their explicit ep-mean via
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+import functools
+from typing import NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -413,24 +414,31 @@ def _zeros_like_of(x):
     return lambda shape, dtype: pvary_missing(jnp.zeros(shape, dtype), axes)
 
 
-@jax.custom_vjp
-def _walk(x, gates, w1, w3, w2, plan):
+#: What gates an expert's hidden rows: ``W2(act(W1 x) * W3 x)``.
+ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _walk(activation, x, gates, w1, w3, w2, plan):
     """``y [N, C]``: the held experts' part of the mixture for tokens ``x``,
     gates ``[N, K]`` and weights in ``x``'s dtype, walked over the filled
     rows of the buffer that ``plan`` lays out (``order`` of the choices
     sorted by held expert, the groups' ``sizes``, their ``start`` in that
-    order, their ``first_row`` in the buffer, their ``padded`` sizes)."""
-    return _walk_fwd(x, gates, w1, w3, w2, plan)[0]
+    order, their ``first_row`` in the buffer, their ``padded`` sizes).
+    ``activation`` names one of ``ACTIVATIONS``: static, and the same in
+    the forward walk and in the hidden rows the backward makes again."""
+    return _walk_fwd(activation, x, gates, w1, w3, w2, plan)[0]
 
 
-def _walk_fwd(x, gates, w1, w3, w2, plan):
+def _walk_fwd(activation, x, gates, w1, w3, w2, plan):
+    act = ACTIVATIONS[activation]
     K = gates.shape[1]
     gate_of = gates.reshape(-1)
 
     def body(c, y):
         _, choice, token, gs = _chunk(c, plan, K)
         xs = _rows(x, token)
-        h = nn.silu(lax.ragged_dot(xs, w1, gs)) * lax.ragged_dot(xs, w3, gs)
+        h = act(lax.ragged_dot(xs, w1, gs)) * lax.ragged_dot(xs, w3, gs)
         ys = lax.ragged_dot(h, w2, gs)
         return y.at[token].add(
             ys * _rows(gate_of, choice)[:, None].astype(ys.dtype),
@@ -441,7 +449,8 @@ def _walk_fwd(x, gates, w1, w3, w2, plan):
     return y, (x, gates, w1, w3, w2, plan)
 
 
-def _walk_bwd(res, dy):
+def _walk_bwd(activation, res, dy):
+    act = ACTIVATIONS[activation]
     x, gates, w1, w3, w2, plan = res
     N, K = gates.shape
     gate_of = gates.reshape(-1)
@@ -458,7 +467,7 @@ def _walk_bwd(res, dy):
         group, choice, token, gs = _chunk(c, plan, K)
         xs, dyr = _rows(x, token), _rows(dy, token)
         gate = _rows(gate_of, choice)[:, None]
-        h, pull = jax.vjp(lambda a, b: nn.silu(a) * b,
+        h, pull = jax.vjp(lambda a, b: act(a) * b,
                           lax.ragged_dot(xs, w1, gs),
                           lax.ragged_dot(xs, w3, gs))
         dhu = lax.ragged_dot(dyr, wt2, gs)        # dy W2^T, not yet gated
@@ -519,20 +528,140 @@ def router_bias_update(bias, load, *, coeff: float):
         return bias + (delta - jnp.mean(delta)).astype(bias.dtype)
 
 
+class MoEPlan(NamedTuple):
+    """What :func:`moe_route` makes of a router's input and
+    :func:`moe_apply` walks another tensor by: every token's ``gates``
+    ``[N, K]`` (float32; the router's gradient comes back through them),
+    the token-choices' ``order`` ``[N * K]`` sorted by held expert (those
+    on an absent expert last), the held groups' ``sizes`` ``[E_held]``, and
+    the routing diagnostics (``load`` the token-choices per GLOBAL expert
+    ``[E]``). The chosen experts and their scores, ``order`` and ``sizes``
+    carry ``PLAN_NAME``."""
+
+    gates: jnp.ndarray
+    order: jnp.ndarray
+    sizes: jnp.ndarray
+    load: jnp.ndarray
+    load_balance_loss: jnp.ndarray
+    z_loss: jnp.ndarray
+
+
+def _route(x, router, *, experts_per_token, first_expert, held,
+           scoring="softmax", **router_kwargs):
+    """``(experts [N, K], gates, order, sizes, lb, z)`` of tokens ``x``
+    through ``router [C, E]``, under the caller's scope."""
+    from ..monitor.registry import counter
+
+    N, K, E = x.shape[0], int(experts_per_token), router.shape[-1]
+    if not 0 <= first_expert <= E - held:
+        raise ValueError(f"experts {first_expert}..{first_expert + held} "
+                         f"are not among the router's {E}")
+    R = rows_grouped(N * K, held)
+    counter("moe.experts_held").inc(held)
+    counter("moe.rows_grouped").inc(R)
+    counter("moe.row_chunks").inc(R // CHUNK_ROWS)
+    counter("moe.scoring", kind=scoring).inc()
+    experts, gates, lb, z, _ = moe_router(x, router, topk=K, scoring=scoring,
+                                          **router_kwargs)
+    local = experts.reshape(-1) - first_expert               # [N*K]
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    _, order = lax.sort((key, jnp.arange(N * K, dtype=jnp.int32)),
+                        num_keys=1)
+    sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
+    order, sizes = (checkpoint_name(a, PLAN_NAME) for a in (order, sizes))
+    return experts, gates, order, sizes, lb, z
+
+
+def _load(experts, E: int):
+    return jnp.sum(jax.nn.one_hot(experts, E, dtype=jnp.float32),
+                   axis=(0, 1))
+
+
+def _apply(x, gates, order, sizes, params, activation):
+    """The two walks over ``x`` by a plan's pieces, under the caller's
+    scope."""
+    from ..monitor.registry import counter
+
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"activation is one of {tuple(ACTIVATIONS)}, got "
+                         f"{activation!r}")
+    if sizes.shape[0] != params["w1"].shape[0]:
+        raise ValueError(f"the plan is laid out for {sizes.shape[0]} held "
+                         f"experts, the weights are {params['w1'].shape[0]}")
+    counter("moe.activation", kind=activation).inc()
+    padded = -(-sizes // GROUP_ALIGN) * GROUP_ALIGN
+    plan = (order, sizes, jnp.cumsum(sizes) - sizes,
+            jnp.cumsum(padded) - padded, padded)
+    y = _walk(activation, *_harmonize_vma(x, gates, *(
+        params[n].astype(x.dtype) for n in ("w1", "w3", "w2"))), plan)
+    return y.astype(x.dtype)
+
+
+def _no_exchange(ep_axis) -> None:
+    if ep_axis is not None and _axis_size(ep_axis) > 1:
+        raise NotImplementedError(
+            "moe_ffn_dropless: the expert exchange across hvd_ep is not "
+            "built for the dropless path (ROADMAP R1); run it with the "
+            "experts this chip holds and no ep axis")
+
+
+def moe_route(router_input, router, *, experts_per_token: int,
+              first_expert: int = 0, held: int, ep_axis=None,
+              router_logits=None, scoring: str = "softmax", bias=None,
+              route_norm: bool = True,
+              route_scale: float = 1.0) -> MoEPlan:
+    """The routing half of :func:`moe_ffn_dropless`, from a tensor of its
+    own: tokens ``router_input [N, C]`` through ``router [C, E]`` (float32
+    matmul, :func:`moe_router`'s scoring, the top ``experts_per_token``),
+    the token-choices sorted by held expert (``first_expert ..
+    first_expert + held``), the groups' sizes and the load, under the scope
+    ``hvd.moe_route`` in both directions. A block whose router reads
+    another tensor than its experts (its input, before attention) calls
+    this where that tensor is and :func:`moe_apply` where the experts'
+    rows are; the exchange across ``hvd_ep`` (ROADMAP R1) will hook in
+    here, where the counts are known before the rows exist."""
+    _no_exchange(ep_axis)
+    with jax.named_scope("hvd.moe_route"):
+        experts, gates, order, sizes, lb, z = _route(
+            router_input, router, experts_per_token=experts_per_token,
+            first_expert=first_expert, held=held,
+            router_logits=router_logits, scoring=scoring, bias=bias,
+            route_norm=route_norm, route_scale=route_scale)
+        load = _load(experts, router.shape[-1])
+    return MoEPlan(gates, order, sizes, load, lb, z)
+
+
+def moe_apply(x, plan: MoEPlan, params, *, activation: str = "silu"):
+    """The experts' half: ``y [N, C]``, the held experts' part of the
+    mixture for tokens ``x [N, C]`` routed as ``plan`` says (made by
+    :func:`moe_route` from these tokens or from others of the same rows),
+    ``sum_{e held, chosen} gate_e * W2_e(act(W1_e x) * W3_e x)`` with
+    ``act`` ``"silu"`` or ``"relu"``; ``params`` holds ``w1``, ``w3``
+    ``[E_held, C, F]`` and ``w2`` ``[E_held, F, C]``. The walk of
+    :func:`moe_ffn_dropless`, under the scope ``hvd.moe_ffn`` in both
+    directions."""
+    with jax.named_scope("hvd.moe_ffn"):
+        return _apply(x, plan.gates, plan.order, plan.sizes, params,
+                      activation)
+
+
 def moe_ffn_dropless(x, params, *, experts_per_token: int,
                      first_expert: int = 0, ep_axis=None,
                      router_logits=None, scoring: str = "softmax",
                      bias=None, route_norm: bool = True,
-                     route_scale: float = 1.0):
-    """Top-k gated SiLU experts over tokens ``x [N, C]`` with no capacity:
-    no token-choice is ever dropped. The layer is told which experts it
+                     route_scale: float = 1.0, activation: str = "silu"):
+    """Top-k gated experts over tokens ``x [N, C]`` with no capacity: no
+    token-choice is ever dropped. :func:`moe_route` and then
+    :func:`moe_apply` on the one tensor, both under the scope
+    ``hvd.moe_ffn``. The layer is told which experts it
     holds: ``params["router"]`` is ``[C, E]`` over ALL the experts (the
     published width), ``w1``, ``w3`` ``[E_held, C, F]`` and ``w2``
     ``[E_held, F, C]`` are experts ``first_expert .. first_expert +
     E_held``. Every token is routed over all E (softmax, top
     ``experts_per_token``, gates renormalised to sum one); the result is
-    the held experts' part, ``sum_{e held, chosen} gate_e * W2_e(silu(W1_e
-    x) * W3_e x)``; what the absent experts would add is left out (it is
+    the held experts' part, ``sum_{e held, chosen} gate_e * W2_e(act(W1_e
+    x) * W3_e x)``, ``act`` the ``activation`` (``"silu"``, or ``"relu"``);
+    what the absent experts would add is left out (it is
     another chip's to add). Returns ``(y [N, C], MoEAux)`` with ``load``
     the token-choices per GLOBAL expert and ``dropped_fraction`` 0.
     ``scoring``, ``bias``, ``route_norm`` and ``route_scale`` are
@@ -566,47 +695,22 @@ def moe_ffn_dropless(x, params, *, experts_per_token: int,
     in place of a second top-k and sort; the result ``y`` carries none (a
     caller whose backward reads it names it itself:
     ``models/sparse_moe_decoder.py``). Without ``ep_axis`` bound nothing is
-    exchanged; the exchange across ``hvd_ep`` for this path is not built
-    (ROADMAP R1)."""
-    if ep_axis is not None and _axis_size(ep_axis) > 1:
-        raise NotImplementedError(
-            "moe_ffn_dropless: the expert exchange across hvd_ep is not "
-            "built for the dropless path (ROADMAP R1); run it with the "
-            "experts this chip holds and no ep axis")
-    from ..monitor.registry import counter
-
-    N, C = x.shape
-    K, held = int(experts_per_token), params["w1"].shape[0]
-    E = params["router"].shape[-1]
-    if not 0 <= first_expert <= E - held:
-        raise ValueError(f"experts {first_expert}..{first_expert + held} "
-                         f"are not among the router's {E}")
-    R = rows_grouped(N * K, held)
-    counter("moe.experts_held").inc(held)
-    counter("moe.rows_grouped").inc(R)
-    counter("moe.row_chunks").inc(R // CHUNK_ROWS)
-    counter("moe.scoring", kind=scoring).inc()
+    exchanged; the exchange across ``hvd_ep`` is built for neither half
+    (ROADMAP R1: :func:`moe_route` is where it will hook in)."""
+    _no_exchange(ep_axis)
+    held = params["w1"].shape[0]
     with jax.named_scope("hvd.moe_ffn"):
-        experts, gates, lb, z, _ = moe_router(
-            x, params["router"], topk=K, router_logits=router_logits,
-            scoring=scoring, bias=bias, route_norm=route_norm,
-            route_scale=route_scale)
-        local = experts.reshape(-1) - first_expert           # [N*K]
-        key = jnp.where((local >= 0) & (local < held), local, held)
-        _, order = lax.sort((key, jnp.arange(N * K, dtype=jnp.int32)),
-                            num_keys=1)
-        sizes = jnp.sum(jax.nn.one_hot(key, held, dtype=jnp.int32), axis=0)
-        order, sizes = (checkpoint_name(a, PLAN_NAME)
-                        for a in (order, sizes))
-        padded = -(-sizes // GROUP_ALIGN) * GROUP_ALIGN
-        plan = (order, sizes, jnp.cumsum(sizes) - sizes,
-                jnp.cumsum(padded) - padded, padded)
-        y = _walk(*_harmonize_vma(x, gates, *(
-            params[n].astype(x.dtype) for n in ("w1", "w3", "w2"))), plan)
-    load = jnp.sum(jax.nn.one_hot(experts, E, dtype=jnp.float32),
-                   axis=(0, 1))
-    return y.astype(x.dtype), MoEAux(
-        load_balance_loss=lb, z_loss=z, load=load,
+        experts, gates, order, sizes, lb, z = _route(
+            x, params["router"], experts_per_token=experts_per_token,
+            first_expert=first_expert, held=held,
+            router_logits=router_logits, scoring=scoring, bias=bias,
+            route_norm=route_norm, route_scale=route_scale)
+        y = _apply(x, gates, order, sizes, params, activation)
+    # The load after the walk, where it always stood: the callers' traces,
+    # and with them their compiled programs, are what they were.
+    return y, MoEAux(
+        load_balance_loss=lb, z_loss=z,
+        load=_load(experts, params["router"].shape[-1]),
         dropped_fraction=jnp.zeros((), jnp.float32))
 
 

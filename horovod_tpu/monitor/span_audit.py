@@ -61,6 +61,8 @@ DEVICE_SCOPES = (
     "hvd.sparse_attention",  # ops/sparse_attention.py: kernels + layout
     "hvd.sparse_indexer",    # ops/sparse_attention.py: scores + selection
     "hvd.moe_ffn",           # moe/layer.py: dropless router..combine
+    "hvd.moe_route",         # moe/layer.py: a router run apart from its
+    #                          experts' walk (moe_route), ahead of attention
     "hvd.shared_expert",     # models/sparse_moe_decoder.py: beside moe_ffn
     "hvd.ssm",               # models/sambay.py: a state-space mixer
     "hvd.selective_scan",    # ops/selective_scan.py: kernels + their layout

@@ -28,10 +28,12 @@ import bench_tiny as tiny  # noqa: E402
 import bench_tiny_afmoe as tiny_afmoe  # noqa: E402
 import bench_tiny_sambay as tiny_sambay  # noqa: E402
 import bench_tiny_sdar as tiny_sdar  # noqa: E402
+import bench_tiny_smallthinker as tiny_smallthinker  # noqa: E402
 import bench_tiny_sparse as tiny_sparse  # noqa: E402
 
 from benchmarks.builders import (afmoe, gpt_decoder, sambay,  # noqa: E402
-                                 sdar_moe, sparse_moe_decoder)
+                                 sdar_moe, smallthinker,
+                                 sparse_moe_decoder)
 from horovod_tpu.ops import selective_scan as scan  # noqa: E402
 from horovod_tpu.ops import sparse_attention as spa  # noqa: E402
 
@@ -58,8 +60,11 @@ SAMBAY_STEP = {"hvd.ssm", "hvd.selective_scan", "hvd.gmu",
 # Nor the block-diffusion objective: the tiny sdar step's
 # (tests/benchmark/bench_tiny_sdar.py).
 SDAR_STEP = {"hvd.flash_block_diffusion", "hvd.block_diffusion_noise"}
+# Nor a router run apart from its experts, ahead of attention: the tiny
+# smallthinker step's (tests/benchmark/bench_tiny_smallthinker.py).
+SMALLTHINKER_STEP = {"hvd.moe_route"}
 OFF_STEP = ({"hvd.layer_norm"} | SPARSE_STEP | AFMOE_STEP | SAMBAY_STEP
-            | SDAR_STEP)
+            | SDAR_STEP | SMALLTHINKER_STEP)
 # The decoder block's names (models/): the programs that hold each. Only
 # the mixture decoder rotates, and the tiny sparse step has no dense layer.
 BLOCK_STEPS = {"hvd.norm": ("gpt", "sparse", "afmoe", "sambay"),
@@ -71,13 +76,14 @@ BLOCK_STEPS = {"hvd.norm": ("gpt", "sparse", "afmoe", "sambay"),
 DIFFERENTIATED = {"hvd.grad", "hvd.lm_head_loss", "hvd.flash_attention",
                   "hvd.layer_norm", "hvd.sparse_attention", "hvd.moe_ffn",
                   "hvd.flash_window", "hvd.shared_expert",
-                  "hvd.flash_block_diffusion"} | set(
+                  "hvd.flash_block_diffusion", "hvd.moe_route"} | set(
                       BLOCK_STEPS) | SAMBAY_STEP
 NESTED_IN = {"hvd.lm_head_loss": "hvd.grad",
              "hvd.flash_attention": "hvd.grad",
              "hvd.sparse_attention": "hvd.grad",
              "hvd.sparse_indexer": "hvd.grad",
              "hvd.moe_ffn": "hvd.grad",
+             "hvd.moe_route": "hvd.grad",
              "hvd.flash_window": "hvd.flash_attention",
              "hvd.flash_block_diffusion": "hvd.flash_attention",
              "hvd.shared_expert": "hvd.grad",
@@ -96,7 +102,8 @@ def _op_names(text: str) -> list:
 STEPS = {"gpt": (gpt_decoder, tiny), "sparse": (sparse_moe_decoder,
                                                 tiny_sparse),
          "afmoe": (afmoe, tiny_afmoe), "sambay": (sambay, tiny_sambay),
-         "sdar": (sdar_moe, tiny_sdar)}
+         "sdar": (sdar_moe, tiny_sdar),
+         "smallthinker": (smallthinker, tiny_smallthinker)}
 
 
 def _step_text(step: str, n_devices: int = 1) -> str:
@@ -108,7 +115,7 @@ def _step_text(step: str, n_devices: int = 1) -> str:
 
 @pytest.fixture(scope="module")
 def step_texts():
-    """The compiled text of the five tiny steps on one device (the tiny
+    """The compiled text of the six tiny steps on one device (the tiny
     GPT step, tests/benchmark/bench_tiny.py, on four too)."""
     try:
         yield {**{step: _step_text(step) for step in STEPS},
@@ -147,6 +154,12 @@ def sambay_step_names(step_texts):
 def sdar_step_names(step_texts):
     """The op_names of the tiny sdar step, one device."""
     return _op_names(step_texts["sdar"])
+
+
+@pytest.fixture(scope="module")
+def smallthinker_step_names(step_texts):
+    """The op_names of the tiny smallthinker step, one device."""
+    return _op_names(step_texts["smallthinker"])
 
 
 @pytest.fixture(scope="module")
@@ -189,7 +202,8 @@ def test_scope_reaches_the_compiled_program(scope, step_names,
                                             sparse_step_names,
                                             afmoe_step_names,
                                             sambay_step_names,
-                                            sdar_step_names):
+                                            sdar_step_names,
+                                            smallthinker_step_names):
     by_step = {"gpt": step_names[1], "sparse": sparse_step_names,
                "afmoe": afmoe_step_names, "sambay": sambay_step_names}
     if scope in BLOCK_STEPS:
@@ -203,6 +217,8 @@ def test_scope_reaches_the_compiled_program(scope, step_names,
         programs = {"sambay decoder": sambay_step_names}
     elif scope in SDAR_STEP:
         programs = {"sdar decoder": sdar_step_names}
+    elif scope in SMALLTHINKER_STEP:
+        programs = {"smallthinker decoder": smallthinker_step_names}
     elif scope in OFF_STEP:
         programs = {"layer_norm": layer_norm_names}
     else:
@@ -352,7 +368,12 @@ OWNED = [("gpt", "hvd.norm", "forward"), ("gpt", "hvd.norm", "backward"),
          ("sparse", "hvd.attn_proj", "remat"),
          ("sparse", "hvd.embed", "backward"),
          ("afmoe", "hvd.mlp", "remat"), ("afmoe", "hvd.mlp", "backward"),
-         ("afmoe", "hvd.rotary", "remat"), ("afmoe", "hvd.norm", "remat")]
+         ("afmoe", "hvd.rotary", "remat"), ("afmoe", "hvd.norm", "remat"),
+         # the router run apart: its matmul is made again in the block's
+         # recomputed forward, its gradient comes back through the gates
+         ("smallthinker", "hvd.moe_route", "forward"),
+         ("smallthinker", "hvd.moe_route", "remat"),
+         ("smallthinker", "hvd.moe_route", "backward")]
 
 
 @pytest.fixture(scope="module")

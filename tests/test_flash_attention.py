@@ -773,6 +773,17 @@ _WALKS = {
                                dict(q_off=128, k_off=384)),
     "masked_runtime_offsets_packed": (512, 1, 1, 32, 512, 512, "packed",
                                       dict(q_off=256, k_off=256)),
+    # seven query heads a KV head (SmallThinker's 28 / 4: no power of two
+    # on the dk/dv kernel's fourth axis nor in ``h // group``), full and
+    # under a window FOUR blocks wide: the band is [far, whole, whole,
+    # whole, diagonal], as 4,096 keys at blocks of 1,024 have it
+    "group7": (1024, 7, 1, 128, 256, 256, "in_place", {}),
+    "group7_two_kv_heads": (512, 14, 2, 128, 256, 256, "in_place", {}),
+    "group7_window_four_blocks": (2048, 7, 1, 128, 256, 256, "in_place",
+                                  dict(window=1024)),
+    "group7_packed": (1024, 7, 1, 64, 256, 256, "packed", {}),
+    "group7_window_four_blocks_packed": (2048, 7, 1, 64, 256, 256,
+                                         "packed", dict(window=1024)),
 }
 
 
@@ -812,6 +823,32 @@ class TestStripWalk:
                                    rtol=1e-5, atol=2e-6)
         assert not np.asarray(o)[:, ~rows].any()
         assert (np.asarray(lse)[:, 0][:, ~rows] == -1e30).all()
+
+    @pytest.mark.parametrize("case", sorted(
+        c for c in _WALKS if c.startswith("group7")))
+    def test_group_of_seven_gradients_are_the_dense_ones(self, case,
+                                                         monkeypatch):
+        """The public call at seven query heads a KV head, full and under
+        the four-block window, in place and packed: o, dq, and dk, dv
+        summed over the seven by the dk/dv kernel's fourth grid axis."""
+        from horovod_tpu.ops import flash_attention as F
+
+        T, H, Hkv, D, bq, bk, layout, mask = _WALKS[case]
+        if layout == "packed":
+            monkeypatch.setattr(F, "_reads_in_place", lambda H, D: False)
+        q, k, v, w = _grouped_qkv(T, H, Hkv, D, seed=len(case))
+        before, groups = _layout_counts(), counter("flash.kv_group").value
+        got = _fwd_and_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=mask.get("window"), block_q=bq,
+            block_k=bk), q, k, v, w)
+        want = _fwd_and_grads(lambda q, k, v: _dense_window(
+            q, k, v, mask.get("window")), q, k, v, w)
+        _assert_close(got, want, 2e-4)
+        assert got[2].shape == k.shape and got[3].shape == v.shape
+        assert _layout_counts()[layout] > before[layout]
+        assert (counter("flash.kv_group").value - groups) % 7 == 0
+        if "window" in mask:
+            assert F._band_blocks(mask["window"], bk, T // bk) == 5
 
     @pytest.mark.parametrize("T,bq,bk,kw,tiles,strips", [
         # the GPT-2 cells: a head is one cell that skips, four strips

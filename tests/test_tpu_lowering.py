@@ -125,6 +125,13 @@ def test_flash_attention_compiles(tpu, real_kernels, B, T, H, D, causal):
                                       # off the sub-tile lattice
     (4096, 8, 2, 64, 2048, 1024),     # grouped at D = 64: packed
     (2048, 6, 2, 128, 300, 1024),     # a window inside one sub-tile row
+    # smallthinker-21b-a3b.train-16k-1chip: SEVEN query heads a KV head
+    # in place over [1, 16384, 3584], a sliding layer (a band of 5 blocks
+    # of 1,024; 9 of 512) and the NoPE full layer
+    (16384, 28, 4, 128, 4096, 1024),
+    (16384, 28, 4, 128, 4096, 512),
+    (16384, 28, 4, 128, None, 1024),
+    (16384, 28, 4, 128, None, 512),
 ])
 def test_flash_window_and_grouped_heads_compile(tpu, real_kernels, T, H,
                                                 Hkv, D, window, block):
