@@ -83,6 +83,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import diff_attention as _diff
 from ..ops import flash_attention as _flash
 from ..ops import selective_scan as _scan
+from ..ops.embed_lookup import embed_lookup
 from .gpt import _layer_norm
 from .sparse_moe_decoder import (MLP_HIDDEN_NAME, QKV_NAME, _GatedMLP,
                                  causal_attention, kept_within)
@@ -390,7 +391,7 @@ class SambaY(nn.Module):
         embed = self.param("embed", _normal(cfg.initializer_range),
                            (cfg.vocab_size, cfg.hidden_size), jnp.float32)
         with jax.named_scope("hvd.embed"):
-            x = embed.astype(cfg.dtype)[tokens]
+            x = embed_lookup(embed, tokens, cfg.dtype)
         block = _Block
         if cfg.remat:
             kept = remat_kept(cfg, *tokens.shape)

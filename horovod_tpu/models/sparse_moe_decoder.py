@@ -106,6 +106,7 @@ from ..moe.layer import (ACTIVATIONS, PLAN_NAME, moe_apply,
                          moe_ffn_dropless, moe_route, plan_bytes,
                          router_bias_update)
 from ..ops import flash_attention as _flash
+from ..ops.embed_lookup import embed_lookup
 from ..ops.sparse_attention import (OUT_NAME, SELECTION_NAME,
                                     sparse_attention)
 
@@ -586,6 +587,13 @@ class _Block(nn.Module):
         # attention: its plan hangs on nothing attention makes.
         plan = (moe.route(x) if routed and cfg.router_input == "block_input"
                 else None)
+        if plan is not None:
+            # Said to the compiler, not left to its scheduler, which is
+            # free to run two independent pieces in either order (PR 46
+            # saw one layer's routing move behind its attention kernel when
+            # the lookup ahead of the blocks changed): attention reads the
+            # stream only once the plan is made.
+            plan, x = jax.lax.optimization_barrier((plan, x))
         u = norm("ln1", x)
         index = (_Indexer(cfg, name="indexer")(u),) if kind == SPARSE else ()
         h = x + after("ln1_post", _Attention(cfg, kind, name="attn")(
@@ -623,7 +631,7 @@ class SparseMoEDecoder(nn.Module):
         head = self.param("head", init,
                           (cfg.vocab_size, cfg.hidden_size), jnp.float32)
         with jax.named_scope("hvd.embed"):
-            x = embed.astype(cfg.dtype)[tokens]
+            x = embed_lookup(embed, tokens, cfg.dtype)
             if cfg.embed_scale != 1.0:
                 x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
         positions = None

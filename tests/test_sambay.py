@@ -248,6 +248,33 @@ def test_loss_and_gradients_are_the_expressions_they_replaced(
     assert float(jnp.abs(want_g["h1"]["mixer"]["subln"]).max()) > 0
 
 
+@pytest.mark.parametrize("hidden, form", [(64, "scatter"),
+                                          (128, "sorted_rows_kernel")])
+@pytest.mark.parametrize("dtype, rtol", [(jnp.float32, 2e-4),
+                                         (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_lookup_with_its_own_backward_is_the_lines_it_replaced(
+        monkeypatch, hidden, form, dtype, rtol):
+    """``ops/embed_lookup.py`` against ``embed.astype(dtype)[tokens]``
+    differentiated by JAX: the model's loss and every gradient leaf, the
+    tied table's among them (the lookup's rows and the head's ``dW`` in one
+    leaf); the rows counted under the form their width takes."""
+    cfg = dict(CFG, hidden_size=hidden)
+    toks, params = _tokens(9)[0], _weights(cfg)
+    model = SambaY(SambaYConfig.from_dict(cfg, dtype=dtype))
+    rows = counter("embed.grad_rows", form=form)
+    before = rows.value
+    got_l, got_g = jax.value_and_grad(_program_loss(model, toks))(params)
+    assert rows.value - before == T
+
+    monkeypatch.setattr(sambay, "embed_lookup",
+                        lambda table, tokens, dt: table.astype(dt)[tokens])
+    want_l, want_g = jax.value_and_grad(_program_loss(model, toks))(params)
+    assert rows.value - before == T
+    assert abs(float(got_l) - float(want_l)) < 0.5 * rtol * abs(float(want_l))
+    _close(got_g, want_g, rtol)
+
+
 def test_one_adamw_step(weights):
     toks = _tokens(7, steps=2)
     s = ref.sizes_from_config(CFG)

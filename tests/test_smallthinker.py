@@ -134,6 +134,36 @@ def test_gradient_leaf_is_the_references(float32_pair, leaf):
                                atol=LEAF_TOL * float(jnp.abs(b).max()))
 
 
+@pytest.mark.parametrize("hidden, form", [(64, "scatter"),
+                                          (128, "sorted_rows_kernel")])
+def test_the_lookup_with_its_own_backward_is_the_lines_it_replaced(
+        monkeypatch, hidden, form):
+    """``ops/embed_lookup.py`` against ``embed.astype(dtype)[tokens]``
+    differentiated by JAX, in float32: the loss and every gradient leaf to
+    the leaves' tolerance, the rows counted under the form their width
+    takes."""
+    cfg = dict(CFG, hidden_size=hidden)
+    params, toks = _params(4, ref.sizes_from_config(cfg)), _tokens(6)
+    model = SparseMoEDecoder(SparseMoEConfig.from_dict(cfg,
+                                                       dtype=jnp.float32))
+    rows = counter("embed.grad_rows", form=form)
+    before = rows.value
+    loss, got = jax.value_and_grad(_program_loss(model, toks))(params)
+    assert rows.value - before == T
+
+    monkeypatch.setattr(decoder, "embed_lookup",
+                        lambda table, tokens, dt: table.astype(dt)[tokens])
+    want_loss, want = jax.value_and_grad(_program_loss(model, toks))(params)
+    assert rows.value - before == T
+    np.testing.assert_allclose(float(loss), float(want_loss), rtol=1e-6)
+    got, want = ref.path_dict(got), ref.path_dict(want)
+    for leaf, b in want.items():
+        np.testing.assert_allclose(
+            np.asarray(got[leaf]), np.asarray(b), err_msg=leaf,
+            atol=LEAF_TOL * float(jnp.abs(b).max()))
+    assert float(jnp.abs(want["embed"]).max()) > 0
+
+
 def test_bfloat16_where_float32_is_stated_fails_the_tolerances(float32_pair):
     """The tolerances above are tight enough to tell a precision: the model
     with bfloat16 activations fails the loss's and most leaves'."""

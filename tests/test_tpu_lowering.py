@@ -406,6 +406,48 @@ def test_ln_residual_compiles(tpu, real_kernels, B, T, C):
     assert _has_kernel(tpu.compile(f, x, x, g, g))
 
 
+@pytest.mark.parametrize("B,T,V,C,dtype,sharded", [
+    (1, 8192, 25008, 2560, jnp.bfloat16, False),   # phi-4-mini-flash
+    (1, 16384, 37984, 2560, jnp.bfloat16, False),  # smallthinker-21b-a3b
+    (1, 16384, 18992, 2048, jnp.bfloat16, False),  # the sparse cell, sdar
+    (1, 8192, 25008, 2560, jnp.float32, False),    # the MXU at ``highest``
+    (4, 8192, 25008, 2560, jnp.bfloat16, True),    # a sequence a chip
+    (1, 1000, 50257, 768, jnp.bfloat16, False),    # N fills no whole chunk
+])
+def test_embed_lookup_backward_compiles(tpu, real_kernels, B, T, V, C, dtype,
+                                        sharded):
+    """The lookup's backward is ONE kernel over the sorted rows and the
+    compiler's scatter is gone; inside ``shard_map`` the kernel's operands
+    agree on what varies and the table's cotangent is the chips' sum."""
+    from horovod_tpu.ops.embed_lookup import embed_lookup
+
+    def grad(table, tokens):
+        def loss(table):
+            with jax.named_scope("hvd.embed"):
+                x = embed_lookup(table, tokens, dtype)
+            return (x.astype(jnp.float32) ** 2).sum()
+        return jax.grad(loss)(table)
+
+    if sharded:
+        f = hvd.shard_map(grad, mesh=tpu.mesh,
+                          in_specs=(P(), P(hvd.HVD_AXES)), out_specs=P())
+        args = (tpu.shape((V, C), jnp.float32, P()),
+                tpu.shape((B, T), jnp.int32, P(hvd.HVD_AXES)))
+    else:
+        f = grad
+        args = (tpu.shape((V, C), jnp.float32),
+                tpu.shape((B, T), jnp.int32))
+    text = tpu.compile(f, *args).as_text()
+    import re
+
+    assert len(re.findall(
+        r"%\w*hvd_embed_rows_add[\w.]* = [^\n]*custom-call\(", text)) == 1
+    assert not re.findall(r" scatter\(", text)
+    assert ("all-reduce" in text) == sharded
+    # the table is neither rounded whole nor made in bfloat16
+    assert f"bf16[{V},{C}]" not in text
+
+
 @pytest.mark.parametrize("entry,N,V,C", [
     ("auto", 32768, 131072, 768),    # the envelope: dense logits = 17 GB
     ("fused", 8192, 32000, 1024),    # GPT-350M head, bench --lm-loss fused
