@@ -57,10 +57,13 @@ and ``v`` as extra inputs and outputs: a block's inputs are kept, so what
 is handed on is kept once, whatever the number of its readers, and their
 gradients come back summed. Kept besides: the flash kernels' and the scan's
 outputs (the recomputed forward runs neither) and, by ``kept_within``'s
-budget, the mixers' projections; the MLP's hidden projections (336 MB a
-layer at 8k) are made again. Trace-time counters:
-``shared.memory_readers``, ``shared.kv_readers``, ``remat.kept_bytes``,
-``remat.kept_names``.
+budget, the mixers' projections, each kind's by every layer that has it or
+by none, and the MLP's hidden projections (336 MB a layer at 8k) by as many
+of the LAST layers as fit what the mixers' leave of the budget (2 of 6 at
+the cell's size); the layers before them make theirs again, rematerialised
+without that name (``rematerialised``).
+Trace-time counters: ``shared.memory_readers``, ``shared.kv_readers``,
+``remat.kept_bytes``, ``remat.kept_layers``, ``remat.kept_names``.
 
 Initial weights: normal(``initializer_range``) for every matrix and the
 embedding, LayerNorm at (1, 0), the convolution's taps and bias
@@ -86,7 +89,8 @@ from ..ops import selective_scan as _scan
 from ..ops.embed_lookup import embed_lookup
 from .gpt import _layer_norm
 from .sparse_moe_decoder import (MLP_HIDDEN_NAME, QKV_NAME, _GatedMLP,
-                                 causal_attention, kept_within)
+                                 causal_attention, kept_within,
+                                 rematerialised)
 
 MAMBA, SLIDING, FULL = "mamba", "sliding_attention", "full_attention"
 GMU, CROSS = "gmu", "cross_attention"
@@ -385,25 +389,19 @@ class SambaY(nn.Module):
 
     @nn.compact
     def __call__(self, tokens):
-        from ..monitor.registry import counter
-
         cfg = self.cfg
         embed = self.param("embed", _normal(cfg.initializer_range),
                            (cfg.vocab_size, cfg.hidden_size), jnp.float32)
         with jax.named_scope("hvd.embed"):
             x = embed_lookup(embed, tokens, cfg.dtype)
-        block = _Block
+        blocks = [_Block] * len(cfg.layers)
         if cfg.remat:
-            kept = remat_kept(cfg, *tokens.shape)
-            block = nn.remat(
-                _Block, policy=jax.checkpoint_policies.save_only_these_names(
-                    _flash.OUT_NAME, _scan.OUT_NAME, *kept))
+            blocks = rematerialised(
+                _Block, remat_kept(cfg, *tokens.shape),
+                remat_candidates(cfg, *tokens.shape), len(blocks),
+                _flash.OUT_NAME, _scan.OUT_NAME)
         m = kv = None
-        for i in range(len(cfg.layers)):
-            if cfg.remat:
-                counter("remat.kept_names").inc(len(kept))
-                for name, by_layer in kept.items():
-                    counter("remat.kept_bytes", value=name).inc(by_layer[i])
+        for i, block in enumerate(blocks):
             x, m, kv = block(cfg, i, name=f"h{i}")(x, m, kv)
         x = _layer_norm(cfg, "ln_f", x, eps=cfg.layer_norm_eps)
         if not cfg.return_hidden:

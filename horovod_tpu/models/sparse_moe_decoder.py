@@ -75,11 +75,27 @@ norms, whose backward reads them, the MLP's or the mixture's output
 (``MLP_OUT_NAME``: the experts are not walked a third time) and the
 attention block's (``ATTN_OUT_NAME``); the q / k / v projections
 (``QKV_NAME``) and the output gate's (``GATE_NAME``); the two hidden
-projections of a dense MLP and a shared expert (``MLP_HIDDEN_NAME``). What
-is still made again is elementwise (norms, rotation, gates, products, the
-weights' bfloat16 casts) and the router's matmul. Trace-time counters:
-``remat.kept_bytes{value=<name>}`` and ``remat.kept_names``, once a
-block.
+projections of a dense MLP and a shared expert (``MLP_HIDDEN_NAME``). A
+name that fits for every layer is kept by every layer, and the walk ends
+at the FIRST that does not (:func:`kept_within`). Where that one is a
+value a matmul reads (``FEEDS_A_MATMUL``: the hidden projections), it is
+kept by as many layers as fit what is left of the share, the LAST layer
+first: the backward runs from the last layer, so a late layer's values are
+released while few gradients exist yet, and an early layer's would live
+beside nearly all of them (the compiled step of the cell that keeps two
+layers' hidden projections stands 0.20 GB lower for the last two than for
+the first two: PERF.md, PR 47). q / k / v are kept by every layer or by
+none: kept, they take the per-head norm and the rotation out of the
+projection's fusion, and in the two cells where the share would hold some
+layers' the step was longer for it (PERF.md, PR 47). The layers before the
+run are rematerialised without the name, the others under every kept name
+(:func:`rematerialised`: one ``nn.remat`` class where nothing is split).
+What is still made again is elementwise (norms, rotation, gates, products,
+the weights' bfloat16 casts), the router's matmul and, in the layers
+before the run, the name that was split. Trace-time counters, once a
+block: ``remat.kept_bytes{value=<name>}``,
+``remat.kept_layers{value=<name>}`` (the layers that keep the name) and
+``remat.kept_names``.
 
 Initial weights: normal(``initializer_range``) for every matrix and the
 embedding, ones for every RMSNorm scale, zeros for a router's bias.
@@ -124,15 +140,28 @@ ATTN_OUT_NAME = "hvd_block_attn_out"
 QKV_NAME = "hvd_block_qkv"
 GATE_NAME = "hvd_block_gate"
 MLP_HIDDEN_NAME = "hvd_block_mlp_hidden"
+#: The candidates that some layers may keep and others make again
+#: (:func:`kept_within`): values that a matmul reads, through no more than
+#: an elementwise product, so that a kept one costs its bytes and no more
+#: (0.6 ms of a forward for 11.6 of a backward in ``phi-4-mini-flash``:
+#: PERF.md, PR 47). Not q / k / v: a flash kernel reads them through the
+#: per-head norm and the rotation, which ride in the projection's fusion
+#: while it is made again and are float32 passes of their own over a kept
+#: one (+18.5 ms of ``hvd.norm`` for 11 ms of projections in
+#: ``sdar-30b-a3b``: PERF.md, PR 47). The other names are not measured.
+FEEDS_A_MATMUL = frozenset({MLP_HIDDEN_NAME})
 
 #: The share of the device's memory that what the blocks keep, over all
 #: layers, may take: what they keep whatever the rule says (input, attention
-#: output, selection) and the candidates in their order. At an eighth
-#: ``trinity-mini`` (0.51 GB kept anyway) keeps all six candidates, 1.43 GB
-#: more, and its compiled step stands at 12.25 GB of 16 (11.56 with none);
-#: the sparse cell (1.42 GB anyway, one 16k sequence) keeps the plan, 14.68
-#: GB, and not its 1.0 GB of q / k / v, with which it would stand at 15.68
-#: (PERF.md, PR 38, has the compiled step at each set).
+#: output, selection), the candidates in their order that fit for every
+#: layer and, where the next one ``FEEDS_A_MATMUL``, the last layers of it
+#: that fit what is left. At an eighth ``trinity-mini`` (0.51 GB kept
+#: anyway) keeps all six candidates, 1.43 GB more, and its compiled step
+#: stands at 12.25 GB of 16 (11.56 with none); the sparse cell (1.42 GB
+#: anyway, one 16k sequence) keeps the plan, 14.70 GB, and not its 1.0 GB of
+#: q / k / v, with which it would stand at 15.68 (15.37 with the last four
+#: layers' 0.67, and a longer step: PERF.md, PRs 38 and 47, have the
+#: compiled step at each set).
 KEEP_SHARE = 0.125
 #: A device that reports no memory (the CPU) is taken for a TPU v5e.
 ASSUMED_MEMORY_BYTES = 16 * 2 ** 30
@@ -322,17 +351,28 @@ def remat_kept_anyway(cfg: SparseMoEConfig, B: int, T: int) -> int:
 
 def kept_within(candidates: dict, anyway: int,
                 memory_bytes: Optional[int] = None) -> dict:
-    """``candidates`` (``{name: bytes a layer}``) in their order while all
-    that the blocks keep (``anyway`` and the candidates so far, over all
-    layers) stays within ``KEEP_SHARE`` of the device's memory
-    (``memory_bytes``, else :func:`device_memory_bytes`). Bytes decide and
-    nothing else does."""
-    budget = KEEP_SHARE * (memory_bytes or device_memory_bytes())
-    kept, total = {}, anyway
+    """``{name: bytes a layer}`` of what the blocks keep of ``candidates``
+    (the same form) within ``KEEP_SHARE`` of the device's memory
+    (``memory_bytes``, else :func:`device_memory_bytes`), ``anyway``
+    counted in: the candidates in their order, each for every layer, while
+    they fit whole, and nothing after the first that does not. That one,
+    where it ``FEEDS_A_MATMUL``, is kept for the longest run of layers from
+    the LAST towards the first whose bytes fit what is left (0 for a layer
+    before the run; the name is left out where the run holds none of it).
+    Bytes decide how much, the name whether a part is worth keeping."""
+    left = KEEP_SHARE * (memory_bytes or device_memory_bytes()) - anyway
+    kept = {}
     for name, by_layer in candidates.items():
-        total += sum(by_layer)
-        if total > budget:
+        if sum(by_layer) > left:
+            first = len(by_layer)
+            while (name in FEEDS_A_MATMUL and first
+                   and by_layer[first - 1] <= left):
+                first -= 1
+                left -= by_layer[first]
+            if any(by_layer[first:]):
+                kept[name] = (0,) * first + tuple(by_layer[first:])
             break
+        left -= sum(by_layer)
         kept[name] = by_layer
     return kept
 
@@ -344,6 +384,36 @@ def remat_kept(cfg: SparseMoEConfig, B: int, T: int,
     :func:`remat_kept_anyway`."""
     return kept_within(remat_candidates(cfg, B, T),
                        remat_kept_anyway(cfg, B, T), memory_bytes)
+
+
+def rematerialised(block, kept: dict, candidates: dict, layers: int,
+                   *anyway) -> list:
+    """Layer by layer, the module class ``block`` rematerialised under
+    ``save_only_these_names`` of ``anyway`` and the names of ``kept``
+    (:func:`kept_within`'s dict of ``candidates``): ONE ``nn.remat`` class
+    under every kept name, and a second, without the name that was split,
+    for the layers whose entry for it is less than the candidate's. Counts
+    what it keeps, once a block: ``remat.kept_names``,
+    ``remat.kept_bytes{value=}`` and ``remat.kept_layers{value=}``."""
+    from ..monitor.registry import counter
+
+    def under(names):
+        return nn.remat(
+            block, policy=jax.checkpoint_policies.save_only_these_names(
+                *anyway, *names))
+
+    split = [name for name in kept if kept[name] != candidates[name]]
+    whole = under(kept)
+    short = under(n for n in kept if n not in split) if split else whole
+    classes = []
+    for i in range(layers):
+        counter("remat.kept_names").inc(len(kept))
+        for name, by_layer in kept.items():
+            counter("remat.kept_bytes", value=name).inc(by_layer[i])
+            counter("remat.kept_layers", value=name).inc(by_layer[i] > 0)
+        before = any(kept[n][i] < candidates[n][i] for n in split)
+        classes.append(short if before else whole)
+    return classes
 
 
 def rms_norm_in_scope(x, scale, eps):
@@ -622,8 +692,6 @@ class SparseMoEDecoder(nn.Module):
 
     @nn.compact
     def __call__(self, tokens):
-        from ..monitor.registry import counter
-
         cfg = self.cfg
         init = nn.initializers.normal(cfg.initializer_range)
         embed = self.param("embed", init,
@@ -642,15 +710,12 @@ class SparseMoEDecoder(nn.Module):
                     f"copy of whole blocks of {cfg.block_length}")
             half = tokens.shape[1] // 2
             positions = jnp.arange(2 * half) % half
-        kept = remat_kept(cfg, *tokens.shape)
-        block = nn.remat(
-            _Block, policy=jax.checkpoint_policies.save_only_these_names(
-                OUT_NAME, SELECTION_NAME, _flash.OUT_NAME, *kept))
+        blocks = rematerialised(
+            _Block, remat_kept(cfg, *tokens.shape),
+            remat_candidates(cfg, *tokens.shape), cfg.layers, OUT_NAME,
+            SELECTION_NAME, _flash.OUT_NAME)
         loads = {}
-        for i in range(cfg.layers):
-            counter("remat.kept_names").inc(len(kept))
-            for name, by_layer in kept.items():
-                counter("remat.kept_bytes", value=name).inc(by_layer[i])
+        for i, block in enumerate(blocks):
             x, load = block(cfg, i, name=f"h{i}")(x, positions)
             if load is not None:
                 loads[f"h{i}"] = load

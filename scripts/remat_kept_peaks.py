@@ -1,17 +1,22 @@
 #!/usr/bin/env python3
-"""What a mixture cell's compiled step holds at each set of values its
+"""What a cell's compiled step holds at each set of values its
 rematerialised blocks could keep (``models/sparse_moe_decoder.py``
-``remat_kept``), compiled for a DESCRIBED v5e with no chip attached.
+``kept_within``, which the mixture decoder and ``models/sambay.py`` both
+call), compiled for a DESCRIBED v5e with no chip attached.
 
-    JAX_PLATFORMS=cpu python3 scripts/remat_kept_peaks.py --workload <cell> [--sets 0,3,6]
+    JAX_PLATFORMS=cpu python3 scripts/remat_kept_peaks.py --workload <cell> [--sets 0,3,whole,rule]
 
-For each k it makes the rule keep the first k candidates of its own order
-and runs ``benchmarks/rehearse_compile.py`` (whose lines give
-``compiled.memory_analysis()``); before that, the candidates' bytes over
-all layers and the set the rule itself chooses at ``KEEP_SHARE``. A cell
-compiles in 1 to 3 minutes of sandbox CPU a set; nothing runs, so it gives
-bytes and never a time. ``KEEP_SHARE`` was fixed from these lines
-(PERF.md, PR 38).
+A set is a number k (the first k candidates of the rule's own order, each
+for every layer), ``rule`` (what the rule keeps at ``KEEP_SHARE``) or
+``whole`` (the rule's names that it keeps for every layer they are in: the
+rule as it stood before PR 47, which kept a candidate for all its layers
+or for none). For each it puts the set in the rule's place and runs
+``benchmarks/rehearse_compile.py`` (whose lines give
+``compiled.memory_analysis()``); the model's own call of the rule says what
+is kept anyway, the candidates' bytes over all layers and, by name, the
+bytes kept and the layers they are kept for. A cell compiles in 1 to 3
+minutes of sandbox CPU a set; nothing runs, so it gives bytes and never a
+time. ``KEEP_SHARE`` was fixed from these lines (PERF.md, PR 38).
 """
 
 from __future__ import annotations
@@ -23,41 +28,72 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def _gb(by_name: dict) -> dict:
+    return {name: round(sum(by) / 1e9, 3) for name, by in by_name.items()}
+
+
+def choose(which: str, candidates: dict, ruled: dict) -> dict:
+    """The set ``which`` names, in ``kept_within``'s form."""
+    if which == "rule":
+        return ruled
+    if which == "whole":
+        return {name: by for name, by in ruled.items()
+                if by == candidates[name]}
+    return dict(list(candidates.items())[:int(which)])
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--sets", default="",
-                    help="how many candidates to keep, comma-separated "
-                         "(default: every prefix)")
+                    help="comma-separated: a number of candidates to keep "
+                         "whole, 'whole', 'rule' (default: the rule, then "
+                         "every prefix)")
     args = ap.parse_args(argv)
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.insert(0, ROOT)
 
     from benchmarks import rehearse_compile
-    from benchmarks.lib import manifest as mf
+    from horovod_tpu.models import sambay
     from horovod_tpu.models import sparse_moe_decoder as decoder
 
-    manifest = mf.load()
-    cell = mf.cell(manifest, args.workload)
-    config = mf.config_of(manifest, cell["config"])
-    shape = (decoder.SparseMoEConfig.from_dict(config),
-             config["per_chip_batch"], mf.job_of(cell["traffic"])["seq_len"])
-    candidates = decoder.remat_candidates(*shape)
-    print(f"[peaks] {args.workload}: kept anyway "
-          f"{decoder.remat_kept_anyway(*shape) / 1e9:.3f} GB; candidates "
-          f"{ {n: round(sum(by) / 1e9, 3) for n, by in candidates.items()} }"
-          f" GB; the rule keeps {list(decoder.remat_kept(*shape))}",
-          flush=True)
-    rule = decoder.remat_kept
-    for k in ([int(k) for k in args.sets.split(",")] if args.sets
-              else range(len(candidates) + 1)):
-        kept = dict(list(candidates.items())[:k])
-        print(f"[peaks] keep {k}: {list(kept)}", flush=True)
-        decoder.remat_kept = lambda *a, **kw: kept
+    rule = decoder.kept_within
+    sets = args.sets.split(",") if args.sets else None
+    seen = {}
+
+    def standing_in(which):
+        def kept_within(candidates, anyway, memory_bytes=None):
+            kept = choose(which, candidates, rule(candidates, anyway,
+                                                  memory_bytes))
+            if which not in seen:
+                seen[which] = len(candidates)
+                layers = {name: [i for i, b in enumerate(by) if b]
+                          for name, by in kept.items()}
+                print(f"[peaks] {args.workload}: kept anyway "
+                      f"{anyway / 1e9:.3f} GB; candidates {_gb(candidates)} "
+                      f"GB\n[peaks] keep {which}: {_gb(kept)} GB, "
+                      f"{sum(map(sum, kept.values())):,} bytes, in layers "
+                      f"{layers}", flush=True)
+            return kept
+        return kept_within
+
+    def run(which):
+        decoder.kept_within = sambay.kept_within = standing_in(which)
         try:
             rehearse_compile.main(["--workload", args.workload])
         finally:
-            decoder.remat_kept = rule
+            decoder.kept_within = sambay.kept_within = rule
+
+    if sets is None:
+        run("rule")
+        if not seen:
+            print(f"[peaks] {args.workload}: its model never asks the rule "
+                  f"(kept_within), so there is no set to put in its place",
+                  file=sys.stderr)
+            return 1
+        sets = [str(k) for k in range(seen["rule"] + 1)]
+    for which in sets:
+        run(which)
     return 0
 
 

@@ -24,6 +24,9 @@ from horovod_tpu.models import SambaY, SambaYConfig
 from horovod_tpu.models import sambay
 from horovod_tpu.monitor.registry import counter
 from test_diff_attention import parent_combine, parent_lay
+from test_sparse_moe_decoder import (_checkpoints, _kept_when_split,
+                                     _names_saved, _one_class_for_all_layers,
+                                     _saved_by_layer)
 
 CFG = {"model_type": "phi4flash", "num_hidden_layers": 32,
        "hidden_size": 64, "num_attention_heads": 4,
@@ -361,20 +364,110 @@ def test_a_configuration_that_cannot_be_built_is_refused(change, message):
         SambaYConfig.from_dict(dict(CFG, **change))
 
 
-def test_the_cells_configuration_builds_and_keeps_its_projections():
+CHIP = 16_909_336_064     # a v5e's ``memory_stats()["bytes_limit"]``
+MIXERS = [sambay.QKV_NAME, sambay.SSM_IN_NAME, sambay.GMU_IN_NAME]
+
+
+@pytest.mark.parametrize("memory, names, hidden_layers, gb", [
+    (16 * 2 ** 30, MIXERS + [sambay.MLP_HIDDEN_NAME], [4, 5], 1.3),
+    (CHIP, MIXERS + [sambay.MLP_HIDDEN_NAME], [4, 5], 1.3),
+    (32 * 2 ** 30, MIXERS + [sambay.MLP_HIDDEN_NAME], [0, 1, 2, 3, 4, 5],
+     2.642),
+    (14 * 2 ** 30, MIXERS + [sambay.MLP_HIDDEN_NAME], [5], 0.965),
+    (12 * 2 ** 30, MIXERS, [], 0.629),
+    # Half the device: q / k / v whole; the state-space in-projections do
+    # not fit whole and, feeding no matmul, are kept by no layer.
+    (8 * 2 ** 30, MIXERS[:1], [], 0.21),
+    (4 * 2 ** 30, [], [], 0),
+], ids=["16GiB", "the_chips", "32GiB", "14GiB", "12GiB", "8GiB", "4GiB"])
+def test_the_cells_configuration_builds_and_keeps_its_projections(
+        memory, names, hidden_layers, gb):
+    """At the cell's size the mixers' projections are kept whole and, since
+    PR 47, the MLP's hidden projections by the LAST 2 of the 6 layers
+    (0.671 GB of the 0.68 left; the six would be 2.0): never more than
+    ``KEEP_SHARE`` of the memory, and the next earlier layer would pass it.
+    A larger or smaller device keeps more or less, by bytes alone; a
+    mixer's projections by every layer that has them or by none."""
     with open(os.path.join(manifest.ROOT, "benchmarks", "configs",
                            "phi-4-mini-flash.json")) as f:
         cfg = SambaYConfig.from_dict(json.load(f))
     assert (cfg.d_inner, cfg.head_dim, cfg.mamba_dt_rank) == (5120, 64, 160)
     assert cfg.layers == (0, 1, 16, 17, 18, 19)
-    kept = sambay.remat_kept(cfg, 1, 8192, memory_bytes=16 * 2 ** 30)
-    assert list(kept) == [sambay.QKV_NAME, sambay.SSM_IN_NAME,
-                          sambay.GMU_IN_NAME]
-    anyway = sambay.remat_kept_anyway(cfg, 1, 8192)
-    total = anyway + sum(sum(by) for by in kept.values())
-    assert total < 0.125 * 16 * 2 ** 30 < total + sum(
-        sambay.remat_candidates(cfg, 1, 8192)[sambay.MLP_HIDDEN_NAME])
-    # A smaller device keeps less, by bytes alone.
-    assert list(sambay.remat_kept(cfg, 1, 8192, memory_bytes=4 * 2 ** 30)) \
-        == []
+    kept = sambay.remat_kept(cfg, 1, 8192, memory_bytes=memory)
+    candidates = sambay.remat_candidates(cfg, 1, 8192)
+    assert list(kept) == names
+    for name in MIXERS:
+        assert name not in kept or kept[name] == candidates[name]
+    hidden = kept.get(sambay.MLP_HIDDEN_NAME, (0,) * 6)
+    assert [i for i, b in enumerate(hidden) if b] == hidden_layers
+    assert all(hidden[i] == candidates[sambay.MLP_HIDDEN_NAME][i]
+               for i in hidden_layers)
+    held = sum(map(sum, kept.values()))
+    assert round(held / 1e9, 3) == gb
+    left = 0.125 * memory - sambay.remat_kept_anyway(cfg, 1, 8192) - held
+    assert not kept or left >= 0
+    if sambay.MLP_HIDDEN_NAME in kept and len(hidden_layers) < 6:
+        assert candidates[sambay.MLP_HIDDEN_NAME][0] > left
     assert dataclasses.replace(cfg, remat=False).remat is False
+
+
+# A candidate the budget splits, at this file's size (PR 47).
+
+# (the first name that does not fit whole, the layers of it the budget has
+# room for, the layers that keep it)
+SPLITS = [(sambay.MLP_HIDDEN_NAME, 2, [4, 5]), (sambay.QKV_NAME, 2, []),
+          (sambay.SSM_IN_NAME, 1, []), (sambay.MLP_HIDDEN_NAME, 5,
+                                        [1, 2, 3, 4, 5])]
+
+
+@pytest.fixture(scope="module")
+def unrematerialised(weights):
+    return jax.grad(_program_loss(_model(remat=False), _tokens(8)[0]))(
+        weights)
+
+
+@pytest.mark.parametrize("split, room, layers", SPLITS)
+def test_a_split_candidate_is_saved_by_its_last_layers_alone(
+        monkeypatch, weights, unrematerialised, split, room, layers):
+    """Layer ``i``'s ``checkpoint`` saves exactly the names its entry says
+    (and, to no effect, a name its kind of mixer does not hold) beside the
+    kernels' outputs, ``remat.kept_layers`` counts the layers a name, a
+    mixer's projections that do not fit for all are kept by none, and the
+    gradients are the unrematerialised model's."""
+    model, toks = _model(), _tokens(8)[0]
+    kept, candidates = _kept_when_split(monkeypatch, model.cfg, split, room,
+                                        layers, sambay)
+    counters = {name: counter("remat.kept_layers", value=name)
+                for name in candidates}
+    before = {name: c.value for name, c in counters.items()}
+    loss = _program_loss(model, toks)
+    blocks = _checkpoints(jax.make_jaxpr(loss)(weights).jaxpr)
+    assert len(blocks) == len(model.cfg.layers)
+    anyway = [sambay._flash.OUT_NAME, sambay._scan.OUT_NAME]
+    for i, eqn in enumerate(blocks):
+        assert _names_saved(
+            eqn, anyway + MIXERS + [sambay.MLP_HIDDEN_NAME]) == anyway + \
+            _saved_by_layer(kept, candidates, i)
+        assert (split in _names_saved(eqn, [split])) == (
+            bool(layers) and i >= layers[0])
+    assert {name: c.value - before[name] for name, c in counters.items()} \
+        == {name: sum(b > 0 for b in kept.get(name, ()))
+            for name in candidates}
+    assert counters[split].value - before[split] == len(layers)
+    _close(jax.grad(loss)(weights), unrematerialised, 1e-6)
+
+
+def test_where_layers_keep_alike_the_lowered_text_is_the_parents(
+        monkeypatch, weights):
+    """Every candidate fits at this file's size, so nothing is split: the
+    layers are ONE rematerialised class, as before PR 47, and the
+    differentiated model lowers to the text it lowered to then."""
+    model = _model()
+    kept = sambay.remat_kept(model.cfg, 1, T)
+    assert kept == sambay.remat_candidates(model.cfg, 1, T)
+    assert len(set(sambay.rematerialised(
+        sambay._Block, kept, kept, len(model.cfg.layers)))) == 1
+    grad = jax.grad(_program_loss(model, _tokens(8)[0]))
+    text = jax.jit(grad).lower(weights).as_text()
+    monkeypatch.setattr(sambay, "rematerialised", _one_class_for_all_layers)
+    assert jax.jit(grad).lower(weights).as_text() == text

@@ -339,36 +339,152 @@ def _cell(name, seq_len):
 
 
 V5E = decoder.ASSUMED_MEMORY_BYTES
+CHIP = 16_909_336_064     # a v5e's ``memory_stats()["bytes_limit"]``
 ALL_SIX = (decoder.PLAN_NAME, decoder.MLP_OUT_NAME, decoder.ATTN_OUT_NAME,
            decoder.QKV_NAME, decoder.GATE_NAME, decoder.MLP_HIDDEN_NAME)
 
 
-@pytest.mark.parametrize("config, seq_len, memory, want, gb", [
-    ("trinity-mini", 8192, V5E, ALL_SIX, 1.429),
-    ("keye-vl2-30b-a3b", 16384, V5E, ALL_SIX[:1], 0.009),
-    ("trinity-mini", 32768, V5E, ALL_SIX[:1], 0.013),
-    ("keye-vl2-30b-a3b", 32768, V5E, (), 0),
-    ("trinity-mini", 8192, V5E // 2, ALL_SIX[:3], 0.339),
-    ("trinity-mini", 8192, V5E // 8, (), 0),
-    ("keye-vl2-30b-a3b", 16384, V5E // 8, (), 0),
+def _parents_rule(candidates, anyway, memory):
+    """``kept_within`` as it stood before PR 47: a candidate for all its
+    layers or for none, and what is left of the budget for nothing."""
+    kept, total = {}, anyway
+    for name, by_layer in candidates.items():
+        total += sum(by_layer)
+        if total > decoder.KEEP_SHARE * memory:
+            break
+        kept[name] = by_layer
+    return kept
+
+
+def _holds_the_rule(candidates, anyway, memory, kept):
+    """``kept`` is the rule's answer, in the rule's own terms; returns (the
+    names kept for every layer, the layers that keep the first name that
+    did not fit whole, None where every candidate fits)."""
+    left = decoder.KEEP_SHARE * memory - anyway - sum(
+        map(sum, kept.values()))
+    assert not kept or left >= 0              # never more than the share
+    whole = list(_parents_rule(candidates, anyway, memory))
+    assert [n for n in kept if kept[n] == candidates[n]] == whole
+    if len(whole) == len(candidates):
+        assert list(kept) == whole
+        return whole, None
+    split = list(candidates)[len(whole)]
+    if split not in decoder.FEEDS_A_MATMUL:
+        assert list(kept) == whole       # all its layers or none: none
+        return whole, []
+    assert list(kept) in (whole, whole + [split])      # nothing after it
+    by_layer = candidates[split]
+    got = kept.get(split, (0,) * len(by_layer))
+    layers = [i for i, b in enumerate(got) if b]
+    first = min(layers, default=len(got))
+    # A run of LAST layers, each at the candidate's bytes ...
+    assert got == (0,) * first + tuple(by_layer[first:])
+    # ... and the next earlier layer that holds the name does not fit.
+    assert [b for b in by_layer[:first] if b][-1] > left
+    return whole, layers
+
+
+GIB = 2 ** 30
+
+
+@pytest.mark.parametrize("config, seq_len, memory, whole, layers, gb", [
+    ("trinity-mini", 8192, V5E, ALL_SIX, None, 1.429),
+    ("keye-vl2-30b-a3b", 16384, V5E, ALL_SIX[:1], [], 0.009),
+    ("trinity-mini", 32768, V5E, ALL_SIX[:1], [], 0.013),
+    ("keye-vl2-30b-a3b", 32768, V5E, (), [], 0),
+    ("trinity-mini", 8192, V5E // 2, ALL_SIX[:3], [], 0.339),
+    ("trinity-mini", 8192, V5E // 8, (), [], 0),
+    ("keye-vl2-30b-a3b", 16384, V5E // 8, (), [], 0),
+    ("keye-vl2-30b-a3b", 16384, CHIP, ALL_SIX[:1], [], 0.009),
+    ("sdar-30b-a3b", 16384, V5E, ALL_SIX[:1], [], 0.009),
+    ("sdar-30b-a3b", 16384, CHIP, ALL_SIX[:1], [], 0.009),
+    ("smallthinker-21b-a3b", 16384, V5E,
+     (decoder.PLAN_NAME, decoder.QKV_NAME), None, 0.609),
+    ("smallthinker-21b-a3b", 32768, V5E, ALL_SIX[:1], [], 0.009),
+    ("trinity-mini", 16384, V5E, ALL_SIX[:3], [], 0.677),
+    ("trinity-mini", 8192, V5E // 4, ALL_SIX[:1], [], 0.003),
+    # A device on which the hidden projections are the first that do not
+    # fit whole: the dense layer's, six times a routed layer's shared
+    # expert's, is the last the budget reaches.
+    ("trinity-mini", 8192, 13 * GIB, ALL_SIX[:5], [1, 2, 3, 4], 1.228),
+    ("trinity-mini", 8192, int(12.6 * GIB), ALL_SIX[:5], [3, 4], 1.161),
+    ("trinity-mini", 8192, 12 * GIB, ALL_SIX[:5], [], 1.094),
+    # Twice the device keeps the two cells' q / k / v for every layer.
+    ("keye-vl2-30b-a3b", 16384, 2 * V5E,
+     (decoder.PLAN_NAME, decoder.QKV_NAME), None, 1.016),
+    ("sdar-30b-a3b", 16384, 2 * V5E,
+     (decoder.PLAN_NAME, decoder.QKV_NAME), None, 1.016),
 ], ids=lambda v: str(v) if isinstance(v, (str, int)) else "")
-def test_the_rule_keeps_by_bytes(config, seq_len, memory, want, gb):
-    """``trinity-mini``'s shape keeps all six candidates and the sparse
-    cell's the plan alone: its blocks keep 1.42 GB whatever the rule says
-    and its q / k / v would be 1.0 GB more (PERF.md, PR 38). Four times /
-    twice the sequence, or a half / an eighth of the memory, keep the
-    shorter prefix that fits ``KEEP_SHARE`` of it, down to nothing."""
+def test_the_rule_keeps_by_bytes(config, seq_len, memory, whole, layers, gb):
+    """``trinity-mini``'s shape keeps all six candidates whole and
+    ``smallthinker-21b-a3b``'s both of its own. The sparse cell's and the
+    block-diffusion cell's (whose rule sees ``2 L`` rows) keep the plan and
+    none of their q / k / v, which do not fit for every layer and are not a
+    name that some layers may keep alone (``FEEDS_A_MATMUL``; PERF.md, PR
+    47, has what the last 4 and 5 layers' cost on the chip). Four times /
+    twice the sequence, or a part of the memory, keep the shorter prefix
+    that fits ``KEEP_SHARE`` of it and, where the next name is the hidden
+    projections, the last layers of it that fit what is left, down to
+    nothing. Never more than the share; the next earlier layer would pass
+    it; nothing after the first name that did not fit whole."""
     cell = _cell(config, seq_len)
     kept = decoder.remat_kept(*cell, memory_bytes=memory)
-    assert tuple(kept) == want
-    total = sum(sum(by_layer) for by_layer in kept.values())
-    assert round(total / 1e9, 3) == gb
-    held = decoder.remat_kept_anyway(*cell) + total
-    assert not kept or held <= decoder.KEEP_SHARE * memory
     candidates = decoder.remat_candidates(*cell)
-    if len(kept) < len(candidates):
-        following = list(candidates.values())[len(kept)]
-        assert held + sum(following) > decoder.KEEP_SHARE * memory
+    got = _holds_the_rule(candidates, decoder.remat_kept_anyway(*cell),
+                          memory, kept)
+    assert got == (list(whole), layers)
+    assert round(sum(map(sum, kept.values())) / 1e9, 3) == gb
+
+
+@pytest.mark.parametrize("memory", [V5E, CHIP], ids=["16GiB", "the_chips"])
+@pytest.mark.parametrize("config, seq_len", [
+    ("trinity-mini", 8192), ("smallthinker-21b-a3b", 16384),
+    ("keye-vl2-30b-a3b", 16384), ("sdar-30b-a3b", 16384)])
+def test_where_no_hidden_projection_is_split_the_dict_is_the_parents(
+        config, seq_len, memory):
+    """Every candidate fits (``trinity-mini``, ``smallthinker-21b-a3b``) or
+    the first that does not is q / k / v (the sparse and the
+    block-diffusion cell): what PR 47's parent kept, name for name."""
+    cell = _cell(config, seq_len)
+    candidates = decoder.remat_candidates(*cell)
+    kept = decoder.remat_kept(*cell, memory_bytes=memory)
+    assert kept == _parents_rule(
+        candidates, decoder.remat_kept_anyway(*cell), memory)
+    assert all(kept[name] == candidates[name] for name in kept)
+
+
+P, Q, H = decoder.PLAN_NAME, decoder.QKV_NAME, decoder.MLP_HIDDEN_NAME
+
+
+@pytest.mark.parametrize("candidates, anyway, budget, want", [
+    # Whole while they fit; the first that does not, from the last layer.
+    ({P: (4, 4, 4), H: (3, 3, 3), Q: (1, 1, 1)}, 10, 29,
+     {P: (4, 4, 4), H: (0, 3, 3)}),
+    # Nothing after the name that was split, though it would fit.
+    ({P: (4, 4, 4), H: (3, 3, 3), Q: (1, 1, 1)}, 10, 24, {P: (4, 4, 4)}),
+    # A layer that does not hold the name costs nothing and ends no run.
+    ({H: (5, 0, 5, 0, 5, 0)}, 0, 10, {H: (0, 0, 5, 0, 5, 0)}),
+    ({H: (5, 0, 5, 0, 5, 0)}, 0, 4, {}),
+    # The run is of LAST layers: an early one that would fit is not taken.
+    ({H: (1, 9, 2)}, 0, 3, {H: (0, 0, 2)}),
+    # What is kept anyway is counted in; over the budget keeps nothing.
+    ({H: (1, 1)}, 9, 8, {}),
+    ({H: (1, 1)}, 7, 8, {H: (0, 1)}),
+    ({H: (1, 1)}, 6, 8, {H: (1, 1)}),
+    # A name that feeds no matmul is kept by every layer or by none, and
+    # the walk ends at it all the same.
+    ({P: (4, 4, 4), Q: (3, 3, 3), H: (1, 1, 1)}, 10, 29, {P: (4, 4, 4)}),
+    ({P: (4, 4, 4), Q: (3, 3, 3), H: (1, 1, 1)}, 10, 31,
+     {P: (4, 4, 4), Q: (3, 3, 3)}),
+    ({Q: (1, 1)}, 7, 8, {}),
+    ({P: (0, 2, 2)}, 0, 3, {}),
+])
+def test_kept_within_spends_what_is_left_on_the_last_layers(
+        candidates, anyway, budget, want):
+    memory = budget / decoder.KEEP_SHARE
+    kept = decoder.kept_within(candidates, anyway, memory)
+    assert kept == want and list(kept) == list(want)
+    _holds_the_rule(candidates, anyway, memory, kept)
 
 
 @pytest.mark.parametrize("reported, memory", [
@@ -418,3 +534,171 @@ def test_kept_bytes_are_counted_once_a_block(family):
     assert names.value - before[1] == len(kept) * model.cfg.layers
     for name in kept:
         assert f"name={name}" in text
+
+
+# ---------------------------------------------------------------------------
+# A candidate the budget splits (PR 47): the last layers keep it, the first
+# make it again.
+# ---------------------------------------------------------------------------
+
+def _memory_that_splits(cfg, split, layers_kept, family=decoder):
+    """The device memory at which ``family``'s rule keeps ``split`` for the
+    last ``layers_kept`` of the layers that hold it at this file's size, to
+    the byte."""
+    candidates = family.remat_candidates(cfg, 1, T)
+    names = list(candidates)
+    holding = [b for b in candidates[split] if b]
+    budget = (family.remat_kept_anyway(cfg, 1, T)
+              + sum(sum(candidates[n]) for n in names[:names.index(split)])
+              + sum(holding[-layers_kept:]))
+    return int(budget / decoder.KEEP_SHARE)
+
+
+def _checkpoints(jaxpr, found=None):
+    """The ``checkpoint`` equations of a forward pass, in the layers'
+    order."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "remat2":
+            found.append(eqn)
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _checkpoints(sub, found)
+    return found
+
+
+@functools.lru_cache(maxsize=None)
+def _name_primitive():
+    from jax.ad_checkpoint import checkpoint_name
+
+    return jax.make_jaxpr(lambda x: checkpoint_name(x, "n"))(
+        1.0).eqns[0].primitive
+
+
+def _names_saved(eqn, names):
+    """Of ``names``, those the ``checkpoint`` equation's policy saves."""
+    return [n for n in names
+            if eqn.params["policy"](_name_primitive(), name=n)]
+
+
+# (family, the first name that does not fit whole, the layers of it the
+# budget has room for, the layers that keep it)
+SPLITS = [("sparse", decoder.QKV_NAME, 1, []),
+          ("afmoe", decoder.ATTN_OUT_NAME, 1, []),
+          ("afmoe", decoder.QKV_NAME, 2, []),
+          ("afmoe", decoder.MLP_HIDDEN_NAME, 1, [2]),
+          ("afmoe", decoder.MLP_HIDDEN_NAME, 2, [1, 2])]
+
+
+def _kept_when_split(monkeypatch, cfg, split, room, layers, family=decoder):
+    """``family``'s dict at this file's size on a device with room for the
+    last ``room`` layers' ``split``, which ``layers`` keep."""
+    monkeypatch.setattr(decoder, "device_memory_bytes", lambda: (
+        _memory_that_splits(cfg, split, room, family)))
+    kept = family.remat_kept(cfg, 1, T)
+    candidates = family.remat_candidates(cfg, 1, T)
+    before = list(candidates)[:list(candidates).index(split)]
+    assert list(kept) == before + [split] * bool(layers)
+    assert [i for i, b in enumerate(kept.get(split, ())) if b] == layers
+    return kept, candidates
+
+
+def _saved_by_layer(kept, candidates, i):
+    """The kept names layer ``i`` is rematerialised under: every one but a
+    name the layer holds and does not keep."""
+    return [name for name in kept if kept[name][i] == candidates[name][i]]
+
+
+@pytest.mark.parametrize("family, split, room, layers", SPLITS)
+def test_a_split_candidate_is_saved_by_its_last_layers_alone(
+        monkeypatch, family, split, room, layers):
+    """Layer ``i``'s ``checkpoint`` saves exactly the names its entry says
+    (and, to no effect, a name the layer does not hold), beside what a
+    block keeps anyway; ``remat.kept_layers`` counts the layers a name; q /
+    k / v and the attention's output, which feed no matmul, are kept by no
+    layer where they do not fit for all."""
+    from horovod_tpu.monitor.registry import counter
+
+    model, loss, params = _family(family)
+    kept, candidates = _kept_when_split(monkeypatch, model.cfg, split, room,
+                                        layers)
+    counters = {name: counter("remat.kept_layers", value=name)
+                for name in candidates}
+    before = {name: c.value for name, c in counters.items()}
+    blocks = _checkpoints(jax.make_jaxpr(loss)(params).jaxpr)
+    assert len(blocks) == model.cfg.layers
+    anyway = [decoder.OUT_NAME, decoder.SELECTION_NAME,
+              decoder._flash.OUT_NAME]
+    for i, eqn in enumerate(blocks):
+        assert _names_saved(eqn, anyway + list(ALL_SIX)) == anyway + \
+            _saved_by_layer(kept, candidates, i)
+        assert (split in _names_saved(eqn, [split])) == (i in layers)
+    assert {name: c.value - before[name] for name, c in counters.items()} \
+        == {name: sum(b > 0 for b in kept.get(name, ()))
+            for name in candidates}
+    assert counters[split].value - before[split] == len(layers)
+
+
+@pytest.mark.parametrize("family, split, room, layers", SPLITS[2:])
+def test_a_split_candidate_leaves_loss_and_gradients_equal(
+        monkeypatch, family, split, room, layers):
+    """Bit for bit the loss and every gradient leaf of the model that keeps
+    the three names of before, as where every layer keeps the same."""
+    def run():
+        _, loss, params = _family(family, seed=11)
+        return jax.jit(jax.value_and_grad(loss)).lower(params).compile(
+            compiler_options={"xla_allow_excess_precision": False})(params)
+
+    model, _, _ = _family(family)
+    _kept_when_split(monkeypatch, model.cfg, split, room, layers)
+    loss, grads = run()
+    _keep(monkeypatch, THE_THREE_NAMES_OF_BEFORE)
+    want_loss, want = run()
+    assert float(loss) == float(want_loss)
+    got, want = ref.path_dict(grads), ref.path_dict(want)
+    assert [leaf for leaf in want if not np.array_equal(
+        np.asarray(got[leaf]), np.asarray(want[leaf]))] == []
+
+
+def _one_class_for_all_layers(block, kept, candidates, layers, *anyway):
+    """The blocks as the model made them before PR 47: ONE rematerialised
+    class under every kept name."""
+    import flax.linen as nn
+
+    return [nn.remat(
+        block, policy=jax.checkpoint_policies.save_only_these_names(
+            *anyway, *kept))] * layers
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_where_layers_keep_alike_the_lowered_text_is_the_parents(
+        monkeypatch, family):
+    """Every candidate fits at this file's size, so nothing is split: the
+    layers are ONE rematerialised class, as before PR 47, and the
+    differentiated model lowers to the text it lowered to then."""
+    model, loss, params = _family(family)
+    kept = decoder.remat_kept(model.cfg, 1, T)
+    assert kept == decoder.remat_candidates(model.cfg, 1, T)
+    assert len(set(decoder.rematerialised(
+        decoder._Block, kept, kept, model.cfg.layers))) == 1
+    text = jax.jit(jax.grad(loss)).lower(params).as_text()
+    monkeypatch.setattr(decoder, "rematerialised", _one_class_for_all_layers)
+    assert jax.jit(jax.grad(loss)).lower(params).as_text() == text
+
+
+@pytest.mark.parametrize("kept, classes", [
+    ({}, [0, 0, 0]),
+    ({P: (0, 2, 2), Q: (3, 3, 3)}, [0, 0, 0]),
+    ({P: (0, 2, 2), H: (0, 3, 3)}, [1, 0, 0]),
+    ({P: (0, 2, 2), H: (0, 0, 3)}, [1, 1, 0]),
+    ({H: (0, 0, 3)}, [1, 1, 0]),
+])
+def test_one_class_unless_a_name_is_split_and_then_two(kept, classes):
+    """A layer that does not hold a name (the dense layer has no plan) is
+    of the one class all the same; the layers before a split name's run
+    are of a second, which does not save it."""
+    candidates = {P: (0, 2, 2), Q: (3, 3, 3), H: (3, 3, 3)}
+    blocks = decoder.rematerialised(decoder._Block, kept, candidates, 3)
+    # The last layer is of the class under every kept name.
+    assert [int(b is not blocks[-1]) for b in blocks] == classes
+    assert len(set(blocks)) == len(set(classes))
