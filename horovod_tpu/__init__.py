@@ -130,6 +130,7 @@ from .ops.block_diffusion import (  # noqa: F401
     block_diffusion_noise,
 )
 from .ops.selective_scan import selective_scan  # noqa: F401
+from .ops.ssd_scan import ssd_scan  # noqa: F401
 from .ops.sparse_attention import (  # noqa: F401
     index_select,
     masked_attention,

@@ -9,6 +9,10 @@ from .sparse_moe_decoder import (  # noqa: F401
     update_router_biases,
 )
 from .sambay import SambaY, SambaYConfig  # noqa: F401
+from .hybrid_mamba_moe import (  # noqa: F401
+    HybridMambaMoE,
+    HybridMambaMoEConfig,
+)
 from .resnet import (  # noqa: F401
     ResNet,
     ResNet18,

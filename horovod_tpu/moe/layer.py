@@ -414,8 +414,11 @@ def _zeros_like_of(x):
     return lambda shape, dtype: pvary_missing(jnp.zeros(shape, dtype), axes)
 
 
-#: What gates an expert's hidden rows: ``W2(act(W1 x) * W3 x)``.
-ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+#: What makes an expert's hidden rows: ``W2(act(W1 x) * W3 x)`` where the
+#: expert has the gate's matrix ``W3``, ``W2 act(W1 x)`` where it has two
+#: matrices (``relu2``: the squared ReLU of the two-matrix experts).
+ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu,
+               "relu2": lambda a: jnp.square(nn.relu(a))}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -426,7 +429,9 @@ def _walk(activation, x, gates, w1, w3, w2, plan):
     sorted by held expert, the groups' ``sizes``, their ``start`` in that
     order, their ``first_row`` in the buffer, their ``padded`` sizes).
     ``activation`` names one of ``ACTIVATIONS``: static, and the same in
-    the forward walk and in the hidden rows the backward makes again."""
+    the forward walk and in the hidden rows the backward makes again.
+    ``w3`` None is a two-matrix expert, ``W2 act(W1 x)``: no gate's matmul
+    in either direction and no gradient for it."""
     return _walk_fwd(activation, x, gates, w1, w3, w2, plan)[0]
 
 
@@ -438,7 +443,9 @@ def _walk_fwd(activation, x, gates, w1, w3, w2, plan):
     def body(c, y):
         _, choice, token, gs = _chunk(c, plan, K)
         xs = _rows(x, token)
-        h = act(lax.ragged_dot(xs, w1, gs)) * lax.ragged_dot(xs, w3, gs)
+        h = act(lax.ragged_dot(xs, w1, gs))
+        if w3 is not None:
+            h = h * lax.ragged_dot(xs, w3, gs)
         ys = lax.ragged_dot(h, w2, gs)
         return y.at[token].add(
             ys * _rows(gate_of, choice)[:, None].astype(ys.dtype),
@@ -454,7 +461,9 @@ def _walk_bwd(activation, res, dy):
     x, gates, w1, w3, w2, plan = res
     N, K = gates.shape
     gate_of = gates.reshape(-1)
-    wt1, wt3, wt2 = (jnp.swapaxes(w, 1, 2) for w in (w1, w3, w2))
+    gated = w3 is not None
+    wt1, wt3, wt2 = (jnp.swapaxes(w, 1, 2) if w is not None else None
+                     for w in (w1, w3, w2))
     f32, zeros = jnp.float32, _zeros_like_of(x)
 
     def dw_of(a, b, group, acc):
@@ -467,22 +476,29 @@ def _walk_bwd(activation, res, dy):
         group, choice, token, gs = _chunk(c, plan, K)
         xs, dyr = _rows(x, token), _rows(dy, token)
         gate = _rows(gate_of, choice)[:, None]
-        h, pull = jax.vjp(lambda a, b: act(a) * b,
-                          lax.ragged_dot(xs, w1, gs),
-                          lax.ragged_dot(xs, w3, gs))
+        if gated:
+            h, pull = jax.vjp(lambda a, b: act(a) * b,
+                              lax.ragged_dot(xs, w1, gs),
+                              lax.ragged_dot(xs, w3, gs))
+        else:
+            h, pull = jax.vjp(act, lax.ragged_dot(xs, w1, gs))
         dhu = lax.ragged_dot(dyr, wt2, gs)        # dy W2^T, not yet gated
-        dh1, dh3 = pull(dhu * gate.astype(dhu.dtype))
-        dxs = lax.ragged_dot(dh1, wt1, gs) + lax.ragged_dot(dh3, wt3, gs)
+        dh1, *dh3 = pull(dhu * gate.astype(dhu.dtype))
+        dxs = lax.ragged_dot(dh1, wt1, gs)
+        if gated:
+            dxs = dxs + lax.ragged_dot(dh3[0], wt3, gs)
         return (dx.at[token].add(dxs, mode="drop"),
                 dgate.at[choice].add(
                     jnp.sum((h * dhu).astype(f32), axis=-1), mode="drop"),
-                dw_of(xs, dh1, group, dw1), dw_of(xs, dh3, group, dw3),
+                dw_of(xs, dh1, group, dw1),
+                dw_of(xs, dh3[0], group, dw3) if gated else None,
                 dw_of(h, dyr * gate.astype(dyr.dtype), group, dw2))
 
     dx, dgate, dw1, dw3, dw2 = lax.fori_loop(
         0, _trips(plan), body,
         (zeros(x.shape, x.dtype), zeros((N * K,), f32),
-         *(zeros(w.shape, f32) for w in (w1, w3, w2))))
+         *(zeros(w.shape, f32) if w is not None else None
+           for w in (w1, w3, w2))))
     # The weights came in the activations' dtype and their gradients leave
     # in it, rounded once from the float32 sums as the grouped matmul's own
     # transposes round theirs. One barrier with dx, which the layer below
@@ -490,8 +506,8 @@ def _walk_bwd(activation, res, dy):
     # accumulators to the optimizer (1.2 to 1.8 GB more in the two cells).
     return (*lax.optimization_barrier((
         dx, dgate.reshape(N, K).astype(gates.dtype),
-        dw1.astype(w1.dtype), dw3.astype(w3.dtype), dw2.astype(w2.dtype))),
-        None)
+        dw1.astype(w1.dtype), dw3.astype(w3.dtype) if gated else None,
+        dw2.astype(w2.dtype))), None)
 
 
 _walk.defvjp(_walk_fwd, _walk_bwd)
@@ -561,6 +577,9 @@ def _route(x, router, *, experts_per_token, first_expert, held,
     counter("moe.rows_grouped").inc(R)
     counter("moe.row_chunks").inc(R // CHUNK_ROWS)
     counter("moe.scoring", kind=scoring).inc()
+    # What the plan keeps of the N * K choices where the router spreads them
+    # evenly: the held experts' share (a step's own count is sum(sizes)).
+    counter("moe.choices_held").inc(N * K * held // E)
     experts, gates, lb, z, _ = moe_router(x, router, topk=K, scoring=scoring,
                                           **router_kwargs)
     local = experts.reshape(-1) - first_expert               # [N*K]
@@ -592,8 +611,11 @@ def _apply(x, gates, order, sizes, params, activation):
     padded = -(-sizes // GROUP_ALIGN) * GROUP_ALIGN
     plan = (order, sizes, jnp.cumsum(sizes) - sizes,
             jnp.cumsum(padded) - padded, padded)
-    y = _walk(activation, *_harmonize_vma(x, gates, *(
-        params[n].astype(x.dtype) for n in ("w1", "w3", "w2"))), plan)
+    names = [n for n in ("w1", "w3", "w2") if n in params]
+    x, gates, *ws = _harmonize_vma(x, gates, *(
+        params[n].astype(x.dtype) for n in names))
+    ws = dict(zip(names, ws))
+    y = _walk(activation, x, gates, ws["w1"], ws.get("w3"), ws["w2"], plan)
     return y.astype(x.dtype)
 
 
@@ -636,8 +658,12 @@ def moe_apply(x, plan: MoEPlan, params, *, activation: str = "silu"):
     mixture for tokens ``x [N, C]`` routed as ``plan`` says (made by
     :func:`moe_route` from these tokens or from others of the same rows),
     ``sum_{e held, chosen} gate_e * W2_e(act(W1_e x) * W3_e x)`` with
-    ``act`` ``"silu"`` or ``"relu"``; ``params`` holds ``w1``, ``w3``
-    ``[E_held, C, F]`` and ``w2`` ``[E_held, F, C]``. The walk of
+    ``act`` one of ``ACTIVATIONS`` (``"silu"``, ``"relu"``, ``"relu2"``);
+    ``params`` holds ``w1``, ``w3`` ``[E_held, C, F]`` and ``w2``
+    ``[E_held, F, C]``, or ``w1`` and ``w2`` alone: a two-matrix expert,
+    ``gate_e * W2_e act(W1_e x)``. ``C`` is ``x``'s width, which need not be
+    the width the router read (experts that work in a latent are handed the
+    latent; the plan knows rows, not widths). The walk of
     :func:`moe_ffn_dropless`, under the scope ``hvd.moe_ffn`` in both
     directions."""
     with jax.named_scope("hvd.moe_ffn"):
@@ -660,9 +686,10 @@ def moe_ffn_dropless(x, params, *, experts_per_token: int,
     E_held``. Every token is routed over all E (softmax, top
     ``experts_per_token``, gates renormalised to sum one); the result is
     the held experts' part, ``sum_{e held, chosen} gate_e * W2_e(act(W1_e
-    x) * W3_e x)``, ``act`` the ``activation`` (``"silu"``, or ``"relu"``);
-    what the absent experts would add is left out (it is
-    another chip's to add). Returns ``(y [N, C], MoEAux)`` with ``load``
+    x) * W3_e x)``, ``act`` the ``activation`` (one of ``ACTIVATIONS``;
+    without ``w3`` in ``params`` the experts have two matrices,
+    ``W2_e act(W1_e x)``); what the absent experts would add is left out
+    (it is another chip's to add). Returns ``(y [N, C], MoEAux)`` with ``load``
     the token-choices per GLOBAL expert and ``dropped_fraction`` 0.
     ``scoring``, ``bias``, ``route_norm`` and ``route_scale`` are
     :func:`moe_router`'s: sigmoid scores, a selection bias carried as

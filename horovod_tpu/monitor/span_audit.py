@@ -64,8 +64,12 @@ DEVICE_SCOPES = (
     "hvd.moe_route",         # moe/layer.py: a router run apart from its
     #                          experts' walk (moe_route), ahead of attention
     "hvd.shared_expert",     # models/sparse_moe_decoder.py: beside moe_ffn
-    "hvd.ssm",               # models/sambay.py: a state-space mixer
+    "hvd.ssm",               # models/sambay.py, hybrid_mamba_moe.py: a
+    #                          state-space mixer
     "hvd.selective_scan",    # ops/selective_scan.py: kernels + their layout
+    "hvd.ssd_scan",          # ops/ssd_scan.py: Mamba-2's chunked scan
+    "hvd.moe_latent",        # models/hybrid_mamba_moe.py: the projections
+    #                          into and out of the experts' latent
     "hvd.gmu",               # models/sambay.py: a gated memory unit
     "hvd.diff_attention",    # models/sambay.py: around the flash call
     "hvd.norm",              # models/: every norm outside ops/layer_norm.py

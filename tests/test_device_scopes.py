@@ -26,13 +26,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "benchmark"))
 import bench_tiny as tiny  # noqa: E402
 import bench_tiny_afmoe as tiny_afmoe  # noqa: E402
+import bench_tiny_nemotron_h as tiny_nemotron_h  # noqa: E402
 import bench_tiny_sambay as tiny_sambay  # noqa: E402
 import bench_tiny_sdar as tiny_sdar  # noqa: E402
 import bench_tiny_smallthinker as tiny_smallthinker  # noqa: E402
 import bench_tiny_sparse as tiny_sparse  # noqa: E402
 
-from benchmarks.builders import (afmoe, gpt_decoder, sambay,  # noqa: E402
-                                 sdar_moe, smallthinker,
+from benchmarks.builders import (afmoe, gpt_decoder, nemotron_h,  # noqa: E402
+                                 sambay, sdar_moe, smallthinker,
                                  sparse_moe_decoder)
 from horovod_tpu.ops import selective_scan as scan  # noqa: E402
 from horovod_tpu.ops import sparse_attention as spa  # noqa: E402
@@ -63,8 +64,11 @@ SDAR_STEP = {"hvd.flash_block_diffusion", "hvd.block_diffusion_noise"}
 # Nor a router run apart from its experts, ahead of attention: the tiny
 # smallthinker step's (tests/benchmark/bench_tiny_smallthinker.py).
 SMALLTHINKER_STEP = {"hvd.moe_route"}
+# Nor Mamba-2's chunked scan or experts that work in a latent: the tiny
+# nemotron_h step's (tests/benchmark/bench_tiny_nemotron_h.py).
+NEMOTRON_STEP = {"hvd.ssd_scan", "hvd.moe_latent"}
 OFF_STEP = ({"hvd.layer_norm"} | SPARSE_STEP | AFMOE_STEP | SAMBAY_STEP
-            | SDAR_STEP | SMALLTHINKER_STEP)
+            | SDAR_STEP | SMALLTHINKER_STEP | NEMOTRON_STEP)
 # The decoder block's names (models/): the programs that hold each. Only
 # the mixture decoder rotates, and the tiny sparse step has no dense layer.
 BLOCK_STEPS = {"hvd.norm": ("gpt", "sparse", "afmoe", "sambay"),
@@ -77,7 +81,7 @@ DIFFERENTIATED = {"hvd.grad", "hvd.lm_head_loss", "hvd.flash_attention",
                   "hvd.layer_norm", "hvd.sparse_attention", "hvd.moe_ffn",
                   "hvd.flash_window", "hvd.shared_expert",
                   "hvd.flash_block_diffusion", "hvd.moe_route"} | set(
-                      BLOCK_STEPS) | SAMBAY_STEP
+                      BLOCK_STEPS) | SAMBAY_STEP | NEMOTRON_STEP
 NESTED_IN = {"hvd.lm_head_loss": "hvd.grad",
              "hvd.flash_attention": "hvd.grad",
              "hvd.sparse_attention": "hvd.grad",
@@ -89,6 +93,7 @@ NESTED_IN = {"hvd.lm_head_loss": "hvd.grad",
              "hvd.shared_expert": "hvd.grad",
              "hvd.ssm": "hvd.grad", "hvd.selective_scan": "hvd.ssm",
              "hvd.gmu": "hvd.grad", "hvd.diff_attention": "hvd.grad",
+             "hvd.ssd_scan": "hvd.ssm", "hvd.moe_latent": "hvd.grad",
              **{scope: "hvd.grad" for scope in BLOCK_STEPS},
              "hvd.bucket_pack": "hvd.allreduce_grads",
              "hvd.bucket_allreduce": "hvd.allreduce_grads",
@@ -103,7 +108,8 @@ STEPS = {"gpt": (gpt_decoder, tiny), "sparse": (sparse_moe_decoder,
                                                 tiny_sparse),
          "afmoe": (afmoe, tiny_afmoe), "sambay": (sambay, tiny_sambay),
          "sdar": (sdar_moe, tiny_sdar),
-         "smallthinker": (smallthinker, tiny_smallthinker)}
+         "smallthinker": (smallthinker, tiny_smallthinker),
+         "nemotron_h": (nemotron_h, tiny_nemotron_h)}
 
 
 def _step_text(step: str, n_devices: int = 1) -> str:
@@ -115,7 +121,7 @@ def _step_text(step: str, n_devices: int = 1) -> str:
 
 @pytest.fixture(scope="module")
 def step_texts():
-    """The compiled text of the six tiny steps on one device (the tiny
+    """The compiled text of the seven tiny steps on one device (the tiny
     GPT step, tests/benchmark/bench_tiny.py, on four too)."""
     try:
         yield {**{step: _step_text(step) for step in STEPS},
@@ -163,6 +169,11 @@ def smallthinker_step_names(step_texts):
 
 
 @pytest.fixture(scope="module")
+def nemotron_step_names(step_texts):
+    return _op_names(step_texts["nemotron_h"])
+
+
+@pytest.fixture(scope="module")
 def layer_norm_names():
     x = jnp.ones((2, 128, 64), jnp.bfloat16)
     g = jnp.ones((64,), jnp.float32)
@@ -203,7 +214,8 @@ def test_scope_reaches_the_compiled_program(scope, step_names,
                                             afmoe_step_names,
                                             sambay_step_names,
                                             sdar_step_names,
-                                            smallthinker_step_names):
+                                            smallthinker_step_names,
+                                            nemotron_step_names):
     by_step = {"gpt": step_names[1], "sparse": sparse_step_names,
                "afmoe": afmoe_step_names, "sambay": sambay_step_names}
     if scope in BLOCK_STEPS:
@@ -219,6 +231,8 @@ def test_scope_reaches_the_compiled_program(scope, step_names,
         programs = {"sdar decoder": sdar_step_names}
     elif scope in SMALLTHINKER_STEP:
         programs = {"smallthinker decoder": smallthinker_step_names}
+    elif scope in NEMOTRON_STEP:
+        programs = {"nemotron_h decoder": nemotron_step_names}
     elif scope in OFF_STEP:
         programs = {"layer_norm": layer_norm_names}
     else:
@@ -373,7 +387,15 @@ OWNED = [("gpt", "hvd.norm", "forward"), ("gpt", "hvd.norm", "backward"),
          # recomputed forward, its gradient comes back through the gates
          ("smallthinker", "hvd.moe_route", "forward"),
          ("smallthinker", "hvd.moe_route", "remat"),
-         ("smallthinker", "hvd.moe_route", "backward")]
+         ("smallthinker", "hvd.moe_route", "backward"),
+         # the scan's output and chunk states are kept: it owns instructions
+         # forward and backward; the latent projections in each direction
+         ("nemotron_h", "hvd.ssd_scan", "forward"),
+         ("nemotron_h", "hvd.ssd_scan", "backward"),
+         ("nemotron_h", "hvd.moe_latent", "forward"),
+         ("nemotron_h", "hvd.moe_latent", "backward"),
+         ("nemotron_h", "hvd.shared_expert", "backward"),
+         ("nemotron_h", "hvd.ssm", "backward")]
 
 
 @pytest.fixture(scope="module")

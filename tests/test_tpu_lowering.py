@@ -132,6 +132,10 @@ def test_flash_attention_compiles(tpu, real_kernels, B, T, H, D, causal):
     (16384, 28, 4, 128, 4096, 512),
     (16384, 28, 4, 128, None, 1024),
     (16384, 28, 4, 128, None, 512),
+    # nemotron-3-super-120b-a12b.train-8k-1chip: the attention layer's
+    # share, FOUR query heads on ONE KV head, causal, no window
+    (8192, 4, 1, 128, None, 1024),
+    (8192, 4, 1, 128, None, 512),
 ])
 def test_flash_window_and_grouped_heads_compile(tpu, real_kernels, T, H,
                                                 Hkv, D, window, block):
@@ -279,6 +283,36 @@ def test_selective_scan_compiles(tpu, real_kernels, T, Dn, N, chunk,
                        tpu.shape((Dn,), jnp.float32)).as_text()
     assert _custom_calls(text, "hvd_selective_scan_fwd") == 1
     assert _custom_calls(text, "hvd_selective_scan_bwd") == 1
+
+
+@pytest.mark.parametrize("T,h,G,chunk", [
+    (8192, 16, 1, 128),   # nemotron-3-super-120b-a12b.train-8k-1chip
+    (8192, 128, 8, 128),  # the published counts: eight groups of 16 heads
+    (1000, 16, 2, 128),   # a last chunk the tokens do not fill
+])
+def test_ssd_scan_compiles_with_no_state_a_token(tpu, T, h, G, chunk):
+    """Mamba-2's chunked scan, forward and backward, at the cell's widths
+    (64 channels a head, 128 states): matmuls the TPU compiler takes as
+    they are, the chunk states the largest thing kept, and no ``[T, h, 64,
+    128]`` array in either direction."""
+    from horovod_tpu.ops.ssd_scan import ssd_scan
+
+    xs = tpu.shape((1, T, h, 64), jnp.bfloat16)
+    bc = tpu.shape((1, T, G, 128), jnp.bfloat16)
+    heads = tpu.shape((h,), jnp.float32)
+
+    def f(*ops):
+        return jax.grad(lambda *o: ssd_scan(*o, chunk=chunk).astype(
+            jnp.float32).sum(), argnums=tuple(range(6)))(*ops)
+
+    compiled = tpu.compile(f, xs, tpu.shape((1, T, h), jnp.float32), heads,
+                           bc, bc, heads)
+    text = compiled.as_text()
+    assert f"[1,{T},{h},64,128]" not in text
+    assert f"{T},{h},64,128]" not in text
+    chunks = -(-T // chunk)
+    states = chunks * h * 64 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 24 * states
 
 
 @pytest.mark.parametrize("T,H,D,dtype", [
